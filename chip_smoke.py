@@ -1,0 +1,423 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once through the entry points a user calls, at the
+full width of the Higgs shape (28 features, 255 bins, 255 leaves, auto
+mode: K=42 split batch, int8 histogram kernels); rows are the one
+reduction (``--rows``, default 1,000,000 of the published 10.5M) and the
+weights are whatever ``--iters`` rounds learn from ``--seed``:
+
+    device -> train (fused scan + valid set) -> predict -> save/load -> serve
+
+One process, no children that touch JAX, JAX imported once.  Every phase
+prints one JSON line as it finishes and raises on any failure, so the
+exit code is non-zero unless every phase passed.  The LAST line of
+standard output is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+and is printed only on a TPU.  Without an accelerator the script exits
+non-zero before any phase.  ``--rehearse-cpu`` is the one exception: it
+runs the same phases at a tiny size on the CPU backend to find wrong
+paths and arguments before chip time is spent, skips the checks only a
+chip can pass, and can never print the success line.
+
+``--chips 4`` runs ONLY the four-chip path and what it is compared with:
+the same job trained ``tree_learner=data`` over all four chips and
+``tree_learner=serial`` on device 0.
+
+Timings on the phase lines are smoke timings (one cold run, compile
+included where it says so) — not benchmark metrics.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+import time
+from importlib import metadata
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+FEATURES = 28
+#: f32-rounding tolerance of device predict vs the host f64 walk
+#: (tests/test_engine.py test_device_predict_parity_paths)
+RTOL, ATOL = 2e-5, 2e-6
+PARAMS = {"objective": "binary", "metric": "auc", "num_leaves": 255,
+          "max_bin": 255, "min_sum_hessian_in_leaf": 100, "verbose": -1}
+
+
+def _require(ok, what) -> None:
+    """A check that decides the result: raises (``assert`` would vanish
+    under ``python -O`` and the smoke would pass without checking)."""
+    if not ok:
+        raise AssertionError(what)
+
+
+def _emit(phase: str, t0: float, **fields) -> None:
+    print(json.dumps({"phase": phase,
+                      "smoke_seconds": round(time.time() - t0, 2),
+                      **fields}), flush=True)
+
+
+def _peak_bytes(dev) -> int:
+    stats = dev.memory_stats() or {}
+    return int(stats.get("peak_bytes_in_use", -1))
+
+
+def _device_arrays(obj):
+    """Every jax.Array held by ``obj``'s attributes (lists, tuples and
+    dicts included) — the booster's device state."""
+    import jax
+
+    def walk(v):
+        if isinstance(v, jax.Array):
+            yield v
+        elif isinstance(v, (list, tuple)):
+            for x in v:
+                yield from walk(x)
+        elif isinstance(v, dict):
+            for x in v.values():
+                yield from walk(x)
+
+    for name, v in vars(obj).items():
+        for a in walk(v):
+            yield name, a
+
+
+def _aval(a):
+    """Shape of a live array as ``jit`` saw it: its sharding only where
+    it spans devices (a one-device array is uncommitted to ``jit``, and
+    naming its device would lower a different module — a cache miss)."""
+    import jax
+    sharding = a.sharding if len(a.devices()) > 1 else None
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+
+def _fused_program_text(gb) -> str:
+    """Compiled text of the round runner ``train_fused`` cached for
+    ``gb`` (re-lowered at the live arrays' shapes; with the persistent
+    compilation cache on, the compile itself is a cache read)."""
+    import jax
+    import jax.numpy as jnp
+    (T, has_fm, _nvalid, es), runner = next(iter(gb._fused_cache.items()))
+    _require(not has_fm and es is None, "unexpected fused runner key")
+    k = gb.num_tree_per_iteration
+    S = jax.ShapeDtypeStruct
+    lowered = runner.lower(
+        _aval(gb.scores), _aval(gb.bins),
+        None if gb.bins_words is None else _aval(gb.bins_words),
+        S((T, 2), jnp.uint32), S((T, k, 2), jnp.uint32), None,
+        S((T,), jnp.int32), tuple(_aval(v) for v in gb.valid_scores), ())
+    return lowered.compile().as_text()
+
+
+def _sharded_program_text(gb) -> str:
+    """Compiled text of one tree grown by ``gb``'s distributed learner
+    (``GBDT._grow`` traced at the live gradients' shapes and shardings:
+    the shard_map round program ``train()`` ran, under one outer jit)."""
+    import jax
+    import jax.numpy as jnp
+    g, h = gb.boosting_gradients()
+    scales = jax.ShapeDtypeStruct((2,), jnp.float32)
+    one_tree = jax.jit(lambda g, h, hs: gb._grow(g, h, None, None, None, hs))
+    return one_tree.lower(_aval(g[:, 0]), _aval(h[:, 0]),
+                          scales).compile().as_text()
+
+
+def _make_data(args):
+    from bench import _synth_higgs
+    rng = np.random.default_rng(args.seed)
+    X, y, w = _synth_higgs(args.rows, FEATURES, rng)
+    Xv, yv, _ = _synth_higgs(args.valid_rows, FEATURES, rng, w=w)
+    return X, y, Xv, yv
+
+
+def _train(lgb, params, ds, dv, iters):
+    evals = {}
+    t0 = time.time()
+    bst = lgb.train(params, ds, num_boost_round=iters, valid_sets=[dv],
+                    callbacks=[lgb.record_evaluation(evals)])
+    return bst, evals["valid_0"]["auc"], time.time() - t0
+
+
+def _check_auc(auc, iters) -> None:
+    _require(len(auc) == iters and np.isfinite(auc).all(),
+             f"valid AUC per round: {auc}")
+    _require(auc[-1] > 0.75, f"valid AUC {auc[-1]} <= 0.75")
+    _require(auc[-1] > auc[0],
+             f"valid AUC did not rise: {auc[0]} -> {auc[-1]}")
+
+
+def _took_fused_path(bst, iters) -> bool:
+    """Every round ran inside ``GBDT.train_fused``'s scan (engine.py
+    takes that path only when ``supports_fused()`` holds)."""
+    gb = bst._gbdt
+    return bool(gb.supports_fused()
+                and gb.metrics.counter("fused_rounds") == iters)
+
+
+# ------------------------------------------------------------------ phases
+
+def phase_device(args):
+    t0 = time.time()
+    import jax
+    import jaxlib
+    from lightgbm_tpu.ops.compile_cache import use_persistent_cache
+    from lightgbm_tpu.ops.histogram import use_pallas
+
+    cache_dir = use_persistent_cache(os.path.join(HERE, ".jax_cache"))
+    devs = jax.devices()
+    platform = devs[0].platform
+    if platform != "tpu" and not (args.rehearse_cpu and platform == "cpu"):
+        raise SystemExit(f"chip_smoke: no TPU (jax platform is "
+                         f"'{platform}'); nothing was run")
+    if args.rehearse_cpu and platform != "cpu":
+        raise SystemExit("chip_smoke: --rehearse-cpu is for the CPU backend")
+    if args.chips == 4 and len(devs) != 4:
+        raise SystemExit(f"chip_smoke: --chips 4 needs exactly 4 devices, "
+                         f"jax sees {len(devs)}")
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = None
+    try:
+        from lightgbm_tpu import native
+        native._load()
+        parser = "built"
+    except ImportError:
+        parser = "numpy"
+    if platform == "tpu":
+        _require(use_pallas(), "Pallas kernels must be selected on a TPU")
+    device = {"platform": platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    _emit("device", t0, **device, jax=jax.__version__,
+          jaxlib=jaxlib.__version__, libtpu=libtpu,
+          use_pallas=use_pallas(), native_parser=parser,
+          hbm_bytes_limit=(devs[0].memory_stats() or {}).get("bytes_limit"),
+          compile_cache_dir=cache_dir
+          or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    return device
+
+
+def phase_train(args, lgb, data):
+    import jax
+    from lightgbm_tpu.ops.compile_cache import GLOBAL_COMPILE_CACHE
+
+    t0 = time.time()
+    X, y, Xv, yv = data
+    ds = lgb.Dataset(X, label=y, params=PARAMS).construct()
+    dv = ds.create_valid(Xv, label=yv)
+    construct_s = time.time() - t0
+    bst, auc, first_s = _train(lgb, PARAMS, ds, dv, args.iters)
+    gb = bst._gbdt
+    cfg = gb.config
+    _require((int(cfg.tpu_split_batch), gb.hp.hist_dtype) == (42, "int8"),
+             "auto mode did not pick K=42 / int8")
+    _require(_took_fused_path(bst, args.iters), "fused path not taken")
+    _check_auc(auc, args.iters)
+    on_tpu = jax.devices()[0].platform == "tpu"
+    calls = None
+    if on_tpu:
+        calls = _fused_program_text(gb).count("tpu_custom_call")
+        _require(calls > 0,
+                 "no Pallas kernel in the compiled round program")
+        off = [n for n, a in _device_arrays(gb)
+               if {d.platform for d in a.devices()} != {"tpu"}]
+        _require(not off, f"booster state not on the TPU: {off}")
+    # same shapes, same datasets, same process: nothing may compile again
+    misses0 = GLOBAL_COMPILE_CACHE.stats()["misses"]
+    bst2, auc2, second_s = _train(lgb, PARAMS, ds, dv, args.iters)
+    new_misses = GLOBAL_COMPILE_CACHE.stats()["misses"] - misses0
+    _require(new_misses == 0 and
+             bst2._gbdt.metrics.counter("round_compile_misses") == 0,
+             f"second train() compiled {new_misses} round program(s) again")
+    _require(auc2 == auc, "second train() of the same job gave another AUC")
+    _emit("train", t0, rows=args.rows, published_rows=10_500_000,
+          reduced="rows only; widths as published", features=FEATURES,
+          valid_rows=args.valid_rows, iters=args.iters,
+          split_batch=int(cfg.tpu_split_batch), hist_dtype=gb.hp.hist_dtype,
+          fused=True, tpu_custom_calls=calls, state_arrays_on_tpu=on_tpu,
+          valid_auc_first=auc[0], valid_auc_last=auc[-1],
+          second_train_round_compile_misses=new_misses,
+          smoke_construct_s=round(construct_s, 2),
+          smoke_first_train_s=round(first_s, 2),
+          smoke_second_train_s=round(second_s, 2),
+          smoke_compile_s=round(first_s - second_s, 2),
+          peak_bytes_in_use=_peak_bytes(jax.devices()[0]))
+    return bst
+
+
+def phase_predict(args, bst, X):
+    import jax
+    from lightgbm_tpu.boosting.gbdt import GBDT
+
+    t0 = time.time()
+    gb = bst._gbdt
+    trees = len(gb.models)
+    if args.rehearse_cpu:
+        # tiny rehearsal input: lower the switch so the device program runs
+        GBDT.DEVICE_PREDICT_MIN_WORK = X.shape[0] * trees
+    n_host = min(10_000, X.shape[0] // 2)
+    _require(X.shape[0] * trees >= GBDT.DEVICE_PREDICT_MIN_WORK,
+             "input too small: predict would walk the trees on the host")
+    _require(n_host * trees < GBDT.DEVICE_PREDICT_MIN_WORK,
+             "reference slice too large: it would not walk on the host")
+    misses0 = gb.metrics.counter("round_compile_misses")
+    raw = bst.predict(X, raw_score=True)
+    _require(gb.metrics.counter("round_compile_misses") > misses0,
+             "device predict program was not built")
+    _require(raw.shape == (X.shape[0],) and np.isfinite(raw).all(),
+             "device predict: wrong shape or non-finite values")
+    dev_raw = raw[:n_host]
+    host_raw = gb.predict_raw(X[:n_host])          # host f64 Tree.predict
+    np.testing.assert_allclose(dev_raw, host_raw, rtol=RTOL, atol=ATOL)
+    _emit("predict", t0, rows=X.shape[0], trees=trees,
+          device_predict_min_work=GBDT.DEVICE_PREDICT_MIN_WORK,
+          host_walk_rows=n_host,
+          max_abs_diff_vs_host_f64=float(np.abs(dev_raw - host_raw).max()),
+          peak_bytes_in_use=_peak_bytes(jax.devices()[0]))
+
+
+def phase_save_load(lgb, bst, X):
+    t0 = time.time()
+    n = min(50_000, X.shape[0])
+    want = bst.predict(X[:n])
+    with tempfile.TemporaryDirectory() as tmp:
+        path = os.path.join(tmp, "model.txt")
+        bst.save_model(path)
+        loaded = lgb.Booster(model_file=path)
+        size = os.path.getsize(path)
+    got = loaded.predict(X[:n])
+    np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
+    _emit("save_load", t0, rows=n, model_bytes=size,
+          max_abs_diff=float(np.abs(got - want).max()))
+
+
+def phase_serve(bst, X):
+    from lightgbm_tpu.serving.server import PredictionServer
+
+    t0 = time.time()
+    server = PredictionServer()
+    try:
+        server.publish("higgs", booster=bst)
+        publish_s = time.time() - t0
+        predictor = server.registry.get("higgs").predictor
+        sizes = (1, 64, 4096)
+        for n in sizes:
+            out = server.predict("higgs", X[:n], raw_score=False)
+            np.testing.assert_allclose(out, bst.predict(X[:n]),
+                                       rtol=RTOL, atol=ATOL)
+            _, stats = predictor.predict_ex(X[:n])
+            _require(not stats.fallback,
+                     "request served by the host booster")
+        fallbacks = server.metrics.counter("serve_host_fallback_requests")
+        _require(fallbacks == 0, f"{fallbacks} host-fallback requests")
+        _emit("serve", t0, request_rows=sizes,
+              requests=server.metrics.counter("serve_requests"),
+              host_fallback_requests=fallbacks,
+              smoke_publish_warm_s=round(publish_s, 2))
+    finally:
+        server.close()
+
+
+def phase_four_chips(args, lgb, data):
+    """tree_learner=data over all four chips against tree_learner=serial
+    on device 0: same data, same process."""
+    import jax
+
+    t0 = time.time()
+    X, y, Xv, yv = data
+    runs = {}
+    for tl in ("data", "serial"):
+        p = {**PARAMS, "tree_learner": tl}
+        ds = lgb.Dataset(X, label=y, params=p).construct()
+        dv = ds.create_valid(Xv, label=yv)
+        bst, auc, secs = _train(lgb, p, ds, dv, args.iters)
+        _check_auc(auc, args.iters)
+        runs[tl] = {"bst": bst, "auc": auc[-1], "secs": round(secs, 2)}
+    gb_d, gb_s = runs["data"]["bst"]._gbdt, runs["serial"]["bst"]._gbdt
+    _require(gb_d.parallel_mode == "data" and gb_d.mesh.devices.size == 4,
+             "tree_learner=data did not build a four-device mesh")
+    for name in ("bins", "scores"):
+        a = getattr(gb_d, name)
+        shards = {s.device: s.data.shape[0] for s in a.addressable_shards}
+        _require(len(shards) == 4,
+                 f"{name} lives on {len(shards)} device(s)")
+        _require(set(shards.values()) == {a.shape[0] // 4},
+                 f"{name} is not split evenly: {shards}")
+    _require(len(gb_s.bins.devices()) == 1, "serial run spans devices")
+    # the serial comparison takes the fused scan; tree_learner=data keeps
+    # the per-iteration loop over the shard_map grower (supports_fused()
+    # admits no explicit-collective mode), so what is asserted is that its
+    # sharded round program ran — and holds the histogram all-reduce
+    _require(_took_fused_path(runs["serial"]["bst"], args.iters),
+             "serial run did not take the fused path")
+    _require(not gb_d.supports_fused(),
+             "tree_learner=data now supports the fused scan: assert it ran")
+    text = _sharded_program_text(gb_d)
+    n_allreduce = text.count("all-reduce(") + text.count("all-reduce-start(")
+    _require(n_allreduce > 0, "no all-reduce in the sharded round program")
+    auc_d, auc_s = runs["data"]["auc"], runs["serial"]["auc"]
+    _require(abs(auc_d - auc_s) < 5e-3,
+             f"valid AUC data {auc_d} vs serial {auc_s}")
+    on_tpu = jax.devices()[0].platform == "tpu"
+    _emit("four_chips", t0, rows=args.rows, iters=args.iters,
+          data_shards=4, serial_fused=True, data_fused=False,
+          data_path="per-iteration loop over the shard_map batched grower",
+          all_reduces_in_sharded_program=n_allreduce,
+          tpu_custom_calls=text.count("tpu_custom_call") if on_tpu else None,
+          valid_auc_data=auc_d, valid_auc_serial=auc_s,
+          smoke_train_data_s=runs["data"]["secs"],
+          smoke_train_serial_s=runs["serial"]["secs"],
+          peak_bytes_in_use=[_peak_bytes(d) for d in jax.devices()])
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rows", type=int, default=None,
+                    help="training rows (default 1,000,000; the published "
+                         "Higgs set has 10,500,000)")
+    ap.add_argument("--iters", type=int, default=None,
+                    help="boosting rounds (default 20)")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only tree_learner=data on four chips and "
+                         "its serial comparison")
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="rehearse the phases on the CPU backend at a tiny "
+                         "size; never prints the success line")
+    args = ap.parse_args(argv)
+    tiny = args.rehearse_cpu
+    # 100,000 rows is the least at which auto mode engages (K=42 / int8)
+    args.rows = args.rows or (100_000 if tiny else 1_000_000)
+    args.iters = args.iters or (2 if tiny else 20)
+    args.valid_rows = 20_000 if tiny else 200_000
+
+    device = phase_device(args)
+    import lightgbm_tpu as lgb
+    t0 = time.time()
+    data = _make_data(args)
+    _emit("data", t0, seed=args.seed, rows=args.rows,
+          valid_rows=args.valid_rows)
+    if args.chips == 4:
+        phase_four_chips(args, lgb, data)
+    else:
+        bst = phase_train(args, lgb, data)
+        phase_predict(args, bst, data[0])
+        phase_save_load(lgb, bst, data[0])
+        phase_serve(bst, data[0])
+    if args.rehearse_cpu:
+        print(json.dumps({"ok": False, "rehearsal": "cpu: every phase "
+                          "passed, nothing was shown about the chip",
+                          "device": device}), flush=True)
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

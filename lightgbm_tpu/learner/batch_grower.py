@@ -44,9 +44,7 @@ from jax import lax
 from ..ops.histogram import (bins_to_words, histogram_for_leaves_auto,
                              ladder_profitable, overlap_enabled,
                              root_histogram, wants_packed_mirror)
-from ..ops.round_fuse import (partition_payload_pallas,
-                              partition_select_pallas, use_fused_partition,
-                              use_fused_payload)
+from ..ops.round_fuse import partition_select_pallas, use_fused_partition
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, SplitHyper,
                          categorical_left_bitset, find_best_split,
                          leaf_output)
@@ -162,11 +160,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # gathers, kept on the XLA path
     fuse_partition = (use_fused_partition() and not hp.has_categorical
                       and bundle is None)
-    # payload-emitting partition variant: only the non-pooled path
-    # consumes the emitted matrix (the pooled path rebuilds its own keys
-    # for its extended leaf set)
     pooled = 0 < hp.hist_pool_slots < hp.num_leaves
-    fuse_payload = fuse_partition and not pooled and use_fused_payload()
     from ..ops.histogram import use_pallas as _use_pallas
     INF = jnp.float32(_INF_BOUND)
 
@@ -837,23 +831,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
           # ---- all K partitions in ONE widened pass (each row belongs to
           # at most one split parent, so the K moves compose by summation)
           sort_key = None
-          payload = None
           with jax.named_scope("partition"):
               feats_k = st["best_feat"][parents]                      # [K]
-              if fuse_partition and fuse_payload:
-                  # payload-emitting variant: the next compacted round's
-                  # [n, W+3] payload rides the partition pass instead of
-                  # a separate XLA concat (round-6 glue elimination)
-                  lor, sort_key, payload = partition_payload_pallas(
-                      bins_t, bins_words, grad, hess, lor,
-                      mask_f.astype(jnp.int32),
-                      feats_k, st["best_thr"][parents],
-                      st["best_dl"][parents].astype(jnp.int32),
-                      nan_bin[feats_k].astype(jnp.int32),
-                      parents, new_leaves, valid.astype(jnp.int32),
-                      smaller, rows_per_block=min(hp.rows_per_block, 2048),
-                      interpret=not _use_pallas())
-              elif fuse_partition:
+              if fuse_partition:
                   lor, sort_key = partition_select_pallas(
                       bins_t, lor, mask_f.astype(jnp.int32),
                       feats_k, st["best_thr"][parents],
@@ -899,21 +879,21 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               small_cnt = (jnp.where(valid, jnp.minimum(l_cnt, r_cnt), 0.0)
                            if axis_name is None else None)
 
-              def hist_call(lv, cnts, skey=None, pay=None):
+              def hist_call(lv, cnts, skey=None):
                   return _scaled(histogram_for_leaves_auto(
                       bins, bins_t, grad, hess, lor, lv, row_mask,
                       n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
                       hist_dtype=hp.hist_dtype, axis_name=hist_axis,
                       counts=cnts, bins_words=bins_words, sort_key=skey,
                       hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-                      payload=pay, overlap=overlap))
+                      overlap=overlap))
 
               left_small = (l_cnt <= r_cnt)[:, None, None, None]
               if not pooled:
                   # the fused kernel's keys target exactly the `smaller`
                   # set; the pooled path's extended leaf set rebuilds its
                   # own keys
-                  h_small = hist_call(smaller, small_cnt, sort_key, payload)
+                  h_small = hist_call(smaller, small_cnt, sort_key)
                   h_parent = st["hist"][parents]
                   h_large = h_parent - h_small
                   h_left = jnp.where(left_small, h_small, h_large)

@@ -77,7 +77,7 @@ def _time_probe(mesh, shape, dtype, overlap_on: bool) -> float:
     from jax.sharding import PartitionSpec as P
 
     from ..ops.histogram import reduce_hist
-    from ..parallel.compat import shard_map
+    from jax import shard_map
     from ..parallel.mesh import DATA_AXIS
 
     def local(x):

@@ -183,8 +183,17 @@ class AOTStore:
         try:
             from jax.experimental import serialize_executable
             serialized, in_tree, out_tree = pickle.loads(payload)
+            # the devices the executable was compiled for: left to its
+            # default, the installed jax loads it onto EVERY device of
+            # the backend and a one-device program then asks for one
+            # argument shard per device
+            import jax
+            by_id = {d.id: d for d in jax.devices()}
+            ids = meta.get("device_ids")
             fn = serialize_executable.deserialize_and_load(
-                serialized, in_tree, out_tree)
+                serialized, in_tree, out_tree,
+                execution_devices=None if ids is None
+                else [by_id[i] for i in ids])
         except Exception as e:   # any decode failure = corrupt artifact
             self._evict(h, f"undeserializable ({type(e).__name__}: {e})")
             self._miss(h, "undeserializable")
@@ -214,9 +223,13 @@ class AOTStore:
                         "store writes disabled for this process")
             return False
         h = key_hash(key)
+        import jax
+        shardings = jax.tree.leaves((compiled.input_shardings,
+                                     compiled.output_shardings))
         meta = {"format": FORMAT, "key": repr(key),
                 "sha256": hashlib.sha256(payload).hexdigest(),
                 "bytes": len(payload), "fingerprint": self._fp,
+                "device_ids": sorted(d.id for d in shardings[0].device_set),
                 "unix_time": time.time()}
         try:
             with self._lock:

@@ -244,3 +244,22 @@ def get_or_build(key: Hashable, builder: Callable[[], Callable], *,
                                              counter_ns=counter_ns,
                                              store=store,
                                              aot_args=aot_args)
+
+
+def use_persistent_cache(default_dir: str) -> Optional[str]:
+    """Place JAX's persistent (on-disk) compilation cache for a program
+    of this checkout: ``chip_smoke.py``, ``bench.py``, the test suite.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, the caller's machine has
+    placed the cache and JAX reads the variable itself: no directory is
+    set in code.  Otherwise ``default_dir`` is used — a FIXED path inside
+    the checkout, because the directory is part of what a later process
+    must find again (never a temporary, pid- or time-derived one).
+    Returns the directory set in code, or ``None``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+    # jax's own config object, not this package's Config
+    jax.config.update(  # tpulint: disable=CFG201
+        "jax_compilation_cache_dir", default_dir)
+    return default_dir

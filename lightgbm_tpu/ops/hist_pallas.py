@@ -32,13 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:  # Pallas TPU backend
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _round_up(x: int, m: int) -> int:

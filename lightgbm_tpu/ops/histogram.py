@@ -147,13 +147,9 @@ def _round_up(x: int, m: int) -> int:
 
 
 def use_pallas() -> bool:
-    """Pallas kernel on TPU; XLA one-hot contraction elsewhere (CPU tests,
-    fallback)."""
-    try:
-        from .hist_pallas import HAS_PALLAS
-        return HAS_PALLAS and jax.default_backend() == "tpu"
-    except ImportError:  # pragma: no cover
-        return False
+    """Pallas kernels on TPU; the XLA one-hot contraction on the CPU
+    (tests).  Selected by platform only."""
+    return jax.default_backend() == "tpu"
 
 
 def _pallas_blk(hist_dtype: str, n_bins: int = 256,
@@ -486,7 +482,6 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                               sort_key: Optional[jax.Array] = None,
                               hist_kernel: str = "auto",
                               bins_words_t: Optional[jax.Array] = None,
-                              payload: Optional[jax.Array] = None,
                               overlap: bool = False
                               ) -> jax.Array:
     """K-leaf histograms with frontier compaction -> f32 [K, F, B, C].
@@ -517,10 +512,6 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
     ``sort_key`` (i32 [n], optional): precomputed (selected ? row :
     row | 2^30) keys from the fused partition kernel (ops/round_fuse.py);
     built here from the membership mask otherwise.
-    ``payload`` (i32 [n, W+3], optional): the full compaction payload
-    already emitted by the payload-fused partition kernel
-    (ops/round_fuse.py ``partition_payload_pallas``) — skips the XLA
-    concat entirely (round-6 glue elimination).
     ``hist_kernel``/``bins_words_t``: masked-pass formulation + packed
     mirror, forwarded to ``histogram_for_leaves_masked``.
     """
@@ -567,26 +558,22 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
 
     def make_branch(S: int):
         def branch(operands):
-            if payload is not None:
-                key_, payload_ = operands
-            else:
-                key_, grad_, hess_, lor_ = operands
-                # One payload matrix holding (bin words, grad, hess, leaf)
-                # so the branch does a SINGLE contiguous row gather —
-                # separate gathers are DMA-descriptor bound (~9 ns/row
-                # each).  The bin words are the hoisted tree-invariant
-                # view; only 12 bytes per row are fresh.  Built INSIDE the
-                # branch so full-pass rounds skip the concat and the sort
-                # entirely.  (The payload-fused partition kernel hands the
-                # matrix in pre-built instead — ops/round_fuse.py.)
-                payload_ = jnp.concatenate([
-                    bins_words,
-                    lax.bitcast_convert_type(grad_, jnp.int32)[:, None],
-                    lax.bitcast_convert_type(hess_, jnp.int32)[:, None],
-                    lor_[:, None],
-                ], axis=1)                                    # [n, W+3] i32
+            key_, grad_, hess_, lor_ = operands
+            # One payload matrix holding (bin words, grad, hess, leaf)
+            # so the branch does a SINGLE contiguous row gather —
+            # separate gathers are DMA-descriptor bound (~9 ns/row
+            # each).  The bin words are the hoisted tree-invariant
+            # view; only 12 bytes per row are fresh.  Built INSIDE the
+            # branch so full-pass rounds skip the concat and the sort
+            # entirely.
+            payload = jnp.concatenate([
+                bins_words,
+                lax.bitcast_convert_type(grad_, jnp.int32)[:, None],
+                lax.bitcast_convert_type(hess_, jnp.int32)[:, None],
+                lor_[:, None],
+            ], axis=1)                                        # [n, W+3] i32
             idxc = jnp.sort(key_, stable=False)[:S] & ((1 << 30) - 1)
-            pc = payload_[idxc]                               # [S, W+3]
+            pc = payload[idxc]                                # [S, W+3]
             if _use_payload_kernel():
                 from .hist_pallas import histogram_payload_pallas
                 return histogram_payload_pallas(
@@ -613,9 +600,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
     j = jnp.int32(0)
     for k, s in enumerate(sizes):  # sizes descending: smallest fit wins
         j = jnp.where(cnt <= s, jnp.int32(k + 1), j)
-    operands = (sort_key, payload) if payload is not None \
-        else (sort_key, grad, hess, lor)
-    hist = lax.switch(j, branches, operands)
+    hist = lax.switch(j, branches, (sort_key, grad, hess, lor))
     return reduce_hist(hist, axis_name, overlap)
 
 

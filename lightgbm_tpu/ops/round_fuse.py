@@ -35,12 +35,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:  # pragma: no cover
-    from jax.experimental import pallas as pl
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 # test hook: CPU suite runs the kernel through the interpreter
 _FUSE_TEST_INTERPRET = False
@@ -58,18 +53,6 @@ def use_fused_partition() -> bool:
         return True
     from .histogram import use_pallas
     return use_pallas()
-
-
-def use_fused_payload() -> bool:
-    """Payload-emitting partition variant (round-6 glue elimination):
-    the per-round XLA payload concat (a full [n, W+3] copy on every
-    compacted round, ops/histogram.py) folds into the partition pass.
-    ``LGBMTPU_NO_PAYLOAD_FUSE=1`` keeps the plain kernel + XLA concat
-    for on-chip A/B."""
-    import os
-    if os.environ.get("LGBMTPU_NO_PAYLOAD_FUSE"):  # perf A/B hatch
-        return False
-    return use_fused_partition()
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
@@ -158,103 +141,3 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
       dl[None, :], nanb[None, :], parents[None, :], new_leaves[None, :],
       validk[None, :], smaller[None, :])
     return out_lor[0, :n], out_key[0, :n]
-
-
-@functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
-def partition_payload_pallas(bins_t: jax.Array, bins_words: jax.Array,
-                             grad: jax.Array, hess: jax.Array,
-                             lor: jax.Array, mask: jax.Array,
-                             feats: jax.Array, thr: jax.Array,
-                             dl: jax.Array, nanb: jax.Array,
-                             parents: jax.Array, new_leaves: jax.Array,
-                             validk: jax.Array, smaller: jax.Array, *,
-                             rows_per_block: int = 2048,
-                             interpret: bool = False
-                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``partition_select_pallas`` that ALSO emits the next histogram
-    pass's compaction payload (round-6 glue elimination, VERDICT r5 #1c:
-    the r5 63-bin profile attributes ~10 of 28.5 ms/tree to XLA
-    partition/sort/take glue — the payload concat was one full
-    [n, W+3] i32 copy per compacted round on top of this kernel's own
-    row pass; here it rides the same pass for free).
-
-    Same operands/semantics as the plain kernel plus ``bins_words``
-    (i32 [n, W], the tree-invariant word view) and ``grad``/``hess``
-    (f32 [n]).  Returns (new_lor, sort_key, payload [n, W+3]) where
-    payload rows are [bin words, grad bits, hess bits, MASKED new leaf]
-    — exactly the matrix ops/histogram.py ``histogram_for_leaves_auto``
-    builds in its compaction branch (bit-for-bit: same words, same f32
-    bitcasts, same bagging-masked leaf ids)."""
-    num_f, n = bins_t.shape
-    W = bins_words.shape[1]
-    K = feats.shape[0]
-    blk = min(rows_per_block, max(128, _round_up(n, 128)))
-    n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        bins_words = jnp.pad(bins_words, ((0, n_pad - n), (0, 0)))
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-        mask = jnp.pad(mask, (0, n_pad - n))
-    nb = n_pad // blk
-
-    def kernel(bins_ref, words_ref, g_ref, h_ref, lor_ref, mask_ref,
-               feats_ref, thr_ref, dl_ref, nanb_ref, par_ref, nl_ref,
-               vk_ref, sm_ref, out_lor_ref, out_key_ref, out_pay_ref):
-        step = pl.program_id(0)
-        fk = feats_ref[0, :]                                  # [K]
-        iota_f = lax.iota(jnp.int32, num_f)
-        ohf = (fk[:, None] == iota_f[None, :]).astype(jnp.bfloat16)
-        # via i32: Mosaic has no u8->bf16 cast (docs/PERF_NOTES.md round 3)
-        b_blk = bins_ref[:].astype(jnp.int32).astype(jnp.bfloat16)
-        cols = lax.dot_general(
-            ohf, b_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(jnp.int32)  # [K, blk]
-        lor_b = lor_ref[0, :]                                 # [blk]
-        # 0/1 i32 arithmetic, not i1 select (Mosaic legalization — see
-        # partition_select_pallas)
-        isnan = (cols == nanb_ref[0, :][:, None]).astype(jnp.int32)
-        le = (cols <= thr_ref[0, :][:, None]).astype(jnp.int32)
-        go_left = isnan * dl_ref[0, :][:, None] \
-            + (1 - isnan) * le                                # [K, blk] 0/1
-        in_par = (lor_b[None, :] == par_ref[0, :][:, None]
-                  ).astype(jnp.int32) * vk_ref[0, :][:, None]
-        move = in_par * (1 - go_left)
-        tgt = jnp.sum(move * nl_ref[0, :][:, None], axis=0)
-        new_lor = jnp.where(jnp.sum(move, axis=0) > 0, tgt, lor_b)
-        out_lor_ref[0, :] = new_lor
-        lor_m = jnp.where(mask_ref[0, :] != 0, new_lor, -1)
-        selv = jnp.sum((lor_m[None, :] == sm_ref[0, :][:, None]
-                        ).astype(jnp.int32), axis=0)          # [blk]
-        row = step * blk + lax.iota(jnp.int32, blk)
-        out_key_ref[0, :] = jnp.where(selv > 0, row, row | (1 << 30))
-        # the compaction payload, written in the same pass: words pass
-        # through, grad/hess as f32 bit patterns, leaf = MASKED new map
-        g_i = lax.bitcast_convert_type(g_ref[0, :], jnp.int32)
-        h_i = lax.bitcast_convert_type(h_ref[0, :], jnp.int32)
-        out_pay_ref[:] = jnp.concatenate(
-            [words_ref[:], g_i[:, None], h_i[:, None], lor_m[:, None]],
-            axis=1)                                           # [blk, W+3]
-
-    row_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
-    k_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
-    out_lor, out_key, out_pay = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((num_f, blk), lambda i: (0, i)),
-                  pl.BlockSpec((blk, W), lambda i: (i, 0)),
-                  row_spec, row_spec, row_spec, row_spec,
-                  k_spec, k_spec, k_spec, k_spec, k_spec, k_spec, k_spec,
-                  k_spec],
-        out_specs=[row_spec, row_spec,
-                   pl.BlockSpec((blk, W + 3), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((n_pad, W + 3), jnp.int32)],
-        interpret=interpret,
-    )(bins_t, bins_words, grad[None, :], hess[None, :], lor[None, :],
-      mask[None, :], feats[None, :], thr[None, :], dl[None, :],
-      nanb[None, :], parents[None, :], new_leaves[None, :],
-      validk[None, :], smaller[None, :])
-    return out_lor[0, :n], out_key[0, :n], out_pay[:n]

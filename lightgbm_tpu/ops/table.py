@@ -15,13 +15,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax.experimental import pallas as pl
-    from .hist_pallas import HAS_PALLAS, _round_up
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
+from .compile_cache import get_or_build, mesh_signature
+from .hist_pallas import _round_up
 
 # keep the in-kernel one-hot under ~4 MB so the scoped-VMEM budget holds at
 # any admitted table size
@@ -81,15 +80,45 @@ def _take_pallas(idx: jax.Array, table: jax.Array, *,
     return out[0, :n]
 
 
+def _even_row_sharding(idx):
+    """``(mesh, spec)`` when ``idx``'s rows are split evenly over a
+    one-axis device mesh (the leaf map a shard_map grower returns),
+    else ``None``."""
+    sh = idx.sharding
+    if (isinstance(sh, NamedSharding) and len(sh.spec) == 1
+            and isinstance(sh.spec[0], str)
+            and idx.shape[0] % sh.mesh.shape[sh.spec[0]] == 0):
+        return sh.mesh, sh.spec
+    return None
+
+
+def _take_per_shard(mesh, spec):
+    """``_take_pallas`` over a row-sharded index: a Mosaic kernel cannot
+    be partitioned automatically, and the lookup is per row, so each
+    device runs it on its own rows (table replicated)."""
+    return get_or_build(
+        ("take_small_table_sharded", mesh_signature(mesh), spec),
+        lambda: jax.jit(shard_map(
+            _take_pallas, mesh=mesh, in_specs=(spec, P()),
+            out_specs=spec, check_vma=False)))
+
+
 def take_small_table(table: jax.Array, idx: jax.Array) -> jax.Array:
     """``table[idx]`` for f32 ``table`` [T<=2048] and i32 ``idx`` [n].
 
     Out-of-range indices (e.g. -1) return 0.0.
     """
-    if (HAS_PALLAS and jax.default_backend() == "tpu"
+    if (jax.default_backend() == "tpu"
             and table.shape[0] <= 2048 and idx.ndim == 1):
-        return _take_pallas(jnp.asarray(idx, jnp.int32),
-                            jnp.asarray(table, jnp.float32))
+        idx = jnp.asarray(idx, jnp.int32)
+        table = jnp.asarray(table, jnp.float32)
+        if isinstance(idx, jax.core.Tracer) or len(idx.devices()) == 1:
+            return _take_pallas(idx, table)
+        rows = _even_row_sharding(idx)
+        if rows is not None:
+            return _take_per_shard(*rows)(idx, table)
+        # any other multi-device placement: the XLA lookup below can be
+        # partitioned, the kernel cannot
     safe = jnp.clip(idx, 0, table.shape[0] - 1)
     ok = (idx >= 0) & (idx < table.shape[0])
     return jnp.where(ok, table[safe], 0.0)

@@ -32,9 +32,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import shard_map
 
 from ..learner.grower import TreeArrays, grow_tree
 from ..ops.compile_cache import get_or_build, mesh_signature, sig

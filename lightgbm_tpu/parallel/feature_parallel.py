@@ -21,9 +21,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import shard_map
 
 from ..learner.grower import TreeArrays, grow_tree
 from ..ops.split import SplitHyper

@@ -241,7 +241,7 @@ def train_multihost(params: Dict[str, Any], data,
                                                      g_shape)
     lr = float(cfg.learning_rate)
 
-    from .compat import shard_map
+    from jax import shard_map
     from ..learner.grower import TreeArrays
 
     tree_specs = jax.tree.map(lambda _: P(),

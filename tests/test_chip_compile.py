@@ -1,0 +1,182 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is described,
+not attached (Higgs widths: F=28, int8, the auto mode's kernel choices).
+
+Interpret mode — what every other test runs the kernels in — cannot see
+what the chip's compiler refuses: unaligned slices, too much VMEM, an
+operand whose layout pads it past HBM.  These compiles can, at no chip
+time.  Nothing runs, so they say nothing about results or speed.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports this file), compiled in the test's own process, with the
+persistent compilation cache off (an executable compiled for a described
+device cannot be read back without one).  Code that asks
+``jax.default_backend()`` would take its CPU branch here; each test
+steers it to the TPU branch with ``monkeypatch``.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from lightgbm_tpu.learner import batch_grower
+from lightgbm_tpu.ops import histogram as H
+from lightgbm_tpu.ops.hist_pallas import histogram_payload_pallas
+from lightgbm_tpu.ops.table import _take_per_shard, take_small_table
+
+F = 28                      # Higgs features
+W = 7                       # packed words per row (4 bins each)
+N = 1_048_576
+HIGGS_ROWS_PADDED = 10_500_096   # 10.5M rounded up to the 2048-row block
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the program's platform branches (ops/histogram.py
+    ``use_pallas``, ops/table.py) to the TPU for the trace."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(one_chip, fn, *shapes):
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    return jax.jit(fn).lower(*avals).compile()
+
+
+def _assert_kernel(compiled, name):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    assert name in text, f"{name} is not the kernel the dispatch picked"
+
+
+ROWS = [((F, N), jnp.uint8), ((N,), jnp.float32), ((N,), jnp.float32),
+        ((N,), jnp.int32)]        # bins_t, grad levels, hess levels, lor
+
+
+@pytest.mark.parametrize("n_bins,K,hist_kernel,words,kernel", [
+    (256, 42, "auto", False, "histogram_leaves_radix2_pallas"),
+    (256, 4, "auto", False, "histogram_radix_joint_pallas"),
+    (64, 42, "auto", True, "histogram_leaves_packed_pallas"),
+    (64, 42, "onehot", False, "_histogram_leaves_impl"),
+], ids=["radix2-K42-256", "radix_joint-K4-256", "packed-K42-64",
+        "flat-K42-64"])
+def test_masked_histogram_kernel_compiles(one_chip, on_tpu, n_bins, K,
+                                          hist_kernel, words, kernel):
+    """The masked multi-leaf pass, through the dispatch the grower calls,
+    so block sizes and radix widths are the default path's own."""
+    def fn(bins_t, g, h, lor, leaves, words_t=None):
+        return H.histogram_for_leaves_masked(
+            bins_t, g, h, lor, leaves, n_bins=n_bins, rows_per_block=8192,
+            hist_dtype="int8", hist_kernel=hist_kernel,
+            bins_words_t=words_t)
+
+    shapes = ROWS + [((K,), jnp.int32)]
+    if words:
+        shapes.append(((W, N), jnp.int32))
+    c = _compile(one_chip, fn, *shapes)
+    _assert_kernel(c, kernel)
+
+
+def test_root_histogram_radix_single_compiles(one_chip, on_tpu):
+    def fn(bins_t, g, h):
+        return H.root_histogram(bins_t, g, h, n_bins=256,
+                                rows_per_block=8192, hist_dtype="int8")
+
+    c = _compile(one_chip, fn, *ROWS[:3])
+    _assert_kernel(c, "histogram_radix_single_pallas")
+
+
+def test_payload_histogram_kernel_compiles(one_chip):
+    """The compacted-frontier kernel at the largest bucket (n/4 rows)."""
+    S = N // 4
+
+    def fn(payload, leaves, cnt):
+        return histogram_payload_pallas(
+            payload, leaves, cnt, num_f=F, n_bins=256,
+            rows_per_block=H._pallas_blk("int8", 256),
+            compute_dtype=jnp.int8)
+
+    c = _compile(one_chip, fn, ((S, W + 3), jnp.int32), ((42,), jnp.int32),
+                 ((), jnp.int32))
+    _assert_kernel(c, "histogram_payload_pallas")
+
+
+K_ARGS = [((42,), jnp.int32)] * 8    # feats thr dl nanb parents new valid smaller
+
+
+def _partition(bins_t, lor, mask, *per_slot):
+    # the kernel batch_grower's default path selects
+    return batch_grower.partition_select_pallas(
+        bins_t, lor, mask, *per_slot, rows_per_block=2048)
+
+
+def test_partition_kernel_compiles(one_chip):
+    c = _compile(one_chip, _partition, ((F, N), jnp.uint8),
+                 ((N,), jnp.int32), ((N,), jnp.int32), *K_ARGS)
+    _assert_kernel(c, "partition_select_pallas")
+
+
+def test_take_small_table_kernel_compiles(one_chip, on_tpu):
+    c = _compile(one_chip, take_small_table, ((255,), jnp.float32),
+                 ((N,), jnp.int32))
+    _assert_kernel(c, "_take_pallas")
+
+
+def test_take_small_table_compiles_per_shard_for_four_chips(topo):
+    """tree_learner=data updates scores from the row-sharded leaf map a
+    shard_map grower returns.  The chip refuses the bare kernel there
+    ("Mosaic kernels cannot be automatically partitioned", first met on
+    four chips in PR 24); the per-shard form take_small_table routes to
+    compiles for the 2x2 mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    idx = jax.ShapeDtypeStruct((N,), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    table = jax.ShapeDtypeStruct((255,), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))
+    c = _take_per_shard(mesh, P("data")).lower(idx, table).compile()
+    _assert_kernel(c, "_take_pallas")
+
+
+def test_partition_at_published_higgs_rows_stays_lane_dense(one_chip):
+    """Size guard: at the published 10.5M rows the partition step's
+    results are two [1, n] i32 vectors, 84 MB.  A kernel that emitted an
+    [n, small] i32 result here (the removed payload variant's [n, 10]) is
+    padded 12.8x by the TPU's (8, 128) tiling — 5.0 GB for 0.4 GB of data
+    — and the whole-tree program no longer fits 16 GB of HBM."""
+    n = HIGGS_ROWS_PADDED
+    c = _compile(one_chip, _partition, ((F, n), jnp.uint8),
+                 ((n,), jnp.int32), ((n,), jnp.int32), *K_ARGS)
+    m = c.memory_analysis()
+    out_and_temp = m.output_size_in_bytes + m.temp_size_in_bytes
+    # rehearsal on this tree: 0.084 GB of output, no temporaries; the
+    # payload variant's output alone took 5.01 GB
+    assert out_and_temp < 256 * 1024 * 1024, \
+        f"partition step needs {out_and_temp / 1e9:.2f} GB at 10.5M rows"

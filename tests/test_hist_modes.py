@@ -233,47 +233,6 @@ def test_e2e_modes_float_path_agrees(interpret_modes):
         assert np.mean(np.abs(preds[hk] - preds["onehot"])) < 1e-3
 
 
-def test_payload_partition_kernel_matches_plain_plus_concat():
-    """The payload-emitting fused partition kernel (round-6 glue
-    elimination) returns the same (lor, keys) as the plain kernel AND a
-    payload bit-identical to the XLA concat it replaces."""
-    from jax import lax
-
-    from lightgbm_tpu.ops.round_fuse import (partition_payload_pallas,
-                                             partition_select_pallas)
-    rng = np.random.default_rng(14)
-    n, num_f, K = 500, 6, 2
-    bins = rng.integers(0, 64, (n, num_f)).astype(np.uint8)
-    bins_t = jnp.asarray(bins.T)
-    words = bins_to_words(jnp.asarray(bins))
-    g = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    h = jnp.asarray(rng.uniform(0.1, 1, n), jnp.float32)
-    lor = jnp.asarray(rng.integers(0, 3, n), jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, n), jnp.int32)
-    ops = dict(feats=jnp.asarray([1, 3], jnp.int32),
-               thr=jnp.asarray([20, 40], jnp.int32),
-               dl=jnp.asarray([1, 0], jnp.int32),
-               nanb=jnp.asarray([63, 63], jnp.int32),
-               parents=jnp.asarray([0, 1], jnp.int32),
-               new_leaves=jnp.asarray([3, 4], jnp.int32),
-               validk=jnp.asarray([1, 1], jnp.int32),
-               smaller=jnp.asarray([3, 4], jnp.int32))
-    nl, key = partition_select_pallas(
-        bins_t, lor, mask, *ops.values(), rows_per_block=256,
-        interpret=True)
-    nl2, key2, pay = partition_payload_pallas(
-        bins_t, words, g, h, lor, mask, *ops.values(),
-        rows_per_block=256, interpret=True)
-    npt.assert_array_equal(np.asarray(nl), np.asarray(nl2))
-    npt.assert_array_equal(np.asarray(key), np.asarray(key2))
-    lor_m = jnp.where(mask != 0, nl, -1)
-    ref_pay = jnp.concatenate([
-        words, lax.bitcast_convert_type(g, jnp.int32)[:, None],
-        lax.bitcast_convert_type(h, jnp.int32)[:, None], lor_m[:, None]],
-        axis=1)
-    npt.assert_array_equal(np.asarray(pay), np.asarray(ref_pay))
-
-
 # ---------------------------------------------------------- fused valid
 def test_fused_valid_skips_frontier_walk():
     """The fused scan's per-round valid scoring takes the matmul

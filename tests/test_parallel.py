@@ -521,3 +521,35 @@ def test_gspmd_booster_state_is_row_sharded(n):
     else:
         assert gb.scores.sharding.is_fully_replicated
     assert np.isfinite(bst.predict(X)).all()
+
+
+def test_take_small_table_runs_per_shard_on_a_row_sharded_index(monkeypatch):
+    """On the TPU the score update's table lookup is a Mosaic kernel,
+    which cannot be partitioned automatically: handed the row-sharded
+    leaf map a shard_map grower returns, it must run per shard (found on
+    four chips in PR 24 — the CPU branch never met a kernel there)."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from lightgbm_tpu.ops import table as T
+    from lightgbm_tpu.parallel.mesh import make_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(T, "_take_pallas",
+                        functools.partial(T._take_pallas, interpret=True))
+    mesh = make_mesh()
+    rng = np.random.default_rng(0)
+    n = mesh.devices.size * 256
+    idx_np = rng.integers(-1, 255, size=n).astype(np.int32)
+    table_np = rng.normal(size=255).astype(np.float32)
+    idx = jax.device_put(idx_np, NamedSharding(mesh, P(DATA_AXIS)))
+    table = jax.device_put(table_np, NamedSharding(mesh, P()))
+    out = T.take_small_table(table, idx)
+    assert out.sharding.spec == P(DATA_AXIS)
+    want = np.where(idx_np >= 0, table_np[np.clip(idx_np, 0, 254)], 0.0)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    # replicated over the mesh: no even row split to run per shard on,
+    # so the partitionable XLA lookup answers
+    rep = jax.device_put(idx_np, NamedSharding(mesh, P()))
+    np.testing.assert_array_equal(
+        np.asarray(T.take_small_table(table, rep)), want)

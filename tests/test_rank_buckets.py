@@ -323,7 +323,7 @@ def test_ndcg_history_matches_across_arms(synthetic_ranking):
 
 class TestBenchRankRoundTrip:
     def test_bench_rank_to_bench_compare_exit0(self, tmp_path):
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_CHILD="1",
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    BENCH_RANK="1", BENCH_ROWS="3000", BENCH_ITERS="2",
                    BENCH_LEAVES="15")
         cap = tmp_path / "BENCH_rank.json"
@@ -333,6 +333,9 @@ class TestBenchRankRoundTrip:
         assert out.returncode == 0, out.stderr[-2000:]
         payload = json.loads(out.stdout)
         assert payload["kind"] == "rank"
+        # every payload names the device it was just measured on
+        assert payload["platform"] == "cpu" and payload["device_kind"]
+        assert payload["device_count"] >= 1
         assert payload["bucketed"]["iters_per_s"] > 0
         assert payload["padded"]["pad_waste_ratio"] >= \
             payload["bucketed"]["pad_waste_ratio"]

@@ -99,24 +99,6 @@ def run_config(k, dtype="bfloat16", warmup=True, iters=ITERS,
     print(json.dumps({"k": k, "dtype": dtype,
                       "warmup": warmup, "ms_per_tree": round(ms_per_tree, 2),
                       "compile_s": round(compile_s, 1)}), flush=True)
-    # A successful on-chip sweep is evidence worth keeping: persist it in
-    # the bench cache (bench.py stale-fallback) — but ONLY when the config
-    # is comparable to the headline bench (255-leaf trees at bench scale);
-    # a small-tree sweep would inflate vs_baseline.
-    try:
-        if (jax.devices()[0].platform != "cpu" and leaves == 255
-                and N >= 1_000_000 and warmup and MAX_BIN == 255):
-            import bench as _bench
-            _bench.record_cache({
-                "metric": f"higgs_synth_{N}rows_{iters}iters_leaves{leaves}"
-                          f"_sweep_k{k}",
-                "value": round(elapsed, 3), "unit": "seconds",
-                "vs_baseline": round(
-                    _bench.BASELINE_S_PER_ROW_ITER * N * iters / elapsed, 4),
-                "platform": jax.devices()[0].platform,
-            }, mode="sweep")
-    except Exception:
-        pass
     return ms_per_tree
 
 
