@@ -1,0 +1,10 @@
+"""Device milliseconds per round under the scope ``round_hist`` (the histogram kernels):
+self time of the device operations whose name path carries the scope,
+from the profiler's trace of the window."""
+
+
+def read(run):
+    trace = run["trace"]
+    if not trace or "round_hist" not in trace["scope_s"]:
+        return None
+    return 1000.0 * trace["scope_s"]["round_hist"] / run["rounds"]
