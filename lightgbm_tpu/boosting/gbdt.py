@@ -172,6 +172,23 @@ def _parse_interaction_sets(spec, used_feature_idx) -> Optional[np.ndarray]:
     return out
 
 
+def _hist_rows_selected(arrays, n_rows: int) -> int:
+    """Rows one tree's histogram passes had to read: ``n_rows`` for the
+    root pass plus, per split, the smaller child's row count, from the
+    counts the tree arrays bring to the host (``internal_count``,
+    ``leaf_count``).  Those are float32 sums of ones, exact below 2**24
+    rows a shard.  Counted, where ``hist_build_rounds`` is a formula."""
+    splits = int(arrays.num_leaves) - 1
+    if splits <= 0:
+        return n_rows
+    parent = np.asarray(arrays.internal_count[:splits], np.float64)
+    lc = np.asarray(arrays.left_child[:splits])
+    left = np.where(lc < 0,
+                    np.asarray(arrays.leaf_count, np.float64)[-lc - 1],
+                    parent[np.maximum(lc, 0)])
+    return n_rows + int(np.minimum(left, parent - left).sum())
+
+
 class GBDT:
     """Training driver (reference gbdt.h/gbdt.cpp ``GBDT``)."""
 
@@ -354,10 +371,23 @@ class GBDT:
         self._valid_bins_t: List[Optional[jnp.ndarray]] = []
 
     # ------------------------------------------------------------- helpers
-    def _phase(self, name: str):
-        """Time one phase into this booster's table, the process-global
-        table AND the active trace (utils/timer.py ``phase``)."""
-        return phase(name, self.timer, global_timer)
+    def _phase(self, name: str, **counts):
+        """One span (utils/timer.py ``phase``): timed into this
+        booster's table and the process-global table, emitted to the
+        active recorder and to an open profiler session."""
+        return phase(name, self.timer, global_timer, **counts)
+
+    def _dispatch_done(self, fin: Dict[str, int]) -> None:
+        """Close one fused dispatch: the counter ``hist_rows_selected``
+        and the span ``dispatch_done``, which carries what the dispatch
+        finalized as counts (an annotation takes its counts when it
+        opens, so it opens here, at the end)."""
+        counts = {"rounds": fin["rounds"], "trees": fin["trees"]}
+        if fin["rows"]:
+            self._count("hist_rows_selected", fin["rows"])
+            counts["hist_rows_selected"] = fin["rows"]
+        with self._phase("dispatch_done", **counts):
+            pass
 
     def _count(self, name: str, value: float = 1) -> None:
         """Bump a telemetry counter in this booster's registry and the
@@ -1288,17 +1318,19 @@ class GBDT:
                     # sc: [n, k].  One gradient evaluation per round,
                     # then k per-class trees (one-vs-all, exactly the
                     # classic loop's class order) — all in this jit.
-                    if k == 1:
-                        g2, h2 = self.objective.get_gradients(sc[:, 0])
-                        g2, h2 = g2[:, None], h2[:, None]
-                    else:
-                        g2, h2 = self.objective.get_gradients(sc)
-                    if dev_sample is not None:
-                        # in-jit bagging/GOSS draw — same key derivation
-                        # as the classic loop (sample_strategy.py)
-                        rmask, g2, h2 = dev_sample(it, g2, h2)
-                    else:
-                        rmask = None
+                    with jax.named_scope("gradients"):
+                        if k == 1:
+                            g2, h2 = self.objective.get_gradients(sc[:, 0])
+                            g2, h2 = g2[:, None], h2[:, None]
+                        else:
+                            g2, h2 = self.objective.get_gradients(sc)
+                        if dev_sample is not None:
+                            # in-jit bagging/GOSS draw — same key
+                            # derivation as the classic loop
+                            # (sample_strategy.py)
+                            rmask, g2, h2 = dev_sample(it, g2, h2)
+                        else:
+                            rmask = None
 
                     def class_body(cs, xs):
                         # one-vs-all tree for one class — a lax.scan
@@ -1315,12 +1347,13 @@ class GBDT:
                                 discretize_gradients_levels)
                             # per-class fold on the raw key words — the
                             # classic loop's fold_in(qkey, cls), in-jit
-                            qkey = jax.random.fold_in(qkey_raw, cls)
-                            g, h, gs, hs = discretize_gradients_levels(
-                                g, h, qkey, n_levels=n_levels,
-                                stochastic=stoch,
-                                constant_hessian=const_hess)
-                            hist_scale = jnp.stack([gs, hs])
+                            with jax.named_scope("quantize"):
+                                qkey = jax.random.fold_in(qkey_raw, cls)
+                                g, h, gs, hs = discretize_gradients_levels(
+                                    g, h, qkey, n_levels=n_levels,
+                                    stochastic=stoch,
+                                    constant_hessian=const_hess)
+                                hist_scale = jnp.stack([gs, hs])
                         arrays, lor = grow_tree_batched(
                             bins, g, h, rmask, self.num_bins_arr,
                             self.nan_bin_arr, self.is_cat_arr, fm, self.hp,
@@ -1331,57 +1364,67 @@ class GBDT:
                             rng_key=nkey, forced=self.forced_splits,
                             bins_words=bwords)
                         if renew:
-                            renewed = renew_leaf_values(
-                                lor, g_t, h_t, rmask,
-                                num_leaves=self.hp.num_leaves,
-                                lambda_l1=self.hp.lambda_l1,
-                                lambda_l2=self.hp.lambda_l2)
-                            arrays = arrays._replace(leaf_value=jnp.where(
-                                arrays.num_leaves > 1, renewed,
-                                arrays.leaf_value))
+                            with jax.named_scope("leaf_renew"):
+                                renewed = renew_leaf_values(
+                                    lor, g_t, h_t, rmask,
+                                    num_leaves=self.hp.num_leaves,
+                                    lambda_l1=self.hp.lambda_l1,
+                                    lambda_l2=self.hp.lambda_l2)
+                                arrays = arrays._replace(
+                                    leaf_value=jnp.where(
+                                        arrays.num_leaves > 1, renewed,
+                                        arrays.leaf_value))
                         # shrink BEFORE the gather, exactly like the
                         # classic loop (train_one_iter: shrunk =
                         # leaf_value * rate, then take_small_table) — the
                         # other order differs by an ulp and cascades
                         # through the quantization grid
-                        shrunk = arrays.leaf_value * shrink
-                        sc_c = sc_c.at[:, cls].add(take_small_table(
-                            shrunk, lor))
+                        with jax.named_scope("score_update"):
+                            shrunk = arrays.leaf_value * shrink
+                            sc_c = sc_c.at[:, cls].add(take_small_table(
+                                shrunk, lor))
                         if nvalid:
                             # matmul path aggregation replaces the
                             # per-round frontier walk (round 6 — the walk
                             # cost ~107 ms/iter at 1M/200k, VERDICT r5 #4)
-                            arrays_s = arrays._replace(leaf_value=shrunk)
-                            vsc_c = tuple(
-                                v.at[:, cls].add(
-                                    self._valid_tree_scores(arrays_s, vi))
-                                for vi, v in enumerate(vsc_c))
+                            with jax.named_scope("valid_score"):
+                                arrays_s = arrays._replace(
+                                    leaf_value=shrunk)
+                                vsc_c = tuple(
+                                    v.at[:, cls].add(
+                                        self._valid_tree_scores(
+                                            arrays_s, vi))
+                                    for vi, v in enumerate(vsc_c))
                         return (sc_c, vsc_c), arrays
 
                     (sc, vsc), stacked_cls = jax.lax.scan(
                         class_body, (sc, vsc),
                         (g2.T, h2.T, node_keys,
                          lax.iota(jnp.int32, k)))        # [k, ...] ys
-                    mvals = eval_valid_traced(vsc) if nvalid else \
-                        jnp.zeros((0,), jnp.float32)
-                    if use_es:
-                        best, best_it, seen, stopped = es
-                        # a first evaluation ALWAYS improves (the host
-                        # callback's `best is None` bootstrap — also the
-                        # NaN case, where a float compare would say no)
-                        improved = (jnp.where(bigger_arr, mvals > best,
-                                              mvals < best) | ~seen) \
-                            & consider
-                        best = jnp.where(improved, mvals, best)
-                        # best_it carries ABSOLUTE iteration indices and
-                        # is always set from a real round before the
-                        # stall test can trip (seen gate), so continued
-                        # training (iter_ > 0 at entry) counts correctly
-                        best_it = jnp.where(improved, it, best_it)
-                        seen = seen | consider
-                        trip = consider & seen & ~improved & \
-                            (it - best_it >= es_rounds)
-                        es = (best, best_it, seen, stopped | jnp.any(trip))
+                    with jax.named_scope("valid_metric"):
+                        mvals = eval_valid_traced(vsc) if nvalid else \
+                            jnp.zeros((0,), jnp.float32)
+                        if use_es:
+                            best, best_it, seen, stopped = es
+                            # a first evaluation ALWAYS improves (the
+                            # host callback's `best is None` bootstrap —
+                            # also the NaN case, where a float compare
+                            # would say no)
+                            improved = (jnp.where(bigger_arr, mvals > best,
+                                                  mvals < best) | ~seen) \
+                                & consider
+                            best = jnp.where(improved, mvals, best)
+                            # best_it carries ABSOLUTE iteration indices
+                            # and is always set from a real round before
+                            # the stall test can trip (seen gate), so
+                            # continued training (iter_ > 0 at entry)
+                            # counts correctly
+                            best_it = jnp.where(improved, it, best_it)
+                            seen = seen | consider
+                            trip = consider & seen & ~improved & \
+                                (it - best_it >= es_rounds)
+                            es = (best, best_it, seen,
+                                  stopped | jnp.any(trip))
                     return (sc, vsc, es), (stacked_cls, mvals)
 
                 def body(carry, xs):
@@ -1434,74 +1477,81 @@ class GBDT:
         else:
             es_host = ()
         self._last_fused_evals = []
+        # rows the histogram passes had to read are counted only where
+        # the tree's own counts say them exactly: the serial learner
+        # without the bounded pool (which rebuilds evicted parents)
+        n_rows = int(self.train_set.num_data)
+        count_rows = self.parallel_mode is None and not \
+            0 < self.hp.hist_pool_slots < self.hp.num_leaves
         while done < num_rounds and not finished:
             T = min(chunk, num_rounds - done)
-            # es window parameters are baked into the runner's closure —
-            # they must key the cache or a later train_fused call with a
-            # different stopping window would reuse a stale in-jit flag
-            key = (T, has_fm, nvalid,
-                   (es_rounds, es_first) if use_es else None)
-            if key not in self._fused_cache:
-                # the booster dict is only a per-train view now; the
-                # compiled runner itself lives in the PROCESS cache, so
-                # a new booster (or reset_config re-derivation) over the
-                # same datasets + config reuses the compiled program
-                # instead of paying XLA again (ISSUE 7 satellite fix).
-                # Keyed on the full config signature + array geometry;
-                # the datasets enter as ANCHORS: their tokens extend the
-                # key (a different dataset with identical shapes cannot
-                # reuse a closure over the old one's device arrays) and
-                # bound the entry's lifetime (no pinned dead HBM).
-                fsig = None if self.forced_splits is None else tuple(
-                    np.asarray(a).tobytes() for a in self.forced_splits)
-                cc_key = ("train_fused", key, k, self._config_signature(),
-                          fsig,
-                          cc_sig((self.scores, self.bins, self.bins_words,
-                                  tuple(self.valid_scores))))
-                built = []
+            with self._phase("fused_prepare"):
+                # es window parameters are baked into the runner's closure —
+                # they must key the cache or a later train_fused call with a
+                # different stopping window would reuse a stale in-jit flag
+                key = (T, has_fm, nvalid,
+                       (es_rounds, es_first) if use_es else None)
+                if key not in self._fused_cache:
+                    # the booster dict is only a per-train view now; the
+                    # compiled runner itself lives in the PROCESS cache, so
+                    # a new booster (or reset_config re-derivation) over the
+                    # same datasets + config reuses the compiled program
+                    # instead of paying XLA again (ISSUE 7 satellite fix).
+                    # Keyed on the full config signature + array geometry;
+                    # the datasets enter as ANCHORS: their tokens extend the
+                    # key (a different dataset with identical shapes cannot
+                    # reuse a closure over the old one's device arrays) and
+                    # bound the entry's lifetime (no pinned dead HBM).
+                    fsig = None if self.forced_splits is None else tuple(
+                        np.asarray(a).tobytes() for a in self.forced_splits)
+                    cc_key = ("train_fused", key, k, self._config_signature(),
+                              fsig,
+                              cc_sig((self.scores, self.bins, self.bins_words,
+                                      tuple(self.valid_scores))))
+                    built = []
 
-                def _build():
-                    built.append(True)
-                    return make_runner(T, has_fm)
+                    def _build():
+                        built.append(True)
+                        return make_runner(T, has_fm)
 
-                self._fused_cache[key] = cc_get_or_build(
-                    cc_key, _build,
-                    anchors=(self.train_set, *self.valid_sets),
-                    metrics=self.metrics)
-                if built:
-                    self._count("fused_runner_cache_misses")
+                    self._fused_cache[key] = cc_get_or_build(
+                        cc_key, _build,
+                        anchors=(self.train_set, *self.valid_sets),
+                        metrics=self.metrics)
+                    if built:
+                        self._count("fused_runner_cache_misses")
+                    else:
+                        self._count("fused_runner_cache_hits")
                 else:
                     self._count("fused_runner_cache_hits")
-            else:
-                self._count("fused_runner_cache_hits")
-            fmasks = None
-            if has_fm:
-                # per-ROUND masks: the seed is feature_fraction_seed +
-                # iteration (matching the classic loop, where iter_
-                # advances between draws) — drawing T masks at the same
-                # iter_ would freeze the subset for the whole chunk
-                fmasks = jnp.stack([
-                    self._feature_mask_for_tree(self.iter_ + t)
-                    for t in range(T)])
-            # per-round PRNG keys: python-int seed arithmetic (no
-            # traced-int32 overflow for large seeds) rendered straight
-            # to threefry key words in numpy — PRNGKey(s) is exactly
-            # [s >> 32, s & 0xffffffff] — so a chunk ships ONE [T, 2]
-            # array instead of ~3T tiny per-round device dispatches;
-            # the class fold_in(., 0) runs inside the jitted body
-            def _key_words(vals):
-                return np.array(
-                    [[v >> 32 & 0xffffffff, v & 0xffffffff]
-                     for v in vals], np.uint32)
-            qkeys = jnp.asarray(_key_words(
-                [seed_q + self.iter_ + t for t in range(T)]))
-            # node keys per (round, class): the classic loop's
-            # PRNGKey(extra_seed * 1000003 + iter * k + cls)
-            nkeys = jnp.asarray(_key_words(
-                [seed_node + (self.iter_ + t) * k + cls
-                 for t in range(T) for cls in range(k)])
-            ).reshape(T, k, 2)
-            iters = jnp.arange(self.iter_, self.iter_ + T, dtype=jnp.int32)
+                fmasks = None
+                if has_fm:
+                    # per-ROUND masks: the seed is feature_fraction_seed +
+                    # iteration (matching the classic loop, where iter_
+                    # advances between draws) — drawing T masks at the same
+                    # iter_ would freeze the subset for the whole chunk
+                    fmasks = jnp.stack([
+                        self._feature_mask_for_tree(self.iter_ + t)
+                        for t in range(T)])
+                # per-round PRNG keys: python-int seed arithmetic (no
+                # traced-int32 overflow for large seeds) rendered straight
+                # to threefry key words in numpy — PRNGKey(s) is exactly
+                # [s >> 32, s & 0xffffffff] — so a chunk ships ONE [T, 2]
+                # array instead of ~3T tiny per-round device dispatches;
+                # the class fold_in(., 0) runs inside the jitted body
+                def _key_words(vals):
+                    return np.array(
+                        [[v >> 32 & 0xffffffff, v & 0xffffffff]
+                         for v in vals], np.uint32)
+                qkeys = jnp.asarray(_key_words(
+                    [seed_q + self.iter_ + t for t in range(T)]))
+                # node keys per (round, class): the classic loop's
+                # PRNGKey(extra_seed * 1000003 + iter * k + cls)
+                nkeys = jnp.asarray(_key_words(
+                    [seed_node + (self.iter_ + t) * k + cls
+                     for t in range(T) for cls in range(k)])
+                ).reshape(T, k, 2)
+                iters = jnp.arange(self.iter_, self.iter_ + T, dtype=jnp.int32)
             with self._phase("fused_round_scan"):
                 (scores, vscores, es_host), (stacked, mvals) = \
                     self._fused_cache[key](
@@ -1513,56 +1563,69 @@ class GBDT:
                 self.valid_scores[vi] = vscores[vi]
             with self._phase("fused_chunk_transfer"):
                 host = jax.device_get(stacked)  # ONE transfer per chunk
-            mhost = np.asarray(jax.device_get(mvals)) if nvalid else None
-            for t in range(T):
-                stumps = 0
-                for cls in range(k):
-                    arrays_tc = jax.tree.map(lambda a: a[t, cls], host)
-                    with self._phase("tree_finalize"):
-                        tree = Tree.from_arrays(arrays_tc, self.train_set)
-                    tree.apply_shrinkage(self.shrinkage_rate)
-                    if self.iter_ == 0 and \
-                            abs(self.init_scores[cls]) > 1e-10:
-                        tree.add_bias(self.init_scores[cls])
-                    self.models.append(tree)
-                    if tree.num_leaves <= 1:
-                        stumps += 1
-                self.iter_ += 1
-                done += 1
-                self._count("iterations")
-                self._count("fused_rounds")
-                self._count("trees_grown", k)
-                self._count("hist_build_rounds",
-                            self._hist_rounds_per_tree() * k)
-                if nvalid:
-                    self._last_fused_evals = [
-                        (mrows[j][0], mrows[j][1], float(mhost[t, j]),
-                         mrows[j][2]) for j in range(len(mrows))]
-                if cb_driver is not None:
-                    try:
-                        # feed the REAL callbacks this round's
-                        # device-evaluated metrics — identical state
-                        # machine to the classic loop's post-iteration
-                        # callback pass; iteration is RELATIVE to this
-                        # train() run, like the classic loop's range()
-                        cb_driver(self.iter_ - 1 - begin_iter,
-                                  self._last_fused_evals)
-                    except EarlyStopException:
-                        # models stop at the detection round (later
-                        # rounds were never materialized); the device
-                        # advanced the score caches by the whole chunk —
-                        # rebuild from the kept models unless the stop
-                        # landed exactly on the chunk's last round
+                mhost = np.asarray(jax.device_get(mvals)) \
+                    if nvalid else None
+            # what this dispatch finalized, for its closing span
+            fin = {"rounds": 0, "trees": 0, "rows": 0}
+            try:
+                for t in range(T):
+                    stumps = 0
+                    for cls in range(k):
+                        arrays_tc = jax.tree.map(lambda a: a[t, cls], host)
+                        with self._phase("tree_finalize"):
+                            tree = Tree.from_arrays(arrays_tc, self.train_set)
+                        fin["trees"] += 1
+                        if count_rows:
+                            fin["rows"] += _hist_rows_selected(arrays_tc, n_rows)
+                        tree.apply_shrinkage(self.shrinkage_rate)
+                        if self.iter_ == 0 and \
+                                abs(self.init_scores[cls]) > 1e-10:
+                            tree.add_bias(self.init_scores[cls])
+                        self.models.append(tree)
+                        if tree.num_leaves <= 1:
+                            stumps += 1
+                    self.iter_ += 1
+                    done += 1
+                    fin["rounds"] += 1
+                    self._count("iterations")
+                    self._count("fused_rounds")
+                    self._count("trees_grown", k)
+                    self._count("hist_build_rounds",
+                                self._hist_rounds_per_tree() * k)
+                    if nvalid:
+                        self._last_fused_evals = [
+                            (mrows[j][0], mrows[j][1], float(mhost[t, j]),
+                             mrows[j][2]) for j in range(len(mrows))]
+                    if cb_driver is not None:
+                        try:
+                            # feed the REAL callbacks this round's
+                            # device-evaluated metrics — identical state
+                            # machine to the classic loop's post-iteration
+                            # callback pass; iteration is RELATIVE to this
+                            # train() run, like the classic loop's range()
+                            with self._phase("callbacks"):
+                                cb_driver(self.iter_ - 1 - begin_iter,
+                                          self._last_fused_evals)
+                        except EarlyStopException:
+                            # models stop at the detection round (later
+                            # rounds were never materialized); the device
+                            # advanced the score caches by the whole chunk —
+                            # rebuild from the kept models unless the stop
+                            # landed exactly on the chunk's last round
+                            if t + 1 < T:
+                                self.invalidate_score_cache()
+                            raise
+                    if stumps == k:
+                        # the classic loop would have stopped here; drop any
+                        # overrun rounds and rebuild scores without them
+                        finished = True
                         if t + 1 < T:
                             self.invalidate_score_cache()
-                        raise
-                if stumps == k:
-                    # the classic loop would have stopped here; drop any
-                    # overrun rounds and rebuild scores without them
-                    finished = True
-                    if t + 1 < T:
-                        self.invalidate_score_cache()
-                    break
+                        break
+            finally:
+                # also on an early stop raised by a callback: the
+                # trees finalized so far are in the model
+                self._dispatch_done(fin)
         return finished
 
     def _grow(self, g: jax.Array, h: jax.Array, row_mask, feature_mask,
@@ -1611,8 +1674,7 @@ class GBDT:
                 feature_mask = jnp.pad(feature_mask, (0, self._pad_cols))
             # quantized levels rejected at construction (__init__ fatal);
             # hist_scale is always None on this path
-            with obs_trace.span("collective_grow_dispatch",
-                                mode="feature"):
+            with phase("collective_grow_dispatch", mode="feature"):
                 arrays, lor = grow_tree_feature_parallel(
                     self.mesh, self.bins, g, h, row_mask, self.num_bins_arr,
                     self.nan_bin_arr, self.is_cat_arr, feature_mask, self.hp)
@@ -1632,8 +1694,8 @@ class GBDT:
         self._maybe_measure_collective(overlap)
         if self.parallel_mode in ("data", "voting") \
                 and self._use_batched_grower():
-            with obs_trace.span("collective_grow_dispatch",
-                                mode=self.parallel_mode, batched=True):
+            with phase("collective_grow_dispatch",
+                       mode=self.parallel_mode, batched=True):
                 arrays, lor = grow_tree_batched_sharded(
                     self.mesh, self.bins, g, h, row_mask, self.num_bins_arr,
                     self.nan_bin_arr, self.is_cat_arr, feature_mask, self.hp,
@@ -1645,8 +1707,8 @@ class GBDT:
                     top_k=int(self.config.top_k), overlap=overlap,
                     metrics=self.metrics)
             return arrays, (lor[:-p] if p else lor)
-        with obs_trace.span("collective_grow_dispatch",
-                            mode=self.parallel_mode, batched=False):
+        with phase("collective_grow_dispatch",
+                   mode=self.parallel_mode, batched=False):
             arrays, lor = grow_tree_sharded(
                 self.mesh, self.bins, g, h, row_mask, self.num_bins_arr,
                 self.nan_bin_arr, self.is_cat_arr, feature_mask, self.hp,

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import copy
 import time
+import types
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -19,7 +20,7 @@ from .basic import Booster, Dataset
 from .callback import (CallbackEnv, EarlyStopException, log_telemetry,
                        record_evaluation)
 from .config import normalize_params
-from .obs import events as obs_events, observe_training, trace as obs_trace
+from .obs import events as obs_events, observe_training
 from .robustness.guards import NumericHalt
 from .utils import log
 from .utils.paths import check_output_path
@@ -61,6 +62,65 @@ def train(params: Dict[str, Any], train_set: Dataset,
     if fobj is not None:
         params["objective"] = "none"
 
+    # observability session (obs/): trace_output starts the span recorder
+    # (exported on exit), profile_dir brackets the run with
+    # jax.profiler.trace; both no-ops when unset.  The session and the
+    # "train" span, the root every other span nests under, open before
+    # the booster exists, so that its construction (``booster_init``) is
+    # part of the job on the recorder's and the profiler's timeline.
+    session = types.SimpleNamespace(
+        **{key: params.get(key, "")
+           for key in ("trace_output", "profile_dir", "event_output")})
+    with observe_training(session), phase("train", global_timer) as job:
+        return _train_job(job, params, train_set, num_boost_round,
+                          valid_sets, valid_names, feval, init_model,
+                          callbacks, fobj, resume, final_checkpoint)
+
+
+def _train_job(job, params, train_set, num_boost_round, valid_sets,
+               valid_names, feval, init_model, callbacks, fobj, resume,
+               final_checkpoint) -> Booster:
+    """``train()`` inside its session and root span ``job``: build the
+    booster and everything around it (span ``booster_init``), then run
+    the rounds."""
+    with phase("booster_init"):
+        setup = _prepare_job(params, train_set, num_boost_round,
+                             valid_sets, valid_names, init_model,
+                             callbacks, resume)
+    (booster, valid_pairs, train_in_valid, callbacks, cbs_before, cbs_after,
+     mgr, tower, resume_state, rounds_to_run, start_round) = setup
+    if resume_state is not None and rounds_to_run <= 0:
+        return booster              # the checkpoint is already there
+    # the booster's own table times the job from here (its timer did
+    # not exist when the root span opened)
+    job.also(booster._gbdt.timer)
+    if resume_state is not None:
+        # the journal activates with the session, so the restore (which
+        # ran in ``booster_init``) is journaled here; an elastic
+        # session's outer journal receives it either way
+        obs_events.emit_event(
+            "checkpoint_resume", round_idx=start_round,
+            total_rounds=int(num_boost_round))
+    try:
+        out = _run_training(booster, params, train_set, rounds_to_run,
+                            valid_pairs, train_in_valid, feval, fobj,
+                            callbacks, cbs_before, cbs_after,
+                            start_round=start_round)
+        if final_checkpoint and mgr is not None:
+            mgr.save_final(out)
+        return out
+    finally:
+        if tower is not None:
+            # flush the final partial rollup window and run the SLO
+            # evaluator over it while the journal is still active
+            tower.close()
+
+
+def _prepare_job(params, train_set, num_boost_round, valid_sets,
+                 valid_names, init_model, callbacks, resume):
+    """Everything of ``train()`` before the first round: checkpoint
+    lookup, the booster, its valid sets, the callbacks and their order,
+    the exact-state restore of a resumed run."""
     ckpt_dir = str(params.get("checkpoint_dir", "") or "")
     resume_state = None
     if resume is not None:
@@ -176,34 +236,9 @@ def train(params: Dict[str, Any], train_set: Dataset,
             log.info(f"checkpoint is already at iteration "
                      f"{resume_state.iteration} >= num_boost_round="
                      f"{num_boost_round}; nothing to train")
-            return booster
 
-    # observability session (obs/): trace_output starts the span recorder
-    # (exported on exit), profile_dir brackets the run with
-    # jax.profiler.trace; both no-ops when unset.  The "train" phase is
-    # the root span every other span nests under.
-    with observe_training(cfg), \
-            phase("train", booster._gbdt.timer, global_timer):
-        if resume_state is not None:
-            # journal activates with the session just above, so the
-            # restore (which ran earlier) is journaled here; an elastic
-            # session's outer journal receives it either way
-            obs_events.emit_event(
-                "checkpoint_resume", round_idx=start_round,
-                total_rounds=int(num_boost_round))
-        try:
-            out = _run_training(booster, params, train_set, rounds_to_run,
-                                valid_pairs, train_in_valid, feval, fobj,
-                                callbacks, cbs_before, cbs_after,
-                                start_round=start_round)
-            if final_checkpoint and mgr is not None:
-                mgr.save_final(out)
-            return out
-        finally:
-            if tower is not None:
-                # flush the final partial rollup window and run the SLO
-                # evaluator over it while the journal is still active
-                tower.close()
+    return (booster, valid_pairs, train_in_valid, callbacks, cbs_before,
+            cbs_after, mgr, tower, resume_state, rounds_to_run, start_round)
 
 
 def _build_watchtower(cfg, booster):
@@ -347,7 +382,7 @@ def _run_training(booster, params, train_set, num_boost_round, valid_pairs,
     evals: List = []
     end_round = start_round + num_boost_round
     for it in range(start_round, end_round):
-        with obs_trace.span("iteration", iter=it):
+        with phase("iteration", iter=it):
             for cb in cbs_before:
                 cb(CallbackEnv(booster, params, it, start_round, end_round,
                                None))

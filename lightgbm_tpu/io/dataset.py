@@ -38,8 +38,8 @@ def device_bins_pow2(widest: int) -> int:
     """Device histogram bin-axis width for a widest-column bin count:
     rounded up to a power of two (lane-friendly), floor 4.  THE rounding
     rule — ``Dataset.device_n_bins`` and the bench scripts (bench.py,
-    tools/sweep_perf.py, tools/profile_bench.py) must agree on it or the
-    bench measures a bin width the real pipeline doesn't use."""
+    tools/sweep_perf.py) must agree on it or the bench measures a bin
+    width the real pipeline doesn't use."""
     return max(1 << max(1, (int(widest) - 1).bit_length()), 4)
 
 

@@ -142,19 +142,20 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         cegb_blk = min(1 << 18, n)
         cegb_pad = (-n) % cegb_blk
         cegb_nb = (n + cegb_pad) // cegb_blk
-    mask_f = jnp.ones_like(grad) if row_mask is None \
-        else row_mask.astype(grad.dtype)
-    bins_t = lax.optimization_barrier(bins.T)
-    # tree-invariant i32 word view of the row-major bins, hoisted out of
-    # the round loop: every compacted round's payload reuses it.  The
-    # booster passes the dataset's construction-time packed mirror
-    # (io/dataset.py packed_mirror) so serial trees skip even the
-    # one-time bitcast; derived in-jit otherwise (distributed shards).
-    bins_words = lax.optimization_barrier(
-        bins_to_words(bins) if bins_words is None else bins_words)
-    # transposed packed mirror for the round-6 packed histogram kernel
-    words_t = lax.optimization_barrier(bins_words.T) \
-        if wants_packed_mirror(hp.hist_kernel, hp.n_bins) else None
+    with jax.named_scope("tree_root"):
+        mask_f = jnp.ones_like(grad) if row_mask is None \
+            else row_mask.astype(grad.dtype)
+        bins_t = lax.optimization_barrier(bins.T)
+        # tree-invariant i32 word view of the row-major bins, hoisted out of
+        # the round loop: every compacted round's payload reuses it.  The
+        # booster passes the dataset's construction-time packed mirror
+        # (io/dataset.py packed_mirror) so serial trees skip even the
+        # one-time bitcast; derived in-jit otherwise (distributed shards).
+        bins_words = lax.optimization_barrier(
+            bins_to_words(bins) if bins_words is None else bins_words)
+        # transposed packed mirror for the round-6 packed histogram kernel
+        words_t = lax.optimization_barrier(bins_words.T) \
+            if wants_packed_mirror(hp.hist_kernel, hp.n_bins) else None
     # fused partition+key kernel (ops/round_fuse.py): numeric non-bundled
     # splits only — categorical bitsets / EFB inverse tables are per-row
     # gathers, kept on the XLA path
@@ -285,116 +286,118 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # each exact integer histogram accumulation
     scale_vec = None
     if hist_scale is not None:
-        scale_vec = jnp.concatenate(
-            [hist_scale.astype(jnp.float32), jnp.ones((2,), jnp.float32)])
+        with jax.named_scope("tree_root"):
+            scale_vec = jnp.concatenate(
+                [hist_scale.astype(jnp.float32), jnp.ones((2,), jnp.float32)])
 
     def _scaled(h):
         return h if scale_vec is None else h * scale_vec
 
-    hist0_b = _scaled(root_histogram(
-        bins_t, grad, hess, row_mask, n_bins=hp.n_bins,
-        rows_per_block=hp.rows_per_block,
-        hist_dtype=hp.hist_dtype, axis_name=hist_axis,
-        hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-        overlap=overlap))
-    g0 = jnp.sum(grad * mask_f)
-    h0 = jnp.sum(hess * mask_f)
-    c0 = jnp.sum(mask_f)
-    if hist_scale is not None:
-        g0 = g0 * hist_scale[0]
-        h0 = h0 * hist_scale[1]
-    if axis_name is not None:
-        if overlap_enabled(overlap):
-            # one [3]-vector psum instead of three scalar collectives:
-            # same per-element sums (bit-identical), one less blocking
-            # round-trip for the scheduler to hide
-            g0, h0, c0 = lax.psum(jnp.stack([g0, h0, c0]), axis_name)
+    with jax.named_scope("tree_root"):
+        hist0_b = _scaled(root_histogram(
+            bins_t, grad, hess, row_mask, n_bins=hp.n_bins,
+            rows_per_block=hp.rows_per_block,
+            hist_dtype=hp.hist_dtype, axis_name=hist_axis,
+            hist_kernel=hp.hist_kernel, bins_words_t=words_t,
+            overlap=overlap))
+        g0 = jnp.sum(grad * mask_f)
+        h0 = jnp.sum(hess * mask_f)
+        c0 = jnp.sum(mask_f)
+        if hist_scale is not None:
+            g0 = g0 * hist_scale[0]
+            h0 = h0 * hist_scale[1]
+        if axis_name is not None:
+            if overlap_enabled(overlap):
+                # one [3]-vector psum instead of three scalar collectives:
+                # same per-element sums (bit-identical), one less blocking
+                # round-trip for the scheduler to hide
+                g0, h0, c0 = lax.psum(jnp.stack([g0, h0, c0]), axis_name)
+            else:
+                g0 = lax.psum(g0, axis_name)
+                h0 = lax.psum(h0, axis_name)
+                c0 = lax.psum(c0, axis_name)
+        root_out = leaf_output(g0, h0, hp.lambda_l1, hp.lambda_l2,
+                               hp.max_delta_step)
+        empty_path = jnp.zeros((num_f,), bool)
+        key_root = jax.random.fold_in(rng_key, 0) if use_rng else None
+        if cegb is not None:
+            cnt0 = (jnp.einsum("n,nf->f", mask_f.astype(jnp.float32),
+                               (~cegb.used_rows).astype(jnp.float32))
+                    if use_lazy else None)
+            pen0 = cegb_penalty(cegb.feature_used, cnt0, c0)
         else:
-            g0 = lax.psum(g0, axis_name)
-            h0 = lax.psum(h0, axis_name)
-            c0 = lax.psum(c0, axis_name)
-    root_out = leaf_output(g0, h0, hp.lambda_l1, hp.lambda_l2,
-                           hp.max_delta_step)
-    empty_path = jnp.zeros((num_f,), bool)
-    key_root = jax.random.fold_in(rng_key, 0) if use_rng else None
-    if cegb is not None:
-        cnt0 = (jnp.einsum("n,nf->f", mask_f.astype(jnp.float32),
-                           (~cegb.used_rows).astype(jnp.float32))
-                if use_lazy else None)
-        pen0 = cegb_penalty(cegb.feature_used, cnt0, c0)
-    else:
-        pen0 = None
-    best0 = child_best(hist0_b, g0, h0, c0, jnp.int32(0), -INF, INF,
-                       node_mask(empty_path, key_root), root_out, key_root,
-                       pen=pen0)
+            pen0 = None
+        best0 = child_best(hist0_b, g0, h0, c0, jnp.int32(0), -INF, INF,
+                           node_mask(empty_path, key_root), root_out, key_root,
+                           pen=pen0)
 
-    tree = _empty_tree(L, hp.n_bins, num_f)
-    tree = tree._replace(
-        leaf_value=tree.leaf_value.at[0].set(root_out),
-        leaf_count=tree.leaf_count.at[0].set(c0),
-        leaf_weight=tree.leaf_weight.at[0].set(h0))
-    C = hist0_b.shape[-1]
-    n_cols = bins.shape[1]
-    # bounded histogram pool (SplitHyper.hist_pool_slots): P slots + one
-    # trash row; leaf_slot/slot_leaf carry the mapping, with trash entries
-    # at index L / P so masked scatters need no branches
-    # (``pooled`` itself is derived up top, before the partition-fusion
-    # gates)
-    P = hp.hist_pool_slots
-    if pooled:
-        assert P >= 3 * K + 2, \
-            "hist_pool_slots must be >= 3*batch+2 for worst-case rounds"
-    state = dict(
-        tree=tree,
-        leaf_of_row=jnp.zeros((n,), jnp.int32),
-        hist=(jnp.zeros((P + 1, n_cols, hp.n_bins, C), jnp.float32)
-              .at[0].set(hist0_b) if pooled else
-              jnp.zeros((L, n_cols, hp.n_bins, C),
-                        jnp.float32).at[0].set(hist0_b)),
-        sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
-        sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
-        count=jnp.zeros((L,), jnp.float32).at[0].set(c0),
-        best_gain=jnp.full((L,), NEG_INF, jnp.float32).at[0].set(best0.gain),
-        best_feat=jnp.zeros((L,), jnp.int32).at[0].set(best0.feature),
-        best_thr=jnp.zeros((L,), jnp.int32).at[0].set(best0.threshold),
-        best_dl=jnp.zeros((L,), bool).at[0].set(best0.default_left),
-        best_var=jnp.zeros((L,), jnp.int32).at[0].set(best0.variant),
-        best_lg=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_sum_g),
-        best_lh=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_sum_h),
-        best_lc=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_count),
-        leaf_min=jnp.full((L,), -INF, jnp.float32),
-        leaf_max=jnp.full((L,), INF, jnp.float32),
-        parent_node=jnp.full((L,), -1, jnp.int32),
-        parent_side=jnp.zeros((L,), jnp.int32),
-        n_splits=jnp.int32(0),
-        progress=jnp.bool_(True),
-    )
-    if hp.has_categorical:
-        state["best_bitset"] = jnp.zeros((L, hp.n_bins), bool).at[0].set(
-            winner_bitset(hist0_b, g0, h0, c0, best0.feature,
-                          best0.variant, best0.threshold))
-    if cegb is not None:
-        state["cegb_used"] = cegb.feature_used
-        if use_lazy:
-            state["cegb_rows"] = cegb.used_rows
-    # leaf path features: tracked unconditionally ([L, F] bool is tiny)
-    # so returned trees carry leaf_path like the strict learner's — the
-    # linear-tree ridge fit (learner/linear.py fit_linear_leaves) selects
-    # each leaf's numeric path features from it
-    state["path_f"] = jnp.zeros((L, num_f), bool)
-    if use_boxes:
-        # bin-space boxes: root spans every bin (hi exclusive); dead slots
-        # hold empty boxes so box_bounds ignores them
-        state["leaf_lo"] = jnp.zeros((L, num_f), jnp.int32)
-        state["leaf_hi"] = jnp.zeros((L, num_f), jnp.int32).at[0].set(
-            num_bins.astype(jnp.int32))
-    if forced is not None:
-        # composes with the bounded pool since round 6: the forced phase
-        # derives evicted leaves' columns directly (forced_col_hist)
-        state["force_failed"] = jnp.bool_(False)
-    if pooled:
-        state["leaf_slot"] = jnp.full((L + 1,), -1, jnp.int32).at[0].set(0)
-        state["slot_leaf"] = jnp.full((P + 1,), -1, jnp.int32).at[0].set(0)
+        tree = _empty_tree(L, hp.n_bins, num_f)
+        tree = tree._replace(
+            leaf_value=tree.leaf_value.at[0].set(root_out),
+            leaf_count=tree.leaf_count.at[0].set(c0),
+            leaf_weight=tree.leaf_weight.at[0].set(h0))
+        C = hist0_b.shape[-1]
+        n_cols = bins.shape[1]
+        # bounded histogram pool (SplitHyper.hist_pool_slots): P slots + one
+        # trash row; leaf_slot/slot_leaf carry the mapping, with trash entries
+        # at index L / P so masked scatters need no branches
+        # (``pooled`` itself is derived up top, before the partition-fusion
+        # gates)
+        P = hp.hist_pool_slots
+        if pooled:
+            assert P >= 3 * K + 2, \
+                "hist_pool_slots must be >= 3*batch+2 for worst-case rounds"
+        state = dict(
+            tree=tree,
+            leaf_of_row=jnp.zeros((n,), jnp.int32),
+            hist=(jnp.zeros((P + 1, n_cols, hp.n_bins, C), jnp.float32)
+                  .at[0].set(hist0_b) if pooled else
+                  jnp.zeros((L, n_cols, hp.n_bins, C),
+                            jnp.float32).at[0].set(hist0_b)),
+            sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
+            sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
+            count=jnp.zeros((L,), jnp.float32).at[0].set(c0),
+            best_gain=jnp.full((L,), NEG_INF, jnp.float32).at[0].set(best0.gain),
+            best_feat=jnp.zeros((L,), jnp.int32).at[0].set(best0.feature),
+            best_thr=jnp.zeros((L,), jnp.int32).at[0].set(best0.threshold),
+            best_dl=jnp.zeros((L,), bool).at[0].set(best0.default_left),
+            best_var=jnp.zeros((L,), jnp.int32).at[0].set(best0.variant),
+            best_lg=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_sum_g),
+            best_lh=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_sum_h),
+            best_lc=jnp.zeros((L,), jnp.float32).at[0].set(best0.left_count),
+            leaf_min=jnp.full((L,), -INF, jnp.float32),
+            leaf_max=jnp.full((L,), INF, jnp.float32),
+            parent_node=jnp.full((L,), -1, jnp.int32),
+            parent_side=jnp.zeros((L,), jnp.int32),
+            n_splits=jnp.int32(0),
+            progress=jnp.bool_(True),
+        )
+        if hp.has_categorical:
+            state["best_bitset"] = jnp.zeros((L, hp.n_bins), bool).at[0].set(
+                winner_bitset(hist0_b, g0, h0, c0, best0.feature,
+                              best0.variant, best0.threshold))
+        if cegb is not None:
+            state["cegb_used"] = cegb.feature_used
+            if use_lazy:
+                state["cegb_rows"] = cegb.used_rows
+        # leaf path features: tracked unconditionally ([L, F] bool is tiny)
+        # so returned trees carry leaf_path like the strict learner's — the
+        # linear-tree ridge fit (learner/linear.py fit_linear_leaves) selects
+        # each leaf's numeric path features from it
+        state["path_f"] = jnp.zeros((L, num_f), bool)
+        if use_boxes:
+            # bin-space boxes: root spans every bin (hi exclusive); dead slots
+            # hold empty boxes so box_bounds ignores them
+            state["leaf_lo"] = jnp.zeros((L, num_f), jnp.int32)
+            state["leaf_hi"] = jnp.zeros((L, num_f), jnp.int32).at[0].set(
+                num_bins.astype(jnp.int32))
+        if forced is not None:
+            # composes with the bounded pool since round 6: the forced phase
+            # derives evicted leaves' columns directly (forced_col_hist)
+            state["force_failed"] = jnp.bool_(False)
+        if pooled:
+            state["leaf_slot"] = jnp.full((L + 1,), -1, jnp.int32).at[0].set(0)
+            state["slot_leaf"] = jnp.full((P + 1,), -1, jnp.int32).at[0].set(0)
 
     def make_round_body(Kr, use_forced=False):
       def round_body(st):
@@ -876,99 +879,109 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               # path.  Under shard_map the state counts are GLOBAL
               # (psum-ed) while compaction is per-shard, so pass no counts
               # there (recomputed locally).
-              small_cnt = (jnp.where(valid, jnp.minimum(l_cnt, r_cnt), 0.0)
-                           if axis_name is None else None)
+              with jax.named_scope("hist_update"):
+                  small_cnt = (jnp.where(valid, jnp.minimum(l_cnt, r_cnt),
+                                         0.0)
+                               if axis_name is None else None)
 
               def hist_call(lv, cnts, skey=None):
-                  return _scaled(histogram_for_leaves_auto(
+                  h = histogram_for_leaves_auto(
                       bins, bins_t, grad, hess, lor, lv, row_mask,
                       n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
                       hist_dtype=hp.hist_dtype, axis_name=hist_axis,
                       counts=cnts, bins_words=bins_words, sort_key=skey,
                       hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-                      overlap=overlap))
+                      overlap=overlap)
+                  with jax.named_scope("hist_update"):
+                      return _scaled(h)
 
+              # hist_update: what the round does around the histogram
+              # call — parent minus smaller child, the writes into the
+              # histogram state or pool, slot allocation
               left_small = (l_cnt <= r_cnt)[:, None, None, None]
               if not pooled:
                   # the fused kernel's keys target exactly the `smaller`
                   # set; the pooled path's extended leaf set rebuilds its
                   # own keys
                   h_small = hist_call(smaller, small_cnt, sort_key)
-                  h_parent = st["hist"][parents]
-                  h_large = h_parent - h_small
-                  h_left = jnp.where(left_small, h_small, h_large)
-                  h_right = jnp.where(left_small, h_large, h_small)
-                  hist = st["hist"]
-                  hist = hist.at[parents].set(
-                      jnp.where(valid[:, None, None, None], h_left,
-                                hist[parents]))
-                  hist = hist.at[safe_nl].set(
-                      jnp.where(valid[:, None, None, None], h_right,
-                                hist[safe_nl]))
-                  st["hist"] = hist
+                  with jax.named_scope("hist_update"):
+                      h_parent = st["hist"][parents]
+                      h_large = h_parent - h_small
+                      h_left = jnp.where(left_small, h_small, h_large)
+                      h_right = jnp.where(left_small, h_large, h_small)
+                      hist = st["hist"]
+                      hist = hist.at[parents].set(
+                          jnp.where(valid[:, None, None, None], h_left,
+                                    hist[parents]))
+                      hist = hist.at[safe_nl].set(
+                          jnp.where(valid[:, None, None, None], h_right,
+                                    hist[safe_nl]))
+                      st["hist"] = hist
               else:
                   # -- bounded pool: parents with an evicted histogram get
                   # BOTH children computed directly (no subtraction);
                   # the widened pass carries K smaller + up-to-K larger
-                  p_slot = st["leaf_slot"][parents]            # [K]
-                  present = (p_slot >= 0) & valid
-                  larger = jnp.where(l_cnt <= r_cnt, safe_nl, parents)
-                  need_direct = valid & ~present
-                  large_cnt = jnp.where(need_direct,
-                                        jnp.maximum(l_cnt, r_cnt), 0.0)
-                  leaves_ext = jnp.concatenate(
-                      [smaller, jnp.where(need_direct, larger, L - 1)])
-                  # counts are GLOBAL under shard_map while compaction is
-                  # per-shard — same gate as the non-pooled path: let the
-                  # histogram op recompute local counts there
-                  ext_cnt = (jnp.concatenate([small_cnt, large_cnt])
-                             if axis_name is None else None)
+                  with jax.named_scope("hist_update"):
+                      p_slot = st["leaf_slot"][parents]            # [K]
+                      present = (p_slot >= 0) & valid
+                      larger = jnp.where(l_cnt <= r_cnt, safe_nl, parents)
+                      need_direct = valid & ~present
+                      large_cnt = jnp.where(need_direct,
+                                            jnp.maximum(l_cnt, r_cnt), 0.0)
+                      leaves_ext = jnp.concatenate(
+                          [smaller, jnp.where(need_direct, larger, L - 1)])
+                      # counts are GLOBAL under shard_map while compaction is
+                      # per-shard — same gate as the non-pooled path: let the
+                      # histogram op recompute local counts there
+                      ext_cnt = (jnp.concatenate([small_cnt, large_cnt])
+                                 if axis_name is None else None)
                   h_ext = hist_call(leaves_ext, ext_cnt)
-                  h_small = h_ext[:Kr]
-                  h_parent = st["hist"][jnp.maximum(p_slot, 0)]
-                  h_large = jnp.where(present[:, None, None, None],
-                                      h_parent - h_small, h_ext[Kr:])
-                  h_left = jnp.where(left_small, h_small, h_large)
-                  h_right = jnp.where(left_small, h_large, h_small)
+                  with jax.named_scope("hist_update"):
+                      h_small = h_ext[:Kr]
+                      h_parent = st["hist"][jnp.maximum(p_slot, 0)]
+                      h_large = jnp.where(present[:, None, None, None],
+                                          h_parent - h_small, h_ext[Kr:])
+                      h_left = jnp.where(left_small, h_small, h_large)
+                      h_right = jnp.where(left_small, h_large, h_small)
 
-                  # -- slot allocation: free slots first, then evict the
-                  # lowest-cached-gain occupants; this round's parent
-                  # slots are locked (they become the left children's)
-                  slot_leaf = st["slot_leaf"]                  # [P+1]
-                  leaf_slot = st["leaf_slot"]                  # [L+1]
-                  locked = jnp.zeros((P + 1,), bool).at[
-                      jnp.where(present, p_slot, P)].set(True)[:P]
-                  occ = slot_leaf[:P]
-                  occ_gain = jnp.where(occ >= 0,
-                                       st["best_gain"][jnp.maximum(occ, 0)],
-                                       -jnp.inf)
-                  order = jnp.argsort(
-                      jnp.where(locked, jnp.inf, occ_gain))    # [P]
-                  req = jnp.concatenate([need_direct, valid])  # [2K]
-                  pos = jnp.cumsum(req.astype(jnp.int32)) - 1
-                  alloc = jnp.where(req, order[jnp.clip(pos, 0, P - 1)], P)
-                  # evict old occupants of granted slots
-                  evicted = jnp.where(alloc < P,
-                                      slot_leaf[jnp.minimum(alloc, P)], -1)
-                  leaf_slot = leaf_slot.at[
-                      jnp.where(evicted >= 0, evicted, L)].set(-1)
-                  slot_l = jnp.where(present, p_slot, alloc[:Kr])
-                  slot_r = alloc[Kr:]
-                  tgt_l = jnp.where(valid, slot_l, P)
-                  tgt_r = jnp.where(valid, slot_r, P)
-                  hist = st["hist"].at[tgt_l].set(h_left)
-                  hist = hist.at[tgt_r].set(h_right)
-                  st["hist"] = hist
-                  slot_leaf = slot_leaf.at[tgt_l].set(
-                      jnp.where(valid, parents, -1))
-                  slot_leaf = slot_leaf.at[tgt_r].set(
-                      jnp.where(valid, safe_nl, -1))
-                  leaf_slot = leaf_slot.at[
-                      jnp.where(valid, parents, L)].set(slot_l)
-                  leaf_slot = leaf_slot.at[
-                      jnp.where(valid, safe_nl, L)].set(slot_r)
-                  st["slot_leaf"] = slot_leaf.at[P].set(-1)
-                  st["leaf_slot"] = leaf_slot.at[L].set(-1)
+                      # -- slot allocation: free slots first, then evict the
+                      # lowest-cached-gain occupants; this round's parent
+                      # slots are locked (they become the left children's)
+                      slot_leaf = st["slot_leaf"]                  # [P+1]
+                      leaf_slot = st["leaf_slot"]                  # [L+1]
+                      locked = jnp.zeros((P + 1,), bool).at[
+                          jnp.where(present, p_slot, P)].set(True)[:P]
+                      occ = slot_leaf[:P]
+                      occ_gain = jnp.where(occ >= 0,
+                                           st["best_gain"][jnp.maximum(occ, 0)],
+                                           -jnp.inf)
+                      order = jnp.argsort(
+                          jnp.where(locked, jnp.inf, occ_gain))    # [P]
+                      req = jnp.concatenate([need_direct, valid])  # [2K]
+                      pos = jnp.cumsum(req.astype(jnp.int32)) - 1
+                      alloc = jnp.where(req, order[jnp.clip(pos, 0, P - 1)], P)
+                      # evict old occupants of granted slots
+                      evicted = jnp.where(alloc < P,
+                                          slot_leaf[jnp.minimum(alloc, P)], -1)
+                      leaf_slot = leaf_slot.at[
+                          jnp.where(evicted >= 0, evicted, L)].set(-1)
+                      slot_l = jnp.where(present, p_slot, alloc[:Kr])
+                      slot_r = alloc[Kr:]
+                      tgt_l = jnp.where(valid, slot_l, P)
+                      tgt_r = jnp.where(valid, slot_r, P)
+                      hist = st["hist"].at[tgt_l].set(h_left)
+                      hist = hist.at[tgt_r].set(h_right)
+                      st["hist"] = hist
+                      slot_leaf = slot_leaf.at[tgt_l].set(
+                          jnp.where(valid, parents, -1))
+                      slot_leaf = slot_leaf.at[tgt_r].set(
+                          jnp.where(valid, safe_nl, -1))
+                      leaf_slot = leaf_slot.at[
+                          jnp.where(valid, parents, L)].set(slot_l)
+                      leaf_slot = leaf_slot.at[
+                          jnp.where(valid, safe_nl, L)].set(slot_r)
+                      st["slot_leaf"] = slot_leaf.at[P].set(-1)
+                      st["leaf_slot"] = leaf_slot.at[L].set(-1)
 
           # ---- child best splits, vmapped over the 2K children
           with jax.named_scope("find_splits"):
@@ -1091,45 +1104,49 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # selection semantics, just fewer masked channels per pass.  Gated on
     # data size (static at trace time): each width is its own kernel
     # compilation, worth it only when passes are expensive.
-    if forced is not None:
-        # forced-split phase: one K=1 round per schedule entry, in BFS
-        # order (entry index == split counter, as in the strict learner);
-        # a failed entry aborts the remaining schedule
-        f_leaf0 = forced[0]
+    # the round loops: what a round does outside its three scopes
+    # (choosing the K parents, the tree's bookkeeping, CEGB) is
+    # ``tree_select``; partition / round_hist / find_splits nest inside
+    with jax.named_scope("tree_select"):
+        if forced is not None:
+            # forced-split phase: one K=1 round per schedule entry, in BFS
+            # order (entry index == split counter, as in the strict learner);
+            # a failed entry aborts the remaining schedule
+            f_leaf0 = forced[0]
+            state = lax.while_loop(
+                lambda st: (st["n_splits"] < L - 1) & ~st["force_failed"]
+                & (f_leaf0[jnp.minimum(st["n_splits"],
+                                       f_leaf0.shape[0] - 1)] >= 0),
+                make_round_body(1, use_forced=True), state)
+            # a failed/exhausted forced round leaves progress False; the
+            # gain-based loops below must still run
+            state["progress"] = jnp.bool_(True)
+        if warmup and n >= _WARMUP_MIN_ROWS and forced is None \
+                and ladder_profitable(hp.hist_kernel, hp.n_bins):
+            # width QUADRUPLING (1, 4, 16, ...): each width always covers the
+            # frontier (it at most doubles per round), and since kernel cost
+            # is K-independent below 128 channels (docs/PERF_NOTES.md round
+            # 3), fewer warmup rounds beat finer width matching — profiled
+            # ~2 full passes saved per tree vs doubling.  Skipped after a
+            # forced phase: the forced frontier can exceed the warmup widths.
+            # Round 6: the ladder only pays where the K<=4 masked pass takes
+            # the radix-JOINT kernel (auto dispatch at >= 128 bins); every
+            # other mode's kernel is K-independent, so those configs SEED the
+            # round loop at full width straight from the root histogram —
+            # identical selections (top-k of a sub-K frontier picks the same
+            # leaves at any width), ~2 fewer compiled round bodies and no
+            # narrow warmup passes (ops/histogram.py ladder_profitable).
+            kw = 1
+            while kw < K:
+                state = lax.cond(state["progress"] & (state["n_splits"] < L - 1),
+                                 make_round_body(kw), lambda st: st, state)
+                kw *= 4
+        # loop until the tree is full or a round makes no progress — a fixed
+        # ceil((L-1)/K) budget would starve narrow-frontier (chain-shaped) trees
+        # where only ~1 leaf per round carries positive gain
         state = lax.while_loop(
-            lambda st: (st["n_splits"] < L - 1) & ~st["force_failed"]
-            & (f_leaf0[jnp.minimum(st["n_splits"],
-                                   f_leaf0.shape[0] - 1)] >= 0),
-            make_round_body(1, use_forced=True), state)
-        # a failed/exhausted forced round leaves progress False; the
-        # gain-based loops below must still run
-        state["progress"] = jnp.bool_(True)
-    if warmup and n >= _WARMUP_MIN_ROWS and forced is None \
-            and ladder_profitable(hp.hist_kernel, hp.n_bins):
-        # width QUADRUPLING (1, 4, 16, ...): each width always covers the
-        # frontier (it at most doubles per round), and since kernel cost
-        # is K-independent below 128 channels (docs/PERF_NOTES.md round
-        # 3), fewer warmup rounds beat finer width matching — profiled
-        # ~2 full passes saved per tree vs doubling.  Skipped after a
-        # forced phase: the forced frontier can exceed the warmup widths.
-        # Round 6: the ladder only pays where the K<=4 masked pass takes
-        # the radix-JOINT kernel (auto dispatch at >= 128 bins); every
-        # other mode's kernel is K-independent, so those configs SEED the
-        # round loop at full width straight from the root histogram —
-        # identical selections (top-k of a sub-K frontier picks the same
-        # leaves at any width), ~2 fewer compiled round bodies and no
-        # narrow warmup passes (ops/histogram.py ladder_profitable).
-        kw = 1
-        while kw < K:
-            state = lax.cond(state["progress"] & (state["n_splits"] < L - 1),
-                             make_round_body(kw), lambda st: st, state)
-            kw *= 4
-    # loop until the tree is full or a round makes no progress — a fixed
-    # ceil((L-1)/K) budget would starve narrow-frontier (chain-shaped) trees
-    # where only ~1 leaf per round carries positive gain
-    state = lax.while_loop(
-        lambda st: st["progress"] & (st["n_splits"] < L - 1),
-        make_round_body(K), state)
+            lambda st: st["progress"] & (st["n_splits"] < L - 1),
+            make_round_body(K), state)
     tree_out = state["tree"]._replace(leaf_path=state["path_f"])
     if cegb is not None:
         new_cegb = cegb._replace(

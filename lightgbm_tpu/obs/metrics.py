@@ -33,7 +33,12 @@ COUNTERS: Dict[str, str] = {
     "strict_rounds": "rounds run on the strict per-tree update path",
     "fused_rounds": "rounds run on the fused round-kernel fast path",
     "trees_grown": "trees grown (k per round for multiclass)",
-    "hist_build_rounds": "histogram build passes dispatched",
+    "hist_build_rounds":
+        "histogram build passes dispatched (a formula: splits over the "
+        "split batch, not a count of what ran)",
+    "hist_rows_selected":
+        "rows the histogram passes had to read, counted from the trees "
+        "(root pass + each split's smaller child; serial learner only)",
     "quantize_rounds": "rounds that quantized gradients before binning",
     "hist_pool_fallbacks": "histogram-pool exhaustion -> rebuild fallbacks",
     "batched_path_fallbacks": "batched-grower bailouts to the strict path",
@@ -49,6 +54,17 @@ COUNTERS: Dict[str, str] = {
         "XLA backend compiles observed by the obs/ compile-event listener",
     "xla_program_lowerings":
         "jaxpr->MLIR lowerings observed by the obs/ compile-event listener",
+    "jaxpr_trace_s":
+        "seconds tracing Python into jaxprs, outermost traces only "
+        "(obs/ compile-event listener)",
+    "xla_lowering_s":
+        "seconds lowering jaxprs to MLIR modules (compile-event listener)",
+    "xla_backend_compile_s":
+        "seconds in the XLA backend compiler, persistent-cache "
+        "retrieval taken out (compile-event listener)",
+    "xla_cache_load_s":
+        "seconds retrieving executables from the persistent compile "
+        "cache (compile-event listener)",
     "collective_allreduce_bytes_est":
         "estimated bytes all-reduced across workers (data-parallel)",
     "nan_guard_trips": "rounds where the numeric guard saw non-finite values",

@@ -23,12 +23,13 @@ Design constraints:
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 #: the process-wide active recorder; ``None`` = tracing disabled (the
 #: one-word fast-path check every instrumentation point makes first)
@@ -181,19 +182,21 @@ def emit_complete(name: str, t0_perf: float, dur_s: float,
     rec.add_complete(name, (t0_perf - rec._t0) * 1e6, dur_s * 1e6, args)
 
 
-@contextlib.contextmanager
-def span(name: str, **args: Any) -> Iterator[None]:
-    """Trace a code region; a single ``is None`` check when disabled."""
-    rec = _ACTIVE
-    if rec is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        emit_complete(name, t0, time.perf_counter() - t0,
-                      args if args else None)
+#: prefix of the program's spans in a profiler trace (``.xplane.pb``)
+ANNOTATION_PREFIX = "lgbtpu."
+
+
+def annotate(name: str, counts: Dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation("lgbtpu.<name>",
+    **counts)`` when a profiler session is collecting (``profile_dir=``,
+    or anyone's ``jax.profiler.start_trace``), else ``None`` after the
+    one activity check: nothing is formatted or allocated.  The caller
+    (utils/timer.py ``phase``, the one span entry point) exits it."""
+    if not _TraceAnnotation.is_enabled():
+        return None
+    ann = _TraceAnnotation(ANNOTATION_PREFIX + name, **counts)
+    ann.__enter__()
+    return ann
 
 
 def counter(name: str, values: Dict[str, Any]) -> None:

@@ -129,13 +129,16 @@ def histogram_pallas(bins_t: jax.Array, vals_t: jax.Array, *, n_bins: int,
     c = vals_t.shape[0]
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        vals_t = jnp.pad(vals_t, ((0, 0), (0, n_pad - n)))
-    fc = _pick_fc(num_f, feats_per_chunk)
-    f_pad = _round_up(num_f, fc)
-    if f_pad != num_f:
-        bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
+    # hist_compact (docs/OBSERVABILITY.md): the pads that prepare the
+    # kernel's operands are data movement, not the kernel
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
+            vals_t = jnp.pad(vals_t, ((0, 0), (0, n_pad - n)))
+        fc = _pick_fc(num_f, feats_per_chunk)
+        f_pad = _round_up(num_f, fc)
+        if f_pad != num_f:
+            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
     nb = n_pad // blk
 
     def kernel(bins_ref, vals_ref, out_ref):
@@ -210,20 +213,21 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        row_pad = ((0, n_pad - n), (0, 0)) if rows_major \
-            else ((0, 0), (0, n_pad - n))
-        bins = jnp.pad(bins, row_pad)
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
-                              constant_values=-1)
-    fc = _pick_fc(num_f, feats_per_chunk)
-    f_pad = _round_up(num_f, fc)
-    if f_pad != num_f:
-        feat_pad = ((0, 0), (0, f_pad - num_f)) if rows_major \
-            else ((0, f_pad - num_f), (0, 0))
-        bins = jnp.pad(bins, feat_pad)
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            row_pad = ((0, n_pad - n), (0, 0)) if rows_major \
+                else ((0, 0), (0, n_pad - n))
+            bins = jnp.pad(bins, row_pad)
+            grad = jnp.pad(grad, (0, n_pad - n))
+            hess = jnp.pad(hess, (0, n_pad - n))
+            leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
+                                  constant_values=-1)
+        fc = _pick_fc(num_f, feats_per_chunk)
+        f_pad = _round_up(num_f, fc)
+        if f_pad != num_f:
+            feat_pad = ((0, 0), (0, f_pad - num_f)) if rows_major \
+                else ((0, f_pad - num_f), (0, 0))
+            bins = jnp.pad(bins, feat_pad)
     nb = n_pad // blk
     grad2 = grad[None, :]
     hess2 = hess[None, :]
@@ -336,10 +340,11 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(S, 128)))
     s_pad = _round_up(max(S, 1), blk)
-    if s_pad != S:
-        # pad rows land at positions >= S >= cnt: excluded by the
-        # position guard regardless of content
-        payload = jnp.pad(payload, ((0, s_pad - S), (0, 0)))
+    with jax.named_scope("hist_compact"):
+        if s_pad != S:
+            # pad rows land at positions >= S >= cnt: excluded by the
+            # position guard regardless of content
+            payload = jnp.pad(payload, ((0, s_pad - S), (0, 0)))
     nb = s_pad // blk
     f_pad = 4 * W
     prec = _prec(compute_dtype)
@@ -459,13 +464,14 @@ def histogram_leaves_packed_pallas(words_t: jax.Array, grad: jax.Array,
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        # pad rows carry word 0 and lor -1: excluded by the sel mask
-        words_t = jnp.pad(words_t, ((0, 0), (0, n_pad - n)))
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
-                              constant_values=-1)
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            # pad rows carry word 0 and lor -1: excluded by the sel mask
+            words_t = jnp.pad(words_t, ((0, 0), (0, n_pad - n)))
+            grad = jnp.pad(grad, (0, n_pad - n))
+            hess = jnp.pad(hess, (0, n_pad - n))
+            leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
+                                  constant_values=-1)
     nb = n_pad // blk
     f_pad = 4 * W
 
@@ -581,15 +587,16 @@ def histogram_leaves_radix2_pallas(bins_t: jax.Array, grad: jax.Array,
     NW = 3 * K * p * nlo
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
-                              constant_values=-1)
-    f_pad = _round_up(num_f, p)
-    if f_pad != num_f:
-        bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
+            grad = jnp.pad(grad, (0, n_pad - n))
+            hess = jnp.pad(hess, (0, n_pad - n))
+            leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
+                                  constant_values=-1)
+        f_pad = _round_up(num_f, p)
+        if f_pad != num_f:
+            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
     nch = f_pad // p
     nb = n_pad // blk
     prec = _prec(compute_dtype)
@@ -761,14 +768,15 @@ def histogram_radix_single_pallas(bins_t: jax.Array, grad: jax.Array,
     nhi, nlo, M, NW = _radix_shapes(n_bins, p)
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-    f_pad = _round_up(num_f, p)
-    if f_pad != num_f:
-        bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
+            grad = jnp.pad(grad, (0, n_pad - n))
+            hess = jnp.pad(hess, (0, n_pad - n))
+            lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
+        f_pad = _round_up(num_f, p)
+        if f_pad != num_f:
+            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
     nch = f_pad // p
     nb = n_pad // blk
     prec = _prec(compute_dtype)
@@ -840,14 +848,15 @@ def histogram_radix_joint_pallas(bins_t: jax.Array, grad: jax.Array,
     M = G * M1
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        grad = jnp.pad(grad, (0, n_pad - n))
-        hess = jnp.pad(hess, (0, n_pad - n))
-        lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-    f_pad = _round_up(num_f, p)
-    if f_pad != num_f:
-        bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
+    with jax.named_scope("hist_compact"):
+        if n_pad != n:
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
+            grad = jnp.pad(grad, (0, n_pad - n))
+            hess = jnp.pad(hess, (0, n_pad - n))
+            lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
+        f_pad = _round_up(num_f, p)
+        if f_pad != num_f:
+            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
     nch = f_pad // p
     nb = n_pad // blk
     prec = _prec(compute_dtype)

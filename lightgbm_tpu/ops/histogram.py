@@ -524,23 +524,30 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
     assert n < (1 << 30), "compaction packing needs n < 2^30 rows per shard"
     num_f = bins_rows.shape[1]
 
-    if counts is not None:
-        cnt = jnp.sum(counts).astype(jnp.int32)
-    else:
-        sel = jnp.any(lor[None, :] == leaves[:, None], axis=0)    # [n]
-        cnt = jnp.sum(sel.astype(jnp.int32))
-    if sort_key is None:
+    # Device scopes (docs/OBSERVABILITY.md): ``hist_compact`` is every
+    # movement of data that prepares a kernel's operands (selection keys,
+    # payload concatenate, sort, row gather), ``hist_kernel`` the kernels
+    # themselves; each ``lax.switch`` branch sits in a ``hist_rows_<S>``
+    # of its static row count, so a trace says how many rows each pass
+    # was handed.  Metadata only: the operations are unchanged.
+    with jax.named_scope("hist_compact"):
         if counts is not None:
-            sel = jnp.any(lor[None, :] == leaves[:, None], axis=0)
-        # pack (selected?, row) into ONE i32 and single-sort in the
-        # branch — the first ``cnt`` sorted entries are exactly the
-        # selected rows in order.  A non-stable single-operand sort costs
-        # ~0.4 ms/1M on TPU vs ~1.4 ms for stable argsort and ~9 ms for
-        # sized ``nonzero`` (docs/PERF_NOTES.md).
-        iota_n = lax.iota(jnp.int32, n)
-        sort_key = jnp.where(sel, iota_n, iota_n | (1 << 30))
-    if bins_words is None:
-        bins_words = bins_to_words(bins_rows)
+            cnt = jnp.sum(counts).astype(jnp.int32)
+        else:
+            sel = jnp.any(lor[None, :] == leaves[:, None], axis=0)  # [n]
+            cnt = jnp.sum(sel.astype(jnp.int32))
+        if sort_key is None:
+            if counts is not None:
+                sel = jnp.any(lor[None, :] == leaves[:, None], axis=0)
+            # pack (selected?, row) into ONE i32 and single-sort in the
+            # branch — the first ``cnt`` sorted entries are exactly the
+            # selected rows in order.  A non-stable single-operand sort
+            # costs ~0.4 ms/1M on TPU vs ~1.4 ms for stable argsort and
+            # ~9 ms for sized ``nonzero`` (docs/PERF_NOTES.md).
+            iota_n = lax.iota(jnp.int32, n)
+            sort_key = jnp.where(sel, iota_n, iota_n | (1 << 30))
+        if bins_words is None:
+            bins_words = bins_to_words(bins_rows)
     W = bins_words.shape[1]
 
     blk = min(rows_per_block, 2048)
@@ -551,14 +558,22 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             sizes.append(s)
 
     def full_branch(operands):
-        return histogram_for_leaves_masked(
-            bins_t, grad, hess, lor, leaves, None, n_bins=n_bins,
-            rows_per_block=rows_per_block, hist_dtype=hist_dtype,
-            hist_kernel=hist_kernel, bins_words_t=bins_words_t)
+        with jax.named_scope("hist_rows_full"), \
+                jax.named_scope("hist_kernel"):
+            return histogram_for_leaves_masked(
+                bins_t, grad, hess, lor, leaves, None, n_bins=n_bins,
+                rows_per_block=rows_per_block, hist_dtype=hist_dtype,
+                hist_kernel=hist_kernel, bins_words_t=bins_words_t)
 
     def make_branch(S: int):
         def branch(operands):
-            key_, grad_, hess_, lor_ = operands
+            with jax.named_scope(f"hist_rows_{S}"):
+                return compacted(S, operands)
+        return branch
+
+    def compacted(S: int, operands):
+        key_, grad_, hess_, lor_ = operands
+        with jax.named_scope("hist_compact"):
             # One payload matrix holding (bin words, grad, hess, leaf)
             # so the branch does a SINGLE contiguous row gather —
             # separate gathers are DMA-descriptor bound (~9 ns/row
@@ -574,6 +589,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             ], axis=1)                                        # [n, W+3] i32
             idxc = jnp.sort(key_, stable=False)[:S] & ((1 << 30) - 1)
             pc = payload[idxc]                                # [S, W+3]
+        with jax.named_scope("hist_kernel"):
             if _use_payload_kernel():
                 from .hist_pallas import histogram_payload_pallas
                 return histogram_payload_pallas(
@@ -594,14 +610,15 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                                      leaves, n_bins=n_bins,
                                      rows_per_block=rows_per_block,
                                      hist_dtype=hist_dtype)
-        return branch
 
     branches = [full_branch] + [make_branch(s) for s in sizes]
-    j = jnp.int32(0)
-    for k, s in enumerate(sizes):  # sizes descending: smallest fit wins
-        j = jnp.where(cnt <= s, jnp.int32(k + 1), j)
+    with jax.named_scope("hist_compact"):
+        j = jnp.int32(0)
+        for k, s in enumerate(sizes):  # sizes descending: smallest fit wins
+            j = jnp.where(cnt <= s, jnp.int32(k + 1), j)
     hist = lax.switch(j, branches, (sort_key, grad, hess, lor))
-    return reduce_hist(hist, axis_name, overlap)
+    with jax.named_scope("hist_kernel"):
+        return reduce_hist(hist, axis_name, overlap)
 
 
 def histogram_for_leaf_bucketed(bins: jax.Array, grad: jax.Array,
@@ -676,20 +693,25 @@ def root_histogram(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                    overlap: bool = False) -> jax.Array:
     """Root histogram from the TRANSPOSED [F, n] bin matrix."""
     hist_kernel = resolve_hist_kernel(hist_kernel)
-    if use_pallas() or _MODE_TEST_INTERPRET:
-        # single-leaf delegation picks the mode kernel (radix single
-        # under auto when bins allow, packed/radix2/flat otherwise)
-        lor = jnp.zeros(grad.shape, jnp.int32)
-        return histogram_for_leaf_masked(
-            bins_t, grad, hess, lor, jnp.int32(0), row_mask, n_bins=n_bins,
-            rows_per_block=rows_per_block, hist_dtype=hist_dtype,
-            axis_name=axis_name, hist_kernel=hist_kernel,
-            bins_words_t=bins_words_t, overlap=overlap)
-    m = jnp.ones_like(grad) if row_mask is None else row_mask.astype(grad.dtype)
-    vals_t = jnp.stack([jnp.where(m > 0, grad, 0.0),
-                        jnp.where(m > 0, hess, 0.0), m,
-                        jnp.zeros_like(m)], axis=0)
-    hist = histogram_rows_t(bins_t, vals_t, n_bins=n_bins,
-                            rows_per_block=rows_per_block,
-                            hist_dtype=hist_dtype)
-    return reduce_hist(hist, axis_name, overlap)
+    # a full pass over every row, under the same scopes as the full
+    # branch of ``histogram_for_leaves_auto``
+    with jax.named_scope("hist_rows_full"), jax.named_scope("hist_kernel"):
+        if use_pallas() or _MODE_TEST_INTERPRET:
+            # single-leaf delegation picks the mode kernel (radix single
+            # under auto when bins allow, packed/radix2/flat otherwise)
+            lor = jnp.zeros(grad.shape, jnp.int32)
+            return histogram_for_leaf_masked(
+                bins_t, grad, hess, lor, jnp.int32(0), row_mask,
+                n_bins=n_bins, rows_per_block=rows_per_block,
+                hist_dtype=hist_dtype, axis_name=axis_name,
+                hist_kernel=hist_kernel, bins_words_t=bins_words_t,
+                overlap=overlap)
+        m = jnp.ones_like(grad) if row_mask is None \
+            else row_mask.astype(grad.dtype)
+        vals_t = jnp.stack([jnp.where(m > 0, grad, 0.0),
+                            jnp.where(m > 0, hess, 0.0), m,
+                            jnp.zeros_like(m)], axis=0)
+        hist = histogram_rows_t(bins_t, vals_t, n_bins=n_bins,
+                                rows_per_block=rows_per_block,
+                                hist_dtype=hist_dtype)
+        return reduce_hist(hist, axis_name, overlap)

@@ -139,9 +139,9 @@ def train_fused_gspmd(mesh: Optional[Mesh], bins: jax.Array,
            learning_rate, batch, objective, quantize, seed,
            sig((bins, scores, label, num_bins, nan_bin, is_cat)))
     fn = get_or_build(key, build, metrics=metrics)
-    from ..obs import trace as obs_trace
-    with obs_trace.span("gspmd_fused_dispatch", rounds=int(num_rounds),
-                        devices=int(mesh.devices.size)):
+    from ..utils.timer import phase
+    with phase("gspmd_fused_dispatch", rounds=int(num_rounds),
+               devices=int(mesh.devices.size)):
         return fn(row_sharded(mesh, bins), row_sharded(mesh, scores),
                   row_sharded(mesh, label), replicated(mesh, num_bins),
                   replicated(mesh, nan_bin), replicated(mesh, is_cat))

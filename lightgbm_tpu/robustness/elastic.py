@@ -450,8 +450,8 @@ class ElasticSession:
     def _train_epochs(self):
         from ..basic import Dataset
         from ..engine import train as _train
-        from ..obs import trace as obs_trace
         from ..parallel.mesh import device_window
+        from ..utils.timer import phase
 
         live = list(range(self.n_workers))
         epoch = 0
@@ -466,8 +466,8 @@ class ElasticSession:
             try:
                 # each epoch is a nested scope on the merged timeline:
                 # the reshape boundary shows as a span break
-                with obs_trace.span("elastic_epoch", epoch=epoch,
-                                    mesh=len(live)), \
+                with phase("elastic_epoch", epoch=epoch,
+                           mesh=len(live)), \
                         device_window(len(live)):
                     ds = Dataset(self.X, label=self.y)
                     booster = _train(dict(self.params), ds,

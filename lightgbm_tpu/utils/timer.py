@@ -12,50 +12,83 @@ context manager.  Two scopes exist since the telemetry round:
     alive boosters never clobber each other's tables; exposed through
     ``Booster.telemetry()``.
 
-``phase(name, *timers)`` times one region into every ENABLED timer with a
-single pair of clock reads, and — when a trace recorder is active
-(obs/trace.py, ``trace_output=...``) — emits the same interval as a span
-event.  Disabled timers with no active trace cost one tuple scan and an
-``is None`` check.
+``phase(name, *timers, **counts)`` is the package's ONE span entry
+point.  It times one region into every ENABLED timer with a single pair
+of clock reads, emits the same interval to the Chrome-JSON recorder when
+one is active (obs/trace.py, ``trace_output=...``), and enters a
+``jax.profiler.TraceAnnotation("lgbtpu.<name>", **counts)`` whenever a
+profiler session is collecting (``profile_dir=...`` or anyone's
+``jax.profiler.start_trace``), so the program's host spans sit in the
+same ``.xplane.pb``, on the same clock, as the device's ``XLA Ops``.
+With no session, no recorder and no enabled timer a span costs the
+profiler's activity check, a tuple scan and an ``is None`` check:
+nothing is formatted, timed or appended.  Spans stay at job, dispatch
+and tree granularity — never per row, per leaf or inside jitted code.
 
 Device work is asynchronous under jit, so phases that end with a host sync
 (eval, metric reads) absorb queued device time — same caveat as any
-wall-clock profile of an async runtime; use the ``profile_dir`` hook
-(``jax.profiler`` traces) for kernel-level attribution.
+wall-clock profile of an async runtime; device time is attributed by the
+``jax.named_scope``s of the round program (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
 import collections
-import contextlib
 import threading
 import time
-from typing import Dict, Iterator
+from typing import Any, Dict
 
 from ..obs import trace as _trace
 
 
-@contextlib.contextmanager
-def phase(name: str, *timers: "PhaseTimer") -> Iterator[None]:
-    """Time one phase into every enabled timer AND the active trace."""
-    on = [t for t in timers if t.enabled]
-    tracing = _trace.active() is not None
-    if not on and not tracing:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        for t in on:
-            # the global timer is shared across concurrently training
-            # boosters; an unlocked += drops accumulations under threads
-            with t._lock:
-                t._acc[name] += dt
-                t._count[name] += 1
-        if tracing:
-            _trace.emit_complete(name, t0, dt)
+class phase:
+    """Context manager for one named span (see the module docstring).
+    ``counts`` are small scalars describing the region (``rounds=8``);
+    they become the recorder event's ``args`` and the annotation's
+    stats.  An annotation takes its counts when it OPENS, so a span that
+    reports results is opened once they are known (``dispatch_done``)."""
+
+    __slots__ = ("name", "counts", "_timers", "_t0", "_ann")
+
+    def __init__(self, name: str, *timers: "PhaseTimer",
+                 **counts: Any) -> None:
+        self.name = name
+        self.counts = counts
+        self._timers = timers
+        self._t0 = None
+        self._ann = None
+
+    def also(self, timer: "PhaseTimer") -> None:
+        """Time the REST of this open span into ``timer`` as well (a
+        job's root span opens before its booster, and so before the
+        booster's own timer, exists)."""
+        self._timers += (timer,)
+        if self._t0 is None and any(t.enabled for t in self._timers):
+            self._t0 = time.perf_counter()
+
+    def __enter__(self) -> "phase":
+        self._ann = _trace.annotate(self.name, self.counts)
+        if _trace.active() is not None or \
+                any(t.enabled for t in self._timers):
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t0 = self._t0
+        if t0 is not None:
+            dt = time.perf_counter() - t0
+            for t in self._timers:
+                if t.enabled:
+                    # the global timer is shared across concurrently
+                    # training boosters; an unlocked += drops
+                    # accumulations under threads
+                    with t._lock:
+                        t._acc[self.name] += dt
+                        t._count[self.name] += 1
+            _trace.emit_complete(self.name, t0, dt, self.counts or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 class PhaseTimer:
