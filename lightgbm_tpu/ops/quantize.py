@@ -23,7 +23,12 @@ i.e. ~8.3M rows at the default 4 levels — beyond that, sums round at
 bit-width selection, gradient_discretizer.hpp).
 
 ``quant_train_renew_leaf`` recomputes final leaf outputs from the TRUE
-gradients (reference ``RenewIntGradTreeOutput``).
+gradients (reference ``RenewIntGradTreeOutput``): per-leaf sums of the
+f32 gradients and hessians by ``leaf_of_row``.  On the TPU they come from
+``ops/table.py sum_small_table``, the second of a pair of one-hot MXU
+kernels over a table bounded by ``num_leaves``: ``take_small_table``
+looks leaf values up FROM the table (the score update),
+``sum_small_table`` sums row values INTO it (this renewal).
 """
 
 from __future__ import annotations
@@ -34,6 +39,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .table import sum_small_table
 
 
 def _pow2_ceil(x: jax.Array) -> jax.Array:
@@ -122,19 +129,22 @@ def discretize_gradients_levels(grad: jax.Array, hess: jax.Array,
     return gi, hi, g_scale, h_scale
 
 
-@functools.partial(jax.jit, static_argnames=("num_leaves",))
+@jax.jit
+def _leaf_outputs(gsum, hsum, lambda_l1, lambda_l2):
+    t = jnp.sign(gsum) * jnp.maximum(jnp.abs(gsum) - lambda_l1, 0.0)
+    return -t / (hsum + lambda_l2 + 1e-15)
+
+
 def renew_leaf_values(leaf_of_row: jax.Array, grad: jax.Array,
                       hess: jax.Array, row_mask: Optional[jax.Array],
                       num_leaves: int, lambda_l1: float,
                       lambda_l2: float) -> jax.Array:
     """Exact leaf outputs from TRUE gradients after a quantized-structure
     tree (reference gradient_discretizer.hpp RenewIntGradTreeOutput):
-    out[l] = -T(sum g_l) / (sum h_l + l2) with L1 soft-threshold T."""
-    L = num_leaves
-    m = jnp.ones_like(grad) if row_mask is None else row_mask.astype(grad.dtype)
-    gsum = jnp.zeros((L,), grad.dtype).at[leaf_of_row].add(
-        jnp.where(m > 0, grad, 0.0))
-    hsum = jnp.zeros((L,), hess.dtype).at[leaf_of_row].add(
-        jnp.where(m > 0, hess, 0.0))
-    t = jnp.sign(gsum) * jnp.maximum(jnp.abs(gsum) - lambda_l1, 0.0)
-    return -t / (hsum + lambda_l2 + 1e-15)
+    out[l] = -T(sum g_l) / (sum h_l + l2) with L1 soft-threshold T.
+
+    Not jitted as a whole: ``sum_small_table`` picks its path from where
+    the rows live (backend, devices, sharding), which a trace hides."""
+    gsum, hsum = sum_small_table(leaf_of_row, grad, hess, row_mask,
+                                 num_leaves)
+    return _leaf_outputs(gsum, hsum, lambda_l1, lambda_l2)

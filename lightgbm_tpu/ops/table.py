@@ -1,17 +1,28 @@
-"""Small-table row lookups without lane-dim gathers.
+"""Small tables by row index, without lane-dim gathers or scatters.
 
-``table[idx]`` for a [n]-sized index vector is the slowest primitive on TPU
-(~8 ms per 1M rows through XLA's gather, docs/PERF_NOTES.md) yet the GBDT
-score update needs exactly that: ``scores += lr * leaf_value[leaf_of_row]``
-(reference score_updater.hpp:21 AddScore).  For tables bounded by num_leaves
-(<= a few hundred) the lookup is reformulated as a VMEM one-hot contraction:
-per row block, onehot(idx) @ table rides the MXU and costs ~0.3 ms/1M —
-~25x faster than the gather.
+A pair of kernels over one [n]-sized index vector and a table bounded by
+``num_leaves`` (<= 2048 entries), one the transpose of the other:
+
+``take_small_table``: ``table[idx]``, the lookup FROM the table.  It is
+the slowest primitive on TPU (~8 ms per 1M rows through XLA's gather,
+docs/PERF_NOTES.md) yet the GBDT score update needs exactly that:
+``scores += lr * leaf_value[leaf_of_row]`` (reference
+score_updater.hpp:21 AddScore).  Reformulated as a VMEM one-hot
+contraction: per row block, onehot(idx) @ table rides the MXU and costs
+~0.3 ms/1M — ~25x faster than the gather.
+
+``sum_small_table``: ``zeros(T).at[idx].add(values)``, the sums INTO the
+table, for two value vectors in one pass over the rows (leaf renewal's
+per-leaf sums of the true gradients and hessians, ops/quantize.py
+``renew_leaf_values``).  XLA's scatter-add ran at 0.9 GB/s on the v5e
+(116 ms per 13.3M rows and vector, PERF.md PR 28); the same one-hot,
+contracted over the rows instead of over the table, is one MXU pass.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,10 +91,18 @@ def _take_pallas(idx: jax.Array, table: jax.Array, *,
     return out[0, :n]
 
 
-def _even_row_sharding(idx):
-    """``(mesh, spec)`` when ``idx``'s rows are split evenly over a
-    one-axis device mesh (the leaf map a shard_map grower returns),
-    else ``None``."""
+def _kernel_rows(idx, size: int):
+    """Over which rows a kernel of this module may run, by what the code
+    can observe: ``"whole"`` on a TPU for a table of ``size <= 2048``
+    when ``idx`` is traced or on one device; ``(mesh, spec)`` when its
+    rows are split evenly over a one-axis device mesh (the leaf map a
+    shard_map grower returns), for a run per shard; ``None`` for any
+    other placement and off the TPU, where the XLA form answers (it can
+    be partitioned, a Mosaic kernel cannot)."""
+    if jax.default_backend() != "tpu" or size > 2048 or idx.ndim != 1:
+        return None
+    if isinstance(idx, jax.core.Tracer) or len(idx.devices()) == 1:
+        return "whole"
     sh = idx.sharding
     if (isinstance(sh, NamedSharding) and len(sh.spec) == 1
             and isinstance(sh.spec[0], str)
@@ -108,17 +127,155 @@ def take_small_table(table: jax.Array, idx: jax.Array) -> jax.Array:
 
     Out-of-range indices (e.g. -1) return 0.0.
     """
-    if (jax.default_backend() == "tpu"
-            and table.shape[0] <= 2048 and idx.ndim == 1):
+    rows = _kernel_rows(idx, table.shape[0])
+    if rows is not None:
         idx = jnp.asarray(idx, jnp.int32)
         table = jnp.asarray(table, jnp.float32)
-        if isinstance(idx, jax.core.Tracer) or len(idx.devices()) == 1:
+        if rows == "whole":
             return _take_pallas(idx, table)
-        rows = _even_row_sharding(idx)
-        if rows is not None:
-            return _take_per_shard(*rows)(idx, table)
-        # any other multi-device placement: the XLA lookup below can be
-        # partitioned, the kernel cannot
+        return _take_per_shard(*rows)(idx, table)
     safe = jnp.clip(idx, 0, table.shape[0] - 1)
     ok = (idx >= 0) & (idx < table.shape[0])
     return jnp.where(ok, table[safe], 0.0)
+
+
+# ---------------------------------------------------------------- the sums
+_PARTS = 3   # an f32 is the exact sum of three bfloat16 (3 x 8 significand bits)
+
+
+def _bf16_parts(v):
+    """``v`` f32 as three bfloat16 whose f32 sum is ``v`` exactly (round
+    to nearest leaves a residual of at most 16, then 8, significant
+    bits), returned widened to f32."""
+    parts = []
+    for _ in range(_PARTS):
+        p = v.astype(jnp.bfloat16).astype(jnp.float32)
+        parts.append(p)
+        v = v - p
+    return parts
+
+
+@functools.partial(jax.jit, static_argnames=("size", "rows_per_block",
+                                             "rows_per_dot", "interpret"))
+def _sum_pallas(idx: jax.Array, g: jax.Array, h: jax.Array,
+                mask: Optional[jax.Array], *, size: int,
+                rows_per_block: int = 16384, rows_per_dot: int = 1024,
+                interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """The transpose of ``_take_pallas``: f32 ``[size]`` sums of ``g`` and
+    of ``h`` by ``idx``, one pass over the rows.
+
+    Per row block the values are split into exact bfloat16 parts, spread
+    over the high one-hot ``[parts * nhi, rows]`` and contracted over the
+    rows against the low one-hot ``[nlo, rows]``: the products are a part
+    times 0 or 1, exact, and the MXU accumulates them in f32.  The output
+    block stays in VMEM across the grid.  Rows past ``n`` (the last
+    block's tail; no operand is padded), rows whose ``mask`` is not
+    positive and indices outside ``[0, size)`` add nothing.  A non-finite
+    value spoils up to ``nlo`` sums (NaN x 0) where the scatter-add
+    spoils one.
+    """
+    n = idx.shape[0]
+    # idx = nlo * hi + lo: the low one-hot fills the MXU's 128 output
+    # lanes, the high one (times the value parts) its streamed rows, in
+    # whole f32 sublane tiles
+    nlo, shift = 128, 7
+    nhi = _round_up(pl.cdiv(max(size, 1), nlo), 8)
+    rows = 2 * _PARTS * nhi
+    cap = max(128, _round_up(n, 128))
+    sub = min(rows_per_dot, cap)        # bounds the one-hots' VMEM
+    blk = _round_up(min(rows_per_block, cap), sub)
+    operands = [jnp.asarray(idx, jnp.int32)[None, :],
+                jnp.asarray(g, jnp.float32)[None, :],
+                jnp.asarray(h, jnp.float32)[None, :]]
+    if mask is not None:
+        operands.append((mask > 0).astype(jnp.int32)[None, :])
+
+    def kernel(*refs):
+        idx_ref, g_ref, h_ref = refs[:3]
+        m_ref = refs[3] if mask is not None else None
+        out_ref = refs[-1]
+        step = pl.program_id(0)
+
+        @pl.when(step == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        iota_h = lax.broadcasted_iota(jnp.int32, (nhi, sub), 0)
+        iota_l = lax.broadcasted_iota(jnp.int32, (nlo, sub), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (1, sub), 1)
+        acc = jnp.zeros((rows, nlo), jnp.float32)
+        for c in range(blk // sub):
+            cols = slice(c * sub, (c + 1) * sub)
+            ok = step * blk + c * sub + lane < n
+            if m_ref is not None:
+                ok &= m_ref[:, cols] > 0
+            ix = jnp.where(ok, idx_ref[:, cols], -1)             # [1, sub]
+            oh_hi = (ix >> shift) == iota_h                      # [nhi, sub]
+            oh_lo = ((ix & (nlo - 1)) == iota_l).astype(jnp.bfloat16)
+            spread = jnp.concatenate(
+                [jnp.where(oh_hi, p, 0.0)
+                 for ref in (g_ref, h_ref)
+                 for p in _bf16_parts(ref[:, cols])], axis=0)    # [rows, sub]
+            acc += lax.dot_general(
+                spread.astype(jnp.bfloat16), oh_lo,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)              # [rows, nlo]
+        out_ref[...] += acc
+
+    row_block = pl.BlockSpec((1, blk), lambda i: (0, i))
+    out = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, blk),),
+        in_specs=[row_block] * len(operands),
+        out_specs=pl.BlockSpec((rows, nlo), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, nlo), jnp.float32),
+        interpret=interpret,
+    )(*operands)
+    # [(value, part, hi), lo] -> [value, entry], small parts first
+    parts = out.reshape(2, _PARTS, nhi * nlo)
+    sums = (parts[:, 2] + parts[:, 1]) + parts[:, 0]
+    return sums[0, :size], sums[1, :size]
+
+
+def _sum_per_shard(mesh, spec, masked: bool, size: int):
+    """``_sum_pallas`` over row-sharded operands: each device sums its
+    own rows (a Mosaic kernel cannot be partitioned automatically) and a
+    ``psum`` adds the ``[size]`` partial sums."""
+    def local(idx, g, h, mask=None):
+        return lax.psum(_sum_pallas(idx, g, h, mask, size=size), spec[0])
+
+    return get_or_build(
+        ("sum_small_table_sharded", mesh_signature(mesh), spec, masked, size),
+        lambda: jax.jit(shard_map(
+            local, mesh=mesh, in_specs=(spec,) * (3 + masked),
+            out_specs=P(), check_vma=False)))
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _sum_scatter(idx, g, h, mask, *, size: int):
+    """The reference and the fallback: XLA's scatter-add, twice.  (A
+    negative index wraps round here, as jnp's indexing does; the leaf
+    map has none.)"""
+    m = jnp.ones_like(g) if mask is None else mask.astype(g.dtype)
+    gsum = jnp.zeros((size,), g.dtype).at[idx].add(jnp.where(m > 0, g, 0.0))
+    hsum = jnp.zeros((size,), h.dtype).at[idx].add(jnp.where(m > 0, h, 0.0))
+    return gsum, hsum
+
+
+def sum_small_table(idx: jax.Array, g: jax.Array, h: jax.Array,
+                    mask: Optional[jax.Array], size: int
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """``zeros(size).at[idx].add(g)`` and the same of ``h``, for i32
+    ``idx`` [n] and f32 ``g``, ``h`` [n]; rows whose ``mask`` is not
+    positive and indices outside ``[0, size)`` add nothing.
+
+    Routed as ``take_small_table`` is (``_kernel_rows``): the kernel,
+    the kernel per shard plus a ``psum``, or XLA's scatter-add.
+    """
+    rows = _kernel_rows(idx, size)
+    if rows is None:
+        return _sum_scatter(idx, g, h, mask, size=size)
+    if rows == "whole":
+        return _sum_pallas(idx, g, h, mask, size=size)
+    args = (idx, g, h) if mask is None else (idx, g, h, mask)
+    return _sum_per_shard(*rows, mask is not None, size)(*args)

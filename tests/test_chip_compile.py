@@ -26,12 +26,14 @@ from jax.sharding import SingleDeviceSharding
 from lightgbm_tpu.learner import batch_grower
 from lightgbm_tpu.ops import histogram as H
 from lightgbm_tpu.ops.hist_pallas import histogram_payload_pallas
-from lightgbm_tpu.ops.table import _take_per_shard, take_small_table
+from lightgbm_tpu.ops.table import (_sum_per_shard, _take_per_shard,
+                                    sum_small_table, take_small_table)
 
 F = 28                      # Higgs features
 W = 7                       # packed words per row (4 bins each)
 N = 1_048_576
 HIGGS_ROWS_PADDED = 10_500_096   # 10.5M rounded up to the 2048-row block
+CRITEO_SHARE_ROWS = 13_281_250   # the benchmark's cells: no block divides it
 
 
 @pytest.fixture(scope="module")
@@ -163,6 +165,94 @@ def test_take_small_table_compiles_per_shard_for_four_chips(topo):
                                  sharding=NamedSharding(mesh, P()))
     c = _take_per_shard(mesh, P("data")).lower(idx, table).compile()
     _assert_kernel(c, "_take_pallas")
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_rows", "masked"])
+def test_sum_small_table_kernel_compiles_at_the_benchmark_rows(
+        one_chip, on_tpu, masked):
+    """Leaf renewal's sums at the cells' own row count, whose tail the
+    kernel masks by row number: no operand is padded, so the program
+    holds no temporary at all."""
+    n = CRITEO_SHARE_ROWS
+    rows = [((n,), jnp.int32), ((n,), jnp.float32), ((n,), jnp.float32)]
+    if masked:
+        c = _compile(one_chip, lambda i, g, h, m: sum_small_table(
+            i, g, h, m, 255), *rows, ((n,), jnp.bool_))
+    else:
+        c = _compile(one_chip, lambda i, g, h: sum_small_table(
+            i, g, h, None, 255), *rows)
+    _assert_kernel(c, "_sum_pallas")
+    assert "scatter" not in c.as_text()
+    # the mask alone is widened for the kernel (bool -> i32, 53 MB)
+    assert c.memory_analysis().temp_size_in_bytes <= (4 * n + 4096) * masked
+
+
+def test_sum_small_table_compiles_per_shard_for_four_chips(topo):
+    """tree_learner=data renews leaves from row-sharded gradients and a
+    row-sharded leaf map: the kernel runs on each chip's rows and one
+    all-reduce adds the [255] partial sums (the bare kernel is refused
+    there, as the score update's was in PR 24)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    avals = [jax.ShapeDtypeStruct((N,), d, sharding=rows)
+             for d in (jnp.int32, jnp.float32, jnp.float32, jnp.bool_)]
+    for masked in (False, True):
+        c = _sum_per_shard(mesh, P("data"), masked, 255).lower(
+            *avals[:3 + masked]).compile()
+        _assert_kernel(c, "_sum_pallas")
+        assert "all-reduce" in c.as_text()
+
+
+def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
+                                                           monkeypatch):
+    """The round program ``train_fused`` builds for a quantised job,
+    compiled for the described chip: ``leaf_renew`` holds the kernel by
+    name (what the benchmark's breakdown and chip_smoke.py look for) and
+    the scatter-add is gone from it."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting import gbdt as gbdt_mod
+    from lightgbm_tpu.ops.compile_cache import GLOBAL_COMPILE_CACHE
+
+    class Captured(Exception):
+        pass
+
+    captured = {}
+    real = gbdt_mod.cc_get_or_build
+
+    def spy(key, build, **kw):
+        fn = real(key, build, **kw)
+
+        def call(*args):
+            # the booster was built on the CPU; only this trace sees a TPU
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            captured["text"] = fn.lower(*avals).compile().as_text()
+            raise Captured
+        return call
+
+    monkeypatch.setattr(gbdt_mod, "cc_get_or_build", spy)
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(3210, 8))
+    y = (X @ rng.normal(size=8) > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+              "min_data_in_leaf": 5, "tpu_split_batch": 4,
+              "use_quantized_grad": True, "quant_train_renew_leaf": True}
+    try:
+        with pytest.raises(Captured):
+            lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                      num_boost_round=8)
+    finally:
+        GLOBAL_COMPILE_CACHE.clear()     # a runner traced for the TPU
+    calls = [line for line in captured["text"].splitlines()
+             if "tpu_custom_call" in line and "leaf_renew" in line]
+    assert len(calls) == 1
+    assert "%_sum_pallas" in calls[0]
+    assert "leaf_renew/jit(_sum_pallas)/pallas_call" in calls[0]
+    assert not any("leaf_renew" in line and "scatter" in line
+                   for line in captured["text"].splitlines())
 
 
 def test_partition_at_published_higgs_rows_stays_lane_dense(one_chip):

@@ -46,10 +46,7 @@ def _train(rounds, n=3000, n_valid=600, seed=5, **extra):
 
 
 # ------------------------------------------------------------ device scopes
-@pytest.fixture(scope="module")
-def lowered_paths():
-    """Every operation name path (the metadata's ``op_name``) of the
-    fused runner of a small binary job with a valid set."""
+def _runner_paths(seed):
     captured = {}
     real = gbdt_mod.cc_get_or_build
 
@@ -66,12 +63,37 @@ def lowered_paths():
 
     gbdt_mod.cc_get_or_build = spy
     try:
-        _train(8, seed=11)
+        _train(8, seed=seed)
     finally:
         gbdt_mod.cc_get_or_build = real
     paths = set(re.findall(r'op_name="([^"]*)"', captured["text"]))
     assert paths, "the lowered text carries no operation name"
     return [p.split("/") for p in paths]
+
+
+@pytest.fixture(scope="module")
+def lowered_paths():
+    """Every operation name path (the metadata's ``op_name``) of the
+    fused runner of a small binary job with a valid set."""
+    return _runner_paths(11)
+
+
+@pytest.fixture(scope="module")
+def lowered_paths_with_the_sum_kernel():
+    """The same with leaf renewal's sums on the path a TPU takes
+    (``ops/table.py _sum_pallas``, here in interpret mode)."""
+    from lightgbm_tpu.ops import quantize, table
+    from lightgbm_tpu.ops.compile_cache import GLOBAL_COMPILE_CACHE
+
+    def kernel_sums(idx, g, h, mask, size):
+        return table._sum_pallas(idx, g, h, mask, size=size, interpret=True)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(quantize, "sum_small_table", kernel_sums)
+        try:
+            return _runner_paths(14)
+        finally:
+            GLOBAL_COMPILE_CACHE.clear()     # a runner traced with the patch
 
 
 def _nested(parts, names):
@@ -99,6 +121,29 @@ SCOPES = ["gradients", "quantize", "tree_root", "tree_select", "leaf_renew",
 def test_lowered_runner_carries_scope(lowered_paths, scope):
     names = scope.split("/")
     assert any(_nested(parts, names) for parts in lowered_paths), scope
+
+
+def test_leaf_renew_holds_the_sum_kernel(lowered_paths,
+                                         lowered_paths_with_the_sum_kernel):
+    """``score_update_ms`` reads scope ``leaf_renew`` whichever path the
+    sums take: XLA's scatter-add off the TPU, the kernel under its own
+    name (the trace's ``_sum_pallas`` line) on it."""
+    assert any(_nested(parts, ["leaf_renew"])
+               and any(p.startswith("scatter") for p in parts)
+               for parts in lowered_paths)
+    assert not any("jit(_sum_pallas)" in parts for parts in lowered_paths)
+    inside = [parts for parts in lowered_paths_with_the_sum_kernel
+              if "jit(_sum_pallas)" in parts]
+    assert inside
+    assert all(_nested(parts, ["leaf_renew", "jit(_sum_pallas)"])
+               for parts in inside)
+    assert not any(_nested(parts, ["leaf_renew"])
+                   and any(p.startswith("scatter") for p in parts)
+                   for parts in lowered_paths_with_the_sum_kernel)
+    # the other scopes are where they were
+    for scope in ("score_update", "tree_select/round_hist", "valid_score"):
+        assert any(_nested(parts, scope.split("/"))
+                   for parts in lowered_paths_with_the_sum_kernel), scope
 
 
 @pytest.mark.parametrize("scope", ["partition", "round_hist", "find_splits"])
