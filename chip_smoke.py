@@ -371,6 +371,11 @@ def phase_four_chips(args, lgb, data):
     text = _sharded_program_text(gb_d)
     n_allreduce = text.count("all-reduce(") + text.count("all-reduce-start(")
     _require(n_allreduce > 0, "no all-reduce in the sharded round program")
+    # by name too: the trace reduction of the four-chip cell finds the
+    # histograms' exchange under this scope (docs/OBSERVABILITY.md)
+    _require("hist_allreduce" in text,
+             "no operation under the scope hist_allreduce in the sharded "
+             "round program")
     auc_d, auc_s = runs["data"]["auc"], runs["serial"]["auc"]
     _require(abs(auc_d - auc_s) < 5e-3,
              f"valid AUC data {auc_d} vs serial {auc_s}")

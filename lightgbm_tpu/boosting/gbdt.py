@@ -244,8 +244,9 @@ class GBDT:
         self.num_init_iteration = 0
         self.best_iteration = -1
 
-        # device operands
-        self.bins = jnp.asarray(train_set.bins)
+        # device operands; the bins go up below, once the tree learner
+        # says where (a row-sharded learner takes each shard from the host)
+        self.bins = None
         self.num_bins_arr = jnp.asarray(train_set.num_bins_array())
         self.nan_bin_arr = jnp.asarray(train_set.nan_bin_array())
         self.is_cat_arr = jnp.asarray(train_set.categorical_array())
@@ -305,6 +306,7 @@ class GBDT:
                                 "shard, not globally")
                 # pad feature columns so F divides the mesh (trivial
                 # single-bin columns can never be chosen for a split)
+                self.bins = jnp.asarray(train_set.bins)
                 pad_f = (-self.bins.shape[1]) % n_dev
                 self._pad_cols = pad_f
                 if pad_f:
@@ -330,17 +332,22 @@ class GBDT:
                         "arrays stay replicated (unpartitioned). Use "
                         "tree_learner=data for padded sharding of "
                         "uneven row counts.")
-                self.bins = self._place_rows(self.bins)
+                self.bins = self._place_rows(train_set.bins)
             else:
-                # pad rows so n divides the mesh (padded rows masked out)
+                # pad rows so n divides the mesh (padded rows masked out).
+                # Rows that divide it go from the host straight to their
+                # shards: through the first device the whole matrix
+                # (3.6 GB at 53M x 67) would sit there first
                 self._pad_rows = (-train_set.num_data) % n_dev
-                if self._pad_rows:
-                    self.bins = jnp.pad(self.bins,
-                                        ((0, self._pad_rows), (0, 0)))
-                self.bins = self._place_rows(self.bins)
+                self.bins = self._place_rows(
+                    jnp.pad(jnp.asarray(train_set.bins),
+                            ((0, self._pad_rows), (0, 0)))
+                    if self._pad_rows else train_set.bins)
         elif tl not in ("serial",):
             log.warning(f"tree_learner={tl} requested but only {n_dev} "
                         "device(s) visible; using serial")
+        if self.bins is None:
+            self.bins = jnp.asarray(train_set.bins)
 
         # linear leaves (linear_tree=true): raw feature values on device
         # (reference LinearTreeLearner keeps Dataset raw_data_)
@@ -355,6 +362,8 @@ class GBDT:
         n = train_set.num_data
         k = self.num_tree_per_iteration
         self.scores = self._place_rows(jnp.zeros((n, k), jnp.float32))
+        if self.objective is not None:
+            self.objective.place_rows(self._place_rows)
         self.init_scores = np.zeros(k)
         self._init_base_score()
 
@@ -402,16 +411,29 @@ class GBDT:
         resident on its own device — left on the first device, every
         tree's dispatch would scatter the whole matrix again; a dim 0
         that does not divide the mesh stays where it is (those modes pad
-        per tree).  Identity in serial and feature-parallel mode."""
-        if x is None or self.mesh is None:
+        per tree).  Identity in serial and feature-parallel mode.  A
+        host array is taken as it is: each shard goes to its device."""
+        if x is None:
             return x
-        from ..parallel.gspmd import row_sharded
-        if self.parallel_mode == "data_gspmd":
-            return row_sharded(self.mesh, x)
-        if self.parallel_mode in ("data", "voting") \
-                and int(x.shape[0]) % int(self.mesh.devices.size) == 0:
-            return row_sharded(self.mesh, x)
-        return x
+        if self.mesh is not None:
+            from ..parallel.gspmd import row_sharded
+            if self.parallel_mode == "data_gspmd":
+                return row_sharded(self.mesh, x)
+            if self.parallel_mode in ("data", "voting") \
+                    and int(x.shape[0]) % int(self.mesh.devices.size) == 0:
+                return row_sharded(self.mesh, x)
+        return jnp.asarray(x)
+
+    def _place_whole(self, x):
+        """Place ``x`` whole on every device of the mesh under the
+        shard_map modes (data, voting): what every shard's program reads
+        in full and the booster keeps, the valid sets' bins.  Left
+        uncommitted on the first device, every call that mixes it with
+        the mesh's arrays copies it to the other devices again."""
+        if self.mesh is not None and self.parallel_mode in ("data", "voting"):
+            from ..parallel.gspmd import replicated
+            return replicated(self.mesh, x)
+        return jnp.asarray(x)
 
     def _config_signature(self):
         """Canonical-config signature for process compile-cache keys:
@@ -437,30 +459,61 @@ class GBDT:
             return -(-splits // k)
         return splits
 
-    def _collective_bytes_per_tree(self) -> int:
-        """Analytic estimate of the bytes all-reduced growing ONE tree in
-        the active parallel mode (psums run inside jit; XLA's actual
-        schedule may reduce-scatter, so this is the logical payload, not
-        wire traffic).  Per histogram pass: data mode psums the full
-        [F, B, 3] f32 histogram; voting psums each shard's 2·top_k voted
-        [B, 3] slices per split; feature mode all-gathers a 12-float
-        SplitInfo per device plus one [n] partition psum per split."""
+    def _collective_bytes_per_tree(self, splits: Optional[int] = None) -> int:
+        """Analytic estimate of the bytes all-reduced growing ONE tree of
+        ``splits`` splits (default: a full tree) in the active parallel
+        mode (psums run inside jit; XLA's actual schedule may
+        reduce-scatter, so this is the logical payload, not wire
+        traffic).  Data mode psums f32 histograms of ``C = 4`` channels
+        (g, h, count and a spare): the root's ``[F, B, C]`` and, per pass
+        of the batched grower, ALL its leaves' ``[K, F, B, C]`` (the
+        operand a trace shows: ``f32[42,67,256,4]``), ``K`` the pass's
+        width: the warm-up widths 1, 4, 16 where the ladder runs, then
+        the split batch until the splits are done (a lower bound: a pass
+        whose frontier holds fewer than K splittable leaves is not in
+        it).  The strict learner psums one ``[F, B, C]`` a split.
+        Voting psums each shard's 2·top_k voted [B, 3] slices per split;
+        feature mode all-gathers a 12-float SplitInfo per device plus
+        one [n] partition psum per split."""
         if self.parallel_mode is None or self.mesh is None:
             return 0
-        splits = max(1, self.hp.num_leaves - 1)
-        rounds = self._hist_rounds_per_tree()
+        if splits is None:
+            splits = self.hp.num_leaves - 1
+        splits = max(1, int(splits))
         B = self.hp.n_bins
         F = self.bins.shape[1]
         if self.parallel_mode in ("data", "data_gspmd"):
             # data_gspmd reduces the same logical histogram payload; the
             # partitioner, not shard_map, chooses the wire schedule
-            return rounds * F * B * 3 * 4
+            one = F * B * 4 * 4
+            return one * (1 + sum(self._pass_widths(splits)))
         if self.parallel_mode == "voting":
             return splits * 2 * int(self.config.top_k) * B * 3 * 4
         if self.parallel_mode == "feature":
             n_dev = int(self.mesh.devices.size)
             return splits * (n_dev * 12 * 4 + self.bins.shape[0] * 4)
         return 0
+
+    def _pass_widths(self, splits: int) -> List[int]:
+        """Leaves per histogram pass after the root's, for a tree of
+        ``splits`` splits: one a split under the strict learner; under
+        the batched grower its warm-up widths (by the rows a shard
+        holds), then the split batch."""
+        if not self._use_batched_grower():
+            return [1] * splits
+        from ..learner.batch_grower import warmup_widths
+        K = min(max(1, int(self.config.tpu_split_batch)),
+                self.hp.num_leaves - 1)
+        rows = self.bins.shape[0]
+        if self.parallel_mode != "data_gspmd":
+            rows //= int(self.mesh.devices.size)
+        widths = []
+        for kw in warmup_widths(rows, K, self.hp, self.forced_splits):
+            if splits <= 0:
+                break
+            widths.append(kw)
+            splits -= kw
+        return widths + [K] * -(-max(splits, 0) // K)
 
     def telemetry(self) -> Dict[str, Any]:
         """This booster's telemetry snapshot: counters/gauges, the phase
@@ -863,9 +916,10 @@ class GBDT:
         if self.objective is not None:
             self.objective.init(train_set.metadata, train_set.num_data)
             self.objective.attach_booster_metrics(self.metrics)
+            self.objective.place_rows(self._place_rows)
         for m in self.train_metrics:
             m.init(train_set.metadata, train_set.num_data)
-        self.bins = self._place_rows(jnp.asarray(train_set.bins))
+        self.bins = self._place_rows(train_set.bins)
         if getattr(self, "bins_words", None) is not None:
             self.bins_words = self._place_rows(
                 jnp.asarray(train_set.packed_mirror()))
@@ -891,13 +945,13 @@ class GBDT:
         if isc is not None:
             vsc += isc.reshape(vsc.shape, order="F") \
                 if isc.size == vsc.size else isc.reshape(-1, 1)
-        self.valid_scores.append(jnp.asarray(vsc))
-        self._valid_bins.append(jnp.asarray(valid_set.bins))
+        self.valid_scores.append(self._place_whole(vsc))
+        self._valid_bins.append(self._place_whole(valid_set.bins))
         # transposed mirror for the matmul valid scorer (round 6): the
         # per-tree path-aggregation wants rows on lanes; cached once per
         # valid set, only for model classes the matmul path serves
         self._valid_bins_t.append(
-            jnp.asarray(np.ascontiguousarray(valid_set.bins.T))
+            self._place_whole(np.ascontiguousarray(valid_set.bins.T))
             if self._matmul_valid_ok() else None)
         self._valid_raw.append(jnp.asarray(valid_set.raw)
                                if self.linear and valid_set.raw is not None
@@ -973,6 +1027,55 @@ class GBDT:
                 log.fatal("debug check: %s child leaf index out of range"
                           % side)
 
+    def _final_leaf_values(self, arrays, leaf_of_row, cls_idx: int,
+                           g_true, h_true, row_mask):
+        """One grown tree's final (unshrunk) leaf values: renewed from
+        the true gradients under quantized training, renewed by the
+        objective (l1/quantile), and the per-leaf ridge fit of a linear
+        tree (``lin``: its constants and coefficients, else None)."""
+        if bool(self.config.use_quantized_grad) and \
+                bool(self.config.quant_train_renew_leaf):
+            renewed = renew_leaf_values(
+                leaf_of_row, g_true[:, cls_idx], h_true[:, cls_idx],
+                row_mask, num_leaves=self.hp.num_leaves,
+                lambda_l1=self.hp.lambda_l1, lambda_l2=self.hp.lambda_l2)
+            # stump (no split found): keep the original leaf value
+            arrays = arrays._replace(leaf_value=jnp.where(
+                arrays.num_leaves > 1, renewed, arrays.leaf_value))
+        arrays = self._renew_leaves(arrays, leaf_of_row, cls_idx)
+        lin = None
+        if self.linear and int(arrays.num_leaves) > 1:
+            # per-leaf ridge fit on the leaf's numeric path features
+            # (reference LinearTreeLearner::CalculateLinear); TRUE
+            # gradients, not quantized levels — the ridge solution is
+            # not scale-invariant across g/h
+            lin = fit_linear_leaves(
+                self.raw_dev, leaf_of_row, arrays.leaf_path,
+                ~self.is_cat_arr, g_true[:, cls_idx], h_true[:, cls_idx],
+                row_mask, arrays.leaf_value,
+                float(self.config.linear_lambda))
+        return arrays, lin
+
+    def _add_linear_scores(self, arrays, leaf_of_row, cls_idx: int,
+                           lin) -> None:
+        """A linear tree's contribution to the training scores and to
+        every valid set's."""
+        const, coeff = lin
+        contrib = linear_leaf_scores(self.raw_dev, leaf_of_row, const,
+                                     coeff, arrays.leaf_value)
+        self.scores = self.scores.at[:, cls_idx].add(
+            self.shrinkage_rate * contrib)
+        for vi in range(len(self.valid_sets)):
+            leaf_v = predict_bins_leaf(arrays, self._valid_bins[vi],
+                                       self.nan_bin_arr, self.bundle,
+                                       self.hp.has_categorical)
+            vraw = self._valid_raw[vi]
+            vc = linear_leaf_scores(vraw, leaf_v, const, coeff,
+                                    arrays.leaf_value) \
+                if vraw is not None else arrays.leaf_value[leaf_v]
+            self.valid_scores[vi] = self.valid_scores[vi] \
+                .at[:, cls_idx].add(self.shrinkage_rate * vc)
+
     def train_one_iter(self, grad: Optional[np.ndarray] = None,
                        hess: Optional[np.ndarray] = None) -> bool:
         """One boosting iteration (reference gbdt.cpp:344 TrainOneIter).
@@ -1028,6 +1131,8 @@ class GBDT:
             self._count("quantize_rounds")
 
         finished = True
+        count_rows = self._use_batched_grower() and not \
+            0 < self.hp.hist_pool_slots < self.hp.num_leaves
         for cls_idx in range(k):
             node_key = None
             if self._needs_node_rng:
@@ -1047,60 +1152,43 @@ class GBDT:
             # linear trees) keep their own sync.
             if bool(self.config.tpu_debug_checks):
                 self._debug_check_tree(arrays, leaf_of_row, row_mask)
-            if bool(self.config.use_quantized_grad) and \
-                    bool(self.config.quant_train_renew_leaf):
-                renewed = renew_leaf_values(
-                    leaf_of_row, g_true[:, cls_idx], h_true[:, cls_idx],
-                    row_mask, num_leaves=self.hp.num_leaves,
-                    lambda_l1=self.hp.lambda_l1, lambda_l2=self.hp.lambda_l2)
-                # stump (no split found): keep the original leaf value
-                arrays = arrays._replace(leaf_value=jnp.where(
-                    arrays.num_leaves > 1, renewed, arrays.leaf_value))
-            arrays = self._renew_leaves(arrays, leaf_of_row, cls_idx)
-            lin = None
-            if self.linear and int(arrays.num_leaves) > 1:
-                # per-leaf ridge fit on the leaf's numeric path features
-                # (reference LinearTreeLearner::CalculateLinear); TRUE
-                # gradients, not quantized levels — the ridge solution is
-                # not scale-invariant across g/h
-                lin = fit_linear_leaves(
-                    self.raw_dev, leaf_of_row, arrays.leaf_path,
-                    ~self.is_cat_arr, g_true[:, cls_idx], h_true[:, cls_idx],
-                    row_mask, arrays.leaf_value,
-                    float(self.config.linear_lambda))
-            if lin is not None:
-                const, coeff = lin
-                contrib = linear_leaf_scores(self.raw_dev, leaf_of_row, const,
-                                             coeff, arrays.leaf_value)
-                self.scores = self.scores.at[:, cls_idx].add(
-                    self.shrinkage_rate * contrib)
-                for vi in range(len(self.valid_sets)):
-                    leaf_v = predict_bins_leaf(arrays, self._valid_bins[vi],
-                                               self.nan_bin_arr, self.bundle,
-                                               self.hp.has_categorical)
-                    vraw = self._valid_raw[vi]
-                    vc = linear_leaf_scores(vraw, leaf_v, const, coeff,
-                                            arrays.leaf_value) \
-                        if vraw is not None else arrays.leaf_value[leaf_v]
-                    self.valid_scores[vi] = self.valid_scores[vi] \
-                        .at[:, cls_idx].add(self.shrinkage_rate * vc)
-            else:
-                shrunk = arrays.leaf_value * self.shrinkage_rate
-                # train score update: one-hot contraction beats the [n] table
-                # gather ~25x on TPU (ops/table.py)
-                self.scores = self.scores.at[:, cls_idx].add(
-                    take_small_table(shrunk, leaf_of_row))
+            # leaf values, then the scores: span ``score_update`` (the
+            # linear branch scores its valid sets inside it); scoring the
+            # valid sets with the new tree: span ``valid_eval``
+            with self._phase("score_update"):
+                arrays, lin = self._final_leaf_values(
+                    arrays, leaf_of_row, cls_idx, g_true, h_true, row_mask)
+                if lin is not None:
+                    self._add_linear_scores(arrays, leaf_of_row, cls_idx, lin)
+                else:
+                    shrunk = arrays.leaf_value * self.shrinkage_rate
+                    # train score update: one-hot contraction beats the [n]
+                    # table gather ~25x on TPU (ops/table.py)
+                    self.scores = self.scores.at[:, cls_idx].add(
+                        take_small_table(shrunk, leaf_of_row))
+            if lin is None:
                 # valid scores: matmul path aggregation where eligible,
                 # frontier traversal otherwise (shrunk values either way)
-                arrays_shrunk = arrays._replace(leaf_value=shrunk)
-                for vi in range(len(self.valid_sets)):
-                    contrib = self._valid_tree_scores(arrays_shrunk, vi)
-                    self.valid_scores[vi] = \
-                        self.valid_scores[vi].at[:, cls_idx].add(contrib)
+                with self._phase("valid_eval"):
+                    arrays_shrunk = arrays._replace(leaf_value=shrunk)
+                    for vi in range(len(self.valid_sets)):
+                        contrib = self._valid_tree_scores(arrays_shrunk, vi)
+                        self.valid_scores[vi] = \
+                            self.valid_scores[vi].at[:, cls_idx].add(contrib)
             with self._phase("tree_finalize"):
                 tree = Tree.from_arrays(arrays, self.train_set)
             if tree.num_leaves > 1:
                 finished = False
+            if self.parallel_mode is not None:
+                # what the tree that came back had all-reduced, and under
+                # the batched data learner the rows its passes read, from
+                # the host tree's own counts (no second transfer)
+                self._count("collective_bytes",
+                            self._collective_bytes_per_tree(
+                                tree.num_leaves - 1))
+                if self.parallel_mode == "data" and count_rows:
+                    self._count("hist_rows_selected",
+                                _hist_rows_selected(tree, n))
             if lin is not None:
                 tree.set_linear(np.asarray(lin[0], np.float64),
                                 np.asarray(lin[1], np.float64),
@@ -1113,6 +1201,8 @@ class GBDT:
         self.iter_ += 1
         self._count("iterations")
         self._count("strict_rounds")
+        if self.parallel_mode is not None:
+            self._count("sharded_rounds")
         self._count("trees_grown", k)
         self._count("hist_build_rounds", self._hist_rounds_per_tree() * k)
         return finished
@@ -1638,8 +1728,6 @@ class GBDT:
             if self.parallel_mode == "data_gspmd":
                 # serial program over row-sharded inputs: GSPMD inserts
                 # the same logical reductions the explicit path psums
-                self._count("collective_allreduce_bytes_est",
-                            self._collective_bytes_per_tree())
                 self._maybe_measure_collective(self._overlap)
             args = (self.bins, g, h, row_mask, self.num_bins_arr,
                     self.nan_bin_arr, self.is_cat_arr, feature_mask, self.hp)
@@ -1666,8 +1754,6 @@ class GBDT:
                                                    **kwargs)
                 return arrays, lor
             return grow_tree(*args, **kwargs)
-        self._count("collective_allreduce_bytes_est",
-                    self._collective_bytes_per_tree())
         if self.parallel_mode == "feature":
             from ..parallel.feature_parallel import grow_tree_feature_parallel
             if feature_mask is not None and self._pad_cols:

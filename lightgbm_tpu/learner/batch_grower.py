@@ -45,6 +45,7 @@ from ..ops.histogram import (bins_to_words, histogram_for_leaves_auto,
                              ladder_profitable, overlap_enabled,
                              root_histogram, wants_packed_mirror)
 from ..ops.round_fuse import partition_select_pallas, use_fused_partition
+from ..ops.table import sum_small_table
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, SplitHyper,
                          categorical_left_bitset, find_best_split,
                          leaf_output)
@@ -56,6 +57,42 @@ from .grower import (CegbInput, DeviceBundle, TreeArrays, _INF_BOUND,
 #: data size below which warmup width-matching is never worth its extra
 #: kernel compilations (tests patch this to exercise the ladder cheaply)
 _WARMUP_MIN_ROWS = 65536
+#: rows (over all shards) from which on a tree's f32 counts can round
+_F32_EXACT_ROWS = 1 << 24
+
+
+def warmup_widths(n: int, K: int, hp: SplitHyper, forced) -> list:
+    """Leaves per pass of the warm-up rounds a tree of ``n`` rows a shard
+    runs before its full-width (``K``) loop: 1, 4, 16, ... below ``K``
+    where the ladder pays (``grow_tree_batched`` says why), none
+    otherwise.  The grower's loops and the booster's count of what a
+    tree all-reduces (``GBDT._pass_widths``) both read it here."""
+    if n < _WARMUP_MIN_ROWS or forced is not None \
+            or not ladder_profitable(hp.hist_kernel, hp.n_bins):
+        return []
+    widths, kw = [], 1
+    while kw < K:
+        widths.append(kw)
+        kw *= 4
+    return widths
+
+
+def _recount_leaves(leaf_of_row: jax.Array, mask_f: jax.Array, size: int,
+                    axis_name: Optional[str]) -> jax.Array:
+    """f32 ``[size]`` counts of the masked-in rows of every leaf, from
+    where the rows are.  The counts a tree carries while it grows are
+    f32 histogram sums, ``parent - left`` down every path, exact only
+    while no node holds ``2**24`` rows; from there on (53M rows over
+    four shards) a rounded parent leaves its last leaf a few rows off.
+    This one is exact while a SHARD's leaf stays under ``2**24`` rows:
+    f32 sums of ones a shard, added over the shards as int32."""
+    with jax.named_scope("leaf_recount"):
+        counts = sum_small_table(leaf_of_row, mask_f, mask_f, None,
+                                 size)[0].astype(jnp.int32)
+        if axis_name is not None:
+            with jax.named_scope("stats_allreduce"):
+                counts = lax.psum(counts, axis_name)
+        return counts.astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("hp", "batch", "axis_name",
@@ -307,6 +344,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             g0 = g0 * hist_scale[0]
             h0 = h0 * hist_scale[1]
         if axis_name is not None:
+          with jax.named_scope("stats_allreduce"):
             if overlap_enabled(overlap):
                 # one [3]-vector psum instead of three scalar collectives:
                 # same per-element sums (bit-identical), one less blocking
@@ -1121,8 +1159,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # a failed/exhausted forced round leaves progress False; the
             # gain-based loops below must still run
             state["progress"] = jnp.bool_(True)
-        if warmup and n >= _WARMUP_MIN_ROWS and forced is None \
-                and ladder_profitable(hp.hist_kernel, hp.n_bins):
+        if warmup:
             # width QUADRUPLING (1, 4, 16, ...): each width always covers the
             # frontier (it at most doubles per round), and since kernel cost
             # is K-independent below 128 channels (docs/PERF_NOTES.md round
@@ -1136,11 +1173,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # identical selections (top-k of a sub-K frontier picks the same
             # leaves at any width), ~2 fewer compiled round bodies and no
             # narrow warmup passes (ops/histogram.py ladder_profitable).
-            kw = 1
-            while kw < K:
+            for kw in warmup_widths(n, K, hp, forced):
                 state = lax.cond(state["progress"] & (state["n_splits"] < L - 1),
                                  make_round_body(kw), lambda st: st, state)
-                kw *= 4
         # loop until the tree is full or a round makes no progress — a fixed
         # ceil((L-1)/K) budget would starve narrow-frontier (chain-shaped) trees
         # where only ~1 leaf per round carries positive gain
@@ -1148,6 +1183,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             lambda st: st["progress"] & (st["n_splits"] < L - 1),
             make_round_body(K), state)
     tree_out = state["tree"]._replace(leaf_path=state["path_f"])
+    if n * num_shards >= _F32_EXACT_ROWS:
+        tree_out = tree_out._replace(leaf_count=_recount_leaves(
+            state["leaf_of_row"], mask_f, L, axis_name))
     if cegb is not None:
         new_cegb = cegb._replace(
             feature_used=state["cegb_used"],

@@ -122,6 +122,13 @@ class ObjectiveFunction:
             self._grad_jit = jax.jit(self.get_gradients)
         return self._grad_jit(score)
 
+    def place_rows(self, place) -> None:
+        """Put the per-row device arrays the gradient program takes as
+        ARGUMENTS where ``place`` puts the booster's rows (a row-sharded
+        tree learner's mesh): left on the first device they cross to the
+        other devices at every call.  Nothing to do where the program
+        closes over them (this class's per-instance jit)."""
+
     def boost_from_score(self, class_id: int = 0) -> float:
         return 0.0
 
@@ -345,6 +352,23 @@ class RegressionTweedieLoss(ObjectiveFunction):
 
 
 # ------------------------------------------------------------------- binary
+def _binary_gradients(score, sign, weight, sigmoid, lw_pos, lw_neg):
+    """Gradients and hessians of the weighted binary log-loss at
+    ``score`` for rows of ``sign`` +1/-1 (``weight`` [n] or None)."""
+    z = sign * sigmoid * score
+    resp = -sign * sigmoid / (1.0 + jnp.exp(z))
+    lw = jnp.where(sign > 0, lw_pos, lw_neg)
+    g = resp * lw
+    h = jnp.abs(resp) * (sigmoid - jnp.abs(resp)) * lw
+    if weight is not None:
+        return g * weight, h * weight
+    return g, h
+
+
+_binary_gradients_jit = jax.jit(
+    _binary_gradients, static_argnames=("sigmoid", "lw_pos", "lw_neg"))
+
+
 class BinaryLogloss(ObjectiveFunction):
     """reference binary_objective.hpp BinaryLogloss."""
     NAME = "binary"
@@ -372,13 +396,24 @@ class BinaryLogloss(ObjectiveFunction):
         self._sign = jnp.asarray(np.where(lbl == 1, 1.0, -1.0), jnp.float32)
 
     def get_gradients(self, score):
-        s = self.config.sigmoid
-        z = self._sign * s * score
-        resp = -self._sign * s / (1.0 + jnp.exp(z))
-        lw = jnp.where(self._sign > 0, self._lw_pos, self._lw_neg)
-        g = resp * lw
-        h = jnp.abs(resp) * (s - jnp.abs(resp)) * lw
-        return self._apply_weight(g, h)
+        return _binary_gradients(score, self._sign, self._weight,
+                                 self.config.sigmoid, self._lw_pos,
+                                 self._lw_neg)
+
+    def jitted_gradients(self, score):
+        """One program a shape and hyperparameter set, the rows' signs
+        and weights its arguments.  The base class's per-instance jit
+        closes over them: every new booster on the same rows compiled
+        its gradients again, with n floats as a constant of the
+        program."""
+        return _binary_gradients_jit(
+            score, self._sign, self._weight,
+            sigmoid=float(self.config.sigmoid), lw_pos=float(self._lw_pos),
+            lw_neg=float(self._lw_neg))
+
+    def place_rows(self, place) -> None:
+        self._sign = place(self._sign)
+        self._weight = place(self._weight)
 
     def boost_from_score(self, class_id=0):
         s = self.config.sigmoid

@@ -38,7 +38,8 @@ COUNTERS: Dict[str, str] = {
         "split batch, not a count of what ran)",
     "hist_rows_selected":
         "rows the histogram passes had to read, counted from the trees "
-        "(root pass + each split's smaller child; serial learner only)",
+        "(root pass + each split's smaller child; the batched serial and "
+        "data learners without the bounded pool)",
     "quantize_rounds": "rounds that quantized gradients before binning",
     "hist_pool_fallbacks": "histogram-pool exhaustion -> rebuild fallbacks",
     "batched_path_fallbacks": "batched-grower bailouts to the strict path",
@@ -65,8 +66,12 @@ COUNTERS: Dict[str, str] = {
     "xla_cache_load_s":
         "seconds retrieving executables from the persistent compile "
         "cache (compile-event listener)",
-    "collective_allreduce_bytes_est":
-        "estimated bytes all-reduced across workers (data-parallel)",
+    "collective_bytes":
+        "logical all-reduce payload of the trees the per-iteration loop "
+        "brought back, by formula from each tree's own split count",
+    "sharded_rounds":
+        "rounds of the per-iteration loop whose trees grew over a device "
+        "mesh (tree_learner data, voting, feature, data_gspmd)",
     "nan_guard_trips": "rounds where the numeric guard saw non-finite values",
     "nan_guard_raises": "numeric-guard trips escalated to an exception",
     "nan_rounds_skipped": "rounds dropped by nan_policy=skip_round",

@@ -133,13 +133,16 @@ def reduce_hist(hist: jax.Array, axis_name: Optional[str],
     """
     if axis_name is None:
         return hist
-    if overlap_enabled(overlap) and hist.ndim >= 1 \
-            and int(hist.shape[0]) >= 2:
-        k = int(hist.shape[0]) // 2
-        lo = lax.psum(hist[:k], axis_name)
-        hi = lax.psum(hist[k:], axis_name)
-        return jnp.concatenate([lo, hi], axis=0)
-    return lax.psum(hist, axis_name)
+    # device scope ``hist_allreduce`` (docs/OBSERVABILITY.md): a trace
+    # finds the collective by this name, whatever XLA makes of it
+    with jax.named_scope("hist_allreduce"):
+        if overlap_enabled(overlap) and hist.ndim >= 1 \
+                and int(hist.shape[0]) >= 2:
+            k = int(hist.shape[0]) // 2
+            lo = lax.psum(hist[:k], axis_name)
+            hi = lax.psum(hist[k:], axis_name)
+            return jnp.concatenate([lo, hi], axis=0)
+        return lax.psum(hist, axis_name)
 
 
 def _round_up(x: int, m: int) -> int:

@@ -242,7 +242,9 @@ def _sum_per_shard(mesh, spec, masked: bool, size: int):
     own rows (a Mosaic kernel cannot be partitioned automatically) and a
     ``psum`` adds the ``[size]`` partial sums."""
     def local(idx, g, h, mask=None):
-        return lax.psum(_sum_pallas(idx, g, h, mask, size=size), spec[0])
+        sums = _sum_pallas(idx, g, h, mask, size=size)
+        with jax.named_scope("stats_allreduce"):
+            return lax.psum(sums, spec[0])
 
     return get_or_build(
         ("sum_small_table_sharded", mesh_signature(mesh), spec, masked, size),
