@@ -128,6 +128,22 @@ def _sharded_program_text(gb) -> str:
                           scales).compile().as_text()
 
 
+def _require_kernel(text: str, kernel: str, scope: str, what: str) -> None:
+    """The compiled program ``text`` holds the Pallas kernel ``kernel``
+    under the device scope ``scope``."""
+    _require(any("tpu_custom_call" in line and f"%{kernel}" in line
+                 and scope in line for line in text.splitlines()), what)
+
+
+def _require_compaction_kernel(text: str, where: str) -> None:
+    """A compacted histogram pass gets its rows from the streaming
+    compaction kernel (ops/hist_pallas.py), never from a sort of the
+    keys and XLA's lane gather (2.2 GB/s: 121 ms a pass at 13M rows)."""
+    _require_kernel(text, "compact_payload_pallas", "hist_compact",
+                    f"no compact_payload_pallas kernel under hist_compact "
+                    f"in {where}")
+
+
 def _make_data(args):
     from bench import _synth_higgs
     rng = np.random.default_rng(args.seed)
@@ -231,13 +247,25 @@ def phase_train(args, lgb, data):
         # XLA's scatter-add (0.9 GB/s: 232 ms a round at 13M rows)
         _require(bool(cfg.quant_train_renew_leaf),
                  "auto mode did not turn on leaf renewal")
-        _require(any("tpu_custom_call" in line and "%_sum_pallas" in line
-                     and "leaf_renew" in line for line in text.splitlines()),
-                 "leaf renewal's sums are not the _sum_pallas kernel in "
-                 "the compiled round program")
+        _require_kernel(text, "_sum_pallas", "leaf_renew",
+                        "leaf renewal's sums are not the _sum_pallas kernel "
+                        "in the compiled round program")
+        _require_compaction_kernel(text, "the compiled round program")
         off = [n for n, a in _device_arrays(gb)
                if {d.platform for d in a.devices()} != {"tpu"}]
         _require(not off, f"booster state not on the TPU: {off}")
+    # the other bin count swaps the full-pass kernel (packed words) and
+    # the compaction's source (the resident word mirror): a short job
+    p63 = {**PARAMS, "max_bin": 63}
+    ds63 = lgb.Dataset(X, label=y, params=p63).construct()
+    dv63 = ds63.create_valid(Xv, label=yv)
+    bst63, auc63, secs63 = _train(lgb, p63, ds63, dv63, args.iters)
+    _require(_took_fused_path(bst63, args.iters),
+             "fused path not taken at 63 bins")
+    _check_auc(auc63, args.iters)
+    if on_tpu:
+        _require_compaction_kernel(_fused_program_text(bst63._gbdt),
+                                   "the 63-bin round program")
     # same shapes, same datasets, same process: nothing may compile again
     misses0 = GLOBAL_COMPILE_CACHE.stats()["misses"]
     bst2, auc2, second_s = _train(lgb, PARAMS, ds, dv, args.iters)
@@ -257,6 +285,7 @@ def phase_train(args, lgb, data):
           smoke_first_train_s=round(first_s, 2),
           smoke_second_train_s=round(second_s, 2),
           smoke_compile_s=round(first_s - second_s, 2),
+          valid_auc_last_63bin=auc63[-1], smoke_train_63bin_s=round(secs63, 2),
           peak_bytes_in_use=_peak_bytes(jax.devices()[0]))
     return bst
 
@@ -376,6 +405,8 @@ def phase_four_chips(args, lgb, data):
     _require("hist_allreduce" in text,
              "no operation under the scope hist_allreduce in the sharded "
              "round program")
+    if jax.devices()[0].platform == "tpu":
+        _require_compaction_kernel(text, "the sharded round program")
     auc_d, auc_s = runs["data"]["auc"], runs["serial"]["auc"]
     _require(abs(auc_d - auc_s) < 5e-3,
              f"valid AUC data {auc_d} vs serial {auc_s}")

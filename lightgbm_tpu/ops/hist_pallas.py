@@ -322,32 +322,24 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     """Masked multi-leaf histogram CONSUMING the compaction payload
     directly: f32 [K, F, n_bins, 4] from i32 words.
 
-    ``payload``: i32 [S, W+3] with W = ceil(num_f/4) — each word packs 4
-    bin bytes (little-endian, a bitcast view of the row-major u8 bin
-    matrix), then one grad, one hess and one leaf word per row.  Rows at
-    positions >= ``cnt`` (i32 [1]) are clipped sort duplicates and are
-    excluded in-kernel, so the caller hands the gather output straight in
-    — no [S, F] slice copy, no bitcast unpack, no where() masking in XLA
-    between the gather and the kernel (VERDICT r3 perf item (c); the
-    unpack copies measured ~1 ms/compacted round).
+    ``payload``: i32 [>= W+3, S] with W = ceil(num_f/4), the compacted
+    positions on the lanes (``compact_payload_pallas``'s result, handed
+    straight in): row j < W packs 4 bin bytes per position
+    (little-endian: feature 4j+k is byte k), then one row of grad bits,
+    one of hess bits and one of leaf ids; further rows are ignored.
+    Positions >= ``cnt`` (i32 [1]) hold no selected row and are excluded
+    in-kernel whatever they contain, and so is the tail of a last block
+    that reaches past S (no operand is padded).
 
     Equivalent to ``histogram_leaves_rows_pallas`` on the unpacked
     operands; the contraction runs per word (fc = 4 features).
     """
-    S, wp3 = payload.shape
-    W = wp3 - 3
-    assert W * 4 >= num_f
+    rows, S = payload.shape
+    W = pl.cdiv(num_f, 4)
+    assert rows >= W + 3
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(S, 128)))
-    s_pad = _round_up(max(S, 1), blk)
-    with jax.named_scope("hist_compact"):
-        if s_pad != S:
-            # pad rows land at positions >= S >= cnt: excluded by the
-            # position guard regardless of content
-            payload = jnp.pad(payload, ((0, s_pad - S), (0, 0)))
-    nb = s_pad // blk
     f_pad = 4 * W
-    prec = _prec(compute_dtype)
 
     def kernel(cnt_ref, payload_ref, leaves_ref, out_ref):
         step = pl.program_id(0)
@@ -356,10 +348,9 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-        pt = payload_ref[:].T                               # [W+3, blk] i32
-        g = lax.bitcast_convert_type(pt[W], jnp.float32)    # [blk]
-        h = lax.bitcast_convert_type(pt[W + 1], jnp.float32)
-        lor_b = pt[W + 2]
+        g = lax.bitcast_convert_type(payload_ref[W], jnp.float32)  # [blk]
+        h = lax.bitcast_convert_type(payload_ref[W + 1], jnp.float32)
+        lor_b = payload_ref[W + 2]
         iota_r = lax.iota(jnp.int32, blk)
         pos_ok = step * blk + iota_r < cnt_ref[0]           # [blk]
         sel = (lor_b[None, :] == leaves_ref[0, :][:, None]) \
@@ -372,16 +363,15 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
             vals = jnp.concatenate([gm, hm, seli], axis=0).astype(jnp.int8)
         else:
             m = sel.astype(jnp.float32)
-            # where(), not multiply: clipped-duplicate rows can carry NaN
+            # where(), not multiply: positions past cnt can carry NaN
             gm = jnp.where(sel, g[None, :], 0.0)
             hm = jnp.where(sel, h[None, :], 0.0)
             vals = jnp.concatenate([gm, hm, m], axis=0).astype(compute_dtype)
         iota = lax.iota(jnp.int32, n_bins)
         # (a 4-words-per-dot widening was tried in round 4 and measured
-        # neutral: this kernel is bound by the [blk, W+3] VMEM transpose
-        # + byte unpack, not dot width)
+        # neutral)
         for j in range(W):
-            w = pt[j]                                       # [blk] i32
+            w = payload_ref[j]                              # [blk] i32
             chunk = jnp.stack([w & 255, (w >> 8) & 255,
                                (w >> 16) & 255, (w >> 24) & 255])  # [4, blk]
             oh_b = (chunk[:, None, :] == iota[None, :, None]
@@ -391,9 +381,9 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nb,),
+        grid=(pl.cdiv(S, blk),),
         in_specs=[
-            pl.BlockSpec((blk, wp3), lambda i, c: (i, 0)),
+            pl.BlockSpec((rows, blk), lambda i, c: (0, i)),
             pl.BlockSpec((1, K), lambda i, c: (0, 0)),
         ],
         out_specs=pl.BlockSpec((3 * K, f_pad * n_bins), lambda i, c: (0, 0)),
@@ -409,6 +399,206 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
     out = out.transpose(1, 2, 3, 0)
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
+
+
+_GROUP = 32     # payload rows a byte-plane group holds: 4 x 32 MXU rows
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("size", "rows_per_block",
+                                    "lanes_per_dot", "interpret"))
+def compact_payload_pallas(src: jax.Array, key: jax.Array, grad: jax.Array,
+                           hess: jax.Array, leaf_of_row: jax.Array, *,
+                           size: int, rows_per_block: int = 1024,
+                           lanes_per_dot: int = 256,
+                           interpret: bool = False) -> jax.Array:
+    """Stream the selected rows into the lane-dense payload of
+    ``histogram_payload_pallas``: i32 [round_up(W+3, 8), >= size], one
+    pass over all n rows, each read once and contiguously.
+
+    ``src``: the resident lane-dense bins, either the packed mirror
+    ``words_t`` i32 [W, n] or ``bins_t`` u8 [F, n] (W = ceil(F/4));
+    ``key`` i32 [n]: a row is selected when its key is below 2^30 (the
+    fused partition kernel's sort keys); ``grad``/``hess`` f32 and
+    ``leaf_of_row`` i32 [n] ride along as words of their bits.
+
+    The selected row of rank r (ascending row order) lands in column r:
+    columns [0, cnt) are bit for bit
+    ``payload.T[:, sort(key)[:cnt] & (2^30 - 1)]`` of the row-major
+    ``[n, W+3]`` payload; columns from cnt on hold zeros or nothing
+    written at all, for the consumer's position guard.  Where more than
+    the output's columns are selected the excess is dropped.
+
+    Per row block: the rank inside the block is a prefix sum of the mask
+    along the lanes (log2(blk) rolls), the running count across blocks a
+    scalar in SMEM.  The block's payload words are split into their four
+    BYTE planes (0..255: exact in bfloat16) and contracted on the MXU
+    against ``onehot(target column)``, one non-zero term per output, then
+    reassembled into words.  Only the ``lanes_per_dot``-wide column
+    windows the block's rows fall in are contracted, so the work follows
+    the number of selected rows; a block with every row selected fills
+    two output blocks, one with none touches nothing.  The result gathers
+    in a two-block VMEM ring and each full output block leaves by one DMA.
+
+    A u8 ``src`` is first brought to the byte-plane order on the MXU too
+    (feature 4j+k is byte k of word j: the same permutation for every
+    128 features), so both sources give the same words.
+    """
+    n = key.shape[0]
+    from_bytes = src.dtype == jnp.uint8
+    F = src.shape[0]
+    W = pl.cdiv(F, 4) if from_bytes else F
+    G = pl.cdiv(W + 3, _GROUP)
+    R = _GROUP * G              # payload rows, in whole groups
+    r_out = _round_up(W + 3, 8)  # a DMA moves whole sublane tiles
+    # the scratch grows with G (about 1.5 MB a group at 1024 rows): the
+    # block shrinks with it, by powers of two so that ch divides it
+    blk = min(rows_per_block, max(128, 1 << (4096 // G).bit_length() - 1),
+              max(128, _round_up(n, 128)))
+    ch = min(lanes_per_dot, blk)
+    assert blk % ch == 0 and ch % 128 == 0, (blk, ch)
+    nb = pl.cdiv(n, blk)
+    nb_out = pl.cdiv(max(size, 1), blk)
+    f_rows = 128 * pl.cdiv(F, 128)
+
+    def byte_planes(x):
+        # i32 [R, blk] -> bf16 [4R, blk]: per group, byte plane k of its
+        # 32 rows at MXU rows [32k, 32k + 32)
+        return jnp.concatenate(
+            [((x[_GROUP * gi:_GROUP * (gi + 1)] >> (8 * k)) & 255)
+             .astype(jnp.float32).astype(jnp.bfloat16)
+             for gi in range(G) for k in range(4)], axis=0)
+
+    def kernel(src_ref, key_ref, g_ref, h_ref, lor_ref, out_ref, x_ref,
+               *scratch):
+        bytes_ref = scratch[0] if from_bytes else None
+        ring_ref, count_ref, sem = scratch[-3:]
+        step = pl.program_id(0)
+
+        @pl.when(step == 0)
+        def _():
+            x_ref[...] = jnp.zeros_like(x_ref)
+            if from_bytes:
+                bytes_ref[...] = jnp.zeros_like(bytes_ref)
+            ring_ref[...] = jnp.zeros_like(ring_ref)
+            count_ref[0] = 0        # selected rows before this block
+            count_ref[1] = 0        # 1 while an output block's DMA runs
+
+        base = count_ref[0]
+        ob = base // blk            # the output block being filled
+        off = base - ob * blk
+        lane = lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+        # the last block's tail is masked by row number: no operand is
+        # padded
+        m = (key_ref[...] < (1 << 30)) & (step * blk + lane < n)
+        ones = m.astype(jnp.int32)
+        c = jnp.sum(ones)
+        rank, s = ones, 1
+        while s < blk:              # inclusive prefix sum along the lanes
+            rank = rank + jnp.where(lane >= s,
+                                    pltpu.roll(rank, s, axis=1), 0)
+            s *= 2
+        # column in the ring, [0, 2 blk); -1 matches no column
+        t = jnp.where(m, off + rank - 1, -1)                 # [1, blk]
+
+        if not from_bytes:
+            x_ref[0:W, :] = src_ref[...]
+        x_ref[W:W + 1, :] = g_ref[...]
+        x_ref[W + 1:W + 2, :] = h_ref[...]
+        x_ref[W + 2:W + 3, :] = lor_ref[...]
+        planes = byte_planes(x_ref[...])                     # [4R, blk]
+        if from_bytes:
+            bytes_ref[0:F, :] = src_ref[...].astype(jnp.int32).astype(
+                jnp.float32)
+            p_i = lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+            f_i = lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+            perm = (f_i == 4 * (p_i % _GROUP) + p_i // _GROUP).astype(
+                jnp.float32).astype(jnp.bfloat16)
+            moved = [lax.dot_general(
+                perm, bytes_ref[128 * gi:128 * (gi + 1), :].astype(
+                    jnp.bfloat16), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+                if 128 * gi < F else jnp.zeros((128, blk), jnp.float32)
+                for gi in range(G)]
+            # the word rows of x_ref are zero here: the sum is a merge
+            planes = (planes.astype(jnp.float32)
+                      + jnp.concatenate(moved, axis=0)).astype(jnp.bfloat16)
+
+        def flush(block):
+            return pltpu.make_async_copy(
+                ring_ref.at[block % 2, 0:r_out, :],
+                out_ref.at[:, pl.ds(pl.multiple_of(block * blk, blk), blk)],
+                sem)
+
+        @pl.when(count_ref[1] == 1)
+        def _():
+            # block ob - 1 left in the step before: its half of the ring
+            # is block ob + 1's from here on
+            flush(ob - 1).wait()
+            ring_ref[(ob + 1) % 2] = jnp.zeros((R, blk), jnp.int32)
+            count_ref[1] = 0
+
+        for q in range(2 * blk // ch):
+            lo = q * ch
+
+            @pl.when((c > 0) & (off + c > lo) & (off < lo + ch))
+            def _():
+                col = lax.broadcasted_iota(jnp.int32, (ch, blk), 0) + lo
+                oh = (t == col).astype(jnp.float32).astype(jnp.bfloat16)
+                d = lax.dot_general(
+                    planes, oh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32).astype(jnp.int32)
+                def plane(gi, k):       # byte plane k of group gi, in place
+                    at = (4 * gi + k) * _GROUP
+                    return d[at:at + _GROUP] << (8 * k)
+
+                words = jnp.concatenate(
+                    [plane(gi, 0) | plane(gi, 1) | plane(gi, 2) | plane(gi, 3)
+                     for gi in range(G)], axis=0)               # [R, ch]
+                at = lo % blk
+                ring_ref[(ob + lo // blk) % 2, :, at:at + ch] += words
+
+        count_ref[0] = base + c
+        filled = (base + c) // blk      # ob or ob + 1
+
+        @pl.when((filled > ob) & (ob < nb_out))
+        def _():
+            flush(ob).start()
+            count_ref[1] = 1
+
+        @pl.when(step == nb - 1)
+        def _():
+            @pl.when(count_ref[1] == 1)
+            def _():
+                flush(ob).wait()
+
+            @pl.when((base + c > filled * blk) & (filled < nb_out))
+            def _():
+                last = flush(filled)
+                last.start()
+                last.wait()
+
+    def rows_block(r):
+        return pl.BlockSpec((r, blk), lambda i: (0, i))
+
+    scratch = [pltpu.VMEM((R, blk), jnp.int32)]
+    if from_bytes:
+        scratch.append(pltpu.VMEM((f_rows, blk), jnp.float32))
+    scratch += [pltpu.VMEM((2, R, blk), jnp.int32),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SemaphoreType.DMA(())]
+    return pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=[rows_block(F)] + [rows_block(1)] * 4,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((r_out, nb_out * blk), jnp.int32),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(src, key[None, :],
+      lax.bitcast_convert_type(grad, jnp.int32)[None, :],
+      lax.bitcast_convert_type(hess, jnp.int32)[None, :],
+      jnp.asarray(leaf_of_row, jnp.int32)[None, :])
 
 
 def _swar_byte_eq_planes(word: jax.Array, iota_bins: jax.Array):

@@ -491,14 +491,17 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
 
     The TPU reformulation of the reference's O(smaller-child) histogram cost
     (serial_tree_learner.cpp:364-378 iterates only the leaf's data indices):
-    when the rows belonging to ``leaves`` fit a power-of-two bucket, they are
-    compacted with a packed single sort + contiguous row gather of an i32
-    WORD payload (4 bin bytes per word + grad/hess/leaf words — same 40
-    bytes/row as the old u8 layout) and the payload kernel runs on the
-    bucket; otherwise one full masked pass (``histogram_for_leaves_masked``).
+    when the rows belonging to ``leaves`` fit a power-of-two bucket, one
+    streaming pass over the resident lane-dense bins
+    (``compact_payload_pallas``) moves them, in row order, into an i32 WORD
+    payload with the compacted positions on the lanes (4 bin bytes per word
+    + grad/hess/leaf words) and the payload kernel runs on the bucket;
+    otherwise one full masked pass (``histogram_for_leaves_masked``).
     Total histogram work per tree drops from O(n x rounds) to ~O(n log L),
     which the flat masked pass cannot do.  Exact: the same rows contribute
-    either way.
+    either way.  Off the TPU the compacted bucket is a sort of the keys and
+    a row gather of the row-major payload, the reference the kernels are
+    tested against.
 
     A leaf-GROUPED compaction variant (rows sorted by leaf, block->leaf
     scalar-prefetch steering) was built and measured slower end-to-end in
@@ -511,12 +514,13 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
     ``counts`` (f32 [K], optional): the caller's known masked row count per
     leaf slot (0 for dummy slots); saves the [K, n] membership reduction.
     ``bins_words`` (i32 [n, ceil(F/4)], optional): ``bins_to_words`` result
-    hoisted out of the round loop by the caller.
+    hoisted out of the round loop by the caller (the XLA path's payload).
     ``sort_key`` (i32 [n], optional): precomputed (selected ? row :
     row | 2^30) keys from the fused partition kernel (ops/round_fuse.py);
     built here from the membership mask otherwise.
     ``hist_kernel``/``bins_words_t``: masked-pass formulation + packed
-    mirror, forwarded to ``histogram_for_leaves_masked``.
+    mirror, forwarded to ``histogram_for_leaves_masked``; where the mirror
+    is resident the compaction reads it, else ``bins_t``.
     """
     hist_kernel = resolve_hist_kernel(hist_kernel)
     n = grad.shape[0]
@@ -529,10 +533,10 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
 
     # Device scopes (docs/OBSERVABILITY.md): ``hist_compact`` is every
     # movement of data that prepares a kernel's operands (selection keys,
-    # payload concatenate, sort, row gather), ``hist_kernel`` the kernels
-    # themselves; each ``lax.switch`` branch sits in a ``hist_rows_<S>``
-    # of its static row count, so a trace says how many rows each pass
-    # was handed.  Metadata only: the operations are unchanged.
+    # the compaction of the selected rows), ``hist_kernel`` the kernels
+    # themselves; each ``lax.switch`` branch's histogram kernel sits in a
+    # ``hist_rows_<S>`` of its static row count, so a trace says how many
+    # rows each pass was handed.  Metadata only.
     with jax.named_scope("hist_compact"):
         if counts is not None:
             cnt = jnp.sum(counts).astype(jnp.int32)
@@ -549,9 +553,6 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             # ~9 ms for sized ``nonzero`` (docs/PERF_NOTES.md).
             iota_n = lax.iota(jnp.int32, n)
             sort_key = jnp.where(sel, iota_n, iota_n | (1 << 30))
-        if bins_words is None:
-            bins_words = bins_to_words(bins_rows)
-    W = bins_words.shape[1]
 
     blk = min(rows_per_block, 2048)
     sizes = []
@@ -568,53 +569,57 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                 rows_per_block=rows_per_block, hist_dtype=hist_dtype,
                 hist_kernel=hist_kernel, bins_words_t=bins_words_t)
 
-    def make_branch(S: int):
-        def branch(operands):
-            with jax.named_scope(f"hist_rows_{S}"):
-                return compacted(S, operands)
-        return branch
-
     def compacted(S: int, operands):
         key_, grad_, hess_, lor_ = operands
-        with jax.named_scope("hist_compact"):
-            # One payload matrix holding (bin words, grad, hess, leaf)
-            # so the branch does a SINGLE contiguous row gather —
-            # separate gathers are DMA-descriptor bound (~9 ns/row
-            # each).  The bin words are the hoisted tree-invariant
-            # view; only 12 bytes per row are fresh.  Built INSIDE the
-            # branch so full-pass rounds skip the concat and the sort
-            # entirely.
-            payload = jnp.concatenate([
-                bins_words,
-                lax.bitcast_convert_type(grad_, jnp.int32)[:, None],
-                lax.bitcast_convert_type(hess_, jnp.int32)[:, None],
-                lor_[:, None],
-            ], axis=1)                                        # [n, W+3] i32
-            idxc = jnp.sort(key_, stable=False)[:S] & ((1 << 30) - 1)
-            pc = payload[idxc]                                # [S, W+3]
-        with jax.named_scope("hist_kernel"):
-            if _use_payload_kernel():
-                from .hist_pallas import histogram_payload_pallas
+        if _use_payload_kernel():
+            from .hist_pallas import (compact_payload_pallas,
+                                      histogram_payload_pallas)
+            interp = not use_pallas()
+            # the compaction is a second pallas_call of the pass: it
+            # stays OUTSIDE hist_rows_<S>, so that a trace counts the
+            # pass once and its time goes to hist_compact
+            with jax.named_scope("hist_compact"):
+                pc = compact_payload_pallas(
+                    bins_t if bins_words_t is None else bins_words_t,
+                    key_, grad_, hess_, lor_, size=S,
+                    interpret=interp)                         # [W+3.., S]
+            with jax.named_scope(f"hist_rows_{S}"), \
+                    jax.named_scope("hist_kernel"):
                 return histogram_payload_pallas(
                     pc, leaves, cnt, num_f=num_f, n_bins=n_bins,
                     rows_per_block=min(rows_per_block,
                                        _pallas_blk(hist_dtype, n_bins)),
                     compute_dtype=jnp.dtype(hist_dtype).type,
-                    interpret=not use_pallas())
-            # XLA fallback (CPU tests / non-TPU): unpack and run the
-            # generic rows path
-            valid = lax.iota(jnp.int32, S) < cnt
-            rows_c = lax.bitcast_convert_type(
-                pc[:, :W], jnp.uint8).reshape(S, 4 * W)[:, :num_f]
-            grad_c = lax.bitcast_convert_type(pc[:, W], jnp.float32)
-            hess_c = lax.bitcast_convert_type(pc[:, W + 1], jnp.float32)
-            lor_c = jnp.where(valid, pc[:, W + 2], -1)
-            return _rows_leaves_hist(rows_c, grad_c, hess_c, lor_c,
-                                     leaves, n_bins=n_bins,
-                                     rows_per_block=rows_per_block,
-                                     hist_dtype=hist_dtype)
+                    interpret=interp)
+        # XLA path (CPU tests / non-TPU): sort the keys, gather the rows
+        # of the row-major payload, unpack and run the generic rows path
+        with jax.named_scope(f"hist_rows_{S}"):
+            with jax.named_scope("hist_compact"):
+                words = bins_to_words(bins_rows) if bins_words is None \
+                    else bins_words
+                W = words.shape[1]
+                payload = jnp.concatenate([
+                    words,
+                    lax.bitcast_convert_type(grad_, jnp.int32)[:, None],
+                    lax.bitcast_convert_type(hess_, jnp.int32)[:, None],
+                    lor_[:, None],
+                ], axis=1)                                    # [n, W+3] i32
+                idxc = jnp.sort(key_, stable=False)[:S] & ((1 << 30) - 1)
+                pc = payload[idxc]                            # [S, W+3]
+            with jax.named_scope("hist_kernel"):
+                valid = lax.iota(jnp.int32, S) < cnt
+                rows_c = lax.bitcast_convert_type(
+                    pc[:, :W], jnp.uint8).reshape(S, 4 * W)[:, :num_f]
+                grad_c = lax.bitcast_convert_type(pc[:, W], jnp.float32)
+                hess_c = lax.bitcast_convert_type(pc[:, W + 1], jnp.float32)
+                lor_c = jnp.where(valid, pc[:, W + 2], -1)
+                return _rows_leaves_hist(rows_c, grad_c, hess_c, lor_c,
+                                         leaves, n_bins=n_bins,
+                                         rows_per_block=rows_per_block,
+                                         hist_dtype=hist_dtype)
 
-    branches = [full_branch] + [make_branch(s) for s in sizes]
+    branches = [full_branch] + [functools.partial(compacted, s)
+                                for s in sizes]
     with jax.named_scope("hist_compact"):
         j = jnp.int32(0)
         for k, s in enumerate(sizes):  # sizes descending: smallest fit wins

@@ -25,7 +25,8 @@ from jax.sharding import SingleDeviceSharding
 
 from lightgbm_tpu.learner import batch_grower
 from lightgbm_tpu.ops import histogram as H
-from lightgbm_tpu.ops.hist_pallas import histogram_payload_pallas
+from lightgbm_tpu.ops.hist_pallas import (compact_payload_pallas,
+                                          histogram_payload_pallas)
 from lightgbm_tpu.ops.table import (_sum_per_shard, _take_per_shard,
                                     sum_small_table, take_small_table)
 
@@ -115,19 +116,82 @@ def test_root_histogram_radix_single_compiles(one_chip, on_tpu):
     _assert_kernel(c, "histogram_radix_single_pallas")
 
 
-def test_payload_histogram_kernel_compiles(one_chip):
-    """The compacted-frontier kernel at the largest bucket (n/4 rows)."""
-    S = N // 4
+CRITEO_F, CRITEO_W = 67, 17
+CRITEO_BUCKET = 3_321_856   # the n/4 bucket of CRITEO_SHARE_ROWS
 
-    def fn(payload, leaves, cnt):
+
+@pytest.mark.parametrize(
+    "source", [((CRITEO_F, CRITEO_SHARE_ROWS), jnp.uint8),
+               ((CRITEO_W, CRITEO_SHARE_ROWS), jnp.int32)],
+    ids=["bins_t-u8", "words_t-i32"])
+def test_payload_histogram_kernel_compiles(one_chip, source):
+    """A compacted pass at the cells' rows and largest bucket (n/4): the
+    streaming compaction from either resident source and the lane-dense
+    payload kernel that reads its result.  Nothing sits between the two:
+    no gather, no sort, and no copy re-laying the ``[W+3, S]`` payload
+    (the ``[S, 20]`` operand this replaced was padded to 128 lanes by
+    one, 1.7 GB a pass)."""
+    n, S = CRITEO_SHARE_ROWS, CRITEO_BUCKET
+
+    def fn(src, key, g, h, lor, leaves, cnt):
+        pc = compact_payload_pallas(src, key, g, h, lor, size=S)
         return histogram_payload_pallas(
-            payload, leaves, cnt, num_f=F, n_bins=256,
+            pc, leaves, cnt, num_f=CRITEO_F, n_bins=256,
             rows_per_block=H._pallas_blk("int8", 256),
             compute_dtype=jnp.int8)
 
-    c = _compile(one_chip, fn, ((S, W + 3), jnp.int32), ((42,), jnp.int32),
-                 ((), jnp.int32))
+    c = _compile(one_chip, fn, source, ((n,), jnp.int32),
+                 ((n,), jnp.float32), ((n,), jnp.float32), ((n,), jnp.int32),
+                 ((42,), jnp.int32), ((), jnp.int32))
+    _assert_kernel(c, "compact_payload_pallas")
     _assert_kernel(c, "histogram_payload_pallas")
+    text = c.as_text()
+    assert " gather(" not in text and " sort(" not in text
+    moved = [line for line in text.splitlines()
+             if " copy(" in line and str(S) in line]
+    assert not moved, moved
+    # the program's temporaries: the payload, [24, S] i32, and one [n]
+    # word vector that XLA prefetches for the kernel
+    assert c.memory_analysis().temp_size_in_bytes <= 24 * S * 4 + 5 * n
+
+
+@pytest.mark.parametrize("n_bins,mirror", [(256, False), (64, True)],
+                         ids=["255bins-bins_t", "63bins-words_t"])
+def test_compacted_branches_hold_no_gather_and_no_relayout(one_chip, on_tpu,
+                                                           n_bins, mirror):
+    """The row ladder the grower calls, at the cells' shapes: the n/4 and
+    n/8 branches are the two kernels back to back; the keys are not
+    sorted, the payload is neither gathered nor copied, and the
+    ``[n, 20]`` concatenate (1.27 GB a pass) is gone with them."""
+    n = CRITEO_SHARE_ROWS
+
+    def fn(bins, bins_t, g, h, lor, leaves, counts, key, words_t=None):
+        return H.histogram_for_leaves_auto(
+            bins, bins_t, g, h, lor, leaves, None, n_bins=n_bins,
+            rows_per_block=8192, hist_dtype="int8", buckets=(4, 8),
+            counts=counts, sort_key=key, bins_words_t=words_t)
+
+    shapes = [((n, CRITEO_F), jnp.uint8), ((CRITEO_F, n), jnp.uint8),
+              ((n,), jnp.float32), ((n,), jnp.float32), ((n,), jnp.int32),
+              ((42,), jnp.int32), ((42,), jnp.float32), ((n,), jnp.int32)]
+    if mirror:
+        shapes.append(((CRITEO_W, n), jnp.int32))
+    text = _compile(one_chip, fn, *shapes).as_text()
+    source = f"s32[{CRITEO_W},{n}]" if mirror else f"u8[{CRITEO_F},{n}]"
+    for S in (CRITEO_BUCKET, 1_660_928):
+        compact = [line for line in text.splitlines()
+                   if "%compact_payload_pallas" in line.split("=")[0]
+                   and f"s32[24,{S}]" in line]
+        assert len(compact) == 1 and source in compact[0], (S, compact)
+        name = compact[0].split("=")[0].strip()
+        readers = [line for line in text.splitlines()
+                   if name + "," in line or name + ")" in line]
+        assert len(readers) == 1, readers
+        assert "%histogram_payload_pallas" in readers[0].split("=")[0]
+        assert f"hist_rows_{S}/hist_kernel" in readers[0]
+        assert "hist_rows_" not in compact[0] and "hist_compact" in compact[0]
+    assert " gather(" not in text and " sort(" not in text
+    assert ",20]" not in text
 
 
 K_ARGS = [((42,), jnp.int32)] * 8    # feats thr dl nanb parents new valid smaller

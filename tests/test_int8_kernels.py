@@ -81,9 +81,9 @@ def test_payload_int8():
         lor[:, None]], axis=1)
     pc = payload[jnp.sort(key, stable=False)[:S] & ((1 << 30) - 1)]
     kw = dict(num_f=f, n_bins=64, rows_per_block=512, interpret=True)
-    got = histogram_payload_pallas(pc, leaves, cnt,
+    got = histogram_payload_pallas(pc.T, leaves, cnt,
                                    compute_dtype=jnp.int8, **kw)
-    want = histogram_payload_pallas(pc, leaves, cnt,
+    want = histogram_payload_pallas(pc.T, leaves, cnt,
                                     compute_dtype=jnp.float32, **kw)
     _assert_same(got, want)
 

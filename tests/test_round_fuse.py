@@ -1,6 +1,7 @@
 """Round-fusion kernels (VERDICT r3 perf items a+c), exercised on CPU via
-the Pallas interpreter: the payload histogram kernel and the fused
-partition+key kernel must be bit-identical to the XLA reference paths.
+the Pallas interpreter: the payload histogram kernel, the compaction
+kernel that feeds it and the fused partition+key kernel must be
+bit-identical to the XLA reference paths.
 """
 
 import dataclasses
@@ -13,7 +14,8 @@ import jax.numpy as jnp
 
 import lightgbm_tpu.ops.histogram as H
 import lightgbm_tpu.ops.round_fuse as RF
-from lightgbm_tpu.ops.hist_pallas import histogram_payload_pallas
+from lightgbm_tpu.ops.hist_pallas import (compact_payload_pallas,
+                                          histogram_payload_pallas)
 from lightgbm_tpu.ops.split import SplitHyper
 from lightgbm_tpu.learner.batch_grower import grow_tree_batched
 
@@ -48,13 +50,128 @@ def test_payload_kernel_matches_masked_reference():
         lor[:, None]], axis=1)
     idxc = jnp.sort(key, stable=False)[:S] & ((1 << 30) - 1)
     pc = payload[idxc]
-    got = histogram_payload_pallas(pc, leaves, cnt, num_f=f, n_bins=64,
+    got = histogram_payload_pallas(pc.T, leaves, cnt, num_f=f, n_bins=64,
                                    rows_per_block=512,
                                    compute_dtype=jnp.float32,
                                    interpret=True)
     want = H.histogram_for_leaves_masked(
         bins.T, grad, hess, lor, leaves, None, n_bins=64,
         hist_dtype="float32")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------------------------------------------- the compaction kernel
+ROWS = 2048 + 994       # the remainder 13,281,250 leaves in a 1024-row block
+SIZE = 1024             # the bucket: one 512-column block short of ROWS / 2
+
+
+def _selection(name, n, size, rng):
+    sel = np.zeros(n, bool)
+    if name.startswith("cnt"):
+        cnt = {"cnt0": 0, "cnt1": 1, "cntS-1": size - 1, "cntS": size}[name]
+        sel[rng.choice(n, cnt, replace=False)] = True
+    elif name.startswith("density"):
+        sel = rng.random(n) < 1 / int(name[len("density"):])
+    elif name == "first":
+        sel[:size] = True
+    elif name == "last":            # the masked tail of the last block too
+        sel[n - size:] = True
+    elif name == "one_block":       # every row of the second 512-row block
+        sel[512:1024] = True
+    return sel
+
+
+def _payload_t(words, grad, hess, lor):
+    return np.concatenate([
+        np.asarray(words).T, np.asarray(grad).view(np.int32)[None],
+        np.asarray(hess).view(np.int32)[None], np.asarray(lor)[None]])
+
+
+@pytest.mark.parametrize("source", ["bins_t", "words_t"])
+@pytest.mark.parametrize("place", ["cnt0", "cnt1", "cntS-1", "cntS",
+                                   "density8", "density4", "first", "last",
+                                   "one_block"])
+def test_compaction_kernel_matches_sorted_gather(place, source):
+    """The first ``cnt`` columns are bit for bit the row-major payload's
+    rows at the sorted keys, for any placement of the selected rows; the
+    float operands move as their bits (no rounding, NaN included)."""
+    rng = np.random.default_rng(7)
+    n, f = ROWS, 10                       # 10 % 4 != 0: a padded word
+    bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
+    grad = rng.normal(size=n).astype(np.float32)
+    hess = rng.integers(-2 ** 31, 2 ** 31, size=n).astype(np.int32).view(
+        np.float32)                       # any bit pattern
+    lor = rng.integers(-1, 255, size=n).astype(np.int32)
+    sel = _selection(place, n, SIZE, rng)
+    cnt = int(sel.sum())
+    assert cnt <= SIZE
+    rows = np.arange(n, dtype=np.int32)
+    key = np.where(sel, rows, rows | (1 << 30)).astype(np.int32)
+    words = H.bins_to_words(jnp.asarray(bins))
+    src = jnp.asarray(bins.T) if source == "bins_t" else words.T
+    got = np.asarray(compact_payload_pallas(
+        src, jnp.asarray(key), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.asarray(lor), size=SIZE, rows_per_block=512, lanes_per_dot=128,
+        interpret=True))
+    w = words.shape[1]
+    assert got.shape == (8 * -(-(w + 3) // 8), SIZE)
+    want = _payload_t(words, grad, hess, lor)[
+        :, np.sort(key)[:cnt] & ((1 << 30) - 1)]
+    np.testing.assert_array_equal(got[:w + 3, :cnt], want)
+
+
+def test_compaction_kernel_past_one_group_of_rows():
+    """More than 32 payload rows (F > 116): a second byte-plane group,
+    and a u8 source whose features span two 128-feature permutations."""
+    rng = np.random.default_rng(8)
+    n, f = 700, 130
+    bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
+    grad = rng.normal(size=n).astype(np.float32)
+    hess = rng.normal(size=n).astype(np.float32)
+    lor = rng.integers(0, 9, size=n).astype(np.int32)
+    sel = rng.random(n) < 0.3
+    rows = np.arange(n, dtype=np.int32)
+    key = np.where(sel, rows, rows | (1 << 30)).astype(np.int32)
+    words = H.bins_to_words(jnp.asarray(bins))
+    want = _payload_t(words, grad, hess, lor)[:, rows[sel]]
+    for src in (jnp.asarray(bins.T), words.T):
+        got = np.asarray(compact_payload_pallas(
+            src, jnp.asarray(key), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.asarray(lor), size=256, rows_per_block=256,
+            interpret=True))
+        np.testing.assert_array_equal(got[:want.shape[0], :sel.sum()], want)
+
+
+@pytest.mark.parametrize("hist_dtype", ["int8", "float32"])
+@pytest.mark.parametrize("mirror", [False, True], ids=["bins_t", "words_t"])
+@pytest.mark.parametrize("divisor", [4, 8, 16, 64])
+def test_auto_through_each_compacted_bucket_equals_the_full_pass(
+        divisor, mirror, hist_dtype):
+    """``histogram_for_leaves_auto`` with the kernels (interpret mode):
+    a selection that lands in the bucket n / divisor gives the full
+    masked pass's histograms exactly (integer fixtures)."""
+    bins, grad, hess, _, leaves = _mk(n=16384 + 994)
+    n = bins.shape[0]
+    rng = np.random.default_rng(divisor)
+    # about 0.8 of the bucket's rows in the four leaves, the rest elsewhere
+    lor = np.where(rng.random(n) < 0.8 / divisor,
+                   np.asarray(leaves)[rng.integers(0, 4, size=n)], 1)
+    lor = jnp.asarray(lor.astype(np.int32))
+    want = H.histogram_for_leaves_masked(
+        bins.T, grad, hess, lor, leaves, None, n_bins=64,
+        hist_dtype="float32")
+    H._PAYLOAD_TEST_INTERPRET = True
+    try:
+        got = H.histogram_for_leaves_auto(
+            bins, bins.T, grad, hess, lor, leaves, None, n_bins=64,
+            rows_per_block=256, hist_dtype=hist_dtype, buckets=(divisor,),
+            bins_words_t=H.bins_to_words(bins).T if mirror else None,
+            hist_kernel="onehot")
+    finally:
+        H._PAYLOAD_TEST_INTERPRET = False
+    cnt = int(jnp.sum(jnp.any(lor[None, :] == leaves[:, None], axis=0)))
+    size = -(-(n // divisor) // 256) * 256
+    assert 0 < cnt <= size < n, "the fixture misses the bucket"
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -96,6 +96,26 @@ def lowered_paths_with_the_sum_kernel():
             GLOBAL_COMPILE_CACHE.clear()     # a runner traced with the patch
 
 
+@pytest.fixture(scope="module")
+def lowered_paths_with_the_payload_kernels():
+    """The same with the compacted pass on the path a TPU takes (the
+    compaction kernel and the payload kernel of ops/hist_pallas.py, here
+    in interpret mode)."""
+    from lightgbm_tpu.ops import histogram
+    from lightgbm_tpu.ops.compile_cache import GLOBAL_COMPILE_CACHE
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(histogram, "_PAYLOAD_TEST_INTERPRET", True)
+        # the hook is read when the jitted grower is traced: no trace
+        # from before the patch may answer, none with it may stay
+        jax.clear_caches()
+        try:
+            return _runner_paths(15)
+        finally:
+            GLOBAL_COMPILE_CACHE.clear()
+            jax.clear_caches()
+
+
 def _nested(parts, names):
     """``names`` appear in ``parts`` in this order (not necessarily
     adjacent: ``while/body`` and ``jit(...)`` parts sit between)."""
@@ -144,6 +164,37 @@ def test_leaf_renew_holds_the_sum_kernel(lowered_paths,
     for scope in ("score_update", "tree_select/round_hist", "valid_score"):
         assert any(_nested(parts, scope.split("/"))
                    for parts in lowered_paths_with_the_sum_kernel), scope
+
+
+def test_a_compacted_pass_is_two_kernels_and_one_counted_pass(
+        lowered_paths_with_the_payload_kernels):
+    """``benchmark/harness/scoped.py`` counts a pass as one operation
+    whose path ends in ``pallas_call`` under a ``hist_rows_*`` scope, and
+    gives time to the innermost scope: the compaction kernel sits under
+    ``hist_compact`` and under NO ``hist_rows_`` part, the payload kernel
+    under exactly one."""
+    paths = lowered_paths_with_the_payload_kernels
+    # (every operation of the jitted kernel function: the interpreter
+    # unrolls the ``pallas_call`` that ends the path on the chip)
+    compact = [parts for parts in paths
+               if "jit(compact_payload_pallas)" in parts]
+    payload = [parts for parts in paths
+               if "jit(histogram_payload_pallas)" in parts]
+    assert compact and payload
+    for parts in compact:
+        assert _nested(parts, ["round_hist", "hist_compact",
+                               "jit(compact_payload_pallas)"])
+        assert not any(p.startswith("hist_rows_") for p in parts)
+        assert "hist_kernel" not in parts
+    for parts in payload:
+        assert _nested(parts, ["round_hist", "hist_rows_2048", "hist_kernel",
+                               "jit(histogram_payload_pallas)"])
+        assert sum(p.startswith("hist_rows_") for p in parts) == 1
+        assert "hist_compact" not in parts
+    # the sort of the keys and the row gather are off this path
+    assert not any(_nested(parts, ["round_hist", "hist_compact"])
+                   and parts[-1].startswith(("sort", "gather"))
+                   for parts in paths)
 
 
 @pytest.mark.parametrize("scope", ["partition", "round_hist", "find_splits"])
