@@ -40,7 +40,7 @@ from ..ops.compile_cache import get_or_build as cc_get_or_build, sig as cc_sig
 from ..ops.quantize import (discretize_gradients_levels,
                             renew_leaf_values)
 from ..ops.split import SplitHyper
-from ..obs import count_event, trace as obs_trace
+from ..obs import count_event
 from ..obs.metrics import MetricsRegistry
 from ..utils import log
 from ..utils.timer import PhaseTimer, global_timer, phase
@@ -105,7 +105,6 @@ def _hp_from_config(cfg: Config, n_bins: int) -> SplitHyper:
         # deterministic contract)
         hist_dtype=_resolve_hist_dtype(cfg),
         hist_kernel=_resolve_hist_kernel_cfg(cfg),
-        leaf_hist=str(cfg.tpu_leaf_hist),
         extra_trees=bool(cfg.extra_trees),
         feature_fraction_bynode=float(cfg.feature_fraction_bynode),
     )
@@ -544,10 +543,10 @@ class GBDT:
     def _resolve_auto_params(self, config: Config) -> None:
         """Fast-by-default policy (VERDICT r3 #3): at scale, a plain
         ``train()`` gets the batched grower and the exact quantized-grad
-        bf16 kernel path without opting in — the same configuration the
-        bench runs.  Decision-identity of that path vs the f32 kernel is
-        proven (ops/quantize.py, tests/test_quantized.py); leaf values are
-        renewed from true gradients.  Small runs keep the exact-f32 strict
+        bf16 kernel path without opting in — the configuration the
+        benchmark's cells run.  Decision-identity of that path vs the f32
+        kernel is proven (ops/quantize.py, tests/test_quantized.py); leaf
+        values are renewed from true gradients.  Small runs keep the exact-f32 strict
         path: there the extra kernel compilations dominate and exactness
         is free.  Any explicit user setting, ``deterministic=true``,
         feature-parallel (no level-scale plumbing) win over the whole
@@ -596,24 +595,9 @@ class GBDT:
         train_set = self.train_set
         self._fused_cache = {}   # compiled fused-round runners (train_fused)
         self._batched_decision = None   # memoized _use_batched_grower
-        self._collective_probed = False  # one-shot obs/collective probe
         # numeric guard policy (robustness/guards.py); validated by
         # Config.check_param_conflict, re-derived on reset_config
         self.nan_policy = str(config.nan_policy or "none")
-        # collective_overlap (ISSUE 7): "on" forces the chunked
-        # overlapped-psum schedule, "off" the single blocking psum,
-        # "auto" engages it exactly where the explicit shard_map modes
-        # issue per-round collectives the scheduler can hide.  The GSPMD
-        # mode ignores it (the partitioner owns the schedule), and
-        # LGBMTPU_NO_OVERLAP kills it at trace time either way
-        # (ops/histogram.py reduce_hist).
-        ov = str(config.collective_overlap or "auto")
-        if ov not in ("auto", "on", "off"):
-            log.warning("collective_overlap=%r not one of auto/on/off; "
-                        "using 'auto'" % ov)
-            ov = "auto"
-        self._overlap = (ov == "on") or (
-            ov == "auto" and self.parallel_mode in ("data", "voting"))
         self._resolve_auto_params(config)
         self.hp = _hp_from_config(config, train_set.device_n_bins())
         if bool(train_set.categorical_array().any()):
@@ -736,8 +720,8 @@ class GBDT:
         if self.parallel_mode in (None, "data_gspmd"):
             # data_gspmd qualifies too: it never pads rows, so the
             # construction-time mirror stays valid (sharded like bins)
-            from ..ops.histogram import wants_packed_mirror
-            if wants_packed_mirror(self.hp.hist_kernel, self.hp.n_bins):
+            from ..ops.histogram import hist_dispatch
+            if hist_dispatch(self.hp.hist_kernel, self.hp.n_bins).mirror:
                 self.bins_words = self._place_rows(
                     jnp.asarray(train_set.packed_mirror()))
 
@@ -1301,8 +1285,8 @@ class GBDT:
     @classmethod
     def fused_chunks(cls, num_rounds: int):
         """The exact scan-length sequence ``train_fused`` will run —
-        the single source of truth shared with warmup code (bench.py)
-        that precompiles each length."""
+        the single source of truth shared with warm-up code (the
+        benchmark's harness) that precompiles each length."""
         c = cls.fused_chunk_for(num_rounds)
         out, done = [], 0
         while done < num_rounds:
@@ -1725,10 +1709,8 @@ class GBDT:
         tree_learner.cpp:15).  ``hist_scale``: [2] (g, h) scales in
         quantized-levels mode."""
         if self.parallel_mode in (None, "data_gspmd"):
-            if self.parallel_mode == "data_gspmd":
-                # serial program over row-sharded inputs: GSPMD inserts
-                # the same logical reductions the explicit path psums
-                self._maybe_measure_collective(self._overlap)
+            # under data_gspmd this is the serial program over row-sharded
+            # inputs: GSPMD inserts the reductions the explicit path psums
             args = (self.bins, g, h, row_mask, self.num_bins_arr,
                     self.nan_bin_arr, self.is_cat_arr, feature_mask, self.hp)
             if self._use_batched_grower():
@@ -1773,11 +1755,6 @@ class GBDT:
             h = jnp.pad(h, (0, p))
             row_mask = jnp.pad(jnp.ones(g.shape[0] - p, bool)
                                if row_mask is None else row_mask, (0, p))
-        overlap = self._overlap
-        if overlap:
-            self._count("collective_overlap_rounds",
-                        self._hist_rounds_per_tree())
-        self._maybe_measure_collective(overlap)
         if self.parallel_mode in ("data", "voting") \
                 and self._use_batched_grower():
             with phase("collective_grow_dispatch",
@@ -1790,8 +1767,7 @@ class GBDT:
                     monotone=self.monotone_arr, hist_scale=hist_scale,
                     interaction_sets=self.interaction_sets,
                     parallel_mode=self.parallel_mode,
-                    top_k=int(self.config.top_k), overlap=overlap,
-                    metrics=self.metrics)
+                    top_k=int(self.config.top_k), metrics=self.metrics)
             return arrays, (lor[:-p] if p else lor)
         with phase("collective_grow_dispatch",
                    mode=self.parallel_mode, batched=False):
@@ -1802,41 +1778,8 @@ class GBDT:
                 top_k=int(self.config.top_k), monotone=self.monotone_arr,
                 rng_key=node_key, interaction_sets=self.interaction_sets,
                 forced=self.forced_splits, hist_scale=hist_scale,
-                overlap=overlap, metrics=self.metrics)
+                metrics=self.metrics)
         return arrays, (lor[:-p] if p else lor)
-
-    def _maybe_measure_collective(self, overlap: bool) -> None:
-        """One-shot collective probe (obs/collective.py): measure this
-        mesh's per-pass histogram all-reduce cost and overlap
-        efficiency, gauged into the booster + global registries so
-        telemetry JSONL rows and bench payloads carry them.  Runs ONLY
-        when observability is configured (a trace recorder or event
-        journal is active, or telemetry_output is set) — the no-outputs
-        path never compiles a probe."""
-        if self._collective_probed or self.mesh is None:
-            return
-        from ..obs import events as obs_events
-        if obs_trace.active() is None and obs_events.active() is None \
-                and not str(getattr(self.config, "telemetry_output", "")
-                            or ""):
-            return
-        self._collective_probed = True
-        try:
-            from ..obs.collective import measure_collective
-            res = measure_collective(
-                self.mesh, (self.bins.shape[1], self.hp.n_bins, 4),
-                overlap=overlap, metrics=self.metrics)
-        except Exception as e:   # a probe failure must not stop training
-            log.warning("collective probe failed (%s: %s); overlap "
-                        "gauges unavailable this run"
-                        % (type(e).__name__, e))
-            return
-        per_round = res["collective_s_per_pass"] * \
-            self._hist_rounds_per_tree()
-        from ..obs.metrics import global_metrics
-        for registry in (self.metrics, global_metrics):
-            registry.set_gauge("collective_s_per_round",
-                               round(per_round, 9))
 
     def _use_batched_grower(self) -> bool:
         """Batched split rounds (learner/batch_grower.py) when requested and

@@ -205,9 +205,7 @@ def log_telemetry(path: str, period: int = 1,
             counters = snap["counters"]
             rec["counters"] = counters
             if snap["gauges"]:
-                # collective probe results (overlap_efficiency,
-                # collective_s_per_pass/_per_round, obs/collective.py)
-                # and any other point-in-time samples
+                # point-in-time samples
                 rec["gauges"] = snap["gauges"]
             fused_now = counters.get("fused_rounds", 0)
             if fused_now > state["fused_seen"]:

@@ -273,10 +273,8 @@ _PARAMS: List[Tuple[str, Any, Tuple[str, ...], Tuple[Tuple[str, float], ...]]] =
     ("tpu_debug_checks", False, (), ()),         # per-tree invariant checks (reference DEBUG CheckSplitValid)
     ("tpu_device_eval", True, (), ()),           # jitted device metric eval (l2/l1/rmse/logloss/error/auc/ndcg); host f64 when false or deterministic=true
     ("tpu_rows_per_block", 16384, (), ()),        # histogram kernel row tile
-    ("tpu_leaf_hist", "masked", (), ()),          # per-leaf hist: masked|bucketed
     ("tpu_split_batch", 1, (), ((">", 0),)),      # splits per histogram pass; AUTO POLICY: unset at >=100k rows resolves to min(42, num_leaves-1)
     ("hist_kernel", "auto", (), ()),              # histogram build formulation: auto|onehot|packed|radix2 (ops/histogram.py HIST_KERNELS; all modes bit-identical — onehot = flat reference, packed = 4 bins per i32 lane SWAR compares, radix2 = shared hi/lo nibble planes reused across split-batch leaf channels)
-    ("collective_overlap", "auto", (), ()),       # distributed histogram-reduction schedule: auto|on|off (ops/histogram.py reduce_hist; "on"/auto-under-data/voting splits each psum into two independent half-collectives — bit-identical sums — so XLA's latency-hiding scheduler can overlap wire time with local compute; LGBMTPU_NO_OVERLAP is the trace-time A/B hatch; data_gspmd ignores it, the partitioner owns its schedule)
     ("serving_buckets", [1, 8, 64, 512, 4096], (), ()),  # serving-tier row-count bucket ladder (lightgbm_tpu/serving/): requests are padded up to the smallest bucket >= n (oversize requests chunk by the largest), so every request re-enters an already-compiled program and XLA never lowers at steady state; sorted/deduped, all entries > 0
     ("predict_bucketing", "on", (), ()),          # batch Booster.predict shape-thrash fix: on|off (boosting/gbdt.py _device_predict_raw pads block tails up to a geometric ladder of tail-quantum multiples instead of the next exact multiple, bounding compiled program count at log2(block/quantum)+1 across ANY mix of row counts; bit-identical — padded rows are sliced off and the path-count matmuls are per-row exact; counters predict_bucketed_calls/predict_bucket_pad_rows)
     ("serving_telemetry_output", "", (), ()),     # serving per-request JSONL path (serving/server.py PredictionServer: one record per predict() with model/version, rows, buckets hit, pad rows, latency_s; "" disables)

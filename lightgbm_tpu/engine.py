@@ -273,7 +273,6 @@ def _build_watchtower(cfg, booster):
         # watched) by PredictionServer
         evaluator.watch_slo("nan_guard_trip_rate")
         evaluator.watch_slo("compile_miss_storm")
-        evaluator.watch_slo("overlap_efficiency_floor")
         evaluator.watch_slo("heartbeat_staleness_s")
     anomaly = None
     if anomaly_on:

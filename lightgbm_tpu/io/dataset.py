@@ -37,9 +37,8 @@ BINARY_FORMAT_VERSION = 2
 def device_bins_pow2(widest: int) -> int:
     """Device histogram bin-axis width for a widest-column bin count:
     rounded up to a power of two (lane-friendly), floor 4.  THE rounding
-    rule — ``Dataset.device_n_bins`` and the bench scripts (bench.py,
-    tools/sweep_perf.py) must agree on it or the bench measures a bin
-    width the real pipeline doesn't use."""
+    rule: ``Dataset.device_n_bins`` and the streamed ingest
+    (io/streaming.py) both take it from here."""
     return max(1 << max(1, (int(widest) - 1).bit_length()), 4)
 
 

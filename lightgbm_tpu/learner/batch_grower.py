@@ -41,9 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import (bins_to_words, histogram_for_leaves_auto,
-                             ladder_profitable, overlap_enabled,
-                             root_histogram, wants_packed_mirror)
+from ..ops.histogram import (bins_to_words, hist_dispatch,
+                             histogram_for_leaves_auto, root_histogram)
 from ..ops.round_fuse import partition_select_pallas, use_fused_partition
 from ..ops.table import sum_small_table
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, SplitHyper,
@@ -68,7 +67,7 @@ def warmup_widths(n: int, K: int, hp: SplitHyper, forced) -> list:
     otherwise.  The grower's loops and the booster's count of what a
     tree all-reduces (``GBDT._pass_widths``) both read it here."""
     if n < _WARMUP_MIN_ROWS or forced is not None \
-            or not ladder_profitable(hp.hist_kernel, hp.n_bins):
+            or not hist_dispatch(hp.hist_kernel, hp.n_bins).ladder:
         return []
     widths, kw = [], 1
     while kw < K:
@@ -97,8 +96,7 @@ def _recount_leaves(leaf_of_row: jax.Array, mask_f: jax.Array, size: int,
 
 @functools.partial(jax.jit, static_argnames=("hp", "batch", "axis_name",
                                              "warmup", "parallel_mode",
-                                             "top_k", "num_shards",
-                                             "overlap"))
+                                             "top_k", "num_shards"))
 def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       row_mask: Optional[jax.Array], num_bins: jax.Array,
                       nan_bin: jax.Array, is_cat: jax.Array,
@@ -116,8 +114,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       parallel_mode: str = "data", top_k: int = 20,
                       num_shards: int = 1,
                       cegb: Optional[CegbInput] = None,
-                      bins_words: Optional[jax.Array] = None,
-                      overlap: bool = False):
+                      bins_words: Optional[jax.Array] = None):
     """Grow one tree with ``batch`` splits per histogram pass.
 
     Same operands and return contract as ``grow_tree`` (a 3-tuple with
@@ -192,7 +189,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             bins_to_words(bins) if bins_words is None else bins_words)
         # transposed packed mirror for the round-6 packed histogram kernel
         words_t = lax.optimization_barrier(bins_words.T) \
-            if wants_packed_mirror(hp.hist_kernel, hp.n_bins) else None
+            if hist_dispatch(hp.hist_kernel, hp.n_bins).mirror else None
     # fused partition+key kernel (ops/round_fuse.py): numeric non-bundled
     # splits only — categorical bitsets / EFB inverse tables are per-row
     # gathers, kept on the XLA path
@@ -335,8 +332,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             bins_t, grad, hess, row_mask, n_bins=hp.n_bins,
             rows_per_block=hp.rows_per_block,
             hist_dtype=hp.hist_dtype, axis_name=hist_axis,
-            hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-            overlap=overlap))
+            hist_kernel=hp.hist_kernel, bins_words_t=words_t))
         g0 = jnp.sum(grad * mask_f)
         h0 = jnp.sum(hess * mask_f)
         c0 = jnp.sum(mask_f)
@@ -344,16 +340,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             g0 = g0 * hist_scale[0]
             h0 = h0 * hist_scale[1]
         if axis_name is not None:
-          with jax.named_scope("stats_allreduce"):
-            if overlap_enabled(overlap):
-                # one [3]-vector psum instead of three scalar collectives:
-                # same per-element sums (bit-identical), one less blocking
-                # round-trip for the scheduler to hide
+            # one [3]-vector psum, not three scalar collectives
+            with jax.named_scope("stats_allreduce"):
                 g0, h0, c0 = lax.psum(jnp.stack([g0, h0, c0]), axis_name)
-            else:
-                g0 = lax.psum(g0, axis_name)
-                h0 = lax.psum(h0, axis_name)
-                c0 = lax.psum(c0, axis_name)
         root_out = leaf_output(g0, h0, hp.lambda_l1, hp.lambda_l2,
                                hp.max_delta_step)
         empty_path = jnp.zeros((num_f,), bool)
@@ -928,8 +917,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
                       hist_dtype=hp.hist_dtype, axis_name=hist_axis,
                       counts=cnts, bins_words=bins_words, sort_key=skey,
-                      hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-                      overlap=overlap)
+                      hist_kernel=hp.hist_kernel, bins_words_t=words_t)
                   with jax.named_scope("hist_update"):
                       return _scaled(h)
 
@@ -1172,7 +1160,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # round loop at full width straight from the root histogram —
             # identical selections (top-k of a sub-K frontier picks the same
             # leaves at any width), ~2 fewer compiled round bodies and no
-            # narrow warmup passes (ops/histogram.py ladder_profitable).
+            # narrow warmup passes (ops/histogram.py hist_dispatch).
             for kw in warmup_widths(n, K, hp, forced):
                 state = lax.cond(state["progress"] & (state["n_splits"] < L - 1),
                                  make_round_body(kw), lambda st: st, state)

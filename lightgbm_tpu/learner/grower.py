@@ -37,9 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.histogram import (bins_to_words, histogram_for_leaf_bucketed,
-                             histogram_for_leaf_masked, overlap_enabled,
-                             root_histogram, wants_packed_mirror)
+from ..ops.histogram import (bins_to_words, hist_dispatch,
+                             histogram_for_leaf_masked, root_histogram)
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, VAR_CAT_ONEHOT,
                          VAR_NUM_RIGHT, SplitHyper, SplitResult,
                          categorical_left_bitset, find_best_split, leaf_gain,
@@ -283,7 +282,7 @@ def _child_best(hist: jax.Array, g: jax.Array, h: jax.Array, c: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("hp", "axis_name",
                                              "parallel_mode", "top_k",
-                                             "num_shards", "overlap"))
+                                             "num_shards"))
 def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               row_mask: Optional[jax.Array], num_bins: jax.Array,
               nan_bin: jax.Array, is_cat: jax.Array,
@@ -298,8 +297,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               num_shards: int = 1,
               cegb: Optional[CegbInput] = None,
               hist_scale: Optional[jax.Array] = None,
-              bins_words: Optional[jax.Array] = None,
-              overlap: bool = False):
+              bins_words: Optional[jax.Array] = None):
     """Grow one tree; returns (TreeArrays, leaf_of_row).
 
     bins: uint8 [n, F]; grad/hess: f32 [n]; row_mask: bool [n] or None
@@ -393,7 +391,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # packed-word mirror for the round-6 packed histogram mode (kept
     # resident per tree like bins_t; ``bins_words`` lets the booster ship
     # the dataset's construction-time mirror instead of re-deriving it)
-    if wants_packed_mirror(hp.hist_kernel, hp.n_bins):
+    if hist_dispatch(hp.hist_kernel, hp.n_bins).mirror:
         words_t = lax.optimization_barrier(
             (bins_to_words(bins) if bins_words is None else bins_words).T)
     else:
@@ -413,8 +411,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         bins_t, grad, hess, row_mask, n_bins=hp.n_bins,
         rows_per_block=hp.rows_per_block,
         hist_dtype=hp.hist_dtype, axis_name=hist_axis,
-        hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-        overlap=overlap))
+        hist_kernel=hp.hist_kernel, bins_words_t=words_t))
     g0 = jnp.sum(grad * mask_f)
     h0 = jnp.sum(hess * mask_f)
     c0 = jnp.sum(mask_f)
@@ -423,14 +420,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         h0 = h0 * hist_scale[1]
     if axis_name is not None and mode != "feature":
         # feature mode holds ALL rows on every shard: sums already global
-        if overlap_enabled(overlap):
-            # one [3]-vector psum (bit-identical per-element sums),
-            # one fewer blocking collective round-trip
-            g0, h0, c0 = lax.psum(jnp.stack([g0, h0, c0]), axis_name)
-        else:
-            g0 = lax.psum(g0, axis_name)
-            h0 = lax.psum(h0, axis_name)
-            c0 = lax.psum(c0, axis_name)
+        # one [3]-vector psum, not three scalar collectives
+        g0, h0, c0 = lax.psum(jnp.stack([g0, h0, c0]), axis_name)
 
     if mode == "voting" and axis_name is not None:
         # locally relaxed validity thresholds
@@ -733,24 +724,14 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 lmin_l = lmin_r = lmin_p
                 lmax_l = lmax_r = lmax_p
 
-            # -- histogram: data pass over ONLY the smaller child's rows
-            # (bucketed gather), subtract for the sibling
+            # -- histogram: one masked data pass for the smaller child,
+            # subtract for the sibling
             smaller = jnp.where(lcn <= rcn, bl, new_leaf)
-            if hp.leaf_hist == "masked":
-                h_small = histogram_for_leaf_masked(
-                    bins_t, grad, hess, leaf_of_row, smaller, row_mask,
-                    n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
-                    hist_dtype=hp.hist_dtype, axis_name=hist_axis,
-                    hist_kernel=hp.hist_kernel, bins_words_t=words_t,
-                    overlap=overlap)
-            else:
-                h_small = histogram_for_leaf_bucketed(
-                    bins, grad, hess, leaf_of_row, smaller,
-                    jnp.minimum(lcn, rcn), row_mask,
-                    n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
-                    hist_dtype=hp.hist_dtype, axis_name=hist_axis,
-                    overlap=overlap)
-            h_small = _scaled(h_small)
+            h_small = _scaled(histogram_for_leaf_masked(
+                bins_t, grad, hess, leaf_of_row, smaller, row_mask,
+                n_bins=hp.n_bins, rows_per_block=hp.rows_per_block,
+                hist_dtype=hp.hist_dtype, axis_name=hist_axis,
+                hist_kernel=hp.hist_kernel, bins_words_t=words_t))
             h_parent = st.hist[bl]
             h_large = h_parent - h_small
             left_small = lcn <= rcn

@@ -576,7 +576,7 @@ def _rank_bucket_ladder(sizes: np.ndarray, spec) -> List[int]:
     is used as-is (extended with the max length when it falls short).
     The ``LGBMTPU_NO_RANK_BUCKETS=1`` hatch collapses the ladder to one
     pad-to-max bucket — the pre-bucketing geometry, kept as the A/B
-    baseline for bench.py and the parity tests."""
+    baseline for the parity tests."""
     qmax = int(sizes.max()) if len(sizes) else 1
     if os.environ.get("LGBMTPU_NO_RANK_BUCKETS"):
         return [qmax]

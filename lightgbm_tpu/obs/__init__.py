@@ -12,9 +12,7 @@ Three pillars (docs/OBSERVABILITY.md):
   * :mod:`.events` — structured lifecycle event journal
     (``event_output=<path>``, JSONL; declared schema, tpulint OBS302),
   * :mod:`.merge` — cross-rank trace merging with barrier-anchored
-    clock alignment (cluster runs),
-  * :mod:`.collective` — collective-overlap probes
-    (``overlap_efficiency`` / ``collective_s_per_pass`` gauges).
+    clock alignment (cluster runs).
 
 Everything is disabled by default and near-zero-cost when disabled: span
 emission is one module-global ``is None`` check, counters bump only on

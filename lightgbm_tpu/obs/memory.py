@@ -51,7 +51,7 @@ def device_memory_stats() -> Optional[Dict[str, Any]]:
     Returns ``{"platform": ..., "devices": [{"id", "bytes_in_use",
     "peak_bytes_in_use", ...}]}`` or ``None`` when no device reports
     (plain CPU backends).  Only called from cold paths (per-iteration
-    telemetry, bench preambles) — it touches the jax backend."""
+    telemetry) — it touches the jax backend."""
     try:
         import jax
         devs = jax.local_devices()
@@ -80,7 +80,7 @@ def device_memory_stats() -> Optional[Dict[str, Any]]:
 
 def memory_snapshot() -> Dict[str, Any]:
     """One sample of every memory axis — the record shape shared by the
-    telemetry JSONL, ``Booster.telemetry()`` and the bench preamble.
+    telemetry JSONL and ``Booster.telemetry()``.
     Host fields may be ``None`` off-Linux; ``device_memory`` is ``None``
     when no backend device reports stats."""
     dev = device_memory_stats()
@@ -90,7 +90,7 @@ def memory_snapshot() -> Dict[str, Any]:
         "device_memory": dev,
     }
     if dev and dev["devices"]:
-        # headline scalars for quick JSONL/bench reading (sum over devices)
+        # headline scalars for quick JSONL reading (sum over devices)
         out["device_bytes_in_use"] = _sum_field(dev, "bytes_in_use")
         out["device_peak_bytes_in_use"] = _sum_field(dev,
                                                      "peak_bytes_in_use")

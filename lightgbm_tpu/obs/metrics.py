@@ -49,8 +49,6 @@ COUNTERS: Dict[str, str] = {
         "process-level compile-cache hits (ops/compile_cache.py)",
     "round_compile_misses":
         "process-level compile-cache misses (ops/compile_cache.py)",
-    "collective_overlap_rounds":
-        "histogram rounds dispatched with the overlapped (chunked) psum",
     "xla_compile_events":
         "XLA backend compiles observed by the obs/ compile-event listener",
     "xla_program_lowerings":
@@ -127,9 +125,6 @@ COUNTERS: Dict[str, str] = {
         "structured events appended to the event journal (obs/events.py)",
     "trace_merges":
         "cross-rank trace merges performed (obs/merge.py)",
-    "collective_probe_runs":
-        "collective-overlap probe measurements compiled+timed "
-        "(obs/collective.py)",
     "rollup_windows_closed":
         "time-series rollup windows finalized into the ring "
         "(obs/timeseries.py)",

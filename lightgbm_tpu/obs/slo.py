@@ -38,8 +38,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 #: Every SLO name the package can watch, declared once as
 #: ``name: (domain, direction, default_budget, one-line meaning)``.
-#: ``direction`` is the violation sense: ``"max"`` = value above budget
-#: violates, ``"min"`` = value below budget violates.  Lint contract
+#: ``direction`` is the violation sense; every declared SLO is ``"max"``
+#: (a value above budget violates).  Lint contract
 #: (tpulint OBS303, same discipline as OBS301/OBS302): watching an
 #: undeclared name — or declaring one nothing watches — fails
 #: ``python tools/tpulint.py``.  Keys are parsed from this literal by
@@ -61,10 +61,6 @@ SLOS: Dict[str, tuple] = {
         "training", "max", 0.0,
         "nan-guard trips per boosting round in a window stays at budget "
         "(robustness/guards.py numeric guard)"),
-    "overlap_efficiency_floor": (
-        "training", "min", 0.25,
-        "collective overlap_efficiency gauge stays ABOVE the floor "
-        "(obs/collective.py probe; min-direction SLO)"),
     "compile_miss_storm": (
         "training", "max", 2.0,
         "compile-cache misses per window at steady state stay under "
@@ -162,10 +158,6 @@ def _heartbeat_staleness(window: Dict[str, Any]) -> Optional[float]:
     return _gauge(window, "heartbeat_staleness_s", "max")
 
 
-def _overlap_efficiency(window: Dict[str, Any]) -> Optional[float]:
-    return _gauge(window, "overlap_efficiency", "last")
-
-
 #: per-SLO value extractor over one finalized rollup window; a missing
 #: series returns None ("no data this window" — neutral for burn-rate)
 _EXTRACTORS: Dict[str, Callable] = {
@@ -173,7 +165,6 @@ _EXTRACTORS: Dict[str, Callable] = {
     "serving_error_rate": _serving_error_rate,
     "heartbeat_staleness_s": _heartbeat_staleness,
     "nan_guard_trip_rate": _nan_trip_rate,
-    "overlap_efficiency_floor": _overlap_efficiency,
     "compile_miss_storm": _compile_misses,
 }
 
@@ -195,11 +186,7 @@ class _Tracker:
         self.transitions = 0
 
     def violates(self, value: Optional[float]) -> bool:
-        if value is None:
-            return False
-        if self.direction == "min":
-            return value < self.budget
-        return value > self.budget
+        return value is not None and value > self.budget
 
 
 class SloEvaluator:

@@ -248,7 +248,7 @@ def get_or_build(key: Hashable, builder: Callable[[], Callable], *,
 
 def use_persistent_cache(default_dir: str) -> Optional[str]:
     """Place JAX's persistent (on-disk) compilation cache for a program
-    of this checkout: ``chip_smoke.py``, ``bench.py``, the test suite.
+    of this checkout: ``chip_smoke.py``, the test suite.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, the caller's machine has
     placed the cache and JAX reads the variable itself: no directory is

@@ -116,80 +116,12 @@ def _is_int8(compute_dtype) -> bool:
                    static_argnames=("n_bins", "rows_per_block",
                                     "feats_per_chunk", "compute_dtype",
                                     "interpret"))
-def histogram_pallas(bins_t: jax.Array, vals_t: jax.Array, *, n_bins: int,
-                     rows_per_block: int = 2048, feats_per_chunk: int = 0,
-                     compute_dtype=jnp.bfloat16,
-                     interpret: bool = False) -> jax.Array:
-    """hist[f, b, c] from transposed operands.
-
-    bins_t: uint8 [F, n] (row dim last); vals_t: f32 [C, n] (masked rows
-    carry zeros).  Returns f32 [F, n_bins, C].
-    """
-    num_f, n = bins_t.shape
-    c = vals_t.shape[0]
-    blk = min(rows_per_block, max(128, _round_up(n, 128)))
-    n_pad = _round_up(max(n, 1), blk)
-    # hist_compact (docs/OBSERVABILITY.md): the pads that prepare the
-    # kernel's operands are data movement, not the kernel
-    with jax.named_scope("hist_compact"):
-        if n_pad != n:
-            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-            vals_t = jnp.pad(vals_t, ((0, 0), (0, n_pad - n)))
-        fc = _pick_fc(num_f, feats_per_chunk)
-        f_pad = _round_up(num_f, fc)
-        if f_pad != num_f:
-            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
-    nb = n_pad // blk
-
-    def kernel(bins_ref, vals_ref, out_ref):
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        b_blk = bins_ref[:].astype(jnp.int32)          # [f_pad, blk]
-        if _is_int8(compute_dtype):
-            v_blk = vals_ref[:].astype(jnp.int32).astype(jnp.int8)
-        else:
-            v_blk = vals_ref[:].astype(compute_dtype)  # [c, blk]
-        iota = lax.iota(jnp.int32, n_bins)
-        for f0 in range(0, f_pad, fc):
-            chunk = b_blk[f0:f0 + fc]                  # [fc, blk]
-            oh_b = (chunk[:, None, :] == iota[None, :, None]
-                    ).reshape(fc * n_bins, blk)
-            acc = _oh_contract(v_blk, oh_b, compute_dtype)     # [c, fc*B]
-            out_ref[:, f0 * n_bins:(f0 + fc) * n_bins] += acc
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
-            pl.BlockSpec((c, blk), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((c, f_pad * n_bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((c, f_pad * n_bins),
-                                       _acc_dtype(compute_dtype)),
-        interpret=interpret,
-    )(bins_t, vals_t)
-    out = out.astype(jnp.float32)
-    # [C, F*B] -> [F, B, C]
-    out = out.reshape(c, f_pad, n_bins).transpose(1, 2, 0)
-    return out[:num_f]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_bins", "rows_per_block",
-                                    "feats_per_chunk", "compute_dtype",
-                                    "rows_major", "interpret"))
-def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
+def _histogram_leaves_impl(bins_t: jax.Array, grad: jax.Array,
                            hess: jax.Array, leaf_of_row: jax.Array,
                            leaves: jax.Array, *, n_bins: int,
                            rows_per_block: int = 2048,
                            feats_per_chunk: int = 0,
                            compute_dtype=jnp.bfloat16,
-                           rows_major: bool = False,
                            interpret: bool = False) -> jax.Array:
     """Fused masked multi-leaf histogram: f32 [K, F, n_bins, 4].
 
@@ -198,26 +130,18 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
     [3K, n] HBM materialization — the separate mask+stack stage measured
     ~12 ms/round at K=16 on 1M rows, ~2x the whole kernel (docs/PERF_NOTES.md).
 
-    ``bins``: u8 [F, n] transposed (``rows_major=False``, the resident
-    training layout) or u8 [S, F] row-major (``rows_major=True``, the layout
-    a compacted-frontier row gather produces — row gathers from [n, F] are
-    contiguous DMAs; lane-dim gathers from [F, n] are the slowest TPU
-    primitive).  grad/hess: f32 [n]; leaf_of_row: i32 [n] (-1 = excluded
-    row, e.g. bagging); leaves: i32 [K] (dummy slots may repeat).  Channel 3
-    of the output is zero padding for API parity.
+    ``bins_t``: u8 [F, n] transposed (the resident training layout).
+    grad/hess: f32 [n]; leaf_of_row: i32 [n] (-1 = excluded row, e.g.
+    bagging); leaves: i32 [K] (dummy slots may repeat).  Channel 3 of the
+    output is zero padding for API parity.
     """
-    if rows_major:
-        n, num_f = bins.shape
-    else:
-        num_f, n = bins.shape
+    num_f, n = bins_t.shape
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
     n_pad = _round_up(max(n, 1), blk)
     with jax.named_scope("hist_compact"):
         if n_pad != n:
-            row_pad = ((0, n_pad - n), (0, 0)) if rows_major \
-                else ((0, 0), (0, n_pad - n))
-            bins = jnp.pad(bins, row_pad)
+            bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
             grad = jnp.pad(grad, (0, n_pad - n))
             hess = jnp.pad(hess, (0, n_pad - n))
             leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
@@ -225,9 +149,7 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
         fc = _pick_fc(num_f, feats_per_chunk)
         f_pad = _round_up(num_f, fc)
         if f_pad != num_f:
-            feat_pad = ((0, 0), (0, f_pad - num_f)) if rows_major \
-                else ((0, f_pad - num_f), (0, 0))
-            bins = jnp.pad(bins, feat_pad)
+            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
     nb = n_pad // blk
     grad2 = grad[None, :]
     hess2 = hess[None, :]
@@ -261,26 +183,17 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
         b_blk = bins_ref[:].astype(jnp.int32)
         iota = lax.iota(jnp.int32, n_bins)
         for f0 in range(0, f_pad, fc):
-            # the one-hot is always built in the [fc*B, blk] orientation —
-            # for row-major input the small [blk, fc] chunk is transposed
-            # in-VMEM (building [blk, fc*B] instead needs a relayout copy of
-            # the one-hot that blows the VMEM scoped-allocation budget)
-            if rows_major:
-                chunk = b_blk[:, f0:f0 + fc].T              # [fc, blk]
-            else:
-                chunk = b_blk[f0:f0 + fc]                   # [fc, blk]
+            chunk = b_blk[f0:f0 + fc]                       # [fc, blk]
             oh_b = (chunk[:, None, :] == iota[None, :, None]
                     ).reshape(fc * n_bins, blk)
             acc = _oh_contract(vals, oh_b, compute_dtype)      # [3K, fc*B]
             out_ref[:, f0 * n_bins:(f0 + fc) * n_bins] += acc
 
-    bins_spec = pl.BlockSpec((blk, f_pad), lambda i: (i, 0)) if rows_major \
-        else pl.BlockSpec((f_pad, blk), lambda i: (0, i))
     out = pl.pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[
-            bins_spec,
+            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
             pl.BlockSpec((1, blk), lambda i: (0, i)),
             pl.BlockSpec((1, blk), lambda i: (0, i)),
             pl.BlockSpec((1, blk), lambda i: (0, i)),
@@ -290,7 +203,7 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
         out_shape=jax.ShapeDtypeStruct((3 * K, f_pad * n_bins),
                                        _acc_dtype(compute_dtype)),
         interpret=interpret,
-    )(bins, grad2, hess2, lor2, leaves2)
+    )(bins_t, grad2, hess2, lor2, leaves2)
     out = out.astype(jnp.float32)
     # [3K, F*B] -> [K, F, B, 3] -> pad channel dim to 4
     out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
@@ -298,17 +211,9 @@ def _histogram_leaves_impl(bins: jax.Array, grad: jax.Array,
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
 
 
-def histogram_leaves_pallas(bins_t, grad, hess, leaf_of_row, leaves, **kw):
-    """Fused masked multi-leaf histogram from TRANSPOSED [F, n] bins."""
-    return _histogram_leaves_impl(bins_t, grad, hess, leaf_of_row, leaves,
-                                  rows_major=False, **kw)
-
-
-def histogram_leaves_rows_pallas(bins_rows, grad, hess, leaf_of_row, leaves,
-                                 **kw):
-    """Fused masked multi-leaf histogram from ROW-major [S, F] bins."""
-    return _histogram_leaves_impl(bins_rows, grad, hess, leaf_of_row, leaves,
-                                  rows_major=True, **kw)
+#: the public name; the jitted function keeps its own, which is how a
+#: device trace and the ledger's ``device_ops`` know the flat kernel
+histogram_leaves_pallas = _histogram_leaves_impl
 
 
 @functools.partial(jax.jit,
@@ -331,7 +236,7 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     in-kernel whatever they contain, and so is the tail of a last block
     that reaches past S (no operand is padded).
 
-    Equivalent to ``histogram_leaves_rows_pallas`` on the unpacked
+    Equivalent to ``histogram_leaves_pallas`` on the unpacked, transposed
     operands; the contraction runs per word (fc = 4 features).
     """
     rows, S = payload.shape

@@ -25,7 +25,7 @@ histogram-subtraction trick (serial_tree_learner.cpp:364-378) on top.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,7 @@ NUM_CHANNELS = 4  # grad, hess, count, pad
 #: differs:
 #:   auto   — measured dispatch: radix single/joint where round-3 data
 #:            says they win, the new packed/radix2 formulations where
-#:            the one-hot build floor binds (see _masked_kernel_for);
+#:            the one-hot build floor binds (see hist_dispatch);
 #:   onehot — the flat one-hot kernels everywhere (the bit-identity
 #:            reference path);
 #:   packed — 4 bins per i32 lane, SWAR compares
@@ -65,83 +65,76 @@ def resolve_hist_kernel(name) -> str:
 _MODE_TEST_INTERPRET = False
 
 
-def wants_packed_mirror(hist_kernel, n_bins: int) -> bool:
-    """True when the resolved masked-pass kernel may consume the packed
-    word mirror — the callers' cue to keep ``bins_words_t`` resident."""
+class HistDispatch(NamedTuple):
+    """What ``hist_dispatch`` answers for one masked pass."""
+    kernel: str    # xla | flat | packed | radix2 | radix_joint | radix_single
+    mirror: bool   # keep the packed word mirror ``bins_words_t`` resident
+    ladder: bool   # the batched grower's width-matched warm-up ladder pays
+
+
+def hist_dispatch(hist_kernel, n_bins: int, K: int = 1, num_f: int = 1,
+                  have_words: bool = False,
+                  single: bool = False) -> HistDispatch:
+    """The one place that says which kernel a masked pass over ``K``
+    leaves of ``num_f`` features takes, whether the mode wants the packed
+    mirror shipped, and whether the warm-up ladder pays.  Pure in its
+    arguments plus the platform (``use_pallas()``; ``_MODE_TEST_INTERPRET``
+    stands in for it in the CPU suite).  ``single`` is the one-leaf entry
+    (``histogram_for_leaf_masked``: the root pass, the strict grower).
+
+    auto keeps the measured dispatch.  At >= 128 bins (a multiple of 16:
+    the radix kernels decompose bin = 16*hi + lo, ops/hist_pallas.py
+    ``_radix_shapes``) one leaf takes radix-single, K <= 4 radix-joint
+    (4.0/5.0/7.5 ms per 1M-row pass at K=1/2/4 against the flat kernel's
+    K-independent ~9.8, docs/PERF_NOTES.md round 3), K > 4 the
+    shared-radix kernel where its accumulator fits.  Below 128 bins the
+    radix build's small [p*nhi, 3*p*nlo] tiles waste the MXU (2.4 ms
+    against flat's 1.7 on a 63-bin K=42 pass, round 5) and the pass goes
+    to the packed-compare kernel where the mirror is resident.  Explicit
+    modes force their kernel where its shape constraints hold; every
+    other case is the flat one-hot kernel (bit-identical).
+
+    The ladder pays only where the K <= 4 pass takes the radix-JOINT
+    kernel, whose build scales with the leaf count; every other kernel is
+    K-independent below one MXU channel tile, so those modes seed the
+    round loop at full width from the root histogram: identical
+    selections, fewer compiled round bodies (docs/PERF_NOTES.md round 6).
+    """
     hk = resolve_hist_kernel(hist_kernel)
-    if hk == "packed":
-        return True
-    return hk == "auto" and not _radix_ok(n_bins) and not _no_packed()
-
-
-def ladder_profitable(hist_kernel, n_bins: int) -> bool:
-    """True when the batched grower's width-matched warmup ladder still
-    pays: only where the K<=4 masked pass takes the radix-JOINT kernel,
-    whose build scales with the leaf count (auto dispatch at >= 128
-    bins).  Every other mode's masked kernel is K-independent below one
-    MXU channel tile (round-3 measurement; packed/onehot/radix2 share
-    one build per block), so those configs seed the round loop at full
-    width straight from the root histogram instead — identical
-    selections (widths always cover the frontier), fewer compiled round
-    bodies (docs/PERF_NOTES.md round 6)."""
-    return resolve_hist_kernel(hist_kernel) == "auto" and _radix_ok(n_bins)
-
-
-def _no_packed() -> bool:
-    import os
-    return bool(os.environ.get("LGBMTPU_NO_PACKED"))  # perf A/B hatch
-
-
-def _no_radix2() -> bool:
-    import os
-    return bool(os.environ.get("LGBMTPU_NO_RADIX2"))  # perf A/B hatch
-
-
-def _no_overlap() -> bool:
-    import os
-    return bool(os.environ.get("LGBMTPU_NO_OVERLAP"))  # perf A/B hatch
-
-
-def overlap_enabled(overlap: bool) -> bool:
-    """Trace-time resolution of the overlapped-collective request:
-    the caller's ``overlap`` flag gated by the ``LGBMTPU_NO_OVERLAP``
-    A/B hatch.  Shared by :func:`reduce_hist` and the growers' scalar
-    root reductions so one env var kills every overlapped schedule."""
-    return bool(overlap) and not _no_overlap()
+    radix = hk == "auto" and n_bins % 16 == 0 and n_bins >= 128
+    mirror = hk == "packed" or (hk == "auto" and not radix)
+    if not (use_pallas() or _MODE_TEST_INTERPRET):
+        kernel = "xla"
+    elif radix and single:
+        kernel = "radix_single"
+    elif radix and K <= 4:
+        kernel = "radix_joint"
+    elif radix or hk == "radix2":
+        from .hist_pallas import radix2_pick_p
+        fits = (n_bins % 16 == 0 and n_bins >= 16
+                and radix2_pick_p(num_f, K, n_bins) > 0)
+        kernel = "radix2" if fits else "flat"
+    elif mirror and have_words:
+        kernel = "packed"
+    else:
+        kernel = "flat"
+    return HistDispatch(kernel, mirror, radix)
 
 
 def reduce_hist(hist: jax.Array, axis_name: Optional[str],
-                overlap: bool = False) -> jax.Array:
-    """All-reduce a histogram across ``axis_name`` (no-op when serial).
-
-    The single sink every histogram builder's cross-device reduction
-    flows through (``collective_overlap``, ISSUE 7).  With ``overlap``
-    off this is exactly the blocking ``lax.psum`` the builders always
-    issued.  With it on (and a leading axis to split), the reduction is
-    issued as TWO independent psums over disjoint leading-axis halves,
-    concatenated back together.  Bit-identical to the single psum: the
-    halves are disjoint slices, and each element still sums the same
-    per-device contributions in the same deterministic all-reduce order
-    — only the *scheduling* changes.  Two independent collective
-    start/done pairs give XLA's latency-hiding scheduler (TPU) a window
-    to overlap the first half's wire time with the second half's local
-    compute, instead of one monolithic blocking all-reduce.
-
-    ``LGBMTPU_NO_OVERLAP`` is the trace-time A/B hatch (same contract
-    as ``LGBMTPU_NO_PACKED``): set it to force the single-psum schedule
-    regardless of config.
-    """
+                _unused=False) -> jax.Array:
+    """All-reduce a histogram across ``axis_name`` (no-op when serial):
+    the single sink every histogram builder's cross-device reduction
+    flows through."""
+    # ``_unused``: benchmark/tools/faults_dp.py wraps this function and
+    # forwards three positional arguments, and this repo's PRs may not edit
+    # benchmark/ outside a benchmark issue; goes once that wrapper forwards
+    # two (ROADMAP D5).  No caller in lightgbm_tpu/ passes it.
     if axis_name is None:
         return hist
     # device scope ``hist_allreduce`` (docs/OBSERVABILITY.md): a trace
     # finds the collective by this name, whatever XLA makes of it
     with jax.named_scope("hist_allreduce"):
-        if overlap_enabled(overlap) and hist.ndim >= 1 \
-                and int(hist.shape[0]) >= 2:
-            k = int(hist.shape[0]) // 2
-            lo = lax.psum(hist[:k], axis_name)
-            hi = lax.psum(hist[k:], axis_name)
-            return jnp.concatenate([lo, hi], axis=0)
         return lax.psum(hist, axis_name)
 
 
@@ -174,38 +167,6 @@ def _pallas_blk(hist_dtype: str, n_bins: int = 256,
     if n_bins <= 64:
         return 2048
     return float_cap
-
-
-def histogram_rows(bins: jax.Array, vals: jax.Array, *, n_bins: int,
-                   rows_per_block: int = 4096,
-                   hist_dtype: str = "float32") -> jax.Array:
-    """Backend-dispatched histogram over a row set.
-
-    bins: uint8 [S, F]; vals: f32 [S, C] (masked rows zero).
-    Returns f32 [F, n_bins, C].
-    """
-    return histogram_rows_t(bins.T, vals.T, n_bins=n_bins,
-                            rows_per_block=rows_per_block,
-                            hist_dtype=hist_dtype)
-
-
-def histogram_rows_t(bins_t: jax.Array, vals_t: jax.Array, *, n_bins: int,
-                     rows_per_block: int = 4096,
-                     hist_dtype: str = "float32") -> jax.Array:
-    """Histogram from TRANSPOSED operands — the layout the TPU kernel wants
-    (row dim on lanes).  Callers on the hot path keep ``bins_t`` [F, n]
-    resident so no per-call 28-byte-strided transpose happens.
-
-    bins_t: uint8 [F, S]; vals_t: f32 [C, S].  Returns f32 [F, n_bins, C].
-    """
-    if use_pallas():
-        from .hist_pallas import histogram_pallas
-        return histogram_pallas(bins_t, vals_t, n_bins=n_bins,
-                                rows_per_block=min(rows_per_block,
-                                                   _pallas_blk(hist_dtype, n_bins)),
-                                compute_dtype=jnp.dtype(hist_dtype).type)
-    return build_histogram(bins_t.T, vals_t.T, n_bins=n_bins,
-                           rows_per_block=rows_per_block)
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "rows_per_block",
@@ -252,22 +213,6 @@ def build_histogram(bins: jax.Array, vals: jax.Array, *, n_bins: int = 256,
     return hist[:num_feat]
 
 
-def _radix_ok(n_bins: int) -> bool:
-    """The radix kernels decompose bin = 16*hi + lo (ops/hist_pallas.py
-    ``_radix_shapes``); any other bin width falls back to the flat kernel.
-    ``LGBMTPU_NO_RADIX=1`` disables them (perf A/B escape hatch).
-
-    Below 128 bins the flat kernel wins outright: the radix build cost is
-    nibble-bound (nhi + nlo one-hot elements — 20 at 64 bins vs the flat
-    kernel's 64) but its small [p*nhi, 3*p*nlo] matmul tiles waste the
-    MXU, measured 2.4 ms (radix joint) vs 1.7 ms (flat, full 63-bin K=42
-    masked pass) on the live chip in round 5."""
-    import os
-    if os.environ.get("LGBMTPU_NO_RADIX"):
-        return False
-    return n_bins % 16 == 0 and n_bins >= 128
-
-
 def histogram_for_leaf_masked(bins_t: jax.Array, grad: jax.Array,
                               hess: jax.Array, leaf_of_row: jax.Array,
                               leaf: jax.Array,
@@ -276,17 +221,15 @@ def histogram_for_leaf_masked(bins_t: jax.Array, grad: jax.Array,
                               hist_dtype: str = "float32",
                               axis_name: Optional[str] = None,
                               hist_kernel: str = "auto",
-                              bins_words_t: Optional[jax.Array] = None,
-                              overlap: bool = False
+                              bins_words_t: Optional[jax.Array] = None
                               ) -> jax.Array:
     """Leaf histogram by masking: one full-data pass with non-leaf rows
     zeroed.  O(n) per call but with NO compaction machinery.  Under
     ``hist_kernel=auto`` on TPU the single-group radix kernel carries it
     (~1.7x the flat one-hot kernel, docs/PERF_NOTES.md round 3);
     ``bins_t`` is the TRANSPOSED [F, n] matrix."""
-    hk = resolve_hist_kernel(hist_kernel)
-    if (use_pallas() or _MODE_TEST_INTERPRET) and hk == "auto" \
-            and _radix_ok(n_bins):
+    if hist_dispatch(hist_kernel, n_bins,
+                     single=True).kernel == "radix_single":
         from .hist_pallas import histogram_radix_single_pallas
         lor = jnp.asarray(leaf_of_row, jnp.int32)
         sel = lor == jnp.asarray(leaf, jnp.int32)
@@ -298,46 +241,14 @@ def histogram_for_leaf_masked(bins_t: jax.Array, grad: jax.Array,
             rows_per_block=min(rows_per_block, 2048),
             compute_dtype=jnp.dtype(hist_dtype).type,
             interpret=not use_pallas())
-        return reduce_hist(hist, axis_name, overlap)
+        return reduce_hist(hist, axis_name)
     leaf_arr = jnp.asarray(leaf, jnp.int32).reshape(1)
     hist = histogram_for_leaves_masked(
         bins_t, grad, hess, leaf_of_row, leaf_arr, row_mask, n_bins=n_bins,
         rows_per_block=rows_per_block, hist_dtype=hist_dtype,
-        axis_name=axis_name, hist_kernel=hk, bins_words_t=bins_words_t,
-        overlap=overlap)
+        axis_name=axis_name, hist_kernel=hist_kernel,
+        bins_words_t=bins_words_t)
     return hist[0]
-
-
-def _masked_kernel_for(hk: str, n_bins: int, K: int, num_f: int,
-                       have_words: bool) -> str:
-    """Resolve the masked-pass kernel for a mode: one of
-    flat / packed / radix2 / radix_joint.
-
-    auto keeps the round-3 measured dispatch (radix joint at K<=4 and
-    >= 128 bins) and routes the two cases the round-5 floor analysis
-    proved formulation-bound to the new kernels: the >= 128-bin K>4
-    masked pass (256-wide one-hot build, ~21% of int8 peak) to the
-    shared-radix kernel, and the sub-128-bin masked pass (build-phase
-    share grows as the dot shrinks, ~17% peak at 63 bins) to the
-    packed-compare kernel.  Explicit modes force their kernel where its
-    shape constraints hold and fall back to flat (bit-identical) where
-    they don't."""
-    from .hist_pallas import radix2_pick_p
-    radix2_fits = (n_bins % 16 == 0 and n_bins >= 16
-                   and radix2_pick_p(num_f, K, n_bins) > 0)
-    if hk == "packed":
-        return "packed" if have_words else "flat"
-    if hk == "radix2":
-        return "radix2" if radix2_fits else "flat"
-    if hk == "auto":
-        if _radix_ok(n_bins):
-            if K <= 4:
-                return "radix_joint"
-            if radix2_fits and not _no_radix2():
-                return "radix2"
-        elif have_words and not _no_packed():
-            return "packed"
-    return "flat"
 
 
 def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
@@ -349,8 +260,7 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
                                 hist_dtype: str = "float32",
                                 axis_name: Optional[str] = None,
                                 hist_kernel: str = "auto",
-                                bins_words_t: Optional[jax.Array] = None,
-                                overlap: bool = False
+                                bins_words_t: Optional[jax.Array] = None
                                 ) -> jax.Array:
     """Histograms of K leaves in ONE data pass -> f32 [K, F, B, C].
 
@@ -366,17 +276,14 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
     mirror [W, n] the packed mode consumes (io/dataset.py
     ``packed_mirror``).
     """
-    hk = resolve_hist_kernel(hist_kernel)
     K = leaves.shape[0]
     num_f = bins_t.shape[0]
     leaves = jnp.asarray(leaves, jnp.int32)
     lor = jnp.asarray(leaf_of_row, jnp.int32)
     if row_mask is not None:
         lor = jnp.where(row_mask, lor, -1)
-    kern_active = use_pallas() or _MODE_TEST_INTERPRET
-    kern = _masked_kernel_for(hk, n_bins, K, num_f,
-                              bins_words_t is not None) \
-        if kern_active else "xla"
+    kern = hist_dispatch(hist_kernel, n_bins, K, num_f,
+                         bins_words_t is not None).kernel
     interp = not use_pallas()
     if kern == "radix_joint":
         # joint (leaf, hi) radix kernel: measured 4.0/5.0/7.5 ms per 1M-row
@@ -387,7 +294,7 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
             bins_t, grad, hess, lor, leaves, n_bins=n_bins,
             rows_per_block=min(rows_per_block, 2048),
             compute_dtype=jnp.dtype(hist_dtype).type, interpret=interp)
-        return reduce_hist(hist, axis_name, overlap)
+        return reduce_hist(hist, axis_name)
     if kern == "radix2":
         from .hist_pallas import (histogram_leaves_radix2_pallas,
                                   radix2_pick_p)
@@ -396,7 +303,7 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
             rows_per_block=min(rows_per_block, 1024),
             p=radix2_pick_p(num_f, K, n_bins),
             compute_dtype=jnp.dtype(hist_dtype).type, interpret=interp)
-        return reduce_hist(hist, axis_name, overlap)
+        return reduce_hist(hist, axis_name)
     if kern == "packed":
         from .hist_pallas import histogram_leaves_packed_pallas
         hist = histogram_leaves_packed_pallas(
@@ -404,7 +311,7 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
             n_bins=n_bins,
             rows_per_block=min(rows_per_block, _pallas_blk(hist_dtype, n_bins)),
             compute_dtype=jnp.dtype(hist_dtype).type, interpret=interp)
-        return reduce_hist(hist, axis_name, overlap)
+        return reduce_hist(hist, axis_name)
     if kern == "flat":
         from .hist_pallas import histogram_leaves_pallas
         hist = histogram_leaves_pallas(
@@ -422,41 +329,16 @@ def histogram_for_leaves_masked(bins_t: jax.Array, grad: jax.Array,
                             jnp.zeros_like(m)], axis=0)
         C = vals_t.shape[0]
         vals_t = vals_t.reshape(C * K, -1)
-        hist = histogram_rows_t(bins_t, vals_t, n_bins=n_bins,
-                                rows_per_block=rows_per_block,
-                                hist_dtype=hist_dtype)        # [F, B, C*K]
+        hist = build_histogram(bins_t.T, vals_t.T, n_bins=n_bins,
+                               rows_per_block=rows_per_block)  # [F, B, C*K]
         F, B = hist.shape[0], hist.shape[1]
         hist = hist.reshape(F, B, C, K).transpose(3, 0, 1, 2)  # [K, F, B, C]
-    return reduce_hist(hist, axis_name, overlap)
-
-
-def _rows_leaves_hist(bins_rows: jax.Array, grad: jax.Array,
-                      hess: jax.Array, lor: jax.Array, leaves: jax.Array, *,
-                      n_bins: int, rows_per_block: int,
-                      hist_dtype: str) -> jax.Array:
-    """[K, F, B, C] histograms from row-major bins (backend-dispatched)."""
-    if use_pallas():
-        from .hist_pallas import histogram_leaves_rows_pallas
-        return histogram_leaves_rows_pallas(
-            bins_rows, grad, hess, lor, leaves, n_bins=n_bins,
-            rows_per_block=min(rows_per_block, _pallas_blk(hist_dtype, n_bins)),
-            compute_dtype=jnp.dtype(hist_dtype).type)
-    return histogram_for_leaves_masked(
-        jnp.asarray(bins_rows).T, grad, hess, lor, leaves, None,
-        n_bins=n_bins, rows_per_block=rows_per_block, hist_dtype=hist_dtype,
-        hist_kernel="onehot")
+    return reduce_hist(hist, axis_name)
 
 
 # test hook: lets the CPU suite exercise the payload Pallas kernel via the
 # interpreter (use_pallas() is False off-TPU)
 _PAYLOAD_TEST_INTERPRET = False
-
-
-def _use_payload_kernel() -> bool:
-    import os
-    if os.environ.get("LGBMTPU_NO_PAYLOAD_KERNEL"):  # perf A/B escape hatch
-        return False
-    return use_pallas() or _PAYLOAD_TEST_INTERPRET
 
 
 def bins_to_words(bins_rows: jax.Array) -> jax.Array:
@@ -484,8 +366,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                               bins_words: Optional[jax.Array] = None,
                               sort_key: Optional[jax.Array] = None,
                               hist_kernel: str = "auto",
-                              bins_words_t: Optional[jax.Array] = None,
-                              overlap: bool = False
+                              bins_words_t: Optional[jax.Array] = None
                               ) -> jax.Array:
     """K-leaf histograms with frontier compaction -> f32 [K, F, B, C].
 
@@ -571,7 +452,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
 
     def compacted(S: int, operands):
         key_, grad_, hess_, lor_ = operands
-        if _use_payload_kernel():
+        if use_pallas() or _PAYLOAD_TEST_INTERPRET:
             from .hist_pallas import (compact_payload_pallas,
                                       histogram_payload_pallas)
             interp = not use_pallas()
@@ -592,7 +473,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                     compute_dtype=jnp.dtype(hist_dtype).type,
                     interpret=interp)
         # XLA path (CPU tests / non-TPU): sort the keys, gather the rows
-        # of the row-major payload, unpack and run the generic rows path
+        # of the row-major payload, unpack and run the masked pass on them
         with jax.named_scope(f"hist_rows_{S}"):
             with jax.named_scope("hist_compact"):
                 words = bins_to_words(bins_rows) if bins_words is None \
@@ -613,10 +494,10 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
                 grad_c = lax.bitcast_convert_type(pc[:, W], jnp.float32)
                 hess_c = lax.bitcast_convert_type(pc[:, W + 1], jnp.float32)
                 lor_c = jnp.where(valid, pc[:, W + 2], -1)
-                return _rows_leaves_hist(rows_c, grad_c, hess_c, lor_c,
-                                         leaves, n_bins=n_bins,
-                                         rows_per_block=rows_per_block,
-                                         hist_dtype=hist_dtype)
+                return histogram_for_leaves_masked(
+                    rows_c.T, grad_c, hess_c, lor_c, leaves, None,
+                    n_bins=n_bins, rows_per_block=rows_per_block,
+                    hist_dtype=hist_dtype, hist_kernel="onehot")
 
     branches = [full_branch] + [functools.partial(compacted, s)
                                 for s in sizes]
@@ -626,69 +507,7 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             j = jnp.where(cnt <= s, jnp.int32(k + 1), j)
     hist = lax.switch(j, branches, (sort_key, grad, hess, lor))
     with jax.named_scope("hist_kernel"):
-        return reduce_hist(hist, axis_name, overlap)
-
-
-def histogram_for_leaf_bucketed(bins: jax.Array, grad: jax.Array,
-                                hess: jax.Array, leaf_of_row: jax.Array,
-                                leaf: jax.Array, leaf_count: jax.Array,
-                                row_mask: Optional[jax.Array] = None, *,
-                                n_bins: int = 256, rows_per_block: int = 4096,
-                                min_bucket: int = 8192, hist_dtype: str = "float32",
-                                axis_name: Optional[str] = None,
-                                overlap: bool = False) -> jax.Array:
-    """Histogram of one leaf touching only ~leaf_count rows.
-
-    The TPU reformulation of the reference's ordered-index iteration
-    (CUDADataPartition keeps rows physically grouped by leaf;
-    dense_bin.hpp iterates data_indices): rows stay in place, but the
-    leaf's row indices are compacted with a sized ``nonzero`` and gathered
-    into the smallest power-of-two buffer that fits (``lax.switch`` over
-    log2(n) precompiled bucket sizes), so histogram cost follows the
-    smaller child's size instead of the full dataset — preserving the
-    O(n log L) total work of leaf-wise growth with histogram subtraction
-    (serial_tree_learner.cpp:364-378).
-
-    ``leaf_count`` is the number of rows in ``leaf`` (device scalar).
-    """
-    n = bins.shape[0]
-    mask = (leaf_of_row == leaf)
-    if row_mask is not None:
-        mask = mask & row_mask
-
-    # bucket sizes n, n/2, n/4, ..., >= min_bucket
-    sizes = []
-    s = _round_up(n, 128)
-    while True:
-        sizes.append(s)
-        if s <= min_bucket:
-            break
-        s = _round_up((s + 1) // 2, 128)
-    # branch index: largest j with sizes[j] >= count
-    count = jnp.maximum(leaf_count.astype(jnp.int32), 1)
-    j = jnp.int32(0)
-    for k, sz in enumerate(sizes):
-        j = jnp.where(count <= sz, jnp.int32(k), j)
-
-    def make_branch(sz: int):
-        def branch(operands):
-            mask_, grad_, hess_ = operands
-            idx = jnp.nonzero(mask_, size=sz, fill_value=n)[0]
-            valid = (idx < n).astype(grad_.dtype)
-            idxc = jnp.minimum(idx, n - 1)
-            b_sub = bins[idxc]
-            g_sub = grad_[idxc] * valid
-            h_sub = hess_[idxc] * valid
-            vals = jnp.stack([g_sub, h_sub, valid, jnp.zeros_like(valid)],
-                             axis=1)
-            return histogram_rows(b_sub, vals, n_bins=n_bins,
-                                  rows_per_block=rows_per_block,
-                                  hist_dtype=hist_dtype)
-        return branch
-
-    hist = lax.switch(j, [make_branch(sz) for sz in sizes],
-                      (mask, grad, hess))
-    return reduce_hist(hist, axis_name, overlap)
+        return reduce_hist(hist, axis_name)
 
 
 def root_histogram(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -697,29 +516,15 @@ def root_histogram(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                    hist_dtype: str = "float32",
                    axis_name: Optional[str] = None,
                    hist_kernel: str = "auto",
-                   bins_words_t: Optional[jax.Array] = None,
-                   overlap: bool = False) -> jax.Array:
-    """Root histogram from the TRANSPOSED [F, n] bin matrix."""
-    hist_kernel = resolve_hist_kernel(hist_kernel)
+                   bins_words_t: Optional[jax.Array] = None) -> jax.Array:
+    """Root histogram from the TRANSPOSED [F, n] bin matrix: the one-leaf
+    pass with every row in leaf 0."""
     # a full pass over every row, under the same scopes as the full
     # branch of ``histogram_for_leaves_auto``
     with jax.named_scope("hist_rows_full"), jax.named_scope("hist_kernel"):
-        if use_pallas() or _MODE_TEST_INTERPRET:
-            # single-leaf delegation picks the mode kernel (radix single
-            # under auto when bins allow, packed/radix2/flat otherwise)
-            lor = jnp.zeros(grad.shape, jnp.int32)
-            return histogram_for_leaf_masked(
-                bins_t, grad, hess, lor, jnp.int32(0), row_mask,
-                n_bins=n_bins, rows_per_block=rows_per_block,
-                hist_dtype=hist_dtype, axis_name=axis_name,
-                hist_kernel=hist_kernel, bins_words_t=bins_words_t,
-                overlap=overlap)
-        m = jnp.ones_like(grad) if row_mask is None \
-            else row_mask.astype(grad.dtype)
-        vals_t = jnp.stack([jnp.where(m > 0, grad, 0.0),
-                            jnp.where(m > 0, hess, 0.0), m,
-                            jnp.zeros_like(m)], axis=0)
-        hist = histogram_rows_t(bins_t, vals_t, n_bins=n_bins,
-                                rows_per_block=rows_per_block,
-                                hist_dtype=hist_dtype)
-        return reduce_hist(hist, axis_name, overlap)
+        return histogram_for_leaf_masked(
+            bins_t, grad, hess, jnp.zeros(grad.shape, jnp.int32),
+            jnp.int32(0), row_mask, n_bins=n_bins,
+            rows_per_block=rows_per_block, hist_dtype=hist_dtype,
+            axis_name=axis_name, hist_kernel=hist_kernel,
+            bins_words_t=bins_words_t)

@@ -46,13 +46,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 def use_fused_partition() -> bool:
-    import os
-    if os.environ.get("LGBMTPU_NO_FUSED_PARTITION"):  # perf A/B hatch
-        return False
-    if _FUSE_TEST_INTERPRET:
-        return True
     from .histogram import use_pallas
-    return use_pallas()
+    return _FUSE_TEST_INTERPRET or use_pallas()
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))

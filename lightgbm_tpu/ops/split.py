@@ -82,11 +82,6 @@ class SplitHyper:
     # kernels, "onehot" = the flat one-hot reference path, "packed" /
     # "radix2" = force a formulation.  All modes are bit-identical.
     hist_kernel: str = "auto"
-    # per-leaf histogram strategy: "masked" = flat full-data pass with
-    # non-leaf rows zeroed (no compaction; TPU-friendly), "bucketed" =
-    # nonzero+gather into power-of-two buckets (wins only when leaves are
-    # tiny relative to n AND gathers are cheap)
-    leaf_hist: str = "masked"
     # bounded histogram pool (reference feature_histogram.hpp:1367
     # HistogramPool, serial_tree_learner.cpp:36-47 histogram_pool_size):
     # 0 = one resident histogram per leaf ([L, F, B, 4]); > 0 = that many

@@ -72,7 +72,7 @@ def grow_tree_sharded(mesh: Optional[Mesh], bins: jax.Array, grad: jax.Array,
                       bundle=None, parallel_mode: str = "data",
                       top_k: int = 20, monotone=None, rng_key=None,
                       interaction_sets=None, forced=None,
-                      hist_scale=None, overlap: bool = False,
+                      hist_scale=None,
                       metrics=None) -> Tuple[TreeArrays, jax.Array]:
     """Grow one tree with rows sharded over ``mesh``'s data axis.
 
@@ -117,13 +117,12 @@ def grow_tree_sharded(mesh: Optional[Mesh], bins: jax.Array, grad: jax.Array,
                          axis_name=DATA_AXIS, bundle=bd, monotone=mono,
                          rng_key=key, interaction_sets=isets, forced=fsp,
                          parallel_mode=parallel_mode, top_k=top_k,
-                         num_shards=mesh.devices.size, hist_scale=hs,
-                         overlap=overlap)
+                         num_shards=mesh.devices.size, hist_scale=hs)
 
     fn = _cached_shard_map(
         "grow_tree_sharded", mesh, local, tuple(s for s in in_specs),
         out_specs,
-        (hp, parallel_mode, top_k, overlap,
+        (hp, parallel_mode, top_k,
          sig((bins, grad, hess, row_mask, num_bins, nan_bin, is_cat,
               feature_mask, bundle, monotone, rng_key, interaction_sets,
               forced, hist_scale))),
@@ -140,7 +139,6 @@ def train_step_sharded(mesh: Optional[Mesh], bins: jax.Array,
                        is_cat: jax.Array, hp: SplitHyper, *,
                        learning_rate: float = 0.1,
                        objective: str = "binary",
-                       overlap: bool = False,
                        metrics=None) -> Tuple[TreeArrays, jax.Array]:
     """One FULL boosting step (gradients -> tree -> score update), rows
     sharded — the unit the driver dry-runs multi-chip.  Gradient math is
@@ -168,14 +166,14 @@ def train_step_sharded(mesh: Optional[Mesh], bins: jax.Array,
             g = sc - y
             h = jnp.ones_like(sc)
         tree, leaf_of_row = grow_tree(b, g, h, m, nb, nanb, cat, None, hp,
-                                      axis_name=DATA_AXIS, overlap=overlap)
+                                      axis_name=DATA_AXIS)
         new_scores = sc + learning_rate * take_small_table(tree.leaf_value,
                                                            leaf_of_row)
         return tree, new_scores
 
     fn = _cached_shard_map(
         "train_step_sharded", mesh, local, in_specs, out_specs,
-        (hp, learning_rate, objective, overlap,
+        (hp, learning_rate, objective,
          sig((bins, scores, label, row_mask, num_bins, nan_bin, is_cat))),
         metrics=metrics)
     return fn(bins, scores, label, row_mask, num_bins, nan_bin, is_cat)
@@ -189,7 +187,6 @@ def train_fused_sharded(mesh: Optional[Mesh], bins: jax.Array,
                         learning_rate: float = 0.1, batch: int = 8,
                         objective: str = "binary",
                         quantize: bool = False, seed: int = 0,
-                        overlap: bool = False,
                         metrics=None) -> Tuple[TreeArrays, jax.Array]:
     """The flagship FUSED round scan (GBDT.train_fused's inner program:
     gradients -> batched tree -> score update, ``num_rounds`` rounds in
@@ -238,8 +235,7 @@ def train_fused_sharded(mesh: Optional[Mesh], bins: jax.Array,
                 hist_scale = jnp.stack([gs, hs])
             tree, lor = grow_tree_batched(
                 b, g, h, None, nb, nanb, cat, None, hp, batch=batch,
-                axis_name=DATA_AXIS, hist_scale=hist_scale,
-                overlap=overlap)
+                axis_name=DATA_AXIS, hist_scale=hist_scale)
             sc = sc + learning_rate * take_small_table(tree.leaf_value, lor)
             return sc, tree
         sc, trees = jax.lax.scan(step, sc, jnp.arange(num_rounds))
@@ -248,7 +244,6 @@ def train_fused_sharded(mesh: Optional[Mesh], bins: jax.Array,
     fn = _cached_shard_map(
         "train_fused_sharded", mesh, local, in_specs, out_specs,
         (hp, num_rounds, learning_rate, batch, objective, quantize, seed,
-         overlap,
          sig((bins, scores, label, num_bins, nan_bin, is_cat))),
         metrics=metrics)
     return fn(bins, scores, label, num_bins, nan_bin, is_cat)
@@ -267,8 +262,7 @@ def grow_tree_batched_sharded(mesh: Optional[Mesh], bins: jax.Array,
                               hist_scale: Optional[jax.Array] = None,
                               interaction_sets: Optional[jax.Array] = None,
                               parallel_mode: str = "data",
-                              top_k: int = 20, overlap: bool = False,
-                              metrics=None
+                              top_k: int = 20, metrics=None
                               ) -> Tuple[TreeArrays, jax.Array]:
     """Batched-round grower (learner/batch_grower.py) under the data mesh:
     K splits per psum-ed widened histogram pass ("data"), or per LOCAL
@@ -302,12 +296,11 @@ def grow_tree_batched_sharded(mesh: Optional[Mesh], bins: jax.Array,
                                  axis_name=DATA_AXIS, hist_scale=hs,
                                  interaction_sets=isets,
                                  parallel_mode=parallel_mode, top_k=top_k,
-                                 num_shards=mesh.devices.size,
-                                 overlap=overlap)
+                                 num_shards=mesh.devices.size)
 
     fn = _cached_shard_map(
         "grow_tree_batched_sharded", mesh, local, in_specs, out_specs,
-        (hp, batch, parallel_mode, top_k, overlap,
+        (hp, batch, parallel_mode, top_k,
          sig((bins, grad, hess, row_mask, num_bins, nan_bin, is_cat,
               feature_mask, bundle, monotone, hist_scale,
               interaction_sets))),

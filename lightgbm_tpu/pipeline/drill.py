@@ -60,7 +60,7 @@ def _drift_weights(i: int, n_chunks: int, n_features: int) -> np.ndarray:
 
 
 def _client_hammer(server, name: str, probe: np.ndarray, log_path: str,
-                   stop: threading.Event) -> None:
+                   stop: threading.Event, served: threading.Event) -> None:
     """Continuously serve ``probe`` against the live registry, appending
     one JSONL observation per request.  'No model yet' is a wait, not a
     failure; any exception once a model exists IS a failure — the drill
@@ -79,6 +79,7 @@ def _client_hammer(server, name: str, probe: np.ndarray, log_path: str,
                 rec = {"ok": False, "error": f"{type(e).__name__}: {e}"}
             fh.write(json.dumps(rec) + "\n")
             fh.flush()
+            served.set()
             time.sleep(0.002)
 
 
@@ -91,13 +92,14 @@ def run_spec(spec: dict) -> dict:
     server = PredictionServer(params=dict(spec.get("server_params") or {}))
     target = ServerTarget(server)
 
-    stop = threading.Event()
+    stop, served = threading.Event(), threading.Event()
     hammer = None
     if spec.get("client_log"):
         probe = X[:8]
         hammer = threading.Thread(
             target=_client_hammer,
-            args=(server, spec["name"], probe, spec["client_log"], stop),
+            args=(server, spec["name"], probe, spec["client_log"], stop,
+                  served),
             daemon=True)
         hammer.start()
 
@@ -122,6 +124,10 @@ def run_spec(spec: dict) -> dict:
         chunk_rows=int(spec["rows_per_chunk"]), phase_hook=hook)
     try:
         summary = trainer.run(num_cycles=spec.get("num_cycles"))
+        if hammer is not None and spec["name"] in server.registry.names():
+            # a one-cycle run publishes as it returns: the hammer's first
+            # request (it compiles) must land before it is told to stop
+            served.wait(timeout=60.0)
     finally:
         stop.set()
         if hammer is not None:

@@ -34,12 +34,15 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-EXAMPLES = "/root/reference/examples"
+#: small seeded stand-ins for the upstream examples/ tree
+#: (tests/data/examples/make_examples.py wrote them)
+EXAMPLES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "examples")
 
 
 @pytest.fixture(scope="session")
 def binary_example():
-    """Reference binary_classification example data (TSV, label col 0)."""
+    """The binary_classification example data (TSV, label col 0)."""
     tr = np.loadtxt(f"{EXAMPLES}/binary_classification/binary.train")
     te = np.loadtxt(f"{EXAMPLES}/binary_classification/binary.test")
     return (tr[:, 1:], tr[:, 0].astype(np.float64),

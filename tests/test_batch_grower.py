@@ -241,7 +241,7 @@ def test_warmup_rounds_same_tree_large_n(monkeypatch):
     selection: the grown tree matches the no-warmup result on identical
     inputs.  Round 6 gates the ladder to configs whose masked pass takes
     the K-scaling radix-joint kernel (auto dispatch, >= 128 bins —
-    ops/histogram.py ladder_profitable), so the test runs there, with
+    ops/histogram.py hist_dispatch), so the test runs there, with
     the row gate patched down to keep it CPU-cheap."""
     import lightgbm_tpu.learner.batch_grower as BG
     monkeypatch.setattr(BG, "_WARMUP_MIN_ROWS", 1024)
@@ -257,8 +257,8 @@ def test_warmup_rounds_same_tree_large_n(monkeypatch):
             jnp.asarray(np.full(f, 128, np.int32)),
             jnp.asarray(np.full(f, -1, np.int32)),
             jnp.asarray(np.zeros(f, bool)), None, hp)
-    from lightgbm_tpu.ops.histogram import ladder_profitable
-    assert ladder_profitable(hp.hist_kernel, hp.n_bins)
+    from lightgbm_tpu.ops.histogram import hist_dispatch
+    assert hist_dispatch(hp.hist_kernel, hp.n_bins).ladder
     t_warm, lor_warm = grow_tree_batched.__wrapped__(*args, batch=4)
     t_ref, lor_ref = grow_tree_batched(*args, batch=4, warmup=False)
     # the warmup widths always cover the whole frontier (frontier after r

@@ -1,5 +1,6 @@
 """CLI application tests (reference test strategy: test_consistency.py runs
-the CLI on examples/*.conf and compares with the Python API)."""
+the CLI on examples/*.conf and compares with the Python API), on the
+stand-in examples under tests/data/examples/."""
 
 import os
 import subprocess
@@ -11,7 +12,8 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.application import main, parse_argv, parse_config_file
 
-EXAMPLES = "/root/reference/examples"
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+EXAMPLES = os.path.join(REPO, "tests", "data", "examples")
 BIN_DIR = f"{EXAMPLES}/binary_classification"
 
 
@@ -94,7 +96,7 @@ def test_python_dash_m_entry(tmp_path):
         [sys.executable, "-m", "lightgbm_tpu",
          f"data={BIN_DIR}/binary.train", "objective=binary",
          "num_trees=2", f"output_model={model}", "verbose=-1"],
-        cwd="/root/repo", env=env, capture_output=True, text=True,
+        cwd=REPO, env=env, capture_output=True, text=True,
         timeout=300)
     assert r.returncode == 0, r.stderr
     assert model.exists()

@@ -1,6 +1,10 @@
-"""CLI-vs-Python-API consistency on the reference's shipped example configs
-(reference test strategy: tests/python_package_test/test_consistency.py runs
-the CLI on examples/*.conf and compares against the Python API)."""
+"""CLI-vs-Python-API consistency on example configs laid out like the
+reference's (reference test strategy: tests/python_package_test/
+test_consistency.py runs the CLI on examples/*.conf and compares against
+the Python API).  The examples are the stand-ins under
+tests/data/examples/."""
+
+import os
 
 import numpy as np
 import pytest
@@ -8,7 +12,8 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.application import main
 
-EXAMPLES = "/root/reference/examples"
+EXAMPLES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "examples")
 
 CASES = {
     "regression": ("regression", "regression.train", "regression.test",
@@ -52,7 +57,6 @@ def test_example_conf_trains_and_matches_python_api(example, tmp_path):
         # the regression example ships companion .init score files; like the
         # reference, predictions EXCLUDE external init scores — add them
         # back for the quality check (gbdt.cpp:308 skips boost_from_average)
-        import os
         init_f = f"{EXAMPLES}/{d}/{test_f}.init"
         base = np.loadtxt(init_f) if os.path.exists(init_f) else 0.0
         # 10 trees at the conf's small lr: require improvement over the

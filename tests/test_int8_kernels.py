@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops.hist_pallas import (
-    _histogram_leaves_impl, histogram_pallas, histogram_payload_pallas,
+    _histogram_leaves_impl, histogram_payload_pallas,
     histogram_radix_joint_pallas, histogram_radix_single_pallas)
 import lightgbm_tpu.ops.histogram as H
 
@@ -41,27 +41,6 @@ def test_flat_masked_int8_matches_f32():
                                  compute_dtype=jnp.int8, **kw)
     want = _histogram_leaves_impl(bins.T, grad, hess, lor, leaves,
                                   compute_dtype=jnp.float32, **kw)
-    _assert_same(got, want)
-
-
-def test_flat_masked_int8_rows_major():
-    bins, grad, hess, lor, leaves = _mk(f=10)
-    kw = dict(n_bins=64, rows_per_block=512, rows_major=True,
-              interpret=True)
-    got = _histogram_leaves_impl(bins, grad, hess, lor, leaves,
-                                 compute_dtype=jnp.int8, **kw)
-    want = _histogram_leaves_impl(bins, grad, hess, lor, leaves,
-                                  compute_dtype=jnp.float32, **kw)
-    _assert_same(got, want)
-
-
-def test_plain_hist_int8():
-    bins, grad, hess, lor, _ = _mk()
-    sel = (lor >= 0).astype(jnp.float32)
-    vals_t = jnp.stack([grad * sel, hess * sel, sel], axis=0)
-    kw = dict(n_bins=64, rows_per_block=512, interpret=True)
-    got = histogram_pallas(bins.T, vals_t, compute_dtype=jnp.int8, **kw)
-    want = histogram_pallas(bins.T, vals_t, compute_dtype=jnp.float32, **kw)
     _assert_same(got, want)
 
 

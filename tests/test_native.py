@@ -1,6 +1,8 @@
 """Native C++ data-plane tests (reference's parser/bin-push are C++:
 src/io/parser.cpp, bin.h ValueToBin — parity vs the NumPy fallback)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -13,7 +15,8 @@ except ImportError:  # no compiler in this environment
 pytestmark = pytest.mark.skipif(not HAVE_NATIVE,
                                 reason="native toolchain unavailable")
 
-BIN_TRAIN = "/root/reference/examples/binary_classification/binary.train"
+BIN_TRAIN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                         "examples", "binary_classification", "binary.train")
 
 
 def test_parse_matches_numpy():
@@ -60,5 +63,6 @@ def test_dataset_from_file_uses_native_transparently():
     """End-to-end: Dataset(path) parses + bins identically to before."""
     import lightgbm_tpu as lgb
     ds = lgb.Dataset(BIN_TRAIN, params={"verbose": -1}).construct()
-    assert ds.num_data() == 7000
-    assert ds.num_feature() == 28
+    ref = np.loadtxt(BIN_TRAIN)
+    assert ds.num_data() == ref.shape[0] == 500
+    assert ds.num_feature() == ref.shape[1] - 1 == 28

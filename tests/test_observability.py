@@ -1,12 +1,11 @@
 """One-pane-of-glass observability tests (obs/events.py, obs/merge.py,
-obs/collective.py, tools/run_report.py — docs/OBSERVABILITY.md).
+tools/run_report.py — docs/OBSERVABILITY.md).
 
 Covers the PR-10 acceptance surface: the structured event journal's
 schema + declared-name discipline, cross-rank trace merging with
 injected clock skew (monotonic, rank-0-aligned, Perfetto-valid), the
-elastic kill drill narrated in journal AND trace, the collective-overlap
-probe's ``LGBMTPU_NO_OVERLAP`` A/B, the serving metrics snapshot, and
-the ``run_report`` CI gate's exit codes — plus off-by-default: no
+elastic kill drill narrated in journal AND trace, the serving metrics
+snapshot, and the ``run_report`` CI gate's exit codes — plus off-by-default: no
 configured outputs, no new files.
 """
 
@@ -319,8 +318,7 @@ def test_run_report_full_join_payload(tmp_path, capsys):
             "round_compile_misses": 2}}) + "\n")
         fh.write(json.dumps({"iteration": 3, "counters": {
             "round_compile_misses": 2, "round_compile_hits": 5},
-            "gauges": {"overlap_efficiency": 0.25,
-                       "collective_s_per_round": 0.001}}) + "\n")
+            "gauges": {"rank_pad_rows": 12}}) + "\n")
     rc = rr.main(["--telemetry", str(tele_p), "--format", "json"])
     doc = json.loads(capsys.readouterr().out)
     assert rc == 0
@@ -328,7 +326,7 @@ def test_run_report_full_join_payload(tmp_path, capsys):
     assert tel["rows"] == 2
     assert tel["first_round"] == 0 and tel["last_round"] == 3
     assert tel["compile"]["round_compile_hits"] == 5
-    assert tel["collective"]["overlap_efficiency"] == 0.25
+    assert tel["rank"]["rank_pad_rows"] == 12
 
 
 # ----------------------------------------------------------- trace_report
@@ -355,56 +353,6 @@ def test_trace_report_merged_and_events_overlay(tmp_path, capsys):
     rc = tr.main([base, "--events", str(tmp_path / "missing.jsonl")])
     capsys.readouterr()
     assert rc == 2
-
-
-# ------------------------------------------------------ collective overlap
-def test_collective_probe_ab_responds_to_no_overlap(monkeypatch):
-    jax = pytest.importorskip("jax")
-    if jax.device_count() < 2:
-        pytest.skip("needs >1 virtual device")
-    from lightgbm_tpu.obs import collective
-    from lightgbm_tpu.obs.metrics import MetricsRegistry
-    from lightgbm_tpu.parallel.mesh import make_mesh
-    mesh = make_mesh()
-    monkeypatch.delenv("LGBMTPU_NO_OVERLAP", raising=False)
-    collective.reset_cache()
-    m_on = MetricsRegistry()
-    res_on = collective.measure_collective(mesh, (64, 16, 4),
-                                           metrics=m_on)
-    assert res_on["overlap_on"] == 1.0
-    assert res_on["collective_s_per_pass"] > 0.0
-    assert 0.0 <= res_on["overlap_efficiency"] <= 1.0
-    g = m_on.snapshot()["gauges"]
-    for key in ("collective_s_per_pass", "collective_s_blocked",
-                "overlap_efficiency", "overlap_on"):
-        assert key in g, key
-    # A/B: the same knob the training path honors kills the overlap
-    monkeypatch.setenv("LGBMTPU_NO_OVERLAP", "1")
-    collective.reset_cache()
-    res_off = collective.measure_collective(mesh, (64, 16, 4))
-    assert res_off["overlap_on"] == 0.0
-    assert res_off["overlap_efficiency"] == 0.0
-    collective.reset_cache()
-
-
-def test_training_records_collective_gauges(tmp_path, synthetic_binary):
-    jax = pytest.importorskip("jax")
-    if jax.device_count() < 2:
-        pytest.skip("needs >1 virtual device")
-    X, y = synthetic_binary
-    tele = str(tmp_path / "tele.jsonl")
-    p = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
-         "verbose": -1, "tree_learner": "data",
-         "telemetry_output": tele}
-    lgb.train(p, lgb.Dataset(X[:512], label=y[:512], params=p),
-              num_boost_round=2)
-    rows = [json.loads(line) for line in open(tele)]
-    gauges = {}
-    for r in rows:
-        gauges.update(r.get("gauges") or {})
-    assert "overlap_efficiency" in gauges
-    assert "collective_s_per_round" in gauges
-    assert gauges["collective_s_per_round"] >= 0.0
 
 
 # ------------------------------------------------------------ off by default

@@ -373,101 +373,6 @@ def test_pooled_grower_composes_with_shard_map(problem):
     np.testing.assert_array_equal(np.asarray(lor_p), np.asarray(lor_f))
 
 
-# --------------------------------------------------------------- round 6
-def _int_grads(problem, seed=5):
-    """Integer-valued f32 gradients (test_hist_modes idiom): sums are
-    exact under ANY reduction order, so a single differing bit between
-    two collective schedules proves a real divergence, not float
-    reassociation."""
-    n = problem[0].shape[0]
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(-8, 8, n).astype(np.float32)),
-            jnp.asarray(rng.integers(1, 8, n).astype(np.float32)))
-
-
-def _assert_trees_identical(a_tree, a_lor, b_tree, b_lor):
-    np.testing.assert_array_equal(np.asarray(a_tree.split_feature),
-                                  np.asarray(b_tree.split_feature))
-    np.testing.assert_array_equal(np.asarray(a_tree.split_bin),
-                                  np.asarray(b_tree.split_bin))
-    # bit-identity, not allclose: the overlapped reduction must change
-    # the SCHEDULE only, never a single accumulated bit
-    np.testing.assert_array_equal(np.asarray(a_tree.leaf_value),
-                                  np.asarray(b_tree.leaf_value))
-    np.testing.assert_array_equal(np.asarray(a_lor), np.asarray(b_lor))
-
-
-@pytest.mark.parametrize("mode", ["data", "voting"])
-def test_overlapped_psum_bit_identical_batched(problem, mode):
-    """Round 6 overlap: the chunked psum (two independent half-
-    collectives over disjoint leading-axis slices) is bit-identical to
-    the blocking reduction — per-element sums are untouched, only the
-    start/done schedule changes (docs/PERF_NOTES.md round 7)."""
-    from lightgbm_tpu.parallel.data_parallel import grow_tree_batched_sharded
-    bins, _, _, nb, nanb, cat = map(jnp.asarray, problem)
-    g, h = _int_grads(problem)
-    mesh = _mesh(DATA_AXIS)
-    kw = {"parallel_mode": mode, "top_k": 4} if mode == "voting" else {}
-    tree_b, lor_b = grow_tree_batched_sharded(
-        mesh, bins, g, h, None, nb, nanb, cat, None, HP, batch=4,
-        overlap=False, **kw)
-    tree_o, lor_o = grow_tree_batched_sharded(
-        mesh, bins, g, h, None, nb, nanb, cat, None, HP, batch=4,
-        overlap=True, **kw)
-    _assert_trees_identical(tree_b, lor_b, tree_o, lor_o)
-
-
-def test_overlapped_psum_bit_identical_strict(problem):
-    """Same contract for the strict (batch=1 cadence) sharded grower —
-    its root stat reduction stacks g0/h0/c0 into ONE psum under overlap,
-    which must also be bit-exact (disjoint lanes of one array)."""
-    bins, _, _, nb, nanb, cat = map(jnp.asarray, problem)
-    g, h = _int_grads(problem, seed=6)
-    mesh = _mesh(DATA_AXIS)
-    tree_b, lor_b = grow_tree_sharded(mesh, bins, g, h, None, nb, nanb,
-                                      cat, None, HP, overlap=False)
-    tree_o, lor_o = grow_tree_sharded(mesh, bins, g, h, None, nb, nanb,
-                                      cat, None, HP, overlap=True)
-    _assert_trees_identical(tree_b, lor_b, tree_o, lor_o)
-
-
-def test_overlapped_psum_bit_identical_int8(problem):
-    """int8 histogram mode (quantized integer gradient LEVELS, exact
-    integer accumulation): overlap on/off trees bit-identical with
-    hist_scale threading."""
-    import dataclasses
-    from lightgbm_tpu.ops.quantize import discretize_gradients_levels
-    from lightgbm_tpu.parallel.data_parallel import grow_tree_batched_sharded
-    bins, _, _, nb, nanb, cat = map(jnp.asarray, problem)
-    g, h = _int_grads(problem, seed=7)
-    gq, hq, gs, hs = discretize_gradients_levels(
-        g / 8.0, h / 8.0, jax.random.PRNGKey(2), n_levels=4,
-        stochastic=False)
-    hist_scale = jnp.stack([gs, hs])
-    hp8 = dataclasses.replace(HP, hist_dtype="int8")
-    mesh = _mesh(DATA_AXIS)
-    tree_b, lor_b = grow_tree_batched_sharded(
-        mesh, bins, gq, hq, None, nb, nanb, cat, None, hp8, batch=4,
-        hist_scale=hist_scale, overlap=False)
-    tree_o, lor_o = grow_tree_batched_sharded(
-        mesh, bins, gq, hq, None, nb, nanb, cat, None, hp8, batch=4,
-        hist_scale=hist_scale, overlap=True)
-    _assert_trees_identical(tree_b, lor_b, tree_o, lor_o)
-
-
-def test_no_overlap_env_hatch_is_blocking(problem, monkeypatch):
-    """LGBMTPU_NO_OVERLAP=1 must force the blocking reduction even when
-    overlap=True is requested (the perf A/B hatch reads the env at
-    trace time) — and, being bit-identical by contract, the output
-    still matches."""
-    from lightgbm_tpu.ops.histogram import overlap_enabled
-    monkeypatch.setenv("LGBMTPU_NO_OVERLAP", "1")
-    assert not overlap_enabled(True)
-    monkeypatch.delenv("LGBMTPU_NO_OVERLAP")
-    assert overlap_enabled(True)
-    assert not overlap_enabled(False)
-
-
 def test_gspmd_fused_scan_matches_shard_map(problem):
     """Round 6: the dedicated GSPMD fused-scan entry (parallel/gspmd.py,
     tree_learner=data_gspmd) — sharding CONSTRAINTS into the serial

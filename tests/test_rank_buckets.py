@@ -6,8 +6,7 @@ hatch) across the truncation x norm x position-bias x xendcg grid,
 a skewed query-length fixture pads strictly fewer rows than pad-to-max,
 identical bucket geometry across boosters is a pure
 ``rank_compile_hits`` path, position-debiased training stays on the
-jitted program with bias factors surviving kill/resume bit-identically,
-and the ``BENCH_RANK`` capture round-trips through bench_compare.
+jitted program with bias factors surviving kill/resume bit-identically.
 
 The parity contract is tight allclose, NOT bitwise: XLA reassociates
 the pairwise reductions shape-dependently, so bucketed and pad-to-max
@@ -16,10 +15,7 @@ programs sum identical pair lambdas in different orders (observed max
 """
 
 import contextlib
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -32,7 +28,6 @@ from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.obs import compile_events
 from lightgbm_tpu.obs.metrics import global_metrics
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 GRAD_TOL = dict(rtol=3e-6, atol=6e-7)
 
@@ -317,32 +312,3 @@ def test_ndcg_history_matches_across_arms(synthetic_ranking):
         hists[arm] = np.asarray(res["training"]["ndcg@5"])
     np.testing.assert_allclose(hists["bucketed"], hists["padded"],
                                rtol=1e-3, atol=1e-4)
-
-
-# ------------------------------------------------- bench capture wiring
-
-class TestBenchRankRoundTrip:
-    def test_bench_rank_to_bench_compare_exit0(self, tmp_path):
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   BENCH_RANK="1", BENCH_ROWS="3000", BENCH_ITERS="2",
-                   BENCH_LEAVES="15")
-        cap = tmp_path / "BENCH_rank.json"
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            capture_output=True, text=True, env=env, timeout=420)
-        assert out.returncode == 0, out.stderr[-2000:]
-        payload = json.loads(out.stdout)
-        assert payload["kind"] == "rank"
-        # every payload names the device it was just measured on
-        assert payload["platform"] == "cpu" and payload["device_kind"]
-        assert payload["device_count"] >= 1
-        assert payload["bucketed"]["iters_per_s"] > 0
-        assert payload["padded"]["pad_waste_ratio"] >= \
-            payload["bucketed"]["pad_waste_ratio"]
-        cap.write_text(out.stdout)
-        cmp_out = subprocess.run(
-            [sys.executable,
-             os.path.join(REPO, "tools", "bench_compare.py"),
-             str(cap), str(cap)],
-            capture_output=True, text=True, env=env, timeout=120)
-        assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr

@@ -221,21 +221,6 @@ def test_watch_slo_rejects_undeclared_name():
         ev.watch_slo("made_up_slo")
 
 
-def test_min_direction_slo_violates_below_floor():
-    ev = SloEvaluator({"overlap_efficiency_floor": 0.25})
-    ev.watch_slo("overlap_efficiency_floor")
-
-    def w(t_end, eff):
-        base = _win(t_end)
-        base["gauges"]["overlap_efficiency"] = {"last": eff, "min": eff,
-                                                "max": eff, "n": 1}
-        return base
-
-    t = ev.evaluate([w(1, 0.1), w(2, 0.05)])
-    assert [x["state"] for x in t] == ["breach"]
-    assert ev.evaluate([w(3, 0.9), w(4, 0.8)])[0]["state"] == "recovered"
-
-
 # --------------------------------------------------- run_report CI gate
 def test_run_report_quick_gate_on_unrecovered_breach(tmp_path, capsys):
     run_report = _load_tool("run_report")

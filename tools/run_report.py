@@ -9,8 +9,8 @@ Joins the three artifact families one run can emit — the Chrome trace
 (``trace_output``), the structured event journal (``event_output``,
 obs/events.py) and the telemetry JSONL (``telemetry_output``) — into a
 single report: top phases, the event timeline, the final counter
-snapshot with the compile-cache and collective-overlap columns pulled
-out.  Any subset of the artifacts may be given; at least one must be.
+snapshot with the compile-cache columns pulled out.  Any subset of the
+artifacts may be given; at least one must be.
 
 A journal carrying continuous-learning records (pipeline/trainer.py)
 additionally gets a pipeline section joining the trainer's cycle events
@@ -55,12 +55,6 @@ _COMPILE_COUNTERS = (
     "xla_compile_events", "xla_program_lowerings",
     "serve_compile_hits", "serve_compile_misses",
     "rank_compile_hits", "rank_compile_misses",
-)
-
-#: final-snapshot gauges surfaced as the "collective" join column
-_COLLECTIVE_GAUGES = (
-    "collective_s_per_pass", "collective_s_blocked",
-    "collective_s_per_round", "overlap_efficiency", "overlap_on",
 )
 
 #: final-snapshot gauges surfaced as the "rank" join column (query
@@ -296,8 +290,6 @@ def telemetry_stats(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
         "gauges": gauges,
         "compile": {k: counters[k] for k in _COMPILE_COUNTERS
                     if k in counters},
-        "collective": {k: gauges[k] for k in _COLLECTIVE_GAUGES
-                       if k in gauges},
         "rank": {k: gauges[k] for k in _RANK_GAUGES if k in gauges},
         "watchtower": {k: counters[k] for k in _WATCHTOWER_COUNTERS
                        if k in counters},
@@ -460,7 +452,7 @@ def _render_report(payload: Dict[str, Any]) -> str:
         if tel.get("last_round") is not None:
             lines.append(f"  rounds {tel['first_round']}"
                          f"..{tel['last_round']}")
-        for section in ("compile", "collective", "rank", "watchtower"):
+        for section in ("compile", "rank", "watchtower"):
             vals = tel.get(section) or {}
             if vals:
                 lines.append(f"  {section}:")
