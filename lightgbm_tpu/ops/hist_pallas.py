@@ -236,6 +236,13 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     in-kernel whatever they contain, and so is the tail of a last block
     that reaches past S (no operand is padded).
 
+    The grid stops at the count: a step whose first position is at or
+    past ``cnt`` runs no body, and its block index is clamped to the last
+    block that holds a selected position, so it fetches nothing either.
+    A pass costs what its ``cnt`` rows cost, whatever S the caller's
+    bucket has; with ``cnt == 0`` the result is zeros.  The skipped
+    positions added zeros before, so the sums are the same bit for bit.
+
     Equivalent to ``histogram_leaves_pallas`` on the unpacked, transposed
     operands; the contraction runs per word (fc = 4 features).
     """
@@ -253,42 +260,48 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-        g = lax.bitcast_convert_type(payload_ref[W], jnp.float32)  # [blk]
-        h = lax.bitcast_convert_type(payload_ref[W + 1], jnp.float32)
-        lor_b = payload_ref[W + 2]
-        iota_r = lax.iota(jnp.int32, blk)
-        pos_ok = step * blk + iota_r < cnt_ref[0]           # [blk]
-        sel = (lor_b[None, :] == leaves_ref[0, :][:, None]) \
-            & pos_ok[None, :]                               # [K, blk]
-        if _is_int8(compute_dtype):
-            # int multiply masking is NaN-safe; levels fit int8
-            seli = sel.astype(jnp.int32)
-            gm = seli * g[None, :].astype(jnp.int32)
-            hm = seli * h[None, :].astype(jnp.int32)
-            vals = jnp.concatenate([gm, hm, seli], axis=0).astype(jnp.int8)
-        else:
-            m = sel.astype(jnp.float32)
-            # where(), not multiply: positions past cnt can carry NaN
-            gm = jnp.where(sel, g[None, :], 0.0)
-            hm = jnp.where(sel, h[None, :], 0.0)
-            vals = jnp.concatenate([gm, hm, m], axis=0).astype(compute_dtype)
-        iota = lax.iota(jnp.int32, n_bins)
-        # (a 4-words-per-dot widening was tried in round 4 and measured
-        # neutral)
-        for j in range(W):
-            w = payload_ref[j]                              # [blk] i32
-            chunk = jnp.stack([w & 255, (w >> 8) & 255,
-                               (w >> 16) & 255, (w >> 24) & 255])  # [4, blk]
-            oh_b = (chunk[:, None, :] == iota[None, :, None]
-                    ).reshape(4 * n_bins, blk)
-            acc = _oh_contract(vals, oh_b, compute_dtype)      # [3K, 4B]
-            out_ref[:, j * 4 * n_bins:(j + 1) * 4 * n_bins] += acc
+        @pl.when(step * blk < cnt_ref[0])
+        def _():
+            g = lax.bitcast_convert_type(payload_ref[W], jnp.float32)
+            h = lax.bitcast_convert_type(payload_ref[W + 1], jnp.float32)
+            lor_b = payload_ref[W + 2]
+            iota_r = lax.iota(jnp.int32, blk)
+            pos_ok = step * blk + iota_r < cnt_ref[0]       # [blk]
+            sel = (lor_b[None, :] == leaves_ref[0, :][:, None]) \
+                & pos_ok[None, :]                           # [K, blk]
+            if _is_int8(compute_dtype):
+                # int multiply masking is NaN-safe; levels fit int8
+                seli = sel.astype(jnp.int32)
+                gm = seli * g[None, :].astype(jnp.int32)
+                hm = seli * h[None, :].astype(jnp.int32)
+                vals = jnp.concatenate([gm, hm, seli],
+                                       axis=0).astype(jnp.int8)
+            else:
+                m = sel.astype(jnp.float32)
+                # where(), not multiply: positions past cnt can carry NaN
+                gm = jnp.where(sel, g[None, :], 0.0)
+                hm = jnp.where(sel, h[None, :], 0.0)
+                vals = jnp.concatenate([gm, hm, m],
+                                       axis=0).astype(compute_dtype)
+            iota = lax.iota(jnp.int32, n_bins)
+            # (a 4-words-per-dot widening was tried in round 4 and measured
+            # neutral)
+            for j in range(W):
+                w = payload_ref[j]                          # [blk] i32
+                chunk = jnp.stack([w & 255, (w >> 8) & 255, (w >> 16) & 255,
+                                   (w >> 24) & 255])        # [4, blk]
+                oh_b = (chunk[:, None, :] == iota[None, :, None]
+                        ).reshape(4 * n_bins, blk)
+                acc = _oh_contract(vals, oh_b, compute_dtype)  # [3K, 4B]
+                out_ref[:, j * 4 * n_bins:(j + 1) * 4 * n_bins] += acc
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(pl.cdiv(S, blk),),
         in_specs=[
-            pl.BlockSpec((rows, blk), lambda i, c: (0, i)),
+            # a step past the count keeps the last needed block: no DMA
+            pl.BlockSpec((rows, blk), lambda i, c: (
+                0, jnp.minimum(i, jnp.maximum(c[0] - 1, 0) // blk))),
             pl.BlockSpec((1, K), lambda i, c: (0, 0)),
         ],
         out_specs=pl.BlockSpec((3 * K, f_pad * n_bins), lambda i, c: (0, 0)),

@@ -70,6 +70,7 @@ class HistDispatch(NamedTuple):
     kernel: str    # xla | flat | packed | radix2 | radix_joint | radix_single
     mirror: bool   # keep the packed word mirror ``bins_words_t`` resident
     ladder: bool   # the batched grower's width-matched warm-up ladder pays
+    top_rung: int  # the row ladder's largest bucket holds n / top_rung rows
 
 
 def hist_dispatch(hist_kernel, n_bins: int, K: int = 1, num_f: int = 1,
@@ -77,35 +78,43 @@ def hist_dispatch(hist_kernel, n_bins: int, K: int = 1, num_f: int = 1,
                   single: bool = False) -> HistDispatch:
     """The one place that says which kernel a masked pass over ``K``
     leaves of ``num_f`` features takes, whether the mode wants the packed
-    mirror shipped, and whether the warm-up ladder pays.  Pure in its
+    mirror shipped, whether the warm-up ladder pays, and where the row
+    ladder of ``histogram_for_leaves_auto`` starts.  Pure in its
     arguments plus the platform (``use_pallas()``; ``_MODE_TEST_INTERPRET``
-    stands in for it in the CPU suite).  ``single`` is the one-leaf entry
+    stands in for it in the CPU suite), and only ``kernel`` depends on
+    the platform.  ``single`` is the one-leaf entry
     (``histogram_for_leaf_masked``: the root pass, the strict grower).
 
-    auto keeps the measured dispatch.  At >= 128 bins (a multiple of 16:
-    the radix kernels decompose bin = 16*hi + lo, ops/hist_pallas.py
-    ``_radix_shapes``) one leaf takes radix-single, K <= 4 radix-joint
-    (4.0/5.0/7.5 ms per 1M-row pass at K=1/2/4 against the flat kernel's
-    K-independent ~9.8, docs/PERF_NOTES.md round 3), K > 4 the
-    shared-radix kernel where its accumulator fits.  Below 128 bins the
-    radix build's small [p*nhi, 3*p*nlo] tiles waste the MXU (2.4 ms
-    against flat's 1.7 on a 63-bin K=42 pass, round 5) and the pass goes
-    to the packed-compare kernel where the mirror is resident.  Explicit
-    modes force their kernel where its shape constraints hold; every
-    other case is the flat one-hot kernel (bit-identical).
+    auto: at >= 128 bins (a multiple of 16: the radix kernels decompose
+    bin = 16*hi + lo, ops/hist_pallas.py ``_radix_shapes``) one leaf
+    takes radix-single, K <= 4 radix-joint, whose build grows with the
+    leaf count, K > 4 the shared-radix kernel where its accumulator
+    fits.  Below 128 bins the radix build's small [p*nhi, 3*p*nlo] tiles
+    waste the MXU and the pass goes to the packed-compare kernel where
+    the mirror is resident.  Explicit modes force their kernel where its
+    shape constraints hold; every other case is the flat one-hot kernel
+    (bit-identical).
 
-    The ladder pays only where the K <= 4 pass takes the radix-JOINT
-    kernel, whose build scales with the leaf count; every other kernel is
-    K-independent below one MXU channel tile, so those modes seed the
-    round loop at full width from the root histogram: identical
-    selections, fewer compiled round bodies (docs/PERF_NOTES.md round 6).
+    The warm-up ladder pays only where the K <= 4 pass takes the
+    radix-JOINT kernel; every other kernel is K-independent below one MXU
+    channel tile, so those modes seed the round loop at full width from
+    the root histogram: identical selections, fewer compiled round bodies.
+
+    The rung rule.  A compacted pass costs a fixed streaming pass over
+    the rows plus the payload kernel over the rows it selected; a full
+    pass costs its kernel over all of them.  So the largest bucket worth
+    compiling follows from what the full pass would cost (PERF.md section
+    5 has the chip's numbers): n/2 where it is the flat or the
+    shared-radix kernel above 64 bins (a round pass never selects more
+    than half the rows, so the full pass is then only the fallback), and
+    n/4 elsewhere: at 64 bins and below a full pass is about as cheap as
+    compaction plus payload at n/2, and so are the radix-joint passes of
+    the K <= 4 bodies.
     """
     hk = resolve_hist_kernel(hist_kernel)
     radix = hk == "auto" and n_bins % 16 == 0 and n_bins >= 128
     mirror = hk == "packed" or (hk == "auto" and not radix)
-    if not (use_pallas() or _MODE_TEST_INTERPRET):
-        kernel = "xla"
-    elif radix and single:
+    if radix and single:
         kernel = "radix_single"
     elif radix and K <= 4:
         kernel = "radix_joint"
@@ -118,7 +127,10 @@ def hist_dispatch(hist_kernel, n_bins: int, K: int = 1, num_f: int = 1,
         kernel = "packed"
     else:
         kernel = "flat"
-    return HistDispatch(kernel, mirror, radix)
+    top_rung = 2 if kernel in ("flat", "radix2") and n_bins > 64 else 4
+    if not (use_pallas() or _MODE_TEST_INTERPRET):
+        kernel = "xla"
+    return HistDispatch(kernel, mirror, radix, top_rung)
 
 
 def reduce_hist(hist: jax.Array, axis_name: Optional[str],
@@ -372,23 +384,33 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
 
     The TPU reformulation of the reference's O(smaller-child) histogram cost
     (serial_tree_learner.cpp:364-378 iterates only the leaf's data indices):
-    when the rows belonging to ``leaves`` fit a power-of-two bucket, one
-    streaming pass over the resident lane-dense bins
-    (``compact_payload_pallas``) moves them, in row order, into an i32 WORD
-    payload with the compacted positions on the lanes (4 bin bytes per word
-    + grad/hess/leaf words) and the payload kernel runs on the bucket;
-    otherwise one full masked pass (``histogram_for_leaves_masked``).
-    Total histogram work per tree drops from O(n x rounds) to ~O(n log L),
-    which the flat masked pass cannot do.  Exact: the same rows contribute
+    a pass does work in proportion to the rows it selects.  When the rows
+    belonging to ``leaves`` fit a bucket of the row ladder, one streaming
+    pass over the resident lane-dense bins (``compact_payload_pallas``)
+    moves them, in row order, into an i32 WORD payload with the compacted
+    positions on the lanes (4 bin bytes per word + grad/hess/leaf words)
+    and the payload kernel runs on the bucket, its grid stopping at the
+    count; otherwise one full masked pass
+    (``histogram_for_leaves_masked``).  Exact: the same rows contribute
     either way.  Off the TPU the compacted bucket is a sort of the keys and
     a row gather of the row-major payload, the reference the kernels are
     tested against.
 
+    The ladder's buckets hold n / d rows for the divisors d of ``buckets``
+    from ``hist_dispatch(...).top_rung`` down (the top rung itself always;
+    smaller divisors are dropped): where the full pass is expensive the
+    ladder starts at n/2.  The invariant that rung rests on: the batched
+    grower asks for the SMALLER child of each of a round's disjoint split
+    leaves (learner/batch_grower.py ``smaller``), so a round pass selects
+    at most half the rows it could.  The full pass stays as branch 0 all
+    the same: under ``shard_map`` the globally smaller child can be more
+    than half of one shard's rows, and the bounded pool's pass holds
+    larger children too.
+
     A leaf-GROUPED compaction variant (rows sorted by leaf, block->leaf
-    scalar-prefetch steering) was built and measured slower end-to-end in
-    round 3 — the K-channel MXU multiplier it removes does not exist below
-    128 output channels, while its layout glue is real — and was deleted
-    (docs/PERF_NOTES.md round 3).
+    scalar-prefetch steering) was built and measured slower end-to-end:
+    the K-channel MXU multiplier it removes does not exist below 128
+    output channels, while its layout glue is real.  It was deleted.
 
     ``bins_rows``: u8 [n, F] row-major; ``bins_t``: u8 [F, n] transposed.
 
@@ -436,8 +458,10 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             sort_key = jnp.where(sel, iota_n, iota_n | (1 << 30))
 
     blk = min(rows_per_block, 2048)
+    top = hist_dispatch(hist_kernel, n_bins, leaves.shape[0], num_f,
+                        bins_words_t is not None).top_rung
     sizes = []
-    for d in buckets:
+    for d in (top,) + tuple(b for b in buckets if b > top):
         s = _round_up(max(n // d, 1), blk)
         if s < n and s not in sizes:
             sizes.append(s)

@@ -194,6 +194,50 @@ def test_compacted_branches_hold_no_gather_and_no_relayout(one_chip, on_tpu,
     assert ",20]" not in text
 
 
+CRITEO_HALF = 6_641_664     # the n/2 bucket of CRITEO_SHARE_ROWS
+
+
+@pytest.mark.parametrize("K,fallback", [
+    (42, "_histogram_leaves_impl"), (16, "histogram_leaves_radix2_pallas")],
+    ids=["K42-flat", "K16-radix2"])
+def test_half_rows_rung_compiles_at_the_benchmark_rows(one_chip, on_tpu, K,
+                                                       fallback):
+    """The row ladder's top rung under the K = 42 and K = 16 round bodies
+    at 255 bins, at the cells' shape (13,281,250 x 67, int8): the bucket
+    of n/2 rows is the two kernels back to back under the scope a trace
+    counts it by, the full pass's kernel stays as the fallback branch,
+    and the ``i32[24, n/2]`` payload adds nothing to the program's
+    temporaries."""
+    n, S = CRITEO_SHARE_ROWS, CRITEO_HALF
+    assert H.hist_dispatch("auto", 256, K, CRITEO_F).top_rung == 2
+
+    def fn(bins, bins_t, g, h, lor, leaves, counts, key):
+        return H.histogram_for_leaves_auto(
+            bins, bins_t, g, h, lor, leaves, None, n_bins=256,
+            rows_per_block=8192, hist_dtype="int8", buckets=(),
+            counts=counts, sort_key=key)
+
+    c = _compile(one_chip, fn, ((n, CRITEO_F), jnp.uint8),
+                 ((CRITEO_F, n), jnp.uint8), ((n,), jnp.float32),
+                 ((n,), jnp.float32), ((n,), jnp.int32), ((K,), jnp.int32),
+                 ((K,), jnp.float32), ((n,), jnp.int32))
+    text = c.as_text()
+    _assert_kernel(c, fallback)
+    compact = [line for line in text.splitlines()
+               if "%compact_payload_pallas" in line.split("=")[0]]
+    assert len(compact) == 1 and f"s32[24,{S}]" in compact[0], compact
+    assert "hist_rows_" not in compact[0] and "hist_compact" in compact[0]
+    reader = [line for line in text.splitlines()
+              if "%histogram_payload_pallas" in line.split("=")[0]]
+    assert len(reader) == 1 and f"hist_rows_{S}/hist_kernel" in reader[0]
+    assert " gather(" not in text and " sort(" not in text
+    # the branches exclude each other, so the payload (638 MB) lies in
+    # the bytes of the full branch's padded operands: 70 feature rows of
+    # u8 and three word vectors, 1.09 GB, and a word vector to spare
+    assert 24 * S * 4 < c.memory_analysis().temp_size_in_bytes \
+        <= (70 + 3 * 4 + 4) * (n + 2048)
+
+
 K_ARGS = [((42,), jnp.int32)] * 8    # feats thr dl nanb parents new valid smaller
 
 

@@ -111,57 +111,60 @@ def test_dispatch_routes_modes(interpret_modes):
 
 
 # the dispatch table, case by case: (mode, n_bins, K, F, mirror resident,
-# one-leaf entry) -> (kernel, wants the mirror, ladder pays) on a TPU.
-# The first nine are what the benchmark's cells execute (F = 67, K = 42
-# with the ladder's widths 1, 4, 16 before it at 256 bins); a kernel PR
-# that changes a row changes what a cell runs and has to say so here.
+# one-leaf entry) -> (kernel, wants the mirror, warm-up ladder pays, the
+# row ladder's top rung n / top_rung) on a TPU.  The first nine are what
+# the benchmark's cells execute (F = 67, K = 42 with the warm-up widths 1,
+# 4, 16 before it at 256 bins); a kernel PR that changes a row changes
+# what a cell runs and has to say so here.  PR 33: the K = 16 and K = 42
+# bodies at 256 bins start their row ladder at n/2 (a round pass selects
+# at most half the rows: the flat and radix2 kernels are the fallback).
 _DISPATCH = [
-    ("auto", 256, 1, 67, False, True, "radix_single", False, True),
-    ("auto", 256, 1, 67, False, False, "radix_joint", False, True),
-    ("auto", 256, 4, 67, False, False, "radix_joint", False, True),
-    ("auto", 256, 8, 67, False, False, "radix2", False, True),
-    ("auto", 256, 16, 67, False, False, "radix2", False, True),
-    ("auto", 256, 42, 67, False, False, "flat", False, True),
-    ("auto", 64, 1, 67, True, True, "packed", True, False),
-    ("auto", 64, 42, 67, True, False, "packed", True, False),
-    ("auto", 64, 42, 67, False, False, "flat", True, False),
+    ("auto", 256, 1, 67, False, True, "radix_single", False, True, 4),
+    ("auto", 256, 1, 67, False, False, "radix_joint", False, True, 4),
+    ("auto", 256, 4, 67, False, False, "radix_joint", False, True, 4),
+    ("auto", 256, 8, 67, False, False, "radix2", False, True, 2),
+    ("auto", 256, 16, 67, False, False, "radix2", False, True, 2),
+    ("auto", 256, 42, 67, False, False, "flat", False, True, 2),
+    ("auto", 64, 1, 67, True, True, "packed", True, False, 4),
+    ("auto", 64, 42, 67, True, False, "packed", True, False, 4),
+    ("auto", 64, 42, 67, False, False, "flat", True, False, 4),
     # 255 is no multiple of 16: no radix kernel, the sub-128-bin route
-    ("auto", 255, 42, 67, True, False, "packed", True, False),
-    ("auto", 255, 1, 67, False, True, "flat", True, False),
-    ("auto", 256, 42, 28, True, False, "radix2", False, True),
+    ("auto", 255, 42, 67, True, False, "packed", True, False, 4),
+    ("auto", 255, 1, 67, False, True, "flat", True, False, 2),
+    ("auto", 256, 42, 28, True, False, "radix2", False, True, 2),
     # explicit modes force their kernel where its shape constraints hold
-    ("onehot", 64, 5, 28, True, False, "flat", False, False),
-    ("onehot", 256, 1, 28, False, True, "flat", False, False),
-    ("packed", 256, 5, 28, True, False, "packed", True, False),
-    ("packed", 256, 5, 28, False, False, "flat", True, False),
-    ("radix2", 256, 5, 28, True, False, "radix2", False, False),
-    ("radix2", 60, 5, 28, True, False, "flat", False, False),   # % 16
+    ("onehot", 64, 5, 28, True, False, "flat", False, False, 4),
+    ("onehot", 256, 1, 28, False, True, "flat", False, False, 2),
+    ("packed", 256, 5, 28, True, False, "packed", True, False, 4),
+    ("packed", 256, 5, 28, False, False, "flat", True, False, 2),
+    ("radix2", 256, 5, 28, True, False, "radix2", False, False, 2),
+    ("radix2", 60, 5, 28, True, False, "flat", False, False, 4),   # % 16
     # accumulator cap: a huge (K, F) product overflows the VMEM budget
     # and radix2 falls back rather than compiling an unshippable kernel
-    ("radix2", 256, 512, 4096, True, False, "flat", False, False),
+    ("radix2", 256, 512, 4096, True, False, "flat", False, False, 2),
 ]
+_DISPATCH_ARGS = "hk,n_bins,K,num_f,words,single,kernel,mirror,ladder,top_rung"
 
 
-@pytest.mark.parametrize("hk,n_bins,K,num_f,words,single,kernel,mirror,ladder",
-                         _DISPATCH)
+@pytest.mark.parametrize(_DISPATCH_ARGS, _DISPATCH)
 def test_hist_dispatch_table(interpret_modes, hk, n_bins, K, num_f, words,
-                             single, kernel, mirror, ladder):
+                             single, kernel, mirror, ladder, top_rung):
     """``hist_dispatch`` is the one answer to which kernel a masked pass
-    takes, whether the mode wants the packed mirror and whether the
-    warm-up ladder pays.  ``interpret_modes`` stands in for the TPU."""
+    takes, whether the mode wants the packed mirror, whether the warm-up
+    ladder pays and where the row ladder starts.  ``interpret_modes``
+    stands in for the TPU."""
     assert hist_dispatch(hk, n_bins, K, num_f, words, single) == \
-        (kernel, mirror, ladder)
+        (kernel, mirror, ladder, top_rung)
 
 
-@pytest.mark.parametrize("hk,n_bins,K,num_f,words,single,kernel,mirror,ladder",
-                         _DISPATCH[:9])
+@pytest.mark.parametrize(_DISPATCH_ARGS, _DISPATCH[:9])
 def test_hist_dispatch_off_tpu_is_xla(hk, n_bins, K, num_f, words, single,
-                                      kernel, mirror, ladder):
+                                      kernel, mirror, ladder, top_rung):
     """Off the TPU every pass is the XLA contraction; what the booster
-    ships (the mirror) and which round bodies compile (the ladder) do
-    not depend on the platform."""
+    ships (the mirror), which round bodies compile (the ladder) and the
+    row ladder's buckets do not depend on the platform."""
     assert hist_dispatch(hk, n_bins, K, num_f, words, single) == \
-        ("xla", mirror, ladder)
+        ("xla", mirror, ladder, top_rung)
 
 
 def test_hist_kernel_unknown_value_raises():

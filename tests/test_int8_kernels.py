@@ -44,12 +44,18 @@ def test_flat_masked_int8_matches_f32():
     _assert_same(got, want)
 
 
-def test_payload_int8():
+@pytest.mark.parametrize("stop_at", [None, 0, 700],
+                         ids=["every_block", "none", "second_block"])
+def test_payload_int8(stop_at):
+    """int8 against float32 over the whole bucket, and with the count
+    cut short so that the grid stops inside the second block."""
     bins, grad, hess, lor, leaves = _mk()
     n, f = bins.shape
     words = H.bins_to_words(bins)
     member = jnp.any(lor[None, :] == leaves[:, None], axis=0)
     cnt = jnp.sum(member.astype(jnp.int32))
+    if stop_at is not None:
+        cnt = jnp.int32(stop_at)
     key = jnp.where(member, jnp.arange(n, dtype=jnp.int32),
                     jnp.arange(n, dtype=jnp.int32) | (1 << 30))
     S = 2560

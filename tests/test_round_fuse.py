@@ -60,6 +60,42 @@ def test_payload_kernel_matches_masked_reference():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+PAYLOAD_BLK = 512
+PAYLOAD_S = 5 * PAYLOAD_BLK + 128   # the sixth block reaches 384 past S
+
+
+@pytest.mark.parametrize("hist_dtype", ["int8", "float32"])
+@pytest.mark.parametrize("cnt", [0, 1, PAYLOAD_BLK - 1, PAYLOAD_BLK,
+                                 PAYLOAD_BLK + 1, PAYLOAD_S - 1, PAYLOAD_S],
+                         ids=["0", "1", "blk-1", "blk", "blk+1", "S-1", "S"])
+def test_payload_kernel_stops_at_the_count(cnt, hist_dtype):
+    """The grid steps from ``cnt`` on are skipped and fetch nothing; the
+    result is the XLA masked pass over the positions below ``cnt``, bit
+    for bit (integer levels: float32 sums are exact in any order), with
+    NaN values and leaf ids that DO match in the columns from ``cnt`` on
+    and a last block that reaches past S."""
+    rng = np.random.default_rng(11)
+    S, f, n_bins = PAYLOAD_S, 9, 64
+    bins, grad, hess, lor, leaves = _mk(n=S, f=f, n_bins=n_bins, seed=11)
+    pc_t = np.concatenate([
+        np.asarray(H.bins_to_words(bins)).T,
+        np.asarray(grad).view(np.int32)[None],
+        np.asarray(hess).view(np.int32)[None], np.asarray(lor)[None]])
+    w = pc_t.shape[0] - 3
+    # what the compaction leaves past the count is anything at all
+    pc_t[:w, cnt:] = rng.integers(-2 ** 31, 2 ** 31, size=(w, S - cnt))
+    pc_t[w:w + 2, cnt:] = np.float32(np.nan).view(np.int32)
+    pc_t[w + 2, cnt:] = np.asarray(leaves)[rng.integers(0, 4, size=S - cnt)]
+    got = histogram_payload_pallas(
+        jnp.asarray(pc_t), leaves, jnp.int32(cnt), num_f=f,
+        n_bins=n_bins, rows_per_block=PAYLOAD_BLK,
+        compute_dtype=jnp.dtype(hist_dtype).type, interpret=True)
+    want = H.histogram_for_leaves_masked(
+        bins[:cnt].T, grad[:cnt], hess[:cnt], lor[:cnt], leaves, None,
+        n_bins=n_bins, hist_dtype="float32")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ------------------------------------------------- the compaction kernel
 ROWS = 2048 + 994       # the remainder 13,281,250 leaves in a 1024-row block
 SIZE = 1024             # the bucket: one 512-column block short of ROWS / 2
@@ -175,6 +211,54 @@ def test_auto_through_each_compacted_bucket_equals_the_full_pass(
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("n_bins,share,branch", [
+    (128, 0.40, "n/2"), (128, 0.60, "full"), (128, 0.20, "n/4"),
+    (64, 0.40, "full"), (64, 0.20, "n/4"),
+], ids=["128bins-0.40n", "128bins-0.60n", "128bins-0.20n", "64bins-0.40n",
+        "64bins-0.20n"])
+def test_auto_starts_the_ladder_where_the_dispatch_says(
+        monkeypatch, n_bins, share, branch):
+    """The flat kernel's full pass above 64 bins is dear enough for a
+    bucket of n/2 (``hist_dispatch(...).top_rung == 2``): a count in
+    (n/4, n/2] takes it, a count above n/2 the full pass.  At 64 bins
+    the ladder starts at n/4 as before.  Which branch RAN is told by the
+    compaction it called; the histograms equal the masked pass's either
+    way."""
+    import lightgbm_tpu.ops.hist_pallas as HP
+    bins, grad, hess, _, leaves = _mk(n=8192 + 994, n_bins=n_bins)
+    n = bins.shape[0]
+    top = H.hist_dispatch("onehot", n_bins, 4, bins.shape[1]).top_rung
+    assert top == (2 if n_bins > 64 else 4)
+    rng = np.random.default_rng(5)
+    lor = np.where(rng.random(n) < share,
+                   np.asarray(leaves)[rng.integers(0, 4, size=n)], 1)
+    lor = jnp.asarray(lor.astype(np.int32))
+    ran = []
+    real = HP.compact_payload_pallas
+
+    def spy(src, key, *rest, size, **kw):
+        jax.debug.callback(lambda _: ran.append(size), key[0])
+        return real(src, key, *rest, size=size, **kw)
+
+    monkeypatch.setattr(HP, "compact_payload_pallas", spy)
+    monkeypatch.setattr(H, "_PAYLOAD_TEST_INTERPRET", True)
+    got = H.histogram_for_leaves_auto(
+        bins, bins.T, grad, hess, lor, leaves, None, n_bins=n_bins,
+        rows_per_block=256, hist_dtype="int8", hist_kernel="onehot")
+    jax.effects_barrier()
+    want = H.histogram_for_leaves_masked(
+        bins.T, grad, hess, lor, leaves, None, n_bins=n_bins,
+        hist_dtype="float32")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cnt = int(jnp.sum(jnp.any(lor[None, :] == leaves[:, None], axis=0)))
+    sizes = {b: -(-(n // d) // 256) * 256 for b, d in (("n/2", 2), ("n/4", 4))}
+    if branch == "full":
+        assert cnt > sizes["n/2" if top == 2 else "n/4"] and ran == []
+    else:
+        assert ran == [sizes[branch]]
+        assert sizes[branch] >= cnt > sizes[branch] // 2, "fixture"
+
+
 def test_bins_to_words_roundtrip():
     bins, *_ = _mk(f=10)  # 10 % 4 != 0: exercises the pad
     words = H.bins_to_words(bins)
@@ -260,3 +344,44 @@ def test_fused_round_tree_identical(batch):
                                   np.asarray(t1.leaf_value))
     np.testing.assert_array_equal(np.asarray(lor0), np.asarray(lor1))
     assert int(t0.num_leaves) > 8
+
+
+@pytest.mark.parametrize("bagging", [False, True], ids=["all_rows", "bagging"])
+def test_round_passes_select_at_most_half_the_rows(monkeypatch, bagging):
+    """The invariant the n/2 rung rests on: every round pass of the
+    serial batched grower asks for the SMALLER child of each of its
+    disjoint split leaves, so the count its branch is chosen by is at
+    most half the (in-bag) rows, and it IS the number of rows the keys
+    select.  (Only a full tree's last pass may select more than it
+    counts: its slots past the room hold real leaves.  Nothing reads
+    that pass's histograms.)"""
+    from lightgbm_tpu.learner import batch_grower
+    rng = np.random.default_rng(2)
+    n, f = 6000, 8
+    bins = jnp.asarray(rng.integers(0, 63, size=(n, f)).astype(np.uint8))
+    grad = jnp.asarray(rng.integers(-2, 3, size=n).astype(np.float32))
+    hess = jnp.asarray(rng.integers(1, 5, size=n).astype(np.float32))
+    row_mask = jnp.asarray(rng.random(n) < 0.6) if bagging else None
+    in_bag = int(row_mask.sum()) if bagging else n
+    hp = SplitHyper(num_leaves=31, min_data_in_leaf=5, n_bins=64,
+                    hist_dtype="float32")
+    passes = []
+    real = batch_grower.histogram_for_leaves_auto
+
+    def spy(bins_rows, bins_t, g, h, lor, leaves, mask=None, **kw):
+        lor_m = lor if mask is None else jnp.where(mask, lor, -1)
+        selected = jnp.sum(jnp.any(lor_m[None, :] == leaves[:, None], axis=0))
+        jax.debug.callback(
+            lambda c, s: passes.append((float(c), int(s))),
+            jnp.sum(kw["counts"]), selected)
+        return real(bins_rows, bins_t, g, h, lor, leaves, mask, **kw)
+
+    monkeypatch.setattr(batch_grower, "histogram_for_leaves_auto", spy)
+    tree, _ = grow_tree_batched.__wrapped__(
+        bins, grad, hess, row_mask, jnp.full((f,), 64, jnp.int32),
+        jnp.full((f,), -1, jnp.int32), jnp.zeros((f,), bool), None, hp,
+        batch=8)
+    jax.effects_barrier()
+    assert int(tree.num_leaves) == 31 and len(passes) >= 5
+    assert all(0 < counted <= in_bag / 2 for counted, _ in passes), passes
+    assert all(counted == selected for counted, selected in passes[:-1])
