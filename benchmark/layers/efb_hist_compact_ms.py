@@ -1,0 +1,10 @@
+"""``hist_compact_ms`` in a bundled job (the cell ``allstate-train``): the
+compaction of the selected rows' bundle columns into the payload
+(``compact_payload_pallas``) and the pads. The reader is
+``layers/hist_compact_ms.py``'s, which says what is read and from where;
+an accepted metric's list of cells is not a new cell's to extend, so the
+cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "hist_compact_ms").read

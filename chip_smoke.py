@@ -6,7 +6,8 @@ mode: K=42 split batch, int8 histogram kernels); rows are the one
 reduction (``--rows``, default 1,000,000 of the published 10.5M) and the
 weights are whatever ``--iters`` rounds learn from ``--seed``:
 
-    device -> train (fused scan + valid set) -> predict -> save/load -> serve
+    device -> train (fused scan + valid set) -> bundled (CSR in, EFB)
+           -> predict -> save/load -> serve
 
 One process, no children that touch JAX, JAX imported once.  Every phase
 prints one JSON line as it finishes and raises on any failure, so the
@@ -303,6 +304,78 @@ def phase_train(args, lgb, data):
     return bst
 
 
+def _one_hot_csr(rows: int, seed: int):
+    """A few hundred one-hot columns beside four numeric ones, as scipy
+    CSR: five blocks of 60 levels, one level a row and block, skewed.
+    Labels follow two of the numeric columns and each block's low
+    levels."""
+    import scipy.sparse as sp
+    rng = np.random.default_rng([seed, 34])
+    numeric, blocks, levels = 4, 5, 60
+    x = rng.normal(size=(rows, numeric)).astype(np.float32)
+    level = np.minimum((levels * rng.random((rows, blocks)) ** 2.5).astype(np.int64),
+                       levels - 1)
+    logit = 1.5 * x[:, 0] - 0.8 * x[:, 1] + (level < 6).sum(1) - 1.2
+    y = (logit + rng.logistic(size=rows) > 0).astype(np.float32)
+    width = numeric + blocks
+    data = np.ones((rows, width), np.float64)
+    data[:, :numeric] = x
+    indices = np.empty((rows, width), np.int32)
+    indices[:, :numeric] = np.arange(numeric)
+    indices[:, numeric:] = numeric + levels * np.arange(blocks) + level
+    return sp.csr_matrix((data.ravel(), indices.ravel(),
+                          np.arange(rows + 1, dtype=np.int64) * width),
+                         shape=(rows, numeric + blocks * levels)), y
+
+
+def phase_bundled(args, lgb):
+    """A short bundled job: scipy CSR in, a few hundred one-hot columns
+    packed into feature bundles, the split search in bundle space (scope
+    ``bundle_search``), no expansion of a bundle histogram to
+    virtual-feature space, and the fused partition kernel routing rows
+    by range predicates on the bundle columns."""
+    import jax
+    from lightgbm_tpu.obs.metrics import global_metrics
+
+    t0 = time.time()
+    rows = args.rows
+    X, y = _one_hot_csr(rows + args.valid_rows, args.seed)
+    params = {**PARAMS, "min_sum_hessian_in_leaf": 20}
+    ds = lgb.Dataset(X[:rows], label=y[:rows], params=params).construct()
+    dv = ds.create_valid(X[rows:], label=y[rows:])
+    before = global_metrics.counter("bundle_expand_calls")
+    bst, auc, secs = _train(lgb, params, ds, dv, args.iters)
+    gb = bst._gbdt
+    _require(gb.bundle is not None and gb.bundle.search is not None,
+             "the CSR job was not bundled with ranges")
+    bundles = int(gb.metrics.counter("efb_bundles"))
+    _require(bundles < X.shape[1] // 4,
+             f"{bundles} bundle columns of {X.shape[1]} features")
+    _require(_took_fused_path(bst, args.iters), "fused path not taken")
+    _require(gb.metrics.counter("bundle_space_search_rounds") == args.iters,
+             "not every round searched its splits in bundle space")
+    expanded = global_metrics.counter("bundle_expand_calls") - before
+    _require(expanded == 0,
+             f"a bundle histogram was expanded to virtual space {expanded}x")
+    _check_auc(auc, args.iters)
+    calls = None
+    if jax.devices()[0].platform == "tpu":
+        text = _fused_program_text(gb)
+        calls = text.count("tpu_custom_call")
+        _require("bundle_search" in text,
+                 "no operation under the scope bundle_search in the "
+                 "compiled round program")
+        _require_kernel(text, "partition_select_pallas", "partition",
+                        "the bundled job's partition is not the fused "
+                        "partition_select_pallas kernel")
+        _require_compaction_kernel(text, "the bundled round program")
+    _emit("bundled", t0, rows=rows, features=X.shape[1], bundles=bundles,
+          iters=args.iters, bundle_space_search_rounds=args.iters,
+          bundle_expand_calls=int(expanded), tpu_custom_calls=calls,
+          valid_auc_first=auc[0], valid_auc_last=auc[-1],
+          smoke_train_s=round(secs, 2))
+
+
 def phase_predict(args, bst, X):
     import jax
     from lightgbm_tpu.boosting.gbdt import GBDT
@@ -466,6 +539,7 @@ def main(argv=None) -> int:
         phase_four_chips(args, lgb, data)
     else:
         bst = phase_train(args, lgb, data)
+        phase_bundled(args, lgb)
         phase_predict(args, bst, data[0])
         phase_save_load(lgb, bst, data[0])
         phase_serve(bst, data[0])

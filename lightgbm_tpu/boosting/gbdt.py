@@ -30,7 +30,9 @@ import numpy as np
 from ..callback import EarlyStopException
 from ..config import Config
 from ..io.dataset import Dataset
-from ..learner.grower import CegbInput, DeviceBundle, TreeArrays, grow_tree
+from ..learner.grower import (BundleSearch, CegbInput, DeviceBundle,
+                              TreeArrays, grow_tree,
+                              searches_in_bundle_space)
 from ..learner.linear import fit_linear_leaves, linear_leaf_scores
 from ..metrics import Metric, create_metrics
 from ..models.predict import predict_bins_leaf, predict_bins_tree
@@ -80,6 +82,18 @@ def _resolve_hist_kernel_cfg(cfg: Config) -> str:
     forced mode's shape constraints don't hold."""
     from ..ops.histogram import resolve_hist_kernel
     return resolve_hist_kernel(cfg.hist_kernel)
+
+
+def _bundle_search(train_set: Dataset) -> Optional[BundleSearch]:
+    """The bundle plan's ranges on device, with each position's member's
+    ``skip`` and last threshold looked up on the host, once."""
+    r = train_set.device_bundle_ranges()
+    if r is None:
+        return None
+    member = np.maximum(r.feat_of, 0)
+    return BundleSearch(*(jnp.asarray(a) for a in (
+        r.lo, r.hi, r.skip, r.feat_of, r.vbin_of, r.skip[member],
+        train_set.num_bins_array()[member] - 1, r.off, r.roff)))
 
 
 def _hp_from_config(cfg: Config, n_bins: int) -> SplitHyper:
@@ -252,7 +266,13 @@ class GBDT:
         self.num_features = train_set.num_features
         ba = train_set.device_bundle_arrays()
         self.bundle = None if ba is None else \
-            DeviceBundle(*(jnp.asarray(a) for a in ba))
+            DeviceBundle(*(jnp.asarray(a) for a in ba),
+                         search=_bundle_search(train_set))
+        if self.bundle is not None:
+            # this booster's own registry; the process-wide one counted
+            # them when the Dataset was constructed (io/dataset.py)
+            self.metrics.inc("efb_bundles", train_set.bundle_plan.num_bundles)
+            self.metrics.inc("efb_features", self.num_features)
 
         # distributed tree learner over all visible devices
         # (reference tree_learner=serial/data/feature/voting,
@@ -669,6 +689,12 @@ class GBDT:
                 used_rows=jnp.zeros((train_set.num_data, self.num_features),
                                     bool) if (lazy != 0).any() else None)
 
+        # whether this job's split search stays on the physical bundle
+        # columns (counter bundle_space_search_rounds); voting votes on
+        # virtual features and expands
+        self._bundle_space = self.parallel_mode != "voting" and \
+            searches_in_bundle_space(self.bundle, self.hp,
+                                     penalised=self.cegb is not None)
         # bounded histogram pool (reference histogram_pool_size MB,
         # serial_tree_learner.cpp:36-47): translate the MB budget into
         # batched-grower pool slots; evicted parents re-histogram both
@@ -944,11 +970,15 @@ class GBDT:
     def _matmul_valid_ok(self) -> bool:
         """True when per-tree valid scoring can take the matmul
         path-aggregation (models/predict.py predict_bins_tree_matmul)
-        instead of the frontier walk: numeric un-bundled non-linear
-        models — categorical bitsets and EFB inverse tables are per-row
-        gathers the matmul formulation has no cheap equivalent for, and
-        linear leaves score through their own raw-feature path."""
-        return (not self.hp.has_categorical and self.bundle is None
+        instead of the frontier walk: numeric non-linear models whose
+        splits are range predicates on the physical column (unbundled, or
+        an EFB plan with ranges; learner/grower.py ``split_ranges``) —
+        categorical bitsets and the inverse table of a plan without
+        ranges are per-row lookups the matmul formulation has no cheap
+        equivalent for, and linear leaves score through their own
+        raw-feature path."""
+        return (not self.hp.has_categorical
+                and (self.bundle is None or self.bundle.search is not None)
                 and not self.linear)
 
     def _valid_tree_scores(self, arrays: TreeArrays, vi: int) -> jax.Array:
@@ -959,7 +989,8 @@ class GBDT:
         if self._matmul_valid_ok() and self._valid_bins_t[vi] is not None:
             from ..models.predict import predict_bins_tree_matmul
             return predict_bins_tree_matmul(
-                arrays, self._valid_bins_t[vi], self.nan_bin_arr)
+                arrays, self._valid_bins_t[vi], self.nan_bin_arr,
+                self.bundle, n_bins=self.hp.n_bins)
         return predict_bins_tree(arrays, self._valid_bins[vi],
                                  self.nan_bin_arr, self.bundle,
                                  self.hp.has_categorical)
@@ -1185,6 +1216,8 @@ class GBDT:
         self.iter_ += 1
         self._count("iterations")
         self._count("strict_rounds")
+        if self._bundle_space:
+            self._count("bundle_space_search_rounds")
         if self.parallel_mode is not None:
             self._count("sharded_rounds")
         self._count("trees_grown", k)
@@ -1663,6 +1696,8 @@ class GBDT:
                     fin["rounds"] += 1
                     self._count("iterations")
                     self._count("fused_rounds")
+                    if self._bundle_space:
+                        self._count("bundle_space_search_rounds")
                     self._count("trees_grown", k)
                     self._count("hist_build_rounds",
                                 self._hist_rounds_per_tree() * k)

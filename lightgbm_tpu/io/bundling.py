@@ -23,6 +23,16 @@ two small host-precomputed index tables make the learner bundle-agnostic:
     ``f`` (default bin when ``v`` belongs to another member).  Used by the
     partition step.
 
+  * ``bundle_ranges`` — the same layout as RANGES: member ``f`` holds one
+    contiguous segment ``[lo_f, hi_f]`` of its column, its bins in order
+    with the default bin left out, so "virtual bin of f <= t" is the range
+    predicate ``lo_f <= c <= pos_f(t)`` on the physical value ``c``, OR-ed
+    with "c outside the segment" when the default bin is <= t.  The split
+    search, the fused partition and the matmul valid scorer work on that
+    predicate and never visit ``[Fv, B]`` space (learner/grower.py
+    ``BundleSearch``); the two tables above remain for the members a
+    range cannot state (categorical members, NaN bins).
+
 Bundle encoding: column value 0 = every member at its default bin; member
 ``k`` with non-default bin ``b`` writes ``offset_k + rank_k(b)`` where
 ``rank_k`` skips the default bin (order-preserving, so numerical thresholds
@@ -75,42 +85,46 @@ def plan_bundles(bins: np.ndarray, num_bins: np.ndarray,
         np.random.default_rng(3).choice(n, sample_cnt, replace=False)]
     ns = sample.shape[0]
 
-    # default (most frequent) bin per feature + nonzero masks on the sample
+    # default (most frequent) bin per feature + non-default rows of the sample
     default_bin = np.zeros(num_f, np.int32)
-    nz_masks = []
-    nz_counts = np.zeros(num_f, np.int64)
+    nz_rows = []
     for f in range(num_f):
         counts = np.bincount(sample[:, f], minlength=int(num_bins[f]))
         default_bin[f] = int(np.argmax(counts))
-        m = sample[:, f] != default_bin[f]
-        nz_masks.append(m)
-        nz_counts[f] = int(m.sum())
-    return _plan_from_masks(nz_masks, nz_counts, default_bin, num_bins, ns,
-                            max_conflict_rate, max_total_bins)
+        nz_rows.append(np.flatnonzero(sample[:, f] != default_bin[f]))
+    return _plan_from_rows(nz_rows.__getitem__,
+                           np.array([len(r) for r in nz_rows], np.int64),
+                           default_bin, num_bins, ns, max_conflict_rate,
+                           max_total_bins)
 
 
-def plan_bundles_sparse(nz_masks: List[np.ndarray], num_bins: np.ndarray,
+def plan_bundles_sparse(nz_rows, nz_counts: np.ndarray, num_bins: np.ndarray,
                         default_bin: np.ndarray, ns: int,
                         max_conflict_rate: float = 0.0,
                         max_total_bins: int = MAX_BUNDLE_BINS
                         ) -> Optional[BundlePlan]:
-    """Bundling plan from per-feature sampled nonzero-row masks — the
+    """Bundling plan from each feature's non-default ROWS — the
     sparse-ingestion entry that never sees a dense [n, F] matrix (reference
-    sparse_bin.hpp data feeding FastFeatureBundling).  ``default_bin`` must
-    be each feature's zero bin (implicit rows ARE zeros)."""
-    if len(nz_masks) < 2:
+    sparse_bin.hpp data feeding FastFeatureBundling).  ``nz_rows(f)`` gives
+    feature f's rows among ``ns`` (a CSC column's indices: no copy),
+    ``nz_counts`` their numbers; ``default_bin`` must be each feature's
+    zero bin (implicit rows ARE zeros).  Conflicts are counted over all
+    ``ns`` rows, so with ``ns`` the whole data set and
+    ``max_conflict_rate = 0`` no row of it holds two members of a column."""
+    if len(nz_counts) < 2:
         return None
-    nz_counts = np.array([int(m.sum()) for m in nz_masks], np.int64)
-    return _plan_from_masks(list(nz_masks), nz_counts,
-                            np.asarray(default_bin, np.int32), num_bins, ns,
-                            max_conflict_rate, max_total_bins)
+    return _plan_from_rows(nz_rows, np.asarray(nz_counts, np.int64),
+                           np.asarray(default_bin, np.int32), num_bins, ns,
+                           max_conflict_rate, max_total_bins)
 
 
-def _plan_from_masks(nz_masks: List[np.ndarray], nz_counts: np.ndarray,
-                     default_bin: np.ndarray, num_bins: np.ndarray, ns: int,
-                     max_conflict_rate: float,
-                     max_total_bins: int) -> Optional[BundlePlan]:
-    num_f = len(nz_masks)
+_WORD = 64      # bundles a packed mask word holds
+
+
+def _plan_from_rows(nz_rows, nz_counts: np.ndarray, default_bin: np.ndarray,
+                    num_bins: np.ndarray, ns: int, max_conflict_rate: float,
+                    max_total_bins: int) -> Optional[BundlePlan]:
+    num_f = len(nz_counts)
     max_total_bins = min(max_total_bins, MAX_BUNDLE_BINS)
     B = MAX_BUNDLE_BINS
     max_conflicts = int(max_conflict_rate * ns)
@@ -119,27 +133,45 @@ def _plan_from_masks(nz_masks: List[np.ndarray], nz_counts: np.ndarray,
     order = np.argsort(-nz_counts, kind="stable")
 
     bundle_members: List[List[int]] = []
-    bundle_mask: List[np.ndarray] = []
     bundle_bins: List[int] = []
+    # which bundles hold a non-default member in each row, packed: bit b of
+    # covered[w][row] is bundle 64 w + b.  One gather of a feature's rows
+    # answers "does it conflict" for 64 bundles at once, where a mask per
+    # bundle costs a pass per bundle tried (4,228 features x 50 bundles
+    # over 13M rows: the whole of set-up)
+    covered: List[np.ndarray] = []
     for f in map(int, order):
         extra = int(num_bins[f]) - 1          # bins beyond the default
-        placed = False
+        rows = nz_rows(f)
+        at = -1
         # a feature whose non-defaults cover most rows can't bundle usefully
         if nz_counts[f] * 2 < ns:
-            for bi in range(len(bundle_members)):
-                if bundle_bins[bi] + extra > max_total_bins:
-                    continue
-                conflicts = int((bundle_mask[bi] & nz_masks[f]).sum())
-                if conflicts <= max_conflicts:
-                    bundle_members[bi].append(f)
-                    bundle_mask[bi] |= nz_masks[f]
-                    bundle_bins[bi] += extra
-                    placed = True
+            for w, word in enumerate(covered):
+                got = word[rows]
+                hit = int(np.bitwise_or.reduce(got)) if len(got) else 0
+                for b in range(min(_WORD, len(bundle_members) - _WORD * w)):
+                    bi = _WORD * w + b
+                    if bundle_bins[bi] + extra > max_total_bins:
+                        continue
+                    if (hit >> b) & 1 and (max_conflicts == 0 or int(
+                            ((got >> np.uint64(b)) & np.uint64(1)).sum())
+                            > max_conflicts):
+                        continue
+                    at = bi
                     break
-        if not placed:
+                if at >= 0:
+                    break
+        if at >= 0:
+            bundle_members[at].append(f)
+            bundle_bins[at] += extra
+        else:
+            at = len(bundle_members)
             bundle_members.append([f])
-            bundle_mask.append(nz_masks[f].copy())
             bundle_bins.append(1 + extra)
+            if at % _WORD == 0:
+                covered.append(np.zeros(ns, np.uint64))
+        covered[at // _WORD][rows] |= np.uint64(1 << (at % _WORD))
+    del covered
 
     if len(bundle_members) == num_f:
         return None
@@ -178,6 +210,57 @@ def _plan_from_masks(nz_masks: List[np.ndarray], nz_counts: np.ndarray,
     return BundlePlan(bundles=bundle_members, feat_col=feat_col,
                       src_idx=src_idx, valid=valid, default_bin=default_bin,
                       inv_table=inv_table, num_bundles=len(bundle_members))
+
+
+class BundleRanges(NamedTuple):
+    """A plan's layout as ranges (module docstring).  Per feature: its
+    segment ``[lo, hi]`` and ``skip``, the default bin the segment leaves
+    out (``NO_SKIP`` for a singleton, whose column is the feature's own
+    bins, default included).  Per physical position: the member there
+    (-1: value 0 of a shared column, or past the last member), its
+    virtual bin, and the position's offsets from its segment's two ends."""
+    lo: np.ndarray        # i32 [Fv]
+    hi: np.ndarray        # i32 [Fv]
+    skip: np.ndarray      # i32 [Fv]
+    feat_of: np.ndarray   # i32 [Fb, B]
+    vbin_of: np.ndarray   # i32 [Fb, B]
+    off: np.ndarray       # i32 [Fb, B] — position - lo of its member
+    roff: np.ndarray      # i32 [Fb, B] — hi of its member - position
+
+
+NO_SKIP = 1 << 20   # above every bin: "default bin <= t" never holds
+
+
+def bundle_ranges(plan: BundlePlan, num_bins: np.ndarray,
+                  width: int = MAX_BUNDLE_BINS) -> BundleRanges:
+    """``plan`` as ranges, ``width`` physical positions a column."""
+    num_f = len(plan.feat_col)
+    lo = np.zeros(num_f, np.int32)
+    hi = np.full(num_f, width - 1, np.int32)
+    skip = np.full(num_f, NO_SKIP, np.int32)
+    feat_of = np.full((plan.num_bundles, width), -1, np.int32)
+    vbin_of = np.zeros((plan.num_bundles, width), np.int32)
+    off = np.zeros((plan.num_bundles, width), np.int32)
+    roff = np.zeros((plan.num_bundles, width), np.int32)
+    for col, members in enumerate(plan.bundles):
+        if len(members) == 1:
+            f, nb = members[0], int(num_bins[members[0]])
+            feat_of[col, :nb] = f
+            vbin_of[col, :nb] = np.arange(nb)
+            off[col, :nb] = np.arange(nb)
+            roff[col, :nb] = nb - 1 - np.arange(nb)
+            continue
+        for f in members:
+            vb = np.flatnonzero(plan.valid[f])
+            at = plan.src_idx[f][vb]
+            assert len(at) and (np.diff(at) == 1).all(), \
+                f"feature {f}: its stored bins are no contiguous segment"
+            lo[f], hi[f], skip[f] = at[0], at[-1], plan.default_bin[f]
+            feat_of[col, at] = f
+            vbin_of[col, at] = vb
+            off[col, at] = at - at[0]
+            roff[col, at] = at[-1] - at
+    return BundleRanges(lo, hi, skip, feat_of, vbin_of, off, roff)
 
 
 def apply_bundles(bins: np.ndarray, plan: BundlePlan) -> np.ndarray:

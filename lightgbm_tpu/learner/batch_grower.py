@@ -46,12 +46,11 @@ from ..ops.histogram import (bins_to_words, hist_dispatch,
 from ..ops.round_fuse import partition_select_pallas, use_fused_partition
 from ..ops.table import sum_small_table
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, SplitHyper,
-                         categorical_left_bitset, find_best_split,
-                         leaf_output)
+                         categorical_left_bitset, leaf_output)
 from .grower import (CegbInput, DeviceBundle, TreeArrays, _INF_BOUND,
-                     _empty_tree, _expand_hist, _expand_hist_col,
-                     _feature_bin_of_rows, pv_vote_best_split,
-                     sample_features_bynode)
+                     _empty_tree, _expand_hist_col, _feature_bin_of_rows,
+                     best_split_of_hist, pv_vote_best_split,
+                     sample_features_bynode, split_ranges)
 
 #: data size below which warmup width-matching is never worth its extra
 #: kernel compilations (tests patch this to exercise the ladder cheaply)
@@ -190,11 +189,13 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # transposed packed mirror for the round-6 packed histogram kernel
         words_t = lax.optimization_barrier(bins_words.T) \
             if hist_dispatch(hp.hist_kernel, hp.n_bins).mirror else None
-    # fused partition+key kernel (ops/round_fuse.py): numeric non-bundled
-    # splits only — categorical bitsets / EFB inverse tables are per-row
-    # gathers, kept on the XLA path
+    # fused partition+key kernel (ops/round_fuse.py): splits a range of
+    # the physical column states (grower.py split_ranges) — numeric
+    # features, bundled or not.  Categorical bitsets and the EFB inverse
+    # table of a plan without ranges are per-row gathers, kept on the
+    # XLA path
     fuse_partition = (use_fused_partition() and not hp.has_categorical
-                      and bundle is None)
+                      and (bundle is None or bundle.search is not None))
     pooled = 0 < hp.hist_pool_slots < hp.num_leaves
     from ..ops.histogram import use_pallas as _use_pallas
     INF = jnp.float32(_INF_BOUND)
@@ -246,13 +247,11 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 nan_bin=nan_bin, is_cat=is_cat, monotone=monotone,
                 bundle=bundle, num_f=num_f, top_k=top_k,
                 axis_name=axis_name)
-        hv = h_phys if bundle is None else \
-            _expand_hist(h_phys, bundle, g_, h_, c_)
-        res = find_best_split(hv, g_, h_, c_, num_bins, nan_bin, is_cat,
-                              fm, hp, monotone=monotone,
-                              leaf_min=lmin, leaf_max=lmax, depth=depth,
-                              parent_output=pout, rng_key=key,
-                              gain_penalty=pen, adv_bounds=adv)
+        res = best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin,
+                                 is_cat, fm, hp, bundle, monotone=monotone,
+                                 leaf_min=lmin, leaf_max=lmax, depth=depth,
+                                 parent_output=pout, rng_key=key,
+                                 gain_penalty=pen, adv_bounds=adv)
         depth_ok = (hp.max_depth <= 0) | (depth < hp.max_depth)
         return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
 
@@ -864,11 +863,13 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
           with jax.named_scope("partition"):
               feats_k = st["best_feat"][parents]                      # [K]
               if fuse_partition:
+                  col_k, lo_k, hi_k, pos_k, dl_k, miss_k = split_ranges(
+                      feats_k, st["best_thr"][parents],
+                      st["best_dl"][parents], nan_bin, bundle, hp.n_bins)
                   lor, sort_key = partition_select_pallas(
                       bins_t, lor, mask_f.astype(jnp.int32),
-                      feats_k, st["best_thr"][parents],
-                      st["best_dl"][parents].astype(jnp.int32),
-                      nan_bin[feats_k].astype(jnp.int32),
+                      col_k, lo_k, hi_k, pos_k, dl_k.astype(jnp.int32),
+                      miss_k.astype(jnp.int32),
                       parents, new_leaves, valid.astype(jnp.int32),
                       smaller, rows_per_block=min(hp.rows_per_block, 2048),
                       interpret=not _use_pallas())

@@ -39,23 +39,87 @@ from jax import lax
 
 from ..ops.histogram import (bins_to_words, hist_dispatch,
                              histogram_for_leaf_masked, root_histogram)
+from ..obs.metrics import count_event
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, VAR_CAT_ONEHOT,
                          VAR_NUM_RIGHT, SplitHyper, SplitResult,
-                         categorical_left_bitset, find_best_split, leaf_gain,
-                         leaf_output, smoothed_output)
+                         categorical_left_bitset, find_best_split,
+                         find_best_split_ranges, leaf_gain, leaf_output,
+                         smoothed_output)
 
 _INF_BOUND = 3.0e38  # leaf-output bound sentinel (±"infinity" in f32)
 
 
+class BundleSearch(NamedTuple):
+    """A bundle plan as RANGES on device (io/bundling.py
+    ``bundle_ranges``): what the split search, the fused partition and
+    the matmul valid scorer need to stay in physical-bin space.  Per
+    feature the segment ``[lo, hi]`` of its column and ``skip``, the
+    default bin the segment leaves out; per physical position the member
+    there (-1 none), its virtual bin, its member's ``skip`` and last
+    threshold, and the position's distances from its segment's ends."""
+    lo: jax.Array           # i32 [Fv]
+    hi: jax.Array           # i32 [Fv]
+    skip: jax.Array         # i32 [Fv]
+    feat_of: jax.Array      # i32 [Fb, B]
+    vbin_of: jax.Array      # i32 [Fb, B]
+    skip_of: jax.Array      # i32 [Fb, B]
+    last_of: jax.Array      # i32 [Fb, B] — num_bins - 1 of the member
+    off: jax.Array          # i32 [Fb, B]
+    roff: jax.Array         # i32 [Fb, B]
+
+
 class DeviceBundle(NamedTuple):
-    """EFB expansion tables on device (io/bundling.py BundlePlan): the
-    physical bin matrix / histograms cover bundle columns, these map them
-    back to per-feature (virtual) bin space."""
+    """EFB tables on device (io/bundling.py BundlePlan): the physical bin
+    matrix / histograms cover bundle columns.  ``search`` states the
+    layout as ranges; it is None where a member cannot be searched or
+    routed by a range — a CATEGORICAL member (its left set is a bitset
+    over virtual bins) or a member with a MISSING bin (the bin goes by
+    ``default_left`` while the other members' positions go by the default
+    bin's side: one flag cannot say both) — and those plans keep the
+    expansion to virtual space (``_expand_hist``) and ``inv_table``."""
     feat_col: jax.Array     # i32 [Fv] — physical column of each feature
     src_idx: jax.Array      # i32 [Fv, B] — virtual bin -> bundle bin
     valid: jax.Array        # bool [Fv, B]
     default_bin: jax.Array  # i32 [Fv] — implicit most-frequent bin
     inv_table: jax.Array    # i32 [Fv, B] — bundle value -> virtual bin
+    search: Optional[BundleSearch] = None
+
+
+def searches_in_bundle_space(bundle: Optional[DeviceBundle], hp: SplitHyper,
+                             penalised: bool = False) -> bool:
+    """Whether a bundled job's split search stays on the physical
+    ``[Fb, B]`` histogram (ops/split.py ``find_best_split_ranges``).  Not
+    where the plan has no ranges (``DeviceBundle.search``), under
+    monotone constraints (bounds per VIRTUAL feature and threshold),
+    extra-trees (one random threshold per virtual feature) or a per-feature
+    gain penalty (CEGB): those expand (``_expand_hist``)."""
+    return (bundle is not None and bundle.search is not None
+            and not hp.use_monotone and not hp.extra_trees
+            and not penalised)
+
+
+def split_ranges(feat: jax.Array, thr: jax.Array, dl: jax.Array,
+                 nan_bin: jax.Array, bundle: Optional[DeviceBundle],
+                 n_bins: int):
+    """Splits ``(feature, bin threshold, default_left)`` as range
+    predicates on the physical column: ``(column, lo, hi, pos,
+    default_left, miss)``.  A row at ``miss``, the position of the
+    feature's missing bin (-1 for none), goes by ``default_left``; any
+    other goes left when ``lo <= c <= pos``, or when c lies outside
+    ``[lo, hi]`` and ``default_left``.  An unbundled feature is the trivial
+    range, the whole column with ``pos = thr``, and its missing bin sits
+    wherever the bin mapper put it (last, or the zero bin under
+    ``zero_as_missing``).  A bundle member's range is its segment; outside
+    it the feature sits at its default bin, left when that bin is <= thr,
+    and a plan with ranges has no missing bin."""
+    if bundle is None:
+        return (feat, jnp.zeros_like(feat), jnp.full_like(feat, n_bins - 1),
+                thr, dl, nan_bin[feat])
+    s = bundle.search
+    below = (s.skip[feat] <= thr)
+    return (bundle.feat_col[feat], s.lo[feat], s.hi[feat],
+            s.lo[feat] + thr - below.astype(jnp.int32), below,
+            jnp.full_like(feat, -1))
 
 
 def _expand_hist(hist_b: jax.Array, bundle: DeviceBundle, sum_g, sum_h,
@@ -65,6 +129,7 @@ def _expand_hist(hist_b: jax.Array, bundle: DeviceBundle, sum_g, sum_h,
     Each feature's stored bins are gathered from its bundle column; the
     implicit default bin is completed from the leaf totals (the reference's
     most-freq-bin completion, Dataset::FixHistogram dataset.h:760)."""
+    count_event("bundle_expand_calls")      # when traced, not when run
     B = hist_b.shape[1]
     hv = hist_b[bundle.feat_col[:, None], bundle.src_idx]       # [Fv, B, C]
     hv = hv * bundle.valid[..., None]
@@ -83,10 +148,34 @@ def _expand_hist_col(hcol: jax.Array, bundle: DeviceBundle,
     The column must already be globally reduced (psum) before expansion when
     the totals are global — the default-bin completion is total − rest and
     mixing global totals with a local rest double-counts."""
+    count_event("bundle_expand_calls")      # when traced, not when run
     hv = hcol[bundle.src_idx[feat]] * bundle.valid[feat][:, None]
     rest = jnp.sum(hv, axis=0)
     total = jnp.stack([sum_g, sum_h, count, jnp.zeros_like(count)])
     return hv.at[bundle.default_bin[feat]].add(total - rest)
+
+
+def best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin, is_cat, fm,
+                       hp: SplitHyper, bundle: Optional[DeviceBundle], *,
+                       monotone=None, parent_output=0.0, leaf_min=None,
+                       leaf_max=None, depth=None, rng_key=None,
+                       gain_penalty=None, adv_bounds=None) -> SplitResult:
+    """Best split of one leaf from its PHYSICAL histogram: in bundle
+    space where the plan and the job allow it
+    (``searches_in_bundle_space``), else ``find_best_split`` on the
+    histogram itself (no bundles) or on its expansion to virtual space."""
+    if searches_in_bundle_space(bundle, hp, gain_penalty is not None):
+        with jax.named_scope("bundle_search"):
+            return find_best_split_ranges(h_phys, g_, h_, c_, bundle.search,
+                                          fm, hp,
+                                          parent_output=parent_output)
+    hv = h_phys if bundle is None else \
+        _expand_hist(h_phys, bundle, g_, h_, c_)
+    return find_best_split(hv, g_, h_, c_, num_bins, nan_bin, is_cat, fm, hp,
+                           monotone=monotone, parent_output=parent_output,
+                           leaf_min=leaf_min, leaf_max=leaf_max, depth=depth,
+                           rng_key=rng_key, gain_penalty=gain_penalty,
+                           adv_bounds=adv_bounds)
 
 
 def _feature_bin_of_rows(bins_t: jax.Array, bundle: Optional[DeviceBundle],
@@ -469,13 +558,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 variant=b[5].astype(jnp.int32),
                 left_sum_g=b[6], left_sum_h=b[7], left_count=b[8],
                 right_sum_g=b[9], right_sum_h=b[10], right_count=b[11])
-        hv = h_phys if bundle is None else \
-            _expand_hist(h_phys, bundle, g_, h_, c_)
-        res = find_best_split(hv, g_, h_, c_, num_bins, nan_bin, is_cat,
-                              fm, hp, monotone=monotone,
-                              parent_output=parent_output, leaf_min=lmin,
-                              leaf_max=lmax, depth=depth, rng_key=key,
-                              gain_penalty=pen, adv_bounds=adv)
+        res = best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin,
+                                 is_cat, fm, hp, bundle, monotone=monotone,
+                                 parent_output=parent_output, leaf_min=lmin,
+                                 leaf_max=lmax, depth=depth, rng_key=key,
+                                 gain_penalty=pen, adv_bounds=adv)
         depth_ok = (hp.max_depth <= 0) | (depth < hp.max_depth)
         return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
 

@@ -70,6 +70,22 @@ COUNTERS: Dict[str, str] = {
     "sharded_rounds":
         "rounds of the per-iteration loop whose trees grew over a device "
         "mesh (tree_learner data, voting, feature, data_gspmd)",
+    "efb_bundles":
+        "physical columns of the EFB plans constructed (io/dataset.py); in "
+        "a booster's own registry, of the plan it trains on",
+    "efb_features":
+        "used (virtual) features those plans cover",
+    "efb_conflict_rows":
+        "rows in which a bundle member's non-default value lost to an "
+        "earlier member of its column (first writer wins); 0 for a plan "
+        "whose conflicts were counted over every row",
+    "bundle_space_search_rounds":
+        "rounds whose split search stayed on the physical bundle columns "
+        "(ops/split.py find_best_split_ranges)",
+    "bundle_expand_calls":
+        "expansions of a bundle histogram to virtual-feature space "
+        "(_expand_hist, _expand_hist_col) put into a program, counted when "
+        "TRACED: 0 for a job searched wholly in bundle space",
     "nan_guard_trips": "rounds where the numeric guard saw non-finite values",
     "nan_guard_raises": "numeric-guard trips escalated to an exception",
     "nan_rounds_skipped": "rounds dropped by nan_policy=skip_round",

@@ -178,6 +178,45 @@ def smoothed_output(g: jax.Array, h: jax.Array, n: jax.Array,
     return out
 
 
+def parent_gain_shift(sum_g, sum_h, parent_output, hp: "SplitHyper"):
+    """``parent gain + min_gain_to_split``: what a split's children have
+    to beat.  The closed form g^2/(h+l2) is exact only when the output is
+    the unconstrained optimum; smoothing / clipping force the evaluated
+    form, at the parent's ACTUAL output (feature_histogram.hpp gain_shift:
+    given-output under smoothing, clipped GetLeafGain under
+    max_delta_step) — otherwise a clipped parent looks artificially good
+    and no split ever clears it."""
+    l1, l2 = hp.lambda_l1, hp.lambda_l2
+    if hp.path_smooth > 0.0:
+        parent_gain = gain_given_output(sum_g, sum_h, parent_output, l1, l2)
+    elif hp.max_delta_step > 0.0:
+        po = leaf_output(sum_g, sum_h, l1, l2, hp.max_delta_step)
+        parent_gain = gain_given_output(sum_g, sum_h, po, l1, l2)
+    else:
+        parent_gain = leaf_gain(sum_g, sum_h, l1, l2)
+    return parent_gain + hp.min_gain_to_split
+
+
+def children_gain(gl, hl, nl, sum_g, sum_h, count, l2, parent_output,
+                  hp: "SplitHyper") -> jax.Array:
+    """Gain of the two children a candidate's left sums give (no monotone
+    constraint), ``NEG_INF`` where a child falls under ``min_data_in_leaf``
+    or ``min_sum_hessian_in_leaf``."""
+    l1 = hp.lambda_l1
+    gr, hr, nr = sum_g - gl, sum_h - hl, count - nl
+    if hp.path_smooth > 0.0 or hp.max_delta_step > 0.0:
+        lo = smoothed_output(gl, hl, nl, parent_output, l1, l2, hp)
+        ro = smoothed_output(gr, hr, nr, parent_output, l1, l2, hp)
+        gain = (gain_given_output(gl, hl, lo, l1, l2)
+                + gain_given_output(gr, hr, ro, l1, l2))
+    else:
+        gain = leaf_gain(gl, hl, l1, l2) + leaf_gain(gr, hr, l1, l2)
+    ok = ((nl >= hp.min_data_in_leaf) & (nr >= hp.min_data_in_leaf)
+          & (hl >= hp.min_sum_hessian_in_leaf)
+          & (hr >= hp.min_sum_hessian_in_leaf))
+    return jnp.where(ok, gain, NEG_INF)
+
+
 def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                     count: jax.Array, num_bins: jax.Array, nan_bin: jax.Array,
                     is_cat: jax.Array, feature_mask: Optional[jax.Array],
@@ -219,24 +258,14 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
     has_missing = nan_bin[:, None] >= 0
 
     l1, l2 = hp.lambda_l1, hp.lambda_l2
-    # the closed form g²/(h+l2) is exact only when the output is the
-    # unconstrained optimum; smoothing / clipping force the evaluated form.
-    # The parent-side gain shift must be evaluated the same way, at the
-    # parent's ACTUAL output (feature_histogram.hpp gain_shift: given-output
-    # under smoothing, clipped GetLeafGain under max_delta_step) — otherwise
-    # a clipped parent looks artificially good and no split ever clears it.
     output_path = (hp.use_monotone or hp.path_smooth > 0.0
                    or hp.max_delta_step > 0.0)
-    if hp.path_smooth > 0.0:
-        parent_gain = gain_given_output(sum_g, sum_h, parent_output, l1, l2)
-    elif hp.max_delta_step > 0.0:
-        po = leaf_output(sum_g, sum_h, l1, l2, hp.max_delta_step)
-        parent_gain = gain_given_output(sum_g, sum_h, po, l1, l2)
-    else:
-        parent_gain = leaf_gain(sum_g, sum_h, l1, l2)
-    min_shift = parent_gain + hp.min_gain_to_split
+    min_shift = parent_gain_shift(sum_g, sum_h, parent_output, hp)
 
     def variant_gain(gl_v, hl_v, nl_v, l2_v, bnds=None):
+        if not hp.use_monotone:
+            return children_gain(gl_v, hl_v, nl_v, sum_g, sum_h, count,
+                                 l2_v, parent_output, hp)
         gr = sum_g - gl_v
         hr = sum_h - hl_v
         nr = count - nl_v
@@ -420,6 +449,93 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
         left_sum_g=lg, left_sum_h=lh, left_count=ln,
         right_sum_g=sum_g - lg, right_sum_h=sum_h - lh, right_count=count - ln,
     )
+
+
+def segment_sums(x: jax.Array, off: jax.Array, roff: jax.Array):
+    """Segmented sums along the last (bin) axis: ``below[p]`` the sum from
+    its segment's first position up to p, ``whole[p]`` its segment's sum.
+    ``off`` / ``roff`` [Fb, B] are each position's distances from its
+    segment's two ends (io/bundling.py ``bundle_ranges``): a doubling
+    scan, log2(B) shifted adds, each taken where the partner lies inside
+    the segment — sums never cross a segment, so a small member beside a
+    large one keeps its own rounding."""
+    width = x.shape[-1]
+    pad = [(0, 0)] * (x.ndim - 1)
+    below, above, d = x, x, 1
+    while d < width:
+        below = jnp.where(off >= d, below + jnp.pad(
+            below[..., :-d], pad + [(d, 0)]), below)
+        above = jnp.where(roff >= d, above + jnp.pad(
+            above[..., d:], pad + [(0, d)]), above)
+        d *= 2
+    return below, below + above - x
+
+
+def find_best_split_ranges(hist_b: jax.Array, sum_g: jax.Array,
+                           sum_h: jax.Array, count: jax.Array, search,
+                           feature_mask: Optional[jax.Array],
+                           hp: SplitHyper, parent_output=0.0) -> SplitResult:
+    """``find_best_split`` for NUMERIC features without missing bins, on
+    the PHYSICAL histogram ``hist_b`` [Fb, B, C] of EFB bundle columns
+    (``search``: learner/grower.py ``BundleSearch``), never on the virtual
+    ``[Fv, B, C]``: at Fv = 4,228 one-hot features that expansion is 84
+    gathers of 17 MB a round pass for features of two bins each.
+
+    A member's bins lie in one segment of its column in order, the
+    default bin left out.  So the left sums of "virtual bin <= t" are the
+    segment's prefix sum up to t's position, plus the default bin's mass
+    (``leaf total - segment total``) where the default bin is <= t.  Each
+    physical position p (member f, virtual bin v) stands for two
+    candidate thresholds: t = v, and t = v - 1 where v - 1 is f's default
+    bin, which has no position of its own.  Gains, the ``min_data`` /
+    ``min_sum_hessian`` checks, ``lambda_l1/l2`` and the tie order (lowest
+    virtual feature, then lowest threshold) are ``find_best_split``'s, and
+    the result states the VIRTUAL feature and bin."""
+    ghn = jnp.moveaxis(hist_b[..., :3], -1, 0)                  # [3, Fb, B]
+    below, whole = segment_sums(ghn, search.off, search.roff)
+    total = jnp.stack([sum_g, sum_h, count])[:, None, None]
+    feat = jnp.maximum(search.feat_of, 0)
+    live = search.feat_of >= 0
+    v, skip = search.vbin_of, search.skip_of                    # [Fb, B]
+    rest = total - whole                # the default bin's mass
+    # t = v: the prefix, with the default bin's mass where it lies below v
+    left_at = below + jnp.where(skip < v, rest, 0.0)
+    # t = v - 1 = the default bin: everything below v, and the default
+    left_dflt = below - ghn + rest
+    ok_at = live & (v < search.last_of)
+    ok_dflt = live & (skip == v - 1)
+    if feature_mask is not None:
+        allowed = feature_mask[feat]
+        ok_at, ok_dflt = ok_at & allowed, ok_dflt & allowed
+    l2 = hp.lambda_l2
+
+    def gains(left, ok):
+        return jnp.where(ok, children_gain(left[0], left[1], left[2], sum_g,
+                                           sum_h, count, l2, parent_output,
+                                           hp), NEG_INF)
+    cand = jnp.stack([gains(left_dflt, ok_dflt), gains(left_at, ok_at)],
+                     axis=-1)                                   # [Fb, B, 2]
+    thr = jnp.stack([v - 1, v], axis=-1)
+    n_b = hist_b.shape[1]
+    # find_best_split's argmax takes the first of equal gains in
+    # (feature, threshold) order: the same winner here
+    order = feat[..., None] * n_b + thr
+    best_gain_raw = jnp.max(cand)
+    at = jnp.argmin(jnp.where(cand >= best_gain_raw, order,
+                              jnp.iinfo(jnp.int32).max).reshape(-1))
+    left = jnp.stack([left_dflt, left_at], axis=-1).reshape(3, -1)[:, at]
+    min_shift = parent_gain_shift(sum_g, sum_h, parent_output, hp)
+    return SplitResult(
+        gain=jnp.where(best_gain_raw <= NEG_INF / 2, jnp.float32(NEG_INF),
+                       best_gain_raw - min_shift),
+        feature=jnp.broadcast_to(feat[..., None], thr.shape)
+        .reshape(-1)[at].astype(jnp.int32),
+        threshold=jnp.maximum(thr.reshape(-1)[at], 0).astype(jnp.int32),
+        default_left=jnp.bool_(False), is_categorical=jnp.bool_(False),
+        variant=jnp.int32(VAR_NUM_RIGHT),
+        left_sum_g=left[0], left_sum_h=left[1], left_count=left[2],
+        right_sum_g=sum_g - left[0], right_sum_h=sum_h - left[1],
+        right_count=count - left[2])
 
 
 def categorical_left_bitset(hist_f: jax.Array, num_bins_f: jax.Array,

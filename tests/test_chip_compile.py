@@ -238,7 +238,7 @@ def test_half_rows_rung_compiles_at_the_benchmark_rows(one_chip, on_tpu, K,
         <= (70 + 3 * 4 + 4) * (n + 2048)
 
 
-K_ARGS = [((42,), jnp.int32)] * 8    # feats thr dl nanb parents new valid smaller
+K_ARGS = [((42,), jnp.int32)] * 10   # cols lo hi pos default_left miss parents new valid smaller
 
 
 def _partition(bins_t, lor, mask, *per_slot):

@@ -38,7 +38,10 @@ def test_chip_smoke_rehearsal_runs_every_phase_but_cannot_succeed():
     lines = [json.loads(ln) for ln in out.stdout.splitlines()
              if ln.startswith("{")]
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "device", "data", "train", "predict", "save_load", "serve"]
+        "device", "data", "train", "bundled", "predict", "save_load", "serve"]
+    bundled = lines[3]
+    assert bundled["bundle_expand_calls"] == 0 and bundled["bundles"] < 76
+    assert bundled["bundle_space_search_rounds"] == bundled["iters"]
     assert lines[-1]["ok"] is False and "rehearsal" in lines[-1]
     assert '"ok": true' not in out.stdout
 

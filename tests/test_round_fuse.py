@@ -17,6 +17,7 @@ import lightgbm_tpu.ops.round_fuse as RF
 from lightgbm_tpu.ops.hist_pallas import (compact_payload_pallas,
                                           histogram_payload_pallas)
 from lightgbm_tpu.ops.split import SplitHyper
+from lightgbm_tpu.learner import grower
 from lightgbm_tpu.learner.batch_grower import grow_tree_batched
 
 
@@ -269,24 +270,30 @@ def test_bins_to_words_roundtrip():
 
 
 def test_partition_kernel_matches_xla():
+    """Four slots: a missing bin that is NOT the last (the zero bin, as
+    ``zero_as_missing`` puts it) going left, no missing bin, a missing bin
+    that is the last going left past the threshold, and a disabled slot
+    whose parent has rows."""
     rng = np.random.default_rng(3)
-    n, f, K = 3000, 7, 3
+    n, f, K = 3000, 7, 4
     bins = rng.integers(0, 32, size=(n, f)).astype(np.uint8)
     lor = rng.integers(0, 5, size=n).astype(np.int32)
     mask = rng.integers(0, 2, size=n).astype(np.int32)
-    feats = np.array([2, 0, 5], np.int32)
-    thr = np.array([10, 3, 20], np.int32)
-    dl = np.array([1, 0, 0], np.int32)
-    nanb = np.array([0, -1, 31], np.int32)
-    parents = np.array([1, 3, 4], np.int32)
-    new_leaves = np.array([5, 6, 7], np.int32)
-    validk = np.array([1, 1, 0], np.int32)
-    smaller = np.array([1, 6, 7], np.int32)
+    feats = np.array([2, 0, 5, 6], np.int32)
+    thr = np.array([10, 3, 20, 12], np.int32)
+    dl = np.array([1, 0, 1, 0], np.int32)
+    nanb = np.array([0, -1, 31, 31], np.int32)
+    parents = np.array([1, 3, 4, 2], np.int32)
+    new_leaves = np.array([5, 6, 7, 8], np.int32)
+    validk = np.array([1, 1, 1, 0], np.int32)
+    smaller = np.array([1, 6, 7, 8], np.int32)
 
     new_lor, key = RF.partition_select_pallas(
         jnp.asarray(bins.T), jnp.asarray(lor), jnp.asarray(mask),
-        jnp.asarray(feats), jnp.asarray(thr), jnp.asarray(dl),
-        jnp.asarray(nanb), jnp.asarray(parents), jnp.asarray(new_leaves),
+        *grower.split_ranges(jnp.asarray(feats), jnp.asarray(thr),
+                             jnp.asarray(dl),
+                             jnp.full((f,), -1).at[feats].set(nanb), None, 32),
+        jnp.asarray(parents), jnp.asarray(new_leaves),
         jnp.asarray(validk), jnp.asarray(smaller),
         rows_per_block=512, interpret=True)
 
@@ -344,6 +351,96 @@ def test_fused_round_tree_identical(batch):
                                   np.asarray(t1.leaf_value))
     np.testing.assert_array_equal(np.asarray(lor0), np.asarray(lor1))
     assert int(t0.num_leaves) > 8
+
+
+@pytest.mark.parametrize("default_left", [0, 1])
+def test_xor_ranges_state_the_range_predicate(default_left):
+    """The two ranges the kernel and the matmul scorer compare with,
+    against the predicate ``split_ranges`` documents, for every value of
+    the column: an unbundled feature with its missing bin nowhere, first,
+    below, at and past the threshold and last; a bundle member's segment
+    with the threshold nowhere (``pos = lo - 1``: every one-hot split),
+    inside and at its end."""
+    cases = [(0, 255, t, m) for t in (0, 7, 254) for m in (-1, 0, 5, 7, 8, 255)] \
+        + [(lo, hi, pos, -1) for lo, hi in ((1, 1), (3, 9), (200, 255))
+           for pos in (lo - 1, lo, hi - 1, hi)]
+    lo, hi, pos, miss = (jnp.asarray(v, jnp.int32) for v in zip(*cases))
+    dl = jnp.full_like(lo, default_left)
+    a1, n1, a2, n2 = (np.asarray(v, np.int64)[:, None]
+                      for v in RF.xor_ranges(lo, hi, pos, dl, miss))
+    c = np.arange(256)[None, :]
+    got = ((c >= a1) & (c - a1 < n1)) != ((c >= a2) & (c - a2 < n2))
+    lo, hi, pos, miss = (np.asarray(v)[:, None] for v in (lo, hi, pos, miss))
+    want = np.where(c == miss, bool(default_left),
+                    ((c >= lo) & (c <= pos))
+                    | (((c < lo) | (c > hi)) & bool(default_left)))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_zero_as_missing_job_keeps_the_fused_kernel_and_the_matmul_scorer(
+        monkeypatch):
+    """``zero_as_missing`` puts a feature's missing bin at its zero bin,
+    in the MIDDLE of its bins.  Such a job rides the fused partition
+    kernel and the matmul valid scorer as any numeric job does (the
+    kernel's ``miss`` descriptor): the round program holds the kernel, the
+    tree and the rows' leaves are the XLA path's, both default directions
+    occur, and the matmul scorer gives the frontier walk's scores."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.learner import batch_grower
+    from lightgbm_tpu.models.predict import (predict_bins_tree,
+                                             predict_bins_tree_matmul)
+    rng = np.random.default_rng(11)
+    n, f = 6000, 6
+    X = rng.normal(size=(n + 1500, f))
+    X[rng.random(X.shape) < 0.3] = 0.0
+    # zeros of feature 0 belong with its high values, zeros of feature 1
+    # with its low ones: missing goes right at one split and left at other
+    z = (np.where(X[:, 0] == 0, 2.0, X[:, 0]) > 0.5).astype(float) \
+        + (np.where(X[:, 1] == 0, -2.0, X[:, 1]) > -0.5) + 0.3 * X[:, 2]
+    y = (z + 0.3 * rng.normal(size=len(z)) > 1.2).astype(float)
+    params = {"objective": "binary", "metric": ["auc"], "num_leaves": 15,
+              "min_data_in_leaf": 5, "zero_as_missing": True, "max_bin": 63,
+              "verbosity": -1}
+    ds = lgb.Dataset(X[:n], label=y[:n], params=params)
+    dv = ds.create_valid(X[n:], label=y[n:])
+    bst = lgb.train(params, ds, num_boost_round=1, valid_sets=[dv],
+                    callbacks=[lgb.record_evaluation({})])
+    gb = bst._gbdt
+    nanb, nbins = np.asarray(gb.nan_bin_arr), np.asarray(gb.num_bins_arr)
+    assert ((nanb > 0) & (nanb < nbins - 1)).all()
+    assert gb._matmul_valid_ok() and gb._valid_bins_t[0] is not None
+
+    sign = jnp.where(jnp.asarray(y[:n]) > 0, 1.0, -1.0)
+    grad, hess = (-sign * 0.5).astype(jnp.float32), jnp.full((n,), 0.25)
+    grow = lambda: grow_tree_batched.__wrapped__(
+        gb.bins, grad, hess, None, gb.num_bins_arr, gb.nan_bin_arr,
+        gb.is_cat_arr, None, gb.hp, batch=4)
+    calls = []
+    kernel = batch_grower.partition_select_pallas
+    monkeypatch.setattr(batch_grower, "partition_select_pallas",
+                        lambda *a, **k: calls.append(1) or kernel(*a, **k))
+    t0, lor0 = grow()
+    assert not calls
+    RF._FUSE_TEST_INTERPRET = True          # read when traced
+    try:
+        t1, lor1 = grow()
+    finally:
+        RF._FUSE_TEST_INTERPRET = False
+    assert calls
+    for a, b in zip(t0, t1):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(lor0), np.asarray(lor1))
+    ni = int(t1.num_leaves) - 1
+    assert ni == 14 and len(set(np.asarray(t1.default_left)[:ni])) == 2
+    walk = predict_bins_tree(t1, gb._valid_bins[0], gb.nan_bin_arr, None,
+                             False)
+    fast = predict_bins_tree_matmul(t1, gb._valid_bins_t[0], gb.nan_bin_arr,
+                                    None, n_bins=gb.hp.n_bins)
+    np.testing.assert_array_equal(np.asarray(fast), np.asarray(walk))
+    # rows sit at the missing position on both sides of such splits
+    vb = np.asarray(gb._valid_bins[0])
+    assert all((vb[:, ft] == nanb[ft]).any()
+               for ft in np.asarray(t1.split_feature)[:ni])
 
 
 @pytest.mark.parametrize("bagging", [False, True], ids=["all_rows", "bagging"])

@@ -391,8 +391,9 @@ def test_span_count_follows_dispatches_and_trees_not_rows(tmp_path):
     large = _span_counts(tmp_path, 3000, "large")
     assert small == large
     dispatches, trees = 2, 42
+    # the train and the valid Dataset construct inside the job's span
     assert small == {"train": 1, "booster_init": 1, "train_fused": 1,
-                     "fused_prepare": dispatches,
+                     "construct": 2, "fused_prepare": dispatches,
                      "fused_round_scan": dispatches,
                      "fused_chunk_transfer": dispatches,
                      "dispatch_done": dispatches,
