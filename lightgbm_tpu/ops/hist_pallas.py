@@ -319,16 +319,49 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
 
 
-_GROUP = 32     # payload rows a byte-plane group holds: 4 x 32 MXU rows
+_WIN = 128      # output columns one contraction fills: a lane tile
+
+
+@functools.partial(jax.jit, static_argnames=("rows_per_block",
+                                             "rows_per_dot"))
+def compaction_ranks(key: jax.Array, *, rows_per_block: int,
+                     rows_per_dot: int):
+    """Where each selected row goes, for ``compact_payload_pallas``: the
+    part of a compacted pass that does not need the rows' data, as a few
+    XLA operations over the keys alone.
+
+    ``key`` i32 [n] (selected when below 2^30).  Returns ``t`` i32
+    [1, n_pad] with n_pad = n rounded up to ``rows_per_block``: the
+    output column of each selected row (its rank among the selected, in
+    row order) and -1 for the others and for the pad; and ``cum`` i32
+    [n_pad / rows_per_dot + 1]: the number of selected rows before each
+    run of ``rows_per_dot`` rows, the total last.
+
+    The rank inside each run of 128 rows is one matrix product of the
+    0/1 mask against a triangular ``[128, 128]`` (exact: integers up to
+    128 in float32 sums); the runs' totals take a running sum 128 times
+    shorter than the rows.
+    """
+    n = key.shape[0]
+    n_pad = _round_up(max(n, 1), rows_per_block)
+    sel = jnp.pad(key < (1 << 30), (0, n_pad - n)).reshape(-1, _WIN)
+    i = lax.iota(jnp.int32, _WIN)
+    tri = (i[:, None] <= i[None, :]).astype(jnp.bfloat16)
+    rank = jnp.dot(sel.astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    per_run = jnp.sum(sel, axis=1, dtype=jnp.int32)
+    before = jnp.cumsum(per_run) - per_run
+    t = jnp.where(sel, before[:, None] + rank - 1, -1).reshape(1, n_pad)
+    cum = jnp.concatenate([before.reshape(-1, rows_per_dot // _WIN)[:, 0],
+                           before[-1:] + per_run[-1:]])
+    return t, cum
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("size", "rows_per_block",
-                                    "lanes_per_dot", "interpret"))
+                   static_argnames=("size", "rows_per_block", "interpret"))
 def compact_payload_pallas(src: jax.Array, key: jax.Array, grad: jax.Array,
                            hess: jax.Array, leaf_of_row: jax.Array, *,
-                           size: int, rows_per_block: int = 1024,
-                           lanes_per_dot: int = 256,
+                           size: int, rows_per_block: int = 4096,
                            interpret: bool = False) -> jax.Array:
     """Stream the selected rows into the lane-dense payload of
     ``histogram_payload_pallas``: i32 [round_up(W+3, 8), >= size], one
@@ -343,179 +376,249 @@ def compact_payload_pallas(src: jax.Array, key: jax.Array, grad: jax.Array,
     The selected row of rank r (ascending row order) lands in column r:
     columns [0, cnt) are bit for bit
     ``payload.T[:, sort(key)[:cnt] & (2^30 - 1)]`` of the row-major
-    ``[n, W+3]`` payload; columns from cnt on hold zeros or nothing
-    written at all, for the consumer's position guard.  Where more than
-    the output's columns are selected the excess is dropped.
+    ``[n, W+3]`` payload; nothing is to be assumed of the columns from
+    cnt on.  Where more than the output's columns are selected the
+    excess is dropped.
 
-    Per row block: the rank inside the block is a prefix sum of the mask
-    along the lanes (log2(blk) rolls), the running count across blocks a
-    scalar in SMEM.  The block's payload words are split into their four
-    BYTE planes (0..255: exact in bfloat16) and contracted on the MXU
-    against ``onehot(target column)``, one non-zero term per output, then
-    reassembled into words.  Only the ``lanes_per_dot``-wide column
-    windows the block's rows fall in are contracted, so the work follows
-    the number of selected rows; a block with every row selected fills
-    two output blocks, one with none touches nothing.  The result gathers
-    in a two-block VMEM ring and each full output block leaves by one DMA.
+    Everything that does not need the rows' data is done BEFORE the
+    kernel by ``compaction_ranks`` (XLA, on the keys): each row's output
+    column and the count of selected rows before every run of rows,
+    which the kernel takes by scalar prefetch.  A grid step (eight runs,
+    at most ``rows_per_block`` rows) therefore computes no rank, reduces
+    nothing to a scalar and waits on no step before it.
 
-    A u8 ``src`` is first brought to the byte-plane order on the MXU too
-    (feature 4j+k is byte k of word j: the same permutation for every
-    128 features), so both sources give the same words.
+    A run is the rows one contraction takes.  A window costs a fixed part
+    and a part per row of the run, a run touches ``1 + selected rows /
+    128`` windows, so the run that costs least shortens as the selected
+    share grows: 256 rows where the bucket holds more than a third of the
+    rows, 512 down to a sixth, 1024 below (PERF.md section 5 has the
+    chip's numbers).
+
+    Per run: its words (a u8 source's four feature rows ARE a word of
+    the packed layout: a bitcast, no arithmetic) with the three riding
+    words below them are split into their four BYTE planes (0..255: exact
+    in bfloat16) and contracted on the MXU against ``onehot(output
+    column)`` for each 128-column window the run's rows fall in, one
+    non-zero term per output, then reassembled into words.  A step takes
+    the number of windows that at least half of its runs need (none, one
+    or two) for EVERY run in straight-line code, which the compiler
+    overlaps from run to run (a window no row falls in goes to a spare
+    place), and the windows beyond that in a loop per run.  The runs ADD
+    into a VMEM ring of output blocks, in any order, each window zeroed
+    in the step its first column falls in; each full block leaves by one
+    DMA, awaited when its place in the ring is next needed.
     """
     n = key.shape[0]
     from_bytes = src.dtype == jnp.uint8
     F = src.shape[0]
     W = pl.cdiv(F, 4) if from_bytes else F
-    G = pl.cdiv(W + 3, _GROUP)
-    R = _GROUP * G              # payload rows, in whole groups
-    r_out = _round_up(W + 3, 8)  # a DMA moves whole sublane tiles
-    # the scratch grows with G (about 1.5 MB a group at 1024 rows): the
-    # block shrinks with it, by powers of two so that ch divides it
-    blk = min(rows_per_block, max(128, 1 << (4096 // G).bit_length() - 1),
-              max(128, _round_up(n, 128)))
-    ch = min(lanes_per_dot, blk)
-    assert blk % ch == 0 and ch % 128 == 0, (blk, ch)
+    R = _round_up(W + 3, 8)     # payload rows: whole sublane tiles
+    # the scratch grows with R (1.1 MB at 24 rows and 2048 rows a step):
+    # the block shrinks with it, by powers of two
+    run = 256 if 3 * size > n else 512 if 6 * size > n else 1024
+    blk = min(rows_per_block, 8 * run,
+              max(128, 1 << (8192 // pl.cdiv(R, 32)).bit_length() - 1),
+              max(128, 1 << (n - 1).bit_length()))
+    sub = min(run, blk)
+    fb = min(1024, blk)         # columns one DMA carries out
+    assert blk % fb == 0 and fb % sub == 0 and sub % _WIN == 0, (blk, fb)
+    nsub, wpb, own_max = blk // sub, fb // _WIN, blk // fb
+    # output blocks the VMEM ring holds: those a step's rows can reach
+    # (own_max + 1) and one whose DMA is still running
+    n_ring = own_max + 2
     nb = pl.cdiv(n, blk)
-    nb_out = pl.cdiv(max(size, 1), blk)
-    f_rows = 128 * pl.cdiv(F, 128)
+    nb_out = pl.cdiv(max(size, 1), fb)
+    # a u8 source comes in whole 32-row tiles, four rows to a word
+    f_rows = _round_up(F, 32) if from_bytes else F
+    w_rows = f_rows // 4 if from_bytes else W
+    t, cum = compaction_ranks(key, rows_per_block=blk, rows_per_dot=sub)
 
     def byte_planes(x):
-        # i32 [R, blk] -> bf16 [4R, blk]: per group, byte plane k of its
-        # 32 rows at MXU rows [32k, 32k + 32)
+        # i32 [R, sub] -> bf16 [4R, sub]: byte plane k of the rows
+        # [8a, 8a + 8) at MXU rows [8 (4a + k), 8 (4a + k) + 8)
         return jnp.concatenate(
-            [((x[_GROUP * gi:_GROUP * (gi + 1)] >> (8 * k)) & 255)
-             .astype(jnp.float32).astype(jnp.bfloat16)
-             for gi in range(G) for k in range(4)], axis=0)
+            [((x[8 * a:8 * a + 8] >> (8 * k)) & 255).astype(jnp.float32)
+             for a in range(R // 8) for k in range(4)],
+            axis=0).astype(jnp.bfloat16)
 
-    def kernel(src_ref, key_ref, g_ref, h_ref, lor_ref, out_ref, x_ref,
-               *scratch):
-        bytes_ref = scratch[0] if from_bytes else None
-        ring_ref, count_ref, sem = scratch[-3:]
+    def words_of(d):
+        # f32 [4R, 128] of byte values -> i32 [R, 128]: three planes add
+        # up exactly below 2^24, the fourth is shifted in
+        def tile(a):
+            p = [d[8 * (4 * a + k):8 * (4 * a + k) + 8] for k in range(4)]
+            low = (p[2] * 65536.0 + p[1] * 256.0 + p[0]).astype(jnp.int32)
+            return low | (p[3].astype(jnp.int32) << 24)
+        return jnp.concatenate([tile(a) for a in range(R // 8)], axis=0)
+
+    def kernel(cum_ref, src_ref, t_ref, g_ref, h_ref, lor_ref, out_ref,
+               x_ref, planes_ref, ring_ref, sem):
         step = pl.program_id(0)
 
         @pl.when(step == 0)
         def _():
             x_ref[...] = jnp.zeros_like(x_ref)
-            if from_bytes:
-                bytes_ref[...] = jnp.zeros_like(bytes_ref)
-            ring_ref[...] = jnp.zeros_like(ring_ref)
-            count_ref[0] = 0        # selected rows before this block
-            count_ref[1] = 0        # 1 while an output block's DMA runs
 
-        base = count_ref[0]
-        ob = base // blk            # the output block being filled
-        off = base - ob * blk
-        lane = lax.broadcasted_iota(jnp.int32, (1, blk), 1)
-        # the last block's tail is masked by row number: no operand is
-        # padded
-        m = (key_ref[...] < (1 << 30)) & (step * blk + lane < n)
-        ones = m.astype(jnp.int32)
-        c = jnp.sum(ones)
-        rank, s = ones, 1
-        while s < blk:              # inclusive prefix sum along the lanes
-            rank = rank + jnp.where(lane >= s,
-                                    pltpu.roll(rank, s, axis=1), 0)
-            s *= 2
-        # column in the ring, [0, 2 blk); -1 matches no column
-        t = jnp.where(m, off + rank - 1, -1)                 # [1, blk]
+        at0 = step * nsub
+        los = [cum_ref[at0 + s] for s in range(nsub + 1)]
+        lo_b, hi_b = los[0], los[nsub]
+        # the windows each run's rows fall in: ``count`` from ``first`` on
+        first = [lo // _WIN for lo in los[:-1]]
+        count = [jnp.where(hi > lo, (hi + _WIN - 1) // _WIN - lo // _WIN, 0)
+                 for lo, hi in zip(los[:-1], los[1:])]
 
-        if not from_bytes:
-            x_ref[0:W, :] = src_ref[...]
-        x_ref[W:W + 1, :] = g_ref[...]
-        x_ref[W + 1:W + 2, :] = h_ref[...]
-        x_ref[W + 2:W + 3, :] = lor_ref[...]
-        planes = byte_planes(x_ref[...])                     # [4R, blk]
-        if from_bytes:
-            bytes_ref[0:F, :] = src_ref[...].astype(jnp.int32).astype(
-                jnp.float32)
-            p_i = lax.broadcasted_iota(jnp.int32, (128, 128), 0)
-            f_i = lax.broadcasted_iota(jnp.int32, (128, 128), 1)
-            perm = (f_i == 4 * (p_i % _GROUP) + p_i // _GROUP).astype(
-                jnp.float32).astype(jnp.bfloat16)
-            moved = [lax.dot_general(
-                perm, bytes_ref[128 * gi:128 * (gi + 1), :].astype(
-                    jnp.bfloat16), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-                if 128 * gi < F else jnp.zeros((128, blk), jnp.float32)
-                for gi in range(G)]
-            # the word rows of x_ref are zero here: the sum is a merge
-            planes = (planes.astype(jnp.float32)
-                      + jnp.concatenate(moved, axis=0)).astype(jnp.bfloat16)
-
-        def flush(block):
+        def flush(k):
             return pltpu.make_async_copy(
-                ring_ref.at[block % 2, 0:r_out, :],
-                out_ref.at[:, pl.ds(pl.multiple_of(block * blk, blk), blk)],
-                sem)
+                ring_ref.at[k % n_ring],
+                out_ref.at[:, pl.ds(pl.multiple_of(k * fb, fb), fb)],
+                sem.at[k % n_ring])
 
-        @pl.when(count_ref[1] == 1)
-        def _():
-            # block ob - 1 left in the step before: its half of the ring
-            # is block ob + 1's from here on
-            flush(ob - 1).wait()
-            ring_ref[(ob + 1) % 2] = jnp.zeros((R, blk), jnp.int32)
-            count_ref[1] = 0
+        def started(k):
+            return (k >= 0) & (k < nb_out)
 
-        for q in range(2 * blk // ch):
-            lo = q * ch
+        def columns(s):         # the run's rows; ``s`` may be traced
+            if isinstance(s, int):
+                return slice(s * sub, (s + 1) * sub)
+            return pl.ds(pl.multiple_of(s * sub, sub), sub)
 
-            @pl.when((c > 0) & (off + c > lo) & (off < lo + ch))
+        def stack(s):
+            # the run's words, the three riding words below them, and
+            # their byte planes for the contractions
+            cols = columns(s)
+            if from_bytes:
+                x_ref[0:w_rows, cols] = pltpu.bitcast(src_ref[:, cols],
+                                                      jnp.int32)
+                if F % 4:       # the last word's bytes past F
+                    x_ref[W - 1:W, cols] = x_ref[W - 1:W, cols] & (
+                        (1 << 8 * (F % 4)) - 1)
+                if w_rows > W + 3:
+                    x_ref[W + 3:w_rows, cols] = jnp.zeros(
+                        (w_rows - W - 3, sub), jnp.int32)
+            else:
+                x_ref[0:W, cols] = src_ref[:, cols]
+            x_ref[W:W + 1, cols] = lax.bitcast_convert_type(
+                g_ref[:, cols], jnp.int32)
+            x_ref[W + 1:W + 2, cols] = lax.bitcast_convert_type(
+                h_ref[:, cols], jnp.int32)
+            x_ref[W + 2:W + 3, cols] = lor_ref[:, cols]
+            planes_ref[:, cols] = byte_planes(x_ref[:, cols])
+
+        def window(s, w, wanted):
+            # columns [128 w, 128 w + 128) of the output, from run s
+            cols = columns(s)
+            col = lax.broadcasted_iota(jnp.int32, (_WIN, sub), 0)
+            oh = ((t_ref[:, cols] - w * _WIN) == col).astype(
+                jnp.float32).astype(jnp.bfloat16)
+            words = words_of(lax.dot_general(
+                planes_ref[:, cols], oh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32))            # [R, 128]
+            # a window no row of the run falls in goes to the spare slot
+            slot = jnp.where(wanted, (w // wpb) % n_ring, n_ring)
+            at = pl.multiple_of(jnp.where(wanted, w % wpb, 0) * _WIN, _WIN)
+            ring_ref[slot, :, pl.ds(at, _WIN)] += words
+
+        def runs(always):
+            # ``always`` windows of every run in straight-line code, then
+            # run by run the windows beyond them
+            if always:
+                for s in range(nsub):
+                    stack(s)
+                    for q in range(always):
+                        window(s, first[s] + q, q < count[s])
+
+            def rest(s, carry):
+                lo, hi = cum_ref[at0 + s], cum_ref[at0 + s + 1]
+                begin = lo // _WIN + always
+                end = (hi + _WIN - 1) // _WIN
+
+                @pl.when((hi > lo) & (end > begin))
+                def _():
+                    if not always:
+                        stack(s)
+
+                    def more(w, c):
+                        window(s, w, True)
+                        return c
+
+                    lax.fori_loop(begin, end, more, 0)
+
+                return carry
+
+            # (seldom: a run is as long as its bucket's share lets a run
+            # touch one window or two)
+            @pl.when(functools.reduce(jnp.maximum, count) > always)
             def _():
-                col = lax.broadcasted_iota(jnp.int32, (ch, blk), 0) + lo
-                oh = (t == col).astype(jnp.float32).astype(jnp.bfloat16)
-                d = lax.dot_general(
-                    planes, oh, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
-                def plane(gi, k):       # byte plane k of group gi, in place
-                    at = (4 * gi + k) * _GROUP
-                    return d[at:at + _GROUP] << (8 * k)
+                lax.fori_loop(0, nsub, rest, 0)
 
-                words = jnp.concatenate(
-                    [plane(gi, 0) | plane(gi, 1) | plane(gi, 2) | plane(gi, 3)
-                     for gi in range(G)], axis=0)               # [R, ch]
-                at = lo % blk
-                ring_ref[(ob + lo // blk) % 2, :, at:at + ch] += words
-
-        count_ref[0] = base + c
-        filled = (base + c) // blk      # ob or ob + 1
-
-        @pl.when((filled > ob) & (ob < nb_out))
+        @pl.when(hi_b > lo_b)
         def _():
-            flush(ob).start()
-            count_ref[1] = 1
+            # the output blocks that START in this step's rows take the
+            # ring slots of blocks that left a step or more ago
+            for j in range(own_max):
+                k = (lo_b + fb - 1) // fb + j
+
+                @pl.when((k * fb < hi_b) & started(k - n_ring))
+                def _():
+                    flush(k - n_ring).wait()
+
+            # the windows that START in this step's rows: the runs add
+            # into them in any order
+            def clear(w, carry):
+                ring_ref[(w // wpb) % n_ring, :, pl.ds(
+                    pl.multiple_of(w % wpb * _WIN, _WIN), _WIN)] = jnp.zeros(
+                        (R, _WIN), jnp.int32)
+                return carry
+
+            lax.fori_loop((lo_b + _WIN - 1) // _WIN,
+                          (hi_b + _WIN - 1) // _WIN, clear, 0)
+
+            need = [sum([(count[s] > q).astype(jnp.int32)
+                         for s in range(nsub)]) for q in range(2)]
+            pl.when(2 * need[1] >= nsub)(functools.partial(runs, 2))
+            pl.when((2 * need[1] < nsub) & (2 * need[0] >= nsub))(
+                functools.partial(runs, 1))
+            pl.when(2 * need[0] < nsub)(functools.partial(runs, 0))
+
+            for j in range(own_max):    # the blocks this step filled
+                k = lo_b // fb + j
+
+                @pl.when((k < hi_b // fb) & started(k))
+                def _():
+                    flush(k).start()
 
         @pl.when(step == nb - 1)
         def _():
-            @pl.when(count_ref[1] == 1)
-            def _():
-                flush(ob).wait()
+            total = cum_ref[nb * nsub]
+            full = total // fb
+            for j in range(n_ring):     # the DMAs nothing has awaited
+                k = full - 1 - j
 
-            @pl.when((base + c > filled * blk) & (filled < nb_out))
+                @pl.when(started(k) & ((k + n_ring) * fb >= total))
+                def _():
+                    flush(k).wait()
+
+            @pl.when((total > full * fb) & started(full))
             def _():
-                last = flush(filled)
-                last.start()
-                last.wait()
+                tail = flush(full)
+                tail.start()
+                tail.wait()
 
     def rows_block(r):
-        return pl.BlockSpec((r, blk), lambda i: (0, i))
+        return pl.BlockSpec((r, blk), lambda i, c: (0, i))
 
-    scratch = [pltpu.VMEM((R, blk), jnp.int32)]
-    if from_bytes:
-        scratch.append(pltpu.VMEM((f_rows, blk), jnp.float32))
-    scratch += [pltpu.VMEM((2, R, blk), jnp.int32),
-                pltpu.SMEM((2,), jnp.int32),
-                pltpu.SemaphoreType.DMA(())]
     return pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[rows_block(F)] + [rows_block(1)] * 4,
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((r_out, nb_out * blk), jnp.int32),
-        scratch_shapes=scratch,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb,),
+            in_specs=[rows_block(f_rows)] + [rows_block(1)] * 4,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((R, blk), jnp.int32),
+                            pltpu.VMEM((4 * R, blk), jnp.bfloat16),
+                            pltpu.VMEM((n_ring + 1, R, fb), jnp.int32),
+                            pltpu.SemaphoreType.DMA((n_ring,))]),
+        out_shape=jax.ShapeDtypeStruct((R, nb_out * fb), jnp.int32),
         interpret=interpret,
-    )(src, key[None, :],
-      lax.bitcast_convert_type(grad, jnp.int32)[None, :],
-      lax.bitcast_convert_type(hess, jnp.int32)[None, :],
+    )(cum, src, t, grad[None, :], hess[None, :],
       jnp.asarray(leaf_of_row, jnp.int32)[None, :])
 
 

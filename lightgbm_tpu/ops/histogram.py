@@ -385,12 +385,14 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
     The TPU reformulation of the reference's O(smaller-child) histogram cost
     (serial_tree_learner.cpp:364-378 iterates only the leaf's data indices):
     a pass does work in proportion to the rows it selects.  When the rows
-    belonging to ``leaves`` fit a bucket of the row ladder, one streaming
-    pass over the resident lane-dense bins (``compact_payload_pallas``)
-    moves them, in row order, into an i32 WORD payload with the compacted
-    positions on the lanes (4 bin bytes per word + grad/hess/leaf words)
-    and the payload kernel runs on the bucket, its grid stopping at the
-    count; otherwise one full masked pass
+    belonging to ``leaves`` fit a bucket of the row ladder, their output
+    columns are ranked from the keys alone (``compaction_ranks``: XLA, a
+    few operations on ``[n]`` words), one streaming pass over the resident
+    lane-dense bins (``compact_payload_pallas``) moves them, in row
+    order, into an i32 WORD payload with the compacted positions on the
+    lanes (4 bin bytes per word + grad/hess/leaf words) and the payload
+    kernel runs on the bucket, its grid stopping at the count; otherwise
+    one full masked pass
     (``histogram_for_leaves_masked``).  Exact: the same rows contribute
     either way.  Off the TPU the compacted bucket is a sort of the keys and
     a row gather of the row-major payload, the reference the kernels are
@@ -480,9 +482,10 @@ def histogram_for_leaves_auto(bins_rows: jax.Array, bins_t: jax.Array,
             from .hist_pallas import (compact_payload_pallas,
                                       histogram_payload_pallas)
             interp = not use_pallas()
-            # the compaction is a second pallas_call of the pass: it
-            # stays OUTSIDE hist_rows_<S>, so that a trace counts the
-            # pass once and its time goes to hist_compact
+            # the compaction (the ranks of the selected rows, XLA on the
+            # keys, then a second pallas_call of the pass) stays OUTSIDE
+            # hist_rows_<S>, so that a trace counts the pass once and
+            # all of its time goes to hist_compact
             with jax.named_scope("hist_compact"):
                 pc = compact_payload_pallas(
                     bins_t if bins_words_t is None else bins_words_t,

@@ -150,9 +150,17 @@ def test_payload_histogram_kernel_compiles(one_chip, source):
     moved = [line for line in text.splitlines()
              if " copy(" in line and str(S) in line]
     assert not moved, moved
-    # the program's temporaries: the payload, [24, S] i32, and one [n]
-    # word vector that XLA prefetches for the kernel
-    assert c.memory_analysis().temp_size_in_bytes <= 24 * S * 4 + 5 * n
+    # the program's temporaries: the payload, [24, S] i32; what precedes
+    # the kernel (``compaction_ranks``: the selected rows' output columns,
+    # i32 [n], and the [n/128, 128] product they are cut from); and one
+    # [n] word vector that XLA prefetches for the kernel
+    assert c.memory_analysis().temp_size_in_bytes <= 24 * S * 4 + 13 * n
+    # the counts before every run of rows reach the kernel by scalar
+    # prefetch: one a run and the total
+    call = [line for line in text.splitlines()
+            if "%compact_payload_pallas" in line.split("=")[0]]
+    # (the n/4 bucket: runs of 512 rows, eight to a step)
+    assert len(call) == 1 and f"s32[{-(-n // 4096) * 8 + 1}]" in call[0]
 
 
 @pytest.mark.parametrize("n_bins,mirror", [(256, False), (64, True)],
@@ -190,6 +198,16 @@ def test_compacted_branches_hold_no_gather_and_no_relayout(one_chip, on_tpu,
         assert "%histogram_payload_pallas" in readers[0].split("=")[0]
         assert f"hist_rows_{S}/hist_kernel" in readers[0]
         assert "hist_rows_" not in compact[0] and "hist_compact" in compact[0]
+        # the pass is counted by its ONE kernel under hist_rows_<S>
+        assert sum("tpu_custom_call" in line and f"hist_rows_{S}/" in line
+                   for line in text.splitlines()) == 1
+    # what precedes the compaction kernel (the ranks, on the keys) is the
+    # compaction's own time: under hist_compact, in no hist_rows_ scope
+    ranks = [line for line in text.splitlines()
+             if "jit(compaction_ranks)" in line]
+    assert any("convolution" in line for line in ranks)
+    assert all("hist_compact/jit(compact_payload_pallas)" in line
+               and "hist_rows_" not in line for line in ranks)
     assert " gather(" not in text and " sort(" not in text
     assert ",20]" not in text
 

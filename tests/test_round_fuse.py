@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import lightgbm_tpu.ops.histogram as H
 import lightgbm_tpu.ops.round_fuse as RF
 from lightgbm_tpu.ops.hist_pallas import (compact_payload_pallas,
+                                          compaction_ranks,
                                           histogram_payload_pallas)
 from lightgbm_tpu.ops.split import SplitHyper
 from lightgbm_tpu.learner import grower
@@ -99,13 +100,16 @@ def test_payload_kernel_stops_at_the_count(cnt, hist_dtype):
 
 # ------------------------------------------------- the compaction kernel
 ROWS = 2048 + 994       # the remainder 13,281,250 leaves in a 1024-row block
-SIZE = 1024             # the bucket: one 512-column block short of ROWS / 2
+SIZE = 1024             # the bucket: two 512-column output blocks
+BLOCK = 512             # rows a grid step takes: two runs of 256
 
 
 def _selection(name, n, size, rng):
     sel = np.zeros(n, bool)
     if name.startswith("cnt"):
-        cnt = {"cnt0": 0, "cnt1": 1, "cntS-1": size - 1, "cntS": size}[name]
+        cnt = {"cnt0": 0, "cnt1": 1, "cntS-1": size - 1, "cntS": size,
+               # exactly one output block; a window's edge inside a block
+               "cnt_block": BLOCK, "cnt_window": BLOCK + 128}[name]
         sel[rng.choice(n, cnt, replace=False)] = True
     elif name.startswith("density"):
         sel = rng.random(n) < 1 / int(name[len("density"):])
@@ -115,6 +119,17 @@ def _selection(name, n, size, rng):
         sel[n - size:] = True
     elif name == "one_block":       # every row of the second 512-row block
         sel[512:1024] = True
+    elif name == "gaps":            # full blocks, empty blocks between them
+        sel[0:BLOCK] = True
+        sel[3 * BLOCK:3 * BLOCK + 300] = True
+        sel[n - 40:] = True
+    elif name == "window_ends":     # runs that end exactly on column 128 k
+        sel[0:128] = True
+        sel[256:512] = True
+        sel[BLOCK:BLOCK + 128] = True
+        sel[4 * BLOCK + 7:4 * BLOCK + 7 + 128] = True
+    elif name == "excess":          # more rows than columns: the rest drops
+        sel = rng.random(n) < 0.6
     return sel
 
 
@@ -124,59 +139,93 @@ def _payload_t(words, grad, hess, lor):
         np.asarray(hess).view(np.int32)[None], np.asarray(lor)[None]])
 
 
+#: name -> (rows, features, bucket, rows a grid step takes)
+SHAPES = {
+    "remainder": (ROWS, 10, SIZE, BLOCK),      # 10 % 4 != 0: a padded word
+    "below_one_block": (300, 10, 256, BLOCK),
+    "blocks_plus_1": (3 * BLOCK + 1, 9, SIZE, BLOCK),
+    # the steps the program runs, eight runs each but the last: runs of
+    # 256 rows (a bucket over n/3), 512 (over n/6) and 1024 (four runs)
+    "default_step": (3 * 2048 + 994, 12, 4096, 4096),
+    "runs_of_512": (3 * 2048 + 994, 12, 2048, 4096),
+    "runs_of_1024": (3 * 2048 + 994, 12, 1024, 4096),
+    # F > 116: a second byte-plane group, u8 rows past one 128-row tile
+    "two_groups": (700, 130, 256, 256),
+}
+CASES = [("remainder", place) for place in (
+    "cnt0", "cnt1", "cntS-1", "cntS", "density8", "density4", "first",
+    "last", "one_block", "cnt_block", "cnt_window", "gaps", "window_ends",
+    "excess")]
+CASES += [("below_one_block", "density4"), ("below_one_block", "first"),
+          ("blocks_plus_1", "last"), ("blocks_plus_1", "density4"),
+          ("default_step", "density4"), ("default_step", "density2"),
+          ("default_step", "gaps"), ("default_step", "first"),
+          ("runs_of_512", "density4"), ("runs_of_512", "gaps"),
+          ("runs_of_512", "first"), ("runs_of_1024", "density8"),
+          ("runs_of_1024", "gaps"), ("runs_of_1024", "first"),
+          ("two_groups", "density4"), ("two_groups", "first")]
+
+
 @pytest.mark.parametrize("source", ["bins_t", "words_t"])
-@pytest.mark.parametrize("place", ["cnt0", "cnt1", "cntS-1", "cntS",
-                                   "density8", "density4", "first", "last",
-                                   "one_block"])
-def test_compaction_kernel_matches_sorted_gather(place, source):
+@pytest.mark.parametrize("shape,place", CASES,
+                         ids=[f"{s}-{p}" if s != "remainder" else p
+                              for s, p in CASES])
+def test_compaction_kernel_matches_sorted_gather(shape, place, source):
     """The first ``cnt`` columns are bit for bit the row-major payload's
-    rows at the sorted keys, for any placement of the selected rows; the
-    float operands move as their bits (no rounding, NaN included)."""
+    rows at the sorted keys, for any placement of the selected rows (runs
+    of empty blocks, counts on an output block's and a window's edge, n
+    below a block and one past a block, more rows than the bucket has
+    columns); the float operands move as their bits (no rounding, NaN
+    included)."""
     rng = np.random.default_rng(7)
-    n, f = ROWS, 10                       # 10 % 4 != 0: a padded word
+    n, f, size, block = SHAPES[shape]
     bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
     grad = rng.normal(size=n).astype(np.float32)
     hess = rng.integers(-2 ** 31, 2 ** 31, size=n).astype(np.int32).view(
         np.float32)                       # any bit pattern
     lor = rng.integers(-1, 255, size=n).astype(np.int32)
-    sel = _selection(place, n, SIZE, rng)
-    cnt = int(sel.sum())
-    assert cnt <= SIZE
+    sel = _selection(place, n, size, rng)
+    cnt = min(int(sel.sum()), size)
+    assert (sel.sum() > size) == (place == "excess")
     rows = np.arange(n, dtype=np.int32)
     key = np.where(sel, rows, rows | (1 << 30)).astype(np.int32)
     words = H.bins_to_words(jnp.asarray(bins))
     src = jnp.asarray(bins.T) if source == "bins_t" else words.T
     got = np.asarray(compact_payload_pallas(
         src, jnp.asarray(key), jnp.asarray(grad), jnp.asarray(hess),
-        jnp.asarray(lor), size=SIZE, rows_per_block=512, lanes_per_dot=128,
-        interpret=True))
+        jnp.asarray(lor), size=size, rows_per_block=block, interpret=True))
     w = words.shape[1]
-    assert got.shape == (8 * -(-(w + 3) // 8), SIZE)
+    assert got.shape[0] == 8 * -(-(w + 3) // 8) and got.shape[1] >= size
     want = _payload_t(words, grad, hess, lor)[
         :, np.sort(key)[:cnt] & ((1 << 30) - 1)]
     np.testing.assert_array_equal(got[:w + 3, :cnt], want)
 
 
-def test_compaction_kernel_past_one_group_of_rows():
-    """More than 32 payload rows (F > 116): a second byte-plane group,
-    and a u8 source whose features span two 128-feature permutations."""
-    rng = np.random.default_rng(8)
-    n, f = 700, 130
-    bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
-    grad = rng.normal(size=n).astype(np.float32)
-    hess = rng.normal(size=n).astype(np.float32)
-    lor = rng.integers(0, 9, size=n).astype(np.int32)
+@pytest.mark.parametrize("n,block,run", [
+    (ROWS, BLOCK, 256), (300, BLOCK, 256), (3 * BLOCK + 1, BLOCK, 256),
+    (128, 128, 128), (40_000, 2048, 256)],
+    ids=["remainder", "below_one_block", "blocks_plus_1", "one_tile",
+         "default_step"])
+def test_compaction_ranks_match_numpy(n, block, run):
+    """What precedes the kernel, on the keys alone: each selected row's
+    output column (its rank in row order), -1 for the others and for the
+    pad up to a whole block, and the count before every run of rows."""
+    rng = np.random.default_rng(n)
     sel = rng.random(n) < 0.3
+    sel[n // 2:n // 2 + 2 * run] = True       # runs with every row
+    sel[n // 4:n // 4 + 2 * run] = False      # and with none
     rows = np.arange(n, dtype=np.int32)
     key = np.where(sel, rows, rows | (1 << 30)).astype(np.int32)
-    words = H.bins_to_words(jnp.asarray(bins))
-    want = _payload_t(words, grad, hess, lor)[:, rows[sel]]
-    for src in (jnp.asarray(bins.T), words.T):
-        got = np.asarray(compact_payload_pallas(
-            src, jnp.asarray(key), jnp.asarray(grad), jnp.asarray(hess),
-            jnp.asarray(lor), size=256, rows_per_block=256,
-            interpret=True))
-        np.testing.assert_array_equal(got[:want.shape[0], :sel.sum()], want)
+    t, cum = compaction_ranks(jnp.asarray(key), rows_per_block=block,
+                              rows_per_dot=run)
+    n_pad = -(-n // block) * block
+    sel_pad = np.zeros(n_pad, bool)
+    sel_pad[:n] = sel
+    before = np.cumsum(sel_pad) - sel_pad
+    np.testing.assert_array_equal(
+        np.asarray(t), np.where(sel_pad, before, -1)[None])
+    np.testing.assert_array_equal(
+        np.asarray(cum), np.append(before[::run], sel.sum()))
 
 
 @pytest.mark.parametrize("hist_dtype", ["int8", "float32"])

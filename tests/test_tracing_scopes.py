@@ -170,22 +170,32 @@ def test_a_compacted_pass_is_two_kernels_and_one_counted_pass(
         lowered_paths_with_the_payload_kernels):
     """``benchmark/harness/scoped.py`` counts a pass as one operation
     whose path ends in ``pallas_call`` under a ``hist_rows_*`` scope, and
-    gives time to the innermost scope: the compaction kernel sits under
-    ``hist_compact`` and under NO ``hist_rows_`` part, the payload kernel
-    under exactly one."""
+    gives time to the innermost scope.  A compacted pass is the ranks of
+    its selected rows (``compaction_ranks``: XLA operations on the keys),
+    the compaction kernel and the payload kernel: the first two sit under
+    ``hist_compact`` and under NO ``hist_rows_`` part, so the pass counts
+    once and ``hist_compact_ms`` holds all of the compaction's time; the
+    payload kernel sits under exactly one."""
     paths = lowered_paths_with_the_payload_kernels
     # (every operation of the jitted kernel function: the interpreter
     # unrolls the ``pallas_call`` that ends the path on the chip)
     compact = [parts for parts in paths
                if "jit(compact_payload_pallas)" in parts]
+    ranks = [parts for parts in paths if "jit(compaction_ranks)" in parts]
     payload = [parts for parts in paths
                if "jit(histogram_payload_pallas)" in parts]
-    assert compact and payload
+    assert compact and ranks and payload
     for parts in compact:
         assert _nested(parts, ["round_hist", "hist_compact",
                                "jit(compact_payload_pallas)"])
         assert not any(p.startswith("hist_rows_") for p in parts)
         assert "hist_kernel" not in parts
+    # what precedes the kernel is part of the compaction, not a pass
+    for parts in ranks:
+        assert _nested(parts, ["round_hist", "hist_compact",
+                               "jit(compact_payload_pallas)",
+                               "jit(compaction_ranks)"])
+    assert any(parts[-1].startswith("dot_general") for parts in ranks)
     for parts in payload:
         assert _nested(parts, ["round_hist", "hist_rows_2048", "hist_kernel",
                                "jit(histogram_payload_pallas)"])
