@@ -1,0 +1,9 @@
+"""``find_splits_ms`` in a ranking job (the cell ``istella-rank-train``):
+the split search. The reader is ``layers/find_splits_ms.py``'s, which
+says what is read and from where; an accepted metric's list of cells is
+not a new cell's to extend, so the cell reports it under a name of its
+own."""
+
+from harness import load_module
+
+read = load_module("layers", "find_splits_ms").read

@@ -1,0 +1,9 @@
+"""``hist_compact_ms`` in a ranking job (the cell ``istella-rank-train``):
+the compaction of the selected rows, the root pass included. The reader
+is ``layers/hist_compact_ms.py``'s, which says what is read and from
+where; an accepted metric's list of cells is not a new cell's to extend,
+so the cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "hist_compact_ms").read

@@ -1,0 +1,10 @@
+"""``tree_root_ms`` in a ranking job (the cell ``istella-rank-train``):
+what a tree does before its round loop (the root's sums and split search
+over 220 columns), without the root histogram's kernel. The reader is
+``layers/tree_root_ms.py``'s, which says what is read and from where; an
+accepted metric's list of cells is not a new cell's to extend, so the
+cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "tree_root_ms").read

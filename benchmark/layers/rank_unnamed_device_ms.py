@@ -1,0 +1,10 @@
+"""``unnamed_device_ms`` in a ranking job (the cell ``istella-rank-
+train``): device time under no named scope at all (the ranking scopes
+lie inside ``gradients`` and ``valid_metric``, which the reader knows).
+The reader is ``layers/unnamed_device_ms.py``'s, which says what is read
+and from where; an accepted metric's list of cells is not a new cell's
+to extend, so the cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "unnamed_device_ms").read

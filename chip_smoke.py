@@ -112,7 +112,8 @@ def _fused_program_text(gb) -> str:
         _aval(gb.scores), _aval(gb.bins),
         None if gb.bins_words is None else _aval(gb.bins_words),
         S((T, 2), jnp.uint32), S((T, k, 2), jnp.uint32), None,
-        S((T,), jnp.int32), tuple(_aval(v) for v in gb.valid_scores), ())
+        S((T,), jnp.int32), tuple(_aval(v) for v in gb.valid_scores), (),
+        jax.tree.map(_aval, gb._fused_operands()))
     return lowered.compile().as_text()
 
 
@@ -376,6 +377,83 @@ def phase_bundled(args, lgb):
           smoke_train_s=round(secs, 2))
 
 
+RANK_SCOPES = ("rank_gather", "rank_sort", "rank_pairs", "rank_accumulate",
+               "ndcg_sort")
+
+
+def _ranking_docs(rows: int, seed: int):
+    """Query-grouped docs: skewed query lengths (8 to 1,000 docs), eight
+    features, relevance 0-4 (most docs 0) from a latent relevance over
+    three of the features and a per-query offset."""
+    rng = np.random.default_rng([seed, 37])
+    sizes = []
+    while sum(sizes) < rows:
+        sizes.append(int(np.clip(rng.lognormal(4.6, 0.8), 8, 1000)))
+    sizes[-1] -= sum(sizes) - rows
+    sizes = np.asarray([s for s in sizes if s > 0], np.int64)
+    x = rng.normal(size=(rows, 8)).astype(np.float32)
+    z = 0.8 * x[:, 0] - 0.5 * x[:, 1] + 0.3 * x[:, 2] * x[:, 3] \
+        + 0.4 * np.repeat(rng.normal(size=len(sizes)), sizes) \
+        + 0.6 * rng.normal(size=rows)
+    y = np.searchsorted([1.4, 1.9, 2.3, 2.8], z).astype(np.float32)
+    return x, y, sizes
+
+
+def phase_ranking(args, lgb):
+    """A short lambdarank job in the fused scan: pairwise gradients per
+    query-length bucket, NDCG@1,3,5,10 of a held-out query set on the
+    device every round, held against the host's ``NDCGMetric.eval`` of the
+    final scores; the ranking scopes required by name in the compiled
+    round program (the benchmark's ``rank_*`` readers read them)."""
+    import jax
+
+    t0 = time.time()
+    rows, held = args.rows, args.valid_rows
+    x, y, sizes = _ranking_docs(rows, args.seed)
+    xv, yv, sizes_v = _ranking_docs(held, args.seed + 1)
+    ks = [1, 3, 5, 10]
+    params = {**PARAMS, "objective": "lambdarank", "metric": "ndcg",
+              "eval_at": ks, "min_sum_hessian_in_leaf": 1e-3}
+    ds = lgb.Dataset(x, label=y, group=sizes, params=params).construct()
+    dv = ds.create_valid(xv, label=yv, group=sizes_v)
+    evals = {}
+    t1 = time.time()
+    bst = lgb.train(params, ds, num_boost_round=args.iters, valid_sets=[dv],
+                    callbacks=[lgb.record_evaluation(evals)])
+    secs = time.time() - t1
+    gb = bst._gbdt
+    _require(_took_fused_path(bst, args.iters), "fused path not taken")
+    _require(gb.metrics.counter("rank_queries") == len(sizes)
+             and gb.metrics.counter("rank_docs") == rows,
+             "the program did not count the job's queries and docs")
+    buckets = int(gb.metrics.gauge("rank_bucket_count"))
+    _require(buckets >= 4, f"{buckets} query-length buckets")
+    recorded = np.array([evals["valid_0"][f"ndcg@{k}"] for k in ks])
+    _require(recorded.shape == (len(ks), args.iters),
+             f"NDCG recorded as {recorded.shape}")
+    want = [v for _, v in gb.valid_metrics[0][0].eval(
+        np.asarray(gb.valid_scores[0][:, 0], np.float64))]
+    gap = float(np.abs(recorded[:, -1] - want).max())
+    _require(gap < 1e-5, f"device NDCG {recorded[:, -1]} against the host's "
+                         f"{want}")
+    _require(recorded[-1, -1] > recorded[-1, 0] + 0.01,
+             f"held-out NDCG@10 did not climb: {recorded[-1]}")
+    calls = None
+    if jax.devices()[0].platform == "tpu":
+        text = _fused_program_text(gb)
+        calls = text.count("tpu_custom_call")
+        missing = [s for s in RANK_SCOPES if s not in text]
+        _require(not missing, f"no operation under the scopes {missing} in "
+                              "the compiled round program")
+        _require_compaction_kernel(text, "the ranking round program")
+    _emit("ranking", t0, rows=rows, queries=len(sizes), buckets=buckets,
+          iters=args.iters, tpu_custom_calls=calls,
+          rank_slot_rows=int(gb.metrics.counter("rank_slot_rows")),
+          valid_ndcg10_first=float(recorded[-1, 0]),
+          valid_ndcg10_last=float(recorded[-1, -1]),
+          device_against_host_ndcg=gap, smoke_train_s=round(secs, 2))
+
+
 def phase_predict(args, bst, X):
     import jax
     from lightgbm_tpu.boosting.gbdt import GBDT
@@ -540,6 +618,7 @@ def main(argv=None) -> int:
     else:
         bst = phase_train(args, lgb, data)
         phase_bundled(args, lgb)
+        phase_ranking(args, lgb)
         phase_predict(args, bst, data[0])
         phase_save_load(lgb, bst, data[0])
         phase_serve(bst, data[0])

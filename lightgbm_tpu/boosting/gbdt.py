@@ -51,6 +51,11 @@ from ..ops.table import take_small_table
 
 GradFn = Callable[[np.ndarray, Any], Tuple[np.ndarray, np.ndarray]]
 
+#: a valid set's transposed bins larger than this ride the fused round
+#: program as an argument; smaller ones stay literals of its HLO, which
+#: is how every job before the ranking cell's 688 MB compiled them
+_LITERAL_MAX_BYTES = 256 << 20
+
 
 def _resolve_hist_dtype(cfg: Config) -> str:
     """Histogram contraction dtype with validity gating.
@@ -1293,6 +1298,21 @@ class GBDT:
                     return False
         return True
 
+    def _fused_operands(self):
+        """What the fused round program takes as ARGUMENTS besides the
+        scores and the training bins: ``(objective's, [per valid set
+        [per metric]], [per valid set the transposed bins or None])``.
+        Whatever is None here the program closes over, and a closed-over
+        array is a literal of its HLO: serialized with it, hashed for the
+        cache key, stored in the cache entry.  The objective and the
+        metrics say what of theirs is large (a ranking job's slot
+        matrices); a valid set's bins go in from ``_LITERAL_MAX_BYTES``."""
+        bins_t = [b if b is not None and b.nbytes > _LITERAL_MAX_BYTES
+                  else None for b in self._valid_bins_t]
+        return (self.objective.fused_operands(),
+                [[m.fused_operands() for m in ms]
+                 for ms in self.valid_metrics], bins_t)
+
     def _sampling_is_noop(self) -> bool:
         """No per-iteration row sampling: the default
         BaggingSampleStrategy no-ops unless bagging is actually
@@ -1405,21 +1425,38 @@ class GBDT:
             dev_sample = self._device_sample_fn() \
                 if not self._sampling_is_noop() else None
 
-            def eval_valid_traced(vsc):
+            def eval_valid_traced(vsc, metric_ops):
                 parts = []
                 for vi, ms in enumerate(self.valid_metrics):
                     # single-output metrics see the [n] column, multi-
                     # output metrics the full [n, k] matrix (round 6)
                     sc = vsc[vi][:, 0] if k == 1 else vsc[vi]
-                    for m in ms:
+                    for m, ops in zip(ms, metric_ops[vi]):
+                        extra = () if ops is None else (ops,)
                         parts.append(jnp.asarray(
-                            m.eval_device_traced(sc, self.objective),
+                            m.eval_device_traced(sc, self.objective, *extra),
                             jnp.float32))
                 return jnp.concatenate(parts) if parts else \
                     jnp.zeros((0,), jnp.float32)
 
             def run(scores, bins, bwords, qkeys, nkeys, fmasks, iters,
-                    vscores, es0):
+                    vscores, es0, operands):
+                objective_ops, metric_ops, valid_bins_t = operands
+                grad_extra = () if objective_ops is None \
+                    else (objective_ops,)
+
+                def valid_tree_scores(arrays_s, vi):
+                    # a valid set whose transposed bins came in as an
+                    # argument is scored from them (``_fused_operands``
+                    # hands over only what ``_valid_tree_scores`` would
+                    # take the matmul path with)
+                    if valid_bins_t[vi] is None:
+                        return self._valid_tree_scores(arrays_s, vi)
+                    from ..models.predict import predict_bins_tree_matmul
+                    return predict_bins_tree_matmul(
+                        arrays_s, valid_bins_t[vi], self.nan_bin_arr,
+                        self.bundle, n_bins=self.hp.n_bins)
+
                 def round_real(carry, qkey_raw, node_keys, fm, it):
                     sc, vsc, es = carry
                     # sc: [n, k].  One gradient evaluation per round,
@@ -1427,10 +1464,12 @@ class GBDT:
                     # classic loop's class order) — all in this jit.
                     with jax.named_scope("gradients"):
                         if k == 1:
-                            g2, h2 = self.objective.get_gradients(sc[:, 0])
+                            g2, h2 = self.objective.get_gradients(
+                                sc[:, 0], *grad_extra)
                             g2, h2 = g2[:, None], h2[:, None]
                         else:
-                            g2, h2 = self.objective.get_gradients(sc)
+                            g2, h2 = self.objective.get_gradients(
+                                sc, *grad_extra)
                         if dev_sample is not None:
                             # in-jit bagging/GOSS draw — same key
                             # derivation as the classic loop
@@ -1499,8 +1538,7 @@ class GBDT:
                                     leaf_value=shrunk)
                                 vsc_c = tuple(
                                     v.at[:, cls].add(
-                                        self._valid_tree_scores(
-                                            arrays_s, vi))
+                                        valid_tree_scores(arrays_s, vi))
                                     for vi, v in enumerate(vsc_c))
                         return (sc_c, vsc_c), arrays
 
@@ -1509,7 +1547,8 @@ class GBDT:
                         (g2.T, h2.T, node_keys,
                          lax.iota(jnp.int32, k)))        # [k, ...] ys
                     with jax.named_scope("valid_metric"):
-                        mvals = eval_valid_traced(vsc) if nvalid else \
+                        mvals = eval_valid_traced(vsc, metric_ops) \
+                            if nvalid else \
                             jnp.zeros((0,), jnp.float32)
                         if use_es:
                             best, best_it, seen, stopped = es
@@ -1590,6 +1629,7 @@ class GBDT:
         n_rows = int(self.train_set.num_data)
         count_rows = self.parallel_mode is None and not \
             0 < self.hp.hist_pool_slots < self.hp.num_leaves
+        operands = self._fused_operands()
         while done < num_rounds and not finished:
             T = min(chunk, num_rounds - done)
             with self._phase("fused_prepare"):
@@ -1614,7 +1654,7 @@ class GBDT:
                     cc_key = ("train_fused", key, k, self._config_signature(),
                               fsig,
                               cc_sig((self.scores, self.bins, self.bins_words,
-                                      tuple(self.valid_scores))))
+                                      tuple(self.valid_scores), operands)))
                     built = []
 
                     def _build():
@@ -1664,7 +1704,7 @@ class GBDT:
                     self._fused_cache[key](
                         self.scores, self.bins, self.bins_words, qkeys,
                         nkeys, fmasks, iters,
-                        tuple(self.valid_scores), es_host)
+                        tuple(self.valid_scores), es_host, operands)
             self.scores = scores
             for vi in range(nvalid):
                 self.valid_scores[vi] = vscores[vi]

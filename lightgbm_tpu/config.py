@@ -249,7 +249,7 @@ _PARAMS: List[Tuple[str, Any, Tuple[str, ...], Tuple[Tuple[str, float], ...]]] =
     ("lambdarank_norm", True, (), ()),
     ("label_gain", [], (), ()),
     ("lambdarank_position_bias_regularization", 0.0, (), ((">=", 0.0),)),
-    ("rank_query_buckets", "auto", (), ()),  # query-length bucket ladder for the device lambdarank/xendcg kernels (objectives.py): "auto" derives power-of-two buckets from the training query-length distribution; an explicit list (e.g. "16,64,256") pins the ladder (extended to cover the longest query); each bucket geometry lowers ONE pairwise program through ops/compile_cache.py (rank_compile_hits/misses), so padded-pair compute is sum_b nq_b*T*Q_b instead of nq*T*Qmax; LGBMTPU_NO_RANK_BUCKETS=1 is the pad-to-max A/B hatch
+    ("rank_query_buckets", "auto", (), ()),  # query-length bucket ladder for the device lambdarank/xendcg kernels (objectives.py): "auto" derives power-of-two buckets from the training query-length distribution; an explicit list (e.g. "16,64,256") pins the ladder (extended to cover the longest query); each bucket geometry lowers ONE pairwise program through ops/compile_cache.py (rank_compile_hits/misses), so padded-pair compute is sum_b nq_b*T*Q_b instead of nq*T*Qmax; a one-entry list at the longest query's length states the pad-to-max layout
     # --- metric ---
     ("metric", [], ("metrics", "metric_types"), ()),
     ("metric_freq", 1, ("output_freq",), ((">", 0),)),

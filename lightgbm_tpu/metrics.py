@@ -99,17 +99,17 @@ def _dev_ndcg_sums(ks: tuple):
     query count — the bucketed twin of the old pad-to-max mean kernel, so
     the fused-eval path pays sum_b nq_b*Q_b sort work instead of
     nq*qmax.  jit's shape-keyed trace cache gives one lowering per bucket
-    geometry, warm across iterations."""
+    geometry, warm across iterations.  ``gain_slot`` is the docs' gains
+    by padded slot (pads 0), laid out once at set-up: it rides the sort
+    as an operand, so only the score is gathered."""
     import jax
     import jax.numpy as jnp
 
-    def run(score, qidx, gain_doc, idcgs, disc):
-        valid = qidx >= 0
-        safe = jnp.maximum(qidx, 0)
-        sc = jnp.where(valid, score[safe], -jnp.inf)
-        order = jnp.argsort(-sc, axis=1, stable=True)
-        g = jnp.where(valid, gain_doc[safe], 0.0)
-        g_srt = jnp.take_along_axis(g, order, axis=1)
+    def run(score, qidx, gain_slot, idcgs, disc):
+        with jax.named_scope("ndcg_sort"):
+            sc = jnp.where(qidx >= 0, score[jnp.maximum(qidx, 0)], -jnp.inf)
+            _, g_srt = jax.lax.sort((-sc, gain_slot), dimension=1,
+                                    num_keys=1, is_stable=True)
         out = []
         for i, k in enumerate(ks):
             kk = min(k, sc.shape[1])
@@ -161,6 +161,14 @@ class Metric:
         host = np.asarray(vals)
         return [(name, float(host[i]))
                 for i, name in enumerate(self.display_names())]
+
+    def fused_operands(self):
+        """Device arrays ``eval_device_traced`` reads besides the score
+        that the fused round program should take as ARGUMENTS (a pytree,
+        handed back as its ``operands``), or None where the program may
+        close over what the metric holds (objectives.py
+        ``ObjectiveFunction.fused_operands`` says why)."""
+        return None
 
     def eval_device_traced(self, score_dev, objective=None):
         """Traceable device evaluation: a f32 [len(display_names())] array
@@ -512,6 +520,7 @@ class NDCGMetric(Metric):
                                            for i in range(max(mx, 31))]
         self.label_gain = np.asarray(gains, np.float64)
         self.ks = list(self.config.eval_at)
+        self.__dict__.pop("_rank_dev_buckets", None)
 
     def eval(self, score, objective=None):
         res = {k: [] for k in self.ks}
@@ -535,48 +544,46 @@ class NDCGMetric(Metric):
     def display_names(self):
         return [f"ndcg@{k}" for k in self.ks]
 
-    def _ndcg_from_buckets(self, score_dev, dev_buckets, gain_dev):
-        nq = len(self.bounds) - 1
-        total = None
-        for qidx_dev, idcg_dev, disc_dev in dev_buckets:
-            part = _dev_ndcg_sums(tuple(self.ks))(
-                score_dev, qidx_dev, gain_dev, idcg_dev, disc_dev)
-            total = part if total is None else total + part
-        return total / nq
+    def fused_operands(self):
+        return self._device_state()
 
-    def eval_device_traced(self, score_dev, objective=None):
-        import jax
-        import jax.numpy as jnp
+    def _device_state(self):
+        """The bucket plan's device arrays, built once (span
+        ``rank_bucket_plan``): per bucket the padded doc-index matrix,
+        the gains by slot, the ideal DCG at each cut-off of its queries
+        and the position discount."""
         if not hasattr(self, "_rank_dev_buckets"):
-            from .objectives import _rank_buckets
-            spec = getattr(self.config, "rank_query_buckets", "auto")
-            buckets, _ = _rank_buckets(np.asarray(self.bounds), spec)
-            gain_dev = jnp.asarray(
-                self.label_gain[self.label.astype(int)], jnp.float32)
-            idcgs = np.zeros((len(self.ks), len(self.bounds) - 1), np.float32)
-            for qi in range(len(self.bounds) - 1):
-                s, e = self.bounds[qi], self.bounds[qi + 1]
-                lbl = self.label[s:e]
-                ideal = np.argsort(-lbl, kind="mergesort")
-                for i, k in enumerate(self.ks):
-                    idcgs[i, qi] = _dcg_at_k(lbl, ideal, k, self.label_gain)
-            dev_buckets = []
-            for cap, qids, idx in buckets:
-                dev_buckets.append((
-                    jnp.asarray(idx),
-                    jnp.asarray(idcgs[:, qids]),
-                    jnp.asarray(1.0 / np.log2(np.arange(max(cap, 1)) + 2.0),
-                                jnp.float32)))
-            if isinstance(gain_dev, jax.core.Tracer) or (
-                    dev_buckets and isinstance(dev_buckets[0][0],
-                                               jax.core.Tracer)):
-                # abstract trace (see Metric._dev_arrays): use uncached
-                return self._ndcg_from_buckets(score_dev, dev_buckets,
-                                               gain_dev)
-            self._rank_dev_buckets = dev_buckets
-            self._gain_dev = gain_dev
-        return self._ndcg_from_buckets(score_dev, self._rank_dev_buckets,
-                                       self._gain_dev)
+            import jax.numpy as jnp
+            from .objectives import (_by_slot, _max_dcg_by_slot,
+                                     _rank_buckets)
+            from .utils.timer import global_timer, phase
+            with phase("rank_bucket_plan", global_timer):
+                spec = getattr(self.config, "rank_query_buckets", "auto")
+                buckets, _ = _rank_buckets(np.asarray(self.bounds), spec)
+                gain_of_doc = self.label_gain[self.label.astype(int)]
+                state = []
+                for cap, _, idx in buckets:
+                    gain_slot = _by_slot(gain_of_doc, idx, 0.0)
+                    state.append((
+                        jnp.asarray(idx),
+                        jnp.asarray(gain_slot, jnp.float32),
+                        jnp.asarray(_max_dcg_by_slot(gain_slot, self.ks),
+                                    jnp.float32),
+                        jnp.asarray(1.0 / np.log2(np.arange(cap) + 2.0),
+                                    jnp.float32)))
+                self._rank_dev_buckets = tuple(state)
+        return self._rank_dev_buckets
+
+    def eval_device_traced(self, score_dev, objective=None, operands=None):
+        """NDCG at every cut-off, on the device.  ``operands`` is
+        ``fused_operands()`` handed back by the fused round program,
+        which takes the plan as arguments and not as literals."""
+        state = self._device_state() if operands is None else operands
+        total = None
+        for bucket in state:
+            part = _dev_ndcg_sums(tuple(self.ks))(score_dev, *bucket)
+            total = part if total is None else total + part
+        return total / (len(self.bounds) - 1)
 
 
 class MapMetric(Metric):

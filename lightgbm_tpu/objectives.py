@@ -26,7 +26,6 @@ pass whose result is L scalars.
 
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Tuple
 
 import jax
@@ -35,9 +34,10 @@ import numpy as np
 
 from .config import Config
 from .io.dataset import Metadata
-from .obs.metrics import global_metrics
+from .obs.metrics import count_event, global_metrics
 from .ops import compile_cache as cc
 from .utils import log
+from .utils.timer import global_timer, phase
 
 
 def _weighted_percentile(values: np.ndarray, weights: Optional[np.ndarray],
@@ -121,6 +121,16 @@ class ObjectiveFunction:
         if not hasattr(self, "_grad_jit"):
             self._grad_jit = jax.jit(self.get_gradients)
         return self._grad_jit(score)
+
+    def fused_operands(self):
+        """Device arrays ``get_gradients`` reads besides the score that
+        the fused round program should take as ARGUMENTS (a pytree, handed
+        back as ``get_gradients``'s second argument), or None where the
+        program may close over whatever the objective holds.  A ranking
+        job's slot matrices are tens of millions of words: as literals
+        they would be serialized into the round program's HLO, hashed for
+        the cache key and stored in the cache entry."""
+        return None
 
     def place_rows(self, place) -> None:
         """Put the per-row device arrays the gradient program takes as
@@ -557,32 +567,17 @@ class CrossEntropyLambda(ObjectiveFunction):
 
 
 # ------------------------------------------------------------------ ranking
-def _pad_queries(boundaries: np.ndarray) -> Tuple[np.ndarray, np.ndarray, int]:
-    """[nq, Q] doc-index matrix (padded with -1) + per-query counts."""
-    sizes = np.diff(boundaries)
-    q = int(sizes.max()) if len(sizes) else 1
-    nq = len(sizes)
-    idx = np.full((nq, q), -1, dtype=np.int32)
-    for i in range(nq):
-        s, e = boundaries[i], boundaries[i + 1]
-        idx[i, :e - s] = np.arange(s, e, dtype=np.int32)
-    return idx, sizes.astype(np.int32), q
-
-
 def _rank_bucket_ladder(sizes: np.ndarray, spec) -> List[int]:
     """Query-length bucket caps, smallest to largest, covering every
     query.  ``spec`` is ``config.rank_query_buckets``: ``"auto"`` derives
     the next-power-of-two set of the observed lengths; an explicit list
-    is used as-is (extended with the max length when it falls short).
-    The ``LGBMTPU_NO_RANK_BUCKETS=1`` hatch collapses the ladder to one
-    pad-to-max bucket — the pre-bucketing geometry, kept as the A/B
-    baseline for the parity tests."""
+    is used as-is (extended with the max length when it falls short), so
+    ``[qmax]`` states the one pad-to-max bucket the parity tests compare
+    the ladder with."""
     qmax = int(sizes.max()) if len(sizes) else 1
-    if os.environ.get("LGBMTPU_NO_RANK_BUCKETS"):
-        return [qmax]
     if isinstance(spec, str):           # "auto"
-        return sorted({1 << max(int(s) - 1, 0).bit_length() for s in sizes}) \
-            or [qmax]
+        return sorted({1 << max(int(s) - 1, 0).bit_length()
+                       for s in np.unique(sizes)}) or [qmax]
     caps = sorted({int(b) for b in spec})
     if caps[-1] < qmax:
         caps.append(qmax)
@@ -597,7 +592,8 @@ def _rank_buckets(boundaries: np.ndarray, spec
     assigned to that cap (the smallest cap >= the query's length) and
     ``pad_rows`` counts the padding slots across all buckets — the
     quantity the pad-to-max layout inflates to ``nq*qmax - ndocs``."""
-    sizes = np.diff(np.asarray(boundaries)).astype(np.int64)
+    bounds = np.asarray(boundaries).astype(np.int64)
+    sizes = np.diff(bounds)
     caps = _rank_bucket_ladder(sizes, spec)
     assign = np.searchsorted(np.asarray(caps), sizes, side="left")
     out: List[Tuple[int, np.ndarray, np.ndarray]] = []
@@ -606,35 +602,70 @@ def _rank_buckets(boundaries: np.ndarray, spec
         qids = np.flatnonzero(assign == bi)
         if not len(qids):
             continue
-        idx = np.full((len(qids), cap), -1, np.int32)
-        for r, qi in enumerate(qids):
-            s, e = int(boundaries[qi]), int(boundaries[qi + 1])
-            idx[r, :e - s] = np.arange(s, e, dtype=np.int32)
+        col = np.arange(cap, dtype=np.int64)[None, :]
+        idx = np.where(col < sizes[qids, None], bounds[qids, None] + col,
+                       -1).astype(np.int32)
         pad_rows += int(len(qids) * cap - sizes[qids].sum())
         out.append((int(cap), qids.astype(np.int32), idx))
     return out, pad_rows
 
 
-def _lambdarank_pair_accum(score, label, gain_doc, qidx, inv_dcg,
-                           g_acc, h_acc, *, sigmoid: float, trunc: int,
-                           norm: bool):
-    """Pairwise |dNDCG| lambda gradients for ONE query-length bucket,
-    scattered onto the per-doc accumulators.  Pure and shape-static in
-    ``qidx`` ([nq_b, Q] padded with -1): the whole pair tensor is
-    [nq_b, T, Q] with T = min(trunc, Q), so a bucket of short queries
-    never pays the longest query's Q.  Each doc belongs to exactly one
-    bucket, so chaining buckets through (g_acc, h_acc) accumulates
-    exactly (the other buckets contribute +0.0 to its slot)."""
-    s = sigmoid
-    valid = qidx >= 0
-    safe = jnp.maximum(qidx, 0)
-    sc = jnp.where(valid, score[safe], -jnp.inf)      # [nq_b, Q]
-    gains = jnp.where(valid, gain_doc[safe], 0.0)
-    lbl = jnp.where(valid, label[safe], -1.0)
+def _by_slot(per_doc: np.ndarray, idx: np.ndarray, pad) -> np.ndarray:
+    """A per-doc vector laid out by padded slot (``idx`` [nq_b, cap], -1
+    pads): what never changes in a job is laid out once, on the host, and
+    not gathered through ``qidx`` every round."""
+    return np.where(idx >= 0, per_doc[np.maximum(idx, 0)], pad)
 
-    # rank of each doc by descending score (ties by index, like ref sort)
-    order = jnp.argsort(-sc, axis=1, stable=True)      # positions -> doc slot
-    rank = jnp.argsort(order, axis=1)                  # doc slot -> position
+
+def _max_dcg_by_slot(gain_slot: np.ndarray, ks) -> np.ndarray:
+    """[len(ks), nq_b] float64 ideal DCG at each cut-off of the queries
+    of one bucket (``gain_slot`` [nq_b, cap], pads 0): gains sorted
+    descending against the position discount (dcg_calculator.cpp
+    CalMaxDCGAtK)."""
+    ideal = -np.sort(-np.asarray(gain_slot, np.float64), axis=1)
+    cap = ideal.shape[1]
+    dcg = np.cumsum(ideal / np.log2(np.arange(cap) + 2.0), axis=1)
+    return np.stack([dcg[:, min(int(k), cap) - 1] for k in ks])
+
+
+def _slot_of_doc(buckets_np, num_data: int) -> np.ndarray:
+    """i32 [n]: where each doc sits in the concatenation of the buckets'
+    flattened ``[nq_b, cap]`` slot matrices.  Every doc has exactly one
+    slot, so per-slot results come back onto the docs as ONE gather
+    through this map (a permutation, not a reduction)."""
+    out = np.zeros(num_data, np.int32)
+    base = 0
+    for _, _, idx in buckets_np:
+        real = idx >= 0
+        out[idx[real]] = base + np.flatnonzero(real.reshape(-1))
+        base += idx.size
+    return out
+
+
+def _lambdarank_bucket(score, qidx, inv_dcg, gain_slot, label_slot, *,
+                       sigmoid: float, trunc: int, norm: bool):
+    """Pairwise |dNDCG| lambda gradients for ONE query-length bucket, by
+    padded slot: ``(g, h)`` [nq_b, Q], zeros in the pads.  Pure and
+    shape-static in ``qidx`` ([nq_b, Q] padded with -1): the whole pair
+    tensor is [nq_b, T, Q] with T = min(trunc, Q), so a bucket of short
+    queries never pays the longest query's Q.  ``gain_slot`` (pads 0)
+    and ``label_slot`` (pads -1) are the job's constants by slot; only
+    the score is gathered."""
+    s = sigmoid
+    with jax.named_scope("rank_gather"):
+        sc = jnp.where(qidx >= 0, score[jnp.maximum(qidx, 0)], -jnp.inf)
+
+    # docs by descending score, ties by index like the reference's stable
+    # sort; the constants ride the sort as operands, so nothing is
+    # gathered through the order afterwards.  Pads score -inf and sort
+    # last, their label -1 marks them in sorted space.
+    with jax.named_scope("rank_sort"):
+        slot = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        neg, g_srt, l_srt, order = jax.lax.sort(
+            (-sc, gain_slot, label_slot, slot), dimension=1, num_keys=1,
+            is_stable=True)
+        s_srt = -neg                                   # [nq_b, Q] desc
+        v_srt = l_srt >= 0
 
     # -- truncation-aware pair enumeration in SORTED space.  The
     # reference (rank_objective.hpp:138-292) iterates i over sorted
@@ -643,64 +674,73 @@ def _lambdarank_pair_accum(score, label, gain_doc, qidx, inv_dcg,
     # is O(Q * trunc), not O(Q^2).  Materializing [nq, T, Q] instead of
     # [nq, Q, Q] is what makes MS-LTR-scale query lengths (thousands of
     # docs) fit in memory (VERDICT r1 #7).
-    Q = sc.shape[1]
-    T = int(min(trunc, Q))
-    s_srt = jnp.take_along_axis(sc, order, axis=1)      # [nq_b, Q] desc
-    g_srt = jnp.take_along_axis(gains, order, axis=1)
-    l_srt = jnp.take_along_axis(lbl, order, axis=1)
-    v_srt = jnp.take_along_axis(valid, order, axis=1)
-    disc = 1.0 / jnp.log2(jnp.arange(Q, dtype=jnp.float32) + 2.0)  # [Q]
-    inv = inv_dcg[:, None, None]                         # [nq_b, 1, 1]
+    with jax.named_scope("rank_pairs"):
+        Q = sc.shape[1]
+        T = int(min(trunc, Q))
+        disc = 1.0 / jnp.log2(jnp.arange(Q, dtype=jnp.float32) + 2.0)  # [Q]
+        inv = inv_dcg[:, None, None]                         # [nq_b, 1, 1]
 
-    sa = s_srt[:, :T, None]                              # [nq_b, T, 1]
-    sb = s_srt[:, None, :]                               # [nq_b, 1, Q]
-    ga_ = g_srt[:, :T, None]
-    gb_ = g_srt[:, None, :]
-    la_ = l_srt[:, :T, None]
-    lb_ = l_srt[:, None, :]
-    delta = jnp.abs((ga_ - gb_)
-                    * (disc[None, :T, None] - disc[None, None, :])) \
-        * inv                                            # [nq_b, T, Q]
-    # each unordered pair once: position b strictly below position a
-    tri = (jnp.arange(Q)[None, None, :]
-           > jnp.arange(T)[None, :, None])
-    pair_ok = (la_ != lb_) & tri & v_srt[:, :T, None] & v_srt[:, None, :]
+        sa = s_srt[:, :T, None]                              # [nq_b, T, 1]
+        sb = s_srt[:, None, :]                               # [nq_b, 1, Q]
+        ga_ = g_srt[:, :T, None]
+        gb_ = g_srt[:, None, :]
+        la_ = l_srt[:, :T, None]
+        lb_ = l_srt[:, None, :]
+        delta = jnp.abs((ga_ - gb_)
+                        * (disc[None, :T, None] - disc[None, None, :])) \
+            * inv                                            # [nq_b, T, Q]
+        # each unordered pair once: position b strictly below position a
+        tri = (jnp.arange(Q)[None, None, :]
+               > jnp.arange(T)[None, :, None])
+        pair_ok = (la_ != lb_) & tri & v_srt[:, :T, None] & v_srt[:, None, :]
 
-    a_better = la_ > lb_
-    diff_hl = jnp.where(a_better, sa - sb, sb - sa)      # s_high - s_low
-    diff_hl = jnp.clip(diff_hl, -50.0 / s, 50.0 / s)
-    rho = 1.0 / (1.0 + jnp.exp(s * diff_hl))
-    lam = -s * rho * delta                    # dL/ds for the better doc
-    hes = s * s * rho * (1.0 - rho) * delta
-    lam = jnp.where(pair_ok, lam, 0.0)
-    hes = jnp.where(pair_ok, hes, 0.0)
+        a_better = la_ > lb_
+        diff_hl = jnp.where(a_better, sa - sb, sb - sa)      # s_high - s_low
+        diff_hl = jnp.clip(diff_hl, -50.0 / s, 50.0 / s)
+        rho = 1.0 / (1.0 + jnp.exp(s * diff_hl))
+        lam = -s * rho * delta                    # dL/ds for the better doc
+        hes = s * s * rho * (1.0 - rho) * delta
+        lam = jnp.where(pair_ok, lam, 0.0)
+        hes = jnp.where(pair_ok, hes, 0.0)
 
-    # accumulate onto sorted positions: a gets +/-lam per label order,
-    # b the negation; hessians add on both ends
-    g_a = jnp.where(a_better, lam, -lam)
-    g_pos = jnp.zeros_like(s_srt).at[:, :T].add(jnp.sum(g_a, axis=2))
-    g_pos = g_pos - jnp.sum(g_a, axis=1)
-    h_pos = jnp.zeros_like(s_srt).at[:, :T].add(jnp.sum(hes, axis=2))
-    h_pos = h_pos + jnp.sum(hes, axis=1)
+        # accumulate onto sorted positions: a gets +/-lam per label order,
+        # b the negation; hessians add on both ends
+        g_a = jnp.where(a_better, lam, -lam)
+        below = ((0, 0), (0, Q - T))        # positions past the truncation
+        g_pos = jnp.pad(jnp.sum(g_a, axis=2), below) - jnp.sum(g_a, axis=1)
+        h_pos = jnp.pad(jnp.sum(hes, axis=2), below) + jnp.sum(hes, axis=1)
 
-    if norm:
-        # reference norm_: scale by log2(1 + |sum lambda|) / |sum lambda|
-        sum_lam = jnp.sum(jnp.abs(lam), axis=(1, 2))
-        nf = jnp.where(sum_lam > 0,
-                       jnp.log2(1.0 + sum_lam) / jnp.maximum(sum_lam, 1e-20),
-                       1.0)
-        g_pos = g_pos * nf[:, None]
-        h_pos = h_pos * nf[:, None]
+        if norm:
+            # reference norm_: scale by log2(1 + |sum lambda|) / |sum lambda|
+            sum_lam = jnp.sum(jnp.abs(lam), axis=(1, 2))
+            nf = jnp.where(sum_lam > 0,
+                           jnp.log2(1.0 + sum_lam)
+                           / jnp.maximum(sum_lam, 1e-20), 1.0)
+            g_pos = g_pos * nf[:, None]
+            h_pos = h_pos * nf[:, None]
 
-    # sorted positions back to padded doc slots
-    g_doc = jnp.take_along_axis(g_pos, rank, axis=1)
-    h_doc = jnp.take_along_axis(h_pos, rank, axis=1)
+    # sorted positions back to padded slots: the order is a permutation
+    # of the slots, so sorting by it undoes it
+    with jax.named_scope("rank_sort"):
+        _, g_slot, h_slot = jax.lax.sort((order, g_pos, h_pos), dimension=1,
+                                         num_keys=1)
+    return g_slot, h_slot
 
-    g_acc = g_acc.at[safe.reshape(-1)].add(
-        jnp.where(valid, g_doc, 0.0).reshape(-1))
-    h_acc = h_acc.at[safe.reshape(-1)].add(
-        jnp.where(valid, h_doc, 0.0).reshape(-1))
-    return g_acc, h_acc
+
+def _lambdarank_gradients(score, state, *, sigmoid: float, trunc: int,
+                          norm: bool):
+    """Every bucket's pairwise gradients, then ONE gather through the
+    static ``slot_of_doc`` back onto the docs.  ``state`` is
+    ``LambdarankNDCG._rank_state``: the per-bucket
+    ``(qidx, inv_dcg, gain_slot, label_slot)`` and ``slot_of_doc``."""
+    buckets, slot_of_doc = state
+    parts = [_lambdarank_bucket(score, *bucket, sigmoid=sigmoid,
+                                trunc=trunc, norm=norm)
+             for bucket in buckets]
+    with jax.named_scope("rank_accumulate"):
+        g_flat, h_flat = (jnp.concatenate([p[i].reshape(-1) for p in parts])
+                          for i in (0, 1))
+        return g_flat[slot_of_doc], h_flat[slot_of_doc]
 
 
 def _xendcg_accum(score, label, gumbel, qidx, g_acc, h_acc):
@@ -772,29 +812,36 @@ class LambdarankNDCG(ObjectiveFunction):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             log.fatal("Lambdarank tasks require query information")
-        self._init_rank_buckets(metadata.query_boundaries)
-        lbl = np.asarray(metadata.label)
-        gains = self.config.label_gain or [float((1 << i) - 1) for i in
-                                           range(max(int(lbl.max()) + 1, 31))]
-        self._label_gain = np.asarray(gains, np.float64)
-        if int(lbl.max()) >= len(self._label_gain):
-            log.fatal("label_gain shorter than max label")
-        # inverse max DCG per query (rank_objective.hpp:165-177)
-        bounds = np.asarray(metadata.query_boundaries)
-        nq = len(bounds) - 1
-        inv = np.zeros(nq, np.float64)
-        trunc = self.config.lambdarank_truncation_level
-        for i in range(nq):
-            docs = np.arange(int(bounds[i]), int(bounds[i + 1]))
-            g = np.sort(self._label_gain[lbl[docs].astype(int)])[::-1][:trunc]
-            dcg = np.sum(g / np.log2(np.arange(2, len(g) + 2)))
-            inv[i] = 1.0 / dcg if dcg > 0 else 0.0
-        # per-bucket device arrays: (cap, qidx [nq_b, cap], inv_dcg [nq_b])
-        self._buckets = [(cap, jnp.asarray(idx),
-                          jnp.asarray(inv[qids], jnp.float32))
-                         for cap, qids, idx in self._buckets_np]
-        self._gain_of_doc = jnp.asarray(
-            self._label_gain[lbl.astype(int)], jnp.float32)
+        with phase("rank_bucket_plan", global_timer):
+            self._init_rank_buckets(metadata.query_boundaries)
+            lbl = np.asarray(metadata.label)
+            gains = self.config.label_gain or [
+                float((1 << i) - 1)
+                for i in range(max(int(lbl.max()) + 1, 31))]
+            self._label_gain = np.asarray(gains, np.float64)
+            if int(lbl.max()) >= len(self._label_gain):
+                log.fatal("label_gain shorter than max label")
+            gain_of_doc = self._label_gain[lbl.astype(int)]
+            trunc = int(self.config.lambdarank_truncation_level)
+            # per-bucket device arrays.  ``_buckets``: (cap, qidx
+            # [nq_b, cap], inv_dcg [nq_b]), the inverse max DCG at the
+            # truncation level (rank_objective.hpp:165-177), 0 for a
+            # query without a relevant doc.  ``_rank_state``: what the
+            # gradient program reads, gains and labels laid out by slot
+            # once, here, and the docs' slots
+            self._buckets, state = [], []
+            for cap, _, idx in self._buckets_np:
+                gain_slot = _by_slot(gain_of_doc, idx, 0.0)
+                max_dcg = _max_dcg_by_slot(gain_slot, [trunc])[0]
+                inv = np.where(max_dcg > 0, 1.0 / np.maximum(max_dcg, 1e-300),
+                               0.0)
+                qidx, inv = jnp.asarray(idx), jnp.asarray(inv, jnp.float32)
+                self._buckets.append((cap, qidx, inv))
+                state.append((qidx, inv, jnp.asarray(gain_slot, jnp.float32),
+                              jnp.asarray(_by_slot(lbl, idx, -1.0),
+                                          jnp.float32)))
+            self._rank_state = (tuple(state), jnp.asarray(
+                _slot_of_doc(self._buckets_np, num_data)))
         # position-debiased LTR (rank_objective.hpp:43-56,295: per-position
         # additive bias factors on the score, Newton-updated each iteration
         # with L2 regularization lambdarank_position_bias_regularization)
@@ -819,23 +866,42 @@ class LambdarankNDCG(ObjectiveFunction):
             # the whole update as one cached device program
             self.jit_safe = False
 
+    def attach_booster_metrics(self, registry) -> None:
+        """The base class's, and the ranking plan's sizes counted once a
+        job, in the booster's registry and the process's."""
+        super().attach_booster_metrics(registry)
+        counts = self._rank_counts
+        count_event("rank_queries", counts["rank_queries"], registry)
+        count_event("rank_docs", counts["rank_docs"], registry)
+        count_event("rank_slot_rows", counts["rank_slot_rows"], registry)
+        count_event("rank_pair_slots", counts["rank_pair_slots"], registry)
+
     def _init_rank_buckets(self, boundaries) -> None:
-        """Build the query-length bucket plan + telemetry gauges (shared
-        with RankXENDCG)."""
+        """Build the query-length bucket plan, its telemetry gauges and
+        the job's ranking counters (shared with RankXENDCG)."""
         bounds = np.asarray(boundaries)
         sizes = np.diff(bounds)
         self._qmax = int(sizes.max()) if len(sizes) else 1
         spec = getattr(self.config, "rank_query_buckets", "auto")
         self._buckets_np, self._rank_pad_rows = _rank_buckets(bounds, spec)
         self._rank_bucket_count = len(self._buckets_np)
-        if self._qmax > 2048 and os.environ.get("LGBMTPU_NO_RANK_BUCKETS"):
+        if self._qmax > 2048 and self._rank_bucket_count == 1 \
+                and len(sizes) > 1:
             log.warning(
-                f"Longest query has {self._qmax} docs and query-length "
-                f"bucketing is disabled (LGBMTPU_NO_RANK_BUCKETS): the "
-                f"pad-to-max pairwise lambda computation is "
-                f"O(max_query_len^2) per query — unset the hatch to "
-                f"restore the bucketed kernels (rank_query_buckets), or "
-                f"lower lambdarank_truncation_level / split queries")
+                f"Longest query has {self._qmax} docs and "
+                f"rank_query_buckets={spec!r} puts every query into one "
+                f"bucket: the pad-to-max pairwise lambda computation is "
+                f"O(max_query_len * truncation) per query whatever its "
+                f"length — leave rank_query_buckets at \"auto\" for the "
+                f"bucketed kernels, or lower lambdarank_truncation_level "
+                f"/ split queries")
+        trunc = int(getattr(self.config, "lambdarank_truncation_level", 30))
+        slot_rows = sum(idx.size for _, _, idx in self._buckets_np)
+        self._rank_counts = {
+            "rank_queries": len(sizes), "rank_docs": int(sizes.sum()),
+            "rank_slot_rows": slot_rows,
+            "rank_pair_slots": sum(idx.size * min(trunc, cap)
+                                   for cap, _, idx in self._buckets_np)}
         global_metrics.set_gauge("rank_pad_rows", self._rank_pad_rows)
         global_metrics.set_gauge("rank_bucket_count",
                                  self._rank_bucket_count)
@@ -848,21 +914,24 @@ class LambdarankNDCG(ObjectiveFunction):
         return tuple((int(qidx.shape[0]), cap)
                      for cap, qidx, _ in self._buckets)
 
-    def get_gradients(self, score):
+    def fused_operands(self):
+        return self._rank_state
+
+    def get_gradients(self, score, rank_state=None):
         """Pure traceable composition over the bucket plan — the function
-        the fused round scan traces inline (plain lambdarank) and tests
-        call eagerly.  Training dispatch goes through jitted_gradients,
-        which runs this same arithmetic as one cached program."""
+        the fused round scan traces inline (plain lambdarank; the scan
+        hands ``rank_state`` in as an operand of the round program, so
+        the slot matrices are arguments and not literals of its HLO) and
+        tests call eagerly.  Training dispatch goes through
+        jitted_gradients, which runs this same arithmetic as one cached
+        program."""
         if self._positions is not None:
             score = score + self._pos_biases_dev[self._positions_dev]
-        g = jnp.zeros_like(score)
-        h = jnp.zeros_like(score)
-        for cap, qidx, inv in self._buckets:
-            g, h = _lambdarank_pair_accum(
-                score, self._label, self._gain_of_doc, qidx, inv, g, h,
-                sigmoid=float(self.config.sigmoid),
-                trunc=int(self.config.lambdarank_truncation_level),
-                norm=bool(self.config.lambdarank_norm))
+        g, h = _lambdarank_gradients(
+            score, self._rank_state if rank_state is None else rank_state,
+            sigmoid=float(self.config.sigmoid),
+            trunc=int(self.config.lambdarank_truncation_level),
+            norm=bool(self.config.lambdarank_norm))
         g, h = self._apply_weight(g, h)
         if self._positions is not None and \
                 not isinstance(score, jax.core.Tracer):
@@ -878,8 +947,8 @@ class LambdarankNDCG(ObjectiveFunction):
         score adjust (position bias), every bucket's pairwise kernel,
         weighting and the functional Newton bias update all lower as a
         SINGLE XLA executable, keyed only by geometry + hyperparameters
-        (no anchors; labels/gains/biases are traced arguments), so a
-        second booster over identical geometry is a pure
+        (no anchors; the slot state, weights and biases are traced
+        arguments), so a second booster over identical geometry is a pure
         ``rank_compile_hits`` path — zero new lowerings."""
         pos = self._positions is not None
         has_w = self._weight is not None
@@ -894,15 +963,10 @@ class LambdarankNDCG(ObjectiveFunction):
         lr, reg = statics[7], statics[8]
 
         def builder():
-            def run(score, label, gain_doc, weight, bias, positions,
-                    counts, buckets):
+            def run(score, weight, bias, positions, counts, state):
                 sc = score + bias[positions] if pos else score
-                g = jnp.zeros_like(score)
-                h = jnp.zeros_like(score)
-                for qidx, inv in buckets:
-                    g, h = _lambdarank_pair_accum(
-                        sc, label, gain_doc, qidx, inv, g, h,
-                        sigmoid=sigmoid, trunc=trunc, norm=norm)
+                g, h = _lambdarank_gradients(sc, state, sigmoid=sigmoid,
+                                             trunc=trunc, norm=norm)
                 if has_w:
                     g, h = g * weight, h * weight
                 if pos:
@@ -916,12 +980,11 @@ class LambdarankNDCG(ObjectiveFunction):
                              metrics=self._metrics, counter_ns="rank")
         empty_f = jnp.zeros((0,), jnp.float32)
         empty_i = jnp.zeros((0,), jnp.int32)
-        out = fn(score, self._label, self._gain_of_doc,
-                 self._weight if has_w else empty_f,
+        out = fn(score, self._weight if has_w else empty_f,
                  self._pos_biases_dev if pos else empty_f,
                  self._positions_dev if pos else empty_i,
                  self._pos_counts_dev if pos else empty_f,
-                 tuple((qidx, inv) for _, qidx, inv in self._buckets))
+                 self._rank_state)
         if pos:
             g, h, nb = out
             self._pos_biases_dev = nb
@@ -941,12 +1004,14 @@ class RankXENDCG(LambdarankNDCG):
     # stays on host (and off the fused scan) while the drawn key rides
     # into the cached device program as a traced argument
     jit_safe = False
+    fused_operands = ObjectiveFunction.fused_operands
 
     def init(self, metadata, num_data):
         ObjectiveFunction.init(self, metadata, num_data)
         if metadata.query_boundaries is None:
             log.fatal("Ranking tasks require query information")
-        self._init_rank_buckets(metadata.query_boundaries)
+        with phase("rank_bucket_plan", global_timer):
+            self._init_rank_buckets(metadata.query_boundaries)
         self._buckets = [(cap, jnp.asarray(idx), None)
                          for cap, qids, idx in self._buckets_np]
         self._positions = None

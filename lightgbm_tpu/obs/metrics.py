@@ -211,6 +211,18 @@ COUNTERS: Dict[str, str] = {
         "lowered a new pairwise program (ops/compile_cache.py)",
     "serve_contrib_requests":
         "serving-tier predict_contrib (tree-SHAP) requests served",
+    "rank_queries":
+        "queries of a ranking job's training set, counted once a job "
+        "when the booster takes its objective (objectives.py)",
+    "rank_docs":
+        "docs (rows) of a ranking job's training set, once a job",
+    "rank_slot_rows":
+        "padded doc slots of the query-length bucket plan, the sum over "
+        "buckets of queries x cap, once a job: what the per-query sorts "
+        "read each round (rank_docs / rank_slot_rows is the plan's fill)",
+    "rank_pair_slots":
+        "pair slots of the lambdarank pair tensors, the sum over buckets "
+        "of queries x min(truncation level, cap) x cap, once a job",
 }
 
 

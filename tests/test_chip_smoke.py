@@ -38,10 +38,15 @@ def test_chip_smoke_rehearsal_runs_every_phase_but_cannot_succeed():
     lines = [json.loads(ln) for ln in out.stdout.splitlines()
              if ln.startswith("{")]
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "device", "data", "train", "bundled", "predict", "save_load", "serve"]
+        "device", "data", "train", "bundled", "ranking", "predict", "save_load",
+        "serve"]
     bundled = lines[3]
     assert bundled["bundle_expand_calls"] == 0 and bundled["bundles"] < 76
     assert bundled["bundle_space_search_rounds"] == bundled["iters"]
+    ranking = lines[4]
+    assert ranking["buckets"] >= 4 and ranking["rank_slot_rows"] > ranking["rows"]
+    assert ranking["device_against_host_ndcg"] < 1e-5
+    assert ranking["valid_ndcg10_last"] > ranking["valid_ndcg10_first"]
     assert lines[-1]["ok"] is False and "rehearsal" in lines[-1]
     assert '"ok": true' not in out.stdout
 

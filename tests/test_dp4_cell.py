@@ -294,6 +294,65 @@ def test_a_second_job_compiles_no_gradient_program(cell, data):
     assert _binary_gradients_jit._cache_size() == size
 
 
+# ------------------------------------------------ the loop, program by program
+_COUNT_PROGRAMS = """
+import json, os, sys
+sys.path.insert(0, os.path.join({repo!r}, "tests"))
+import conftest  # noqa: F401  (the CPU's eight devices, the compile cache)
+import jax
+from jax._src import pjit
+from jax._src.interpreters import pxla
+import test_dp4_cell as T
+
+cfg = T._cell()[1]
+data = T._data(cfg)
+T._train(cfg, data, "data")            # everything compiled
+# every execution through Python: no C++ fast path, its entries dropped
+calls = [0]
+execute = pxla.ExecuteReplicated.__call__
+def counted(self, *args):
+    calls[0] += 1
+    return execute(self, *args)
+pxla.ExecuteReplicated.__call__ = counted
+pjit._get_fastpath_data = lambda *a, **k: None
+jax.clear_caches()
+marks = []
+def after_round(env):
+    marks.append(calls[0])
+after_round.order = 95
+(xt32, xt64, y), (xv32, xv64, yv) = data
+params = dict(cfg["params"], tree_learner="data")
+ds = T.lgb.Dataset(xt64.T, label=y, params=params).construct()
+dv = ds.create_valid(xv64.T, label=yv).construct()
+before = sorted(vars(dv)), sorted(vars(ds))
+with T.device_window(T.CHIPS):
+    T.lgb.train(params, ds, num_boost_round=T.ROUNDS, valid_sets=[dv],
+                callbacks=[after_round])
+print(json.dumps({{"marks": marks,
+                  "same_attributes":
+                  before == (sorted(vars(dv)), sorted(vars(ds)))}}))
+"""
+
+
+def test_the_loop_runs_the_parents_programs_and_leaves_the_dataset_alone():
+    """PR 38's rule for the four-chip cell, pinned: a ``tree_learner=data``
+    binary job with a valid set executes as many programs as at the
+    parent of the PR that brought the ranking cell (a645479, counted
+    there with this same script: 83 up to the end of the first round,
+    booster and ``add_valid`` included, then 74 a round on the CPU's mesh
+    with this container's JAX; the chip's loop runs 63), and the booster
+    puts no attribute on either ``Dataset`` (no memoised transpose: the
+    refused PR 37's ``_bins_t_host``)."""
+    out = subprocess.run(
+        [sys.executable, "-c", _COUNT_PROGRAMS.format(repo=REPO)],
+        capture_output=True, text=True, timeout=1200, cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    got = json.loads(out.stdout.splitlines()[-1])
+    assert got["same_attributes"]
+    assert got["marks"] == [83, 157, 231], got
+
+
 # ----------------------------------------------------- the cell's rehearsal
 def test_the_cell_rehearses_on_four_cpu_devices():
     """``benchmark/run.py --workload criteo-dp4-train --rehearse-cpu``:
