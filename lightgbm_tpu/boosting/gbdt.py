@@ -57,6 +57,19 @@ GradFn = Callable[[np.ndarray, Any], Tuple[np.ndarray, np.ndarray]]
 _LITERAL_MAX_BYTES = 256 << 20
 
 
+@functools.lru_cache(maxsize=None)
+def _valid_mirror_program(sharding):
+    """The program that turns a valid set's placed ``[n, F]`` bins into
+    the ``[F, n]`` mirror, one a placement: ``sharding`` is the mesh's
+    replicated ``NamedSharding`` that ``_place_whole`` committed the bins
+    to, stated for the result too, or None for the uncommitted array of
+    the other modes, whose result stays uncommitted.  Kept by the
+    process, so that a second booster compiles nothing."""
+    def valid_mirror(bins):
+        return bins.T
+    return jax.jit(valid_mirror, out_shardings=sharding)
+
+
 def _resolve_hist_dtype(cfg: Config) -> str:
     """Histogram contraction dtype with validity gating.
 
@@ -961,13 +974,25 @@ class GBDT:
             vsc += isc.reshape(vsc.shape, order="F") \
                 if isc.size == vsc.size else isc.reshape(-1, 1)
         self.valid_scores.append(self._place_whole(vsc))
-        self._valid_bins.append(self._place_whole(valid_set.bins))
-        # transposed mirror for the matmul valid scorer (round 6): the
-        # per-tree path-aggregation wants rows on lanes; cached once per
-        # valid set, only for model classes the matmul path serves
-        self._valid_bins_t.append(
-            self._place_whole(np.ascontiguousarray(valid_set.bins.T))
-            if self._matmul_valid_ok() else None)
+        # transposed mirror for the matmul valid scorer (round 6) and the
+        # fused round program: the per-tree path-aggregation wants rows
+        # on lanes.  Made for every booster, only for model classes the
+        # matmul path serves, ON THE DEVICE from the placed bins: the
+        # host makes no [F, n] array and copies the bins over once (a
+        # numpy transpose of the ranking cell's 688 MB took 4.2 s a
+        # booster; PERF.md section 6, PR 39)
+        with self._phase("valid_mirror"):
+            bins = self._place_whole(valid_set.bins)
+            mirror = None
+            if self._matmul_valid_ok():
+                mirror = _valid_mirror_program(
+                    bins.sharding if bins.committed else None)(bins)
+                self._count("valid_mirror_device_bytes", mirror.nbytes)
+                # what a mirror transposed on the host and copied over
+                # would count: none is
+                self._count("valid_mirror_host_bytes", 0)
+        self._valid_bins.append(bins)
+        self._valid_bins_t.append(mirror)
         self._valid_raw.append(jnp.asarray(valid_set.raw)
                                if self.linear and valid_set.raw is not None
                                else None)

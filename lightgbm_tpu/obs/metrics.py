@@ -223,6 +223,13 @@ COUNTERS: Dict[str, str] = {
     "rank_pair_slots":
         "pair slots of the lambdarank pair tensors, the sum over buckets "
         "of queries x min(truncation level, cap) x cap, once a job",
+    "valid_mirror_device_bytes":
+        "bytes of the valid sets' transposed bins ([F, n], what the "
+        "matmul valid scorer and the fused round program read) that "
+        "GBDT.add_valid made on the device from the placed [n, F] bins",
+    "valid_mirror_host_bytes":
+        "bytes of those mirrors transposed on the host and copied to "
+        "the device: 0 since PR 39, what a return to the host path shows",
 }
 
 

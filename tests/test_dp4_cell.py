@@ -141,6 +141,10 @@ def test_the_job_ran_over_four_devices_in_the_per_iteration_loop(data_job):
     # the valid set whole on every device, placed once
     for a in (gb._valid_bins[0], gb._valid_bins_t[0], gb.valid_scores[0]):
         assert len(a.devices()) == CHIPS and a.is_fully_replicated
+    # the mirror is transposed on the devices from the placed bins, into
+    # the placement the loop's programs compiled for
+    assert gb._valid_bins_t[0].sharding == gb._valid_bins[0].sharding
+    assert gb._valid_bins_t[0].committed
     c = gb.metrics.counter
     assert c("sharded_rounds") == c("strict_rounds") == ROUNDS
     assert c("fused_rounds") == 0
@@ -342,7 +346,11 @@ def test_the_loop_runs_the_parents_programs_and_leaves_the_dataset_alone():
     booster and ``add_valid`` included, then 74 a round on the CPU's mesh
     with this container's JAX; the chip's loop runs 63), and the booster
     puts no attribute on either ``Dataset`` (no memoised transpose: the
-    refused PR 37's ``_bins_t_host``)."""
+    refused PR 37's ``_bins_t_host``).  Since PR 39 the first mark is 84:
+    ``add_valid`` executes ONE program more a valid set, the transpose of
+    the placed bins on the devices (``_valid_mirror_program``), where the
+    parent transposed on the host and placed the result (a placement is
+    no execution and was never counted here); the rounds keep their 74."""
     out = subprocess.run(
         [sys.executable, "-c", _COUNT_PROGRAMS.format(repo=REPO)],
         capture_output=True, text=True, timeout=1200, cwd=REPO,
@@ -350,7 +358,7 @@ def test_the_loop_runs_the_parents_programs_and_leaves_the_dataset_alone():
     assert out.returncode == 0, out.stderr[-3000:]
     got = json.loads(out.stdout.splitlines()[-1])
     assert got["same_attributes"]
-    assert got["marks"] == [83, 157, 231], got
+    assert got["marks"] == [84, 158, 232], got
 
 
 # ----------------------------------------------------- the cell's rehearsal

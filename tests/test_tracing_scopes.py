@@ -402,7 +402,9 @@ def test_span_count_follows_dispatches_and_trees_not_rows(tmp_path):
     assert small == large
     dispatches, trees = 2, 42
     # the train and the valid Dataset construct inside the job's span
-    assert small == {"train": 1, "booster_init": 1, "train_fused": 1,
+    # and one valid set's bins are placed and mirrored in ``booster_init``
+    assert small == {"train": 1, "booster_init": 1, "valid_mirror": 1,
+                     "train_fused": 1,
                      "construct": 2, "fused_prepare": dispatches,
                      "fused_round_scan": dispatches,
                      "fused_chunk_transfer": dispatches,
