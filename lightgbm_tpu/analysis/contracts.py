@@ -485,6 +485,17 @@ class UndeclaredCounter(Rule):
             for node in ast.walk(ctx.tree):
                 if not isinstance(node, ast.Call) or not node.args:
                     continue
+                if isinstance(node.func, ast.Name) and \
+                        node.func.id == "phase":
+                    # a span that adds its seconds to a counter on exit
+                    # (utils/timer.py ``phase(..., seconds=<counter>)``)
+                    uses.extend(
+                        (ctx.relpath, node.lineno, node.col_offset,
+                         kw.value.value) for kw in node.keywords
+                        if kw.arg == "seconds"
+                        and isinstance(kw.value, ast.Constant)
+                        and isinstance(kw.value.value, str))
+                    continue
                 first = node.args[0]
                 if not (isinstance(first, ast.Constant)
                         and isinstance(first.value, str)):

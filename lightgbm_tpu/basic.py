@@ -709,12 +709,15 @@ class Booster:
     def telemetry(self) -> Dict[str, Any]:
         """Telemetry snapshot for this booster (obs/): counters/gauges
         accumulated while training, the per-booster phase-timing table,
-        and a current host/device memory sample.  Loaded (predict-only)
-        boosters report memory only."""
+        the process's compile table (obs/compile_events.py ``table()``:
+        which programs were traced, lowered, compiled or loaded, under
+        which span) and a current host/device memory sample.  Loaded
+        (predict-only) boosters report the last two only."""
         if self._gbdt is not None:
             return self._gbdt.telemetry()
-        from .obs import memory as obs_memory
+        from .obs import compile_events, memory as obs_memory
         return {"counters": {}, "gauges": {}, "phases": {},
+                "compile_table": compile_events.table(),
                 "memory": obs_memory.memory_snapshot()}
 
     def prometheus_text(self) -> str:

@@ -208,7 +208,7 @@ def _hist_rows_selected(arrays, n_rows: int) -> int:
     root pass plus, per split, the smaller child's row count, from the
     counts the tree arrays bring to the host (``internal_count``,
     ``leaf_count``).  Those are float32 sums of ones, exact below 2**24
-    rows a shard.  Counted, where ``hist_build_rounds`` is a formula."""
+    rows a shard."""
     splits = int(arrays.num_leaves) - 1
     if splits <= 0:
         return n_rows
@@ -230,12 +230,17 @@ class GBDT:
         self.train_set = train_set
         self.objective = objective if objective is not None else \
             create_objective(config)
-        if self.objective is not None:
-            self.objective.init(train_set.metadata, train_set.num_data)
-        self.train_metrics = metrics if metrics is not None else \
-            create_metrics(config)
-        for m in self.train_metrics:
-            m.init(train_set.metadata, train_set.num_data)
+        # the labels' statistics and the metrics' (0.34-0.43 s of a
+        # 13.28M-row binary job's ``booster_init``: PERF.md section 5), a
+        # ranking job's bucket plan: before this booster's timer exists,
+        # so in no table
+        with phase("objective_init"):
+            if self.objective is not None:
+                self.objective.init(train_set.metadata, train_set.num_data)
+            self.train_metrics = metrics if metrics is not None else \
+                create_metrics(config)
+            for m in self.train_metrics:
+                m.init(train_set.metadata, train_set.num_data)
 
         # reference USE_TIMETAG phase table (utils/common.h Timer).  Each
         # booster owns its OWN accumulator so concurrently alive boosters
@@ -452,14 +457,15 @@ class GBDT:
         host array is taken as it is: each shard goes to its device."""
         if x is None:
             return x
-        if self.mesh is not None:
-            from ..parallel.gspmd import row_sharded
-            if self.parallel_mode == "data_gspmd":
-                return row_sharded(self.mesh, x)
-            if self.parallel_mode in ("data", "voting") \
-                    and int(x.shape[0]) % int(self.mesh.devices.size) == 0:
-                return row_sharded(self.mesh, x)
-        return jnp.asarray(x)
+        with self._place(x, "rows"):
+            if self.mesh is not None:
+                from ..parallel.gspmd import row_sharded
+                if self.parallel_mode == "data_gspmd":
+                    return row_sharded(self.mesh, x)
+                if self.parallel_mode in ("data", "voting") and \
+                        int(x.shape[0]) % int(self.mesh.devices.size) == 0:
+                    return row_sharded(self.mesh, x)
+            return jnp.asarray(x)
 
     def _place_whole(self, x):
         """Place ``x`` whole on every device of the mesh under the
@@ -467,10 +473,22 @@ class GBDT:
         in full and the booster keeps, the valid sets' bins.  Left
         uncommitted on the first device, every call that mixes it with
         the mesh's arrays copies it to the other devices again."""
-        if self.mesh is not None and self.parallel_mode in ("data", "voting"):
-            from ..parallel.gspmd import replicated
-            return replicated(self.mesh, x)
-        return jnp.asarray(x)
+        with self._place(x, "whole"):
+            if self.mesh is not None and \
+                    self.parallel_mode in ("data", "voting"):
+                from ..parallel.gspmd import replicated
+                return replicated(self.mesh, x)
+            return jnp.asarray(x)
+
+    def _place(self, x, what: str):
+        """The span ``place`` around one placement (``_place_rows``,
+        ``_place_whole``: the one place bins, words, scores and the
+        objective's row arrays go to the device) with the array's
+        ``bytes`` and ``what`` (``rows`` / ``whole``) as counts.  The
+        span holds the ENQUEUE of a host array's copy, not its
+        arrival."""
+        return self._phase("place", bytes=int(getattr(x, "nbytes", 0)),
+                           what=what)
 
     def _config_signature(self):
         """Canonical-config signature for process compile-cache keys:
@@ -483,18 +501,6 @@ class GBDT:
         c = self.config
         return tuple((name, repr(getattr(c, name, None)))
                      for name in sorted(_CANONICAL))
-
-    def _hist_rounds_per_tree(self) -> int:
-        """Analytic histogram-pass count one grown tree costs: the strict
-        leaf-wise learner runs one build+split-find pass per split, the
-        batched grower one per K-split round.  A host-side tally — the
-        passes themselves run inside jit where counting would record
-        compilations, not executions."""
-        splits = max(1, self.hp.num_leaves - 1)
-        if self._use_batched_grower():
-            k = max(1, int(self.config.tpu_split_batch))
-            return -(-splits // k)
-        return splits
 
     def _collective_bytes_per_tree(self, splits: Optional[int] = None) -> int:
         """Analytic estimate of the bytes all-reduced growing ONE tree of
@@ -556,10 +562,11 @@ class GBDT:
         """This booster's telemetry snapshot: counters/gauges, the phase
         table, and a current memory sample (surfaced publicly as
         ``Booster.telemetry()``)."""
-        from ..obs import memory as obs_memory
+        from ..obs import compile_events, memory as obs_memory
         snap = self.metrics.snapshot()
         return {"counters": snap["counters"], "gauges": snap["gauges"],
                 "phases": self.timer.as_dict(),
+                "compile_table": compile_events.table(),
                 "memory": obs_memory.memory_snapshot()}
 
     def prometheus_text(self) -> str:
@@ -1173,7 +1180,6 @@ class GBDT:
                     hist_scales[c] = jnp.stack([gs, hs])
                 g = jnp.stack(gq, axis=1)
                 h = jnp.stack(hq, axis=1)
-            self._count("quantize_rounds")
 
         finished = True
         count_rows = self._use_batched_grower() and not \
@@ -1251,7 +1257,6 @@ class GBDT:
         if self.parallel_mode is not None:
             self._count("sharded_rounds")
         self._count("trees_grown", k)
-        self._count("hist_build_rounds", self._hist_rounds_per_tree() * k)
         return finished
 
     # ------------------------------------------------- fused iterations
@@ -1654,7 +1659,8 @@ class GBDT:
         n_rows = int(self.train_set.num_data)
         count_rows = self.parallel_mode is None and not \
             0 < self.hp.hist_pool_slots < self.hp.num_leaves
-        operands = self._fused_operands()
+        with self._phase("fused_operands"):
+            operands = self._fused_operands()
         while done < num_rounds and not finished:
             T = min(chunk, num_rounds - done)
             with self._phase("fused_prepare"):
@@ -1764,8 +1770,6 @@ class GBDT:
                     if self._bundle_space:
                         self._count("bundle_space_search_rounds")
                     self._count("trees_grown", k)
-                    self._count("hist_build_rounds",
-                                self._hist_rounds_per_tree() * k)
                     if nvalid:
                         self._last_fused_evals = [
                             (mrows[j][0], mrows[j][1], float(mhost[t, j]),

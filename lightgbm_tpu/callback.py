@@ -135,6 +135,10 @@ def _prune_stale_telemetry(path: str, cut: int) -> int:
     return dropped
 
 
+#: rows of the compile table a telemetry record carries
+_COMPILE_TABLE_ROWS = 12
+
+
 def log_telemetry(path: str, period: int = 1,
                   resume_from: Optional[int] = None) -> Callable:
     """Append one JSONL telemetry record per boosting iteration to
@@ -227,6 +231,16 @@ def log_telemetry(path: str, period: int = 1,
             "round_compile_misses":
                 global_metrics.counter("round_compile_misses"),
         }
+        # which programs those were, by span and stage: the compile
+        # table's top rows, in the records after which it had grown
+        # (none once nothing compiles any more)
+        from .obs import compile_events
+        stages = compile_events.stages_seen()
+        if stages != state.get("compile_stages"):
+            state["compile_stages"] = stages
+            rec["compile_table"] = [
+                {**row, "seconds": round(row["seconds"], 6)}
+                for row in compile_events.table()[:_COMPILE_TABLE_ROWS]]
         rec.update(mem)
         try:
             with open(path, "a") as f:

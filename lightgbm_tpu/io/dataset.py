@@ -289,7 +289,9 @@ class Dataset:
             ds.num_total_features = reference.num_total_features
             ds.feature_names = reference.feature_names
             ds._reference = reference
-            ds._bin_all(arr)
+            with phase("dense_bin_matrix", global_timer,
+                       seconds="construct_bin_matrix_s"):
+                ds._bin_all(arr)
             if reference.bundle_plan is not None:
                 ds.bundle_plan = reference.bundle_plan
                 ds.bins = apply_bundles(ds.bins, ds.bundle_plan)
@@ -298,8 +300,12 @@ class Dataset:
             return ds
 
         cat_idx = _resolve_categorical(categorical_feature, ds.feature_names)
-        ds._construct_mappers(arr, cfg, cat_idx)
-        ds._bin_all(arr)
+        with phase("dense_bin_mappers", global_timer,
+                   seconds="construct_bin_mappers_s"):
+            ds._construct_mappers(arr, cfg, cat_idx)
+        with phase("dense_bin_matrix", global_timer,
+                   seconds="construct_bin_matrix_s"):
+            ds._bin_all(arr)
         if bool(cfg.enable_bundle) and cfg.tree_learner not in (
                 "feature", "feature_parallel"):
             # cap bundle width at the pre-EFB histogram width so EFB can

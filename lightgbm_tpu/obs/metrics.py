@@ -33,14 +33,10 @@ COUNTERS: Dict[str, str] = {
     "strict_rounds": "rounds run on the strict per-tree update path",
     "fused_rounds": "rounds run on the fused round-kernel fast path",
     "trees_grown": "trees grown (k per round for multiclass)",
-    "hist_build_rounds":
-        "histogram build passes dispatched (a formula: splits over the "
-        "split batch, not a count of what ran)",
     "hist_rows_selected":
         "rows the histogram passes had to read, counted from the trees "
         "(root pass + each split's smaller child; the batched serial and "
         "data learners without the bounded pool)",
-    "quantize_rounds": "rounds that quantized gradients before binning",
     "hist_pool_fallbacks": "histogram-pool exhaustion -> rebuild fallbacks",
     "batched_path_fallbacks": "batched-grower bailouts to the strict path",
     "fused_runner_cache_hits": "fused round-runner compile-cache hits",
@@ -230,6 +226,13 @@ COUNTERS: Dict[str, str] = {
     "valid_mirror_host_bytes":
         "bytes of those mirrors transposed on the host and copied to "
         "the device: 0 since PR 39, what a return to the host path shows",
+    "construct_bin_mappers_s":
+        "seconds of the dense Dataset.construct in the span "
+        "`dense_bin_mappers` (row sample and the bin finder of every "
+        "feature), always timed",
+    "construct_bin_matrix_s":
+        "seconds in the span `dense_bin_matrix` (every value to its "
+        "bin: the uint8 matrix), always timed",
 }
 
 

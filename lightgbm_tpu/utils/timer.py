@@ -21,9 +21,19 @@ profiler session is collecting (``profile_dir=...`` or anyone's
 ``jax.profiler.start_trace``), so the program's host spans sit in the
 same ``.xplane.pb``, on the same clock, as the device's ``XLA Ops``.
 With no session, no recorder and no enabled timer a span costs the
-profiler's activity check, a tuple scan and an ``is None`` check:
-nothing is formatted, timed or appended.  Spans stay at job, dispatch
-and tree granularity — never per row, per leaf or inside jitted code.
+profiler's activity check, a tuple scan, an ``is None`` check and one
+append and one pop of its name on this thread's list of open spans
+(``open_spans()``): nothing is formatted or timed.  That list is what
+lets the compile-event listener (obs/compile_events.py) say, with
+nothing switched on, under which span a program was traced, lowered
+or compiled.  Spans stay at job, dispatch and tree granularity — never
+per row, per leaf or inside jitted code.
+
+``seconds=<counter>`` makes a span ALWAYS timed: on exit its seconds
+are added to that process-wide telemetry counter (obs/metrics.py), for
+the few set-up stages that are read where no timer table and no
+session is on (``Dataset.construct``'s: two clock reads in a stage of
+seconds).
 
 Device work is asynchronous under jit, so phases that end with a host sync
 (eval, metric reads) absorb queued device time — same caveat as any
@@ -39,6 +49,18 @@ import time
 from typing import Any, Dict
 
 from ..obs import trace as _trace
+from ..obs.metrics import count_event
+
+_open = threading.local()
+
+
+def open_spans() -> list:
+    """Names of the spans open on this thread, outermost first."""
+    try:
+        return _open.names
+    except AttributeError:
+        _open.names = names = []
+        return names
 
 
 class phase:
@@ -46,17 +68,20 @@ class phase:
     ``counts`` are small scalars describing the region (``rounds=8``);
     they become the recorder event's ``args`` and the annotation's
     stats.  An annotation takes its counts when it OPENS, so a span that
-    reports results is opened once they are known (``dispatch_done``)."""
+    reports results is opened once they are known (``dispatch_done``).
+    ``seconds`` names a process-wide counter the span's seconds are
+    added to on exit, whatever is switched on."""
 
-    __slots__ = ("name", "counts", "_timers", "_t0", "_ann")
+    __slots__ = ("name", "counts", "_timers", "_t0", "_ann", "_seconds")
 
     def __init__(self, name: str, *timers: "PhaseTimer",
-                 **counts: Any) -> None:
+                 seconds: str = "", **counts: Any) -> None:
         self.name = name
         self.counts = counts
         self._timers = timers
         self._t0 = None
         self._ann = None
+        self._seconds = seconds
 
     def also(self, timer: "PhaseTimer") -> None:
         """Time the REST of this open span into ``timer`` as well (a
@@ -68,15 +93,19 @@ class phase:
 
     def __enter__(self) -> "phase":
         self._ann = _trace.annotate(self.name, self.counts)
-        if _trace.active() is not None or \
+        if self._seconds or _trace.active() is not None or \
                 any(t.enabled for t in self._timers):
             self._t0 = time.perf_counter()
+        open_spans().append(self.name)
         return self
 
     def __exit__(self, *exc) -> bool:
+        open_spans().pop()
         t0 = self._t0
         if t0 is not None:
             dt = time.perf_counter() - t0
+            if self._seconds:
+                count_event(self._seconds, dt)
             for t in self._timers:
                 if t.enabled:
                     # the global timer is shared across concurrently

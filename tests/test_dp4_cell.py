@@ -113,9 +113,11 @@ def test_the_manifest_names_the_cell_and_its_six_metrics():
         ("criteo-host4", "train-jobs-dp", 4)
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == ["criteo-dp4-train"]]
+    # PR 30's six, and since PR 40 the job's start
     assert sorted(m["name"] for m in mine) == [
         "dp_between_programs_ms", "dp_chip_skew_ms", "dp_collective_ms",
-        "dp_device_idle_share", "dp_hist_ms", "dp_valid_eval_ms"]
+        "dp_device_idle_share", "dp_hist_ms", "dp_job_start_ms",
+        "dp_valid_eval_ms"]
     assert all(m["moves"] == "train_round_ms" and os.path.exists(
         os.path.join(BENCH, "layers", m["name"] + ".py")) for m in mine)
     with open(os.path.join(BENCH, "configs", "criteo-share.json")) as fh:

@@ -64,14 +64,7 @@ def test_trace_spans_properly_nested(traced_run):
     thread: every iteration inside train, every tree_growth inside an
     iteration (context-manager discipline must survive export)."""
     _, trace_path, _ = traced_run
-    with open(trace_path) as f:
-        spans = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
-
-    def covers(outer, inner):
-        return (outer["ts"] <= inner["ts"] + 1e-3
-                and outer["ts"] + outer["dur"]
-                >= inner["ts"] + inner["dur"] - 1e-3)
-
+    spans, covers = _spans_of(trace_path), _inside
     train_spans = [e for e in spans if e["name"] == "train"]
     iters = [e for e in spans if e["name"] == "iteration"]
     grows = [e for e in spans if e["name"] == "tree_growth"]
@@ -83,6 +76,100 @@ def test_trace_spans_properly_nested(traced_run):
     for g in grows:
         assert any(covers(it, g) for it in iters), \
             "tree_growth span not nested in any iteration span"
+
+
+def _spans_of(trace_path):
+    with open(trace_path) as f:
+        return [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
+
+
+def _inside(outer, inner):
+    return (outer["ts"] <= inner["ts"] + 1e-3
+            and outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"] - 1e-3)
+
+
+def test_place_spans_count_the_arrays_bytes(traced_run):
+    """Every placement of ``GBDT._place_rows`` / ``_place_whole`` is a
+    span ``place`` inside ``booster_init`` whose ``bytes`` are the
+    array's ``nbytes``."""
+    bst, trace_path, _ = traced_run
+    spans = _spans_of(trace_path)
+    (init,) = [e for e in spans if e["name"] == "booster_init"]
+    places = [e for e in spans if e["name"] == "place"]
+    assert places and all(_inside(init, e) for e in places)
+    gb = bst._gbdt
+    n, k = gb.train_set.num_data, gb.num_tree_per_iteration
+    by_what = {}
+    for e in places:
+        by_what.setdefault(e["args"]["what"], []).append(e["args"]["bytes"])
+    # rows: the scores and the objective's row arrays (a serial learner
+    # takes the bins without a placement); whole: a valid set's scores
+    # and its bins
+    assert n * k * 4 in by_what["rows"]
+    (valid,) = gb.valid_sets
+    scores, bins = sorted(by_what["whole"], key=lambda b: b != valid.bins.nbytes,
+                          reverse=True)
+    assert bins == valid.bins.nbytes
+    assert scores in (valid.num_data * k * 4, valid.num_data * k * 8)
+    assert all(e["args"]["bytes"] > 0 for e in places)
+
+
+def test_fused_operands_is_a_span_of_train_fused(tmp_path):
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(1200, 5))
+    y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
+    out = str(tmp_path / "fused.json")
+    p = {"objective": "binary", "metric": ["auc"], "num_leaves": 7,
+         "min_data_in_leaf": 5, "verbose": -1, "tpu_split_batch": 4,
+         "use_quantized_grad": True, "quant_train_renew_leaf": True,
+         "trace_output": out}
+    ds = lgb.Dataset(X[:1000], label=y[:1000], params=p)
+    bst = lgb.train(p, ds, num_boost_round=8,
+                    valid_sets=[ds.create_valid(X[1000:], label=y[1000:])],
+                    callbacks=[lgb.record_evaluation({})])
+    assert bst._gbdt.metrics.counter("fused_rounds") == 8
+    spans = _spans_of(out)
+    (fused,) = [e for e in spans if e["name"] == "train_fused"]
+    (operands,) = [e for e in spans if e["name"] == "fused_operands"]
+    scans = [e for e in spans if e["name"] == "fused_round_scan"]
+    assert _inside(fused, operands)
+    assert operands["ts"] + operands["dur"] <= scans[0]["ts"] + 1e-3
+
+
+def test_dense_construct_stages_are_spans_and_always_armed_counters():
+    """The dense ``Dataset.construct`` in two stages, each a span
+    inside ``construct`` and a seconds counter that is bumped with
+    nothing switched on; together they are the call, within 5%."""
+    from lightgbm_tpu.utils.timer import phase
+    names = {"dense_bin_mappers": "construct_bin_mappers_s",
+             "dense_bin_matrix": "construct_bin_matrix_s"}
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(200_000, 12))
+    y = (X[:, 0] > 0).astype(np.float64)
+    assert trace.active() is None and not global_timer.enabled
+    before = {c: global_metrics.counter(c) for c in names.values()}
+    import time
+    t0 = time.perf_counter()
+    ds = lgb.Dataset(X, label=y, params={"verbose": -1}).construct()
+    took = time.perf_counter() - t0
+    delta = {c: global_metrics.counter(c) - before[c] for c in names.values()}
+    assert all(v > 0.0 for v in delta.values()), delta
+    assert sum(delta.values()) <= took
+    assert sum(delta.values()) >= 0.95 * took, (delta, took)
+
+    rec = trace.start()
+    try:
+        ds.create_valid(X[:50_000], label=y[:50_000]).construct()
+    finally:
+        trace.stop(rec)
+    spans = [e for e in rec.to_dict()["traceEvents"] if e.get("ph") == "X"]
+    (outer,) = [e for e in spans if e["name"] == "construct"]
+    got = {e["name"]: e for e in spans if e["name"] in names}
+    # a valid set takes the training set's mappers: no bin finder
+    assert set(got) == {"dense_bin_matrix"}
+    assert all(_inside(outer, e) for e in got.values())
+    assert sum(e["dur"] for e in got.values()) >= 0.95 * outer["dur"]
+    assert phase("anything")._seconds == ""
 
 
 def test_telemetry_jsonl_one_record_per_iteration(traced_run):
@@ -152,6 +239,34 @@ def test_disabled_mode_emits_no_files(tmp_path, synthetic_binary):
         assert list(tmp_path.iterdir()) == []
     finally:
         os.chdir(cwd)
+
+
+def test_a_model_trained_with_everything_on_is_bit_identical(tmp_path,
+                                                             synthetic_binary):
+    """``trace_output``, ``profile_dir`` and ``verbosity=2`` observe a
+    job and change nothing of it: the model text is the one a job with
+    none of them writes."""
+    X, y = synthetic_binary
+    base = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+            "verbose": -1, "metric": ["auc"]}
+
+    def model(extra):
+        p = {**base, **extra}
+        ds = lgb.Dataset(X[:400], label=y[:400], params=p)
+        bst = lgb.train(p, ds, num_boost_round=4,
+                        valid_sets=[ds.create_valid(X[400:], label=y[400:])])
+        text = bst.model_to_string()
+        # the parameters block names the output paths; the trees do not
+        return text[:text.index("parameters:")] if "parameters:" in text \
+            else text, bst.predict(X[:50])
+
+    quiet_text, quiet_pred = model({})
+    loud_text, loud_pred = model({
+        "trace_output": str(tmp_path / "t.json"),
+        "profile_dir": str(tmp_path / "prof"), "verbosity": 2})
+    assert (tmp_path / "t.json").exists()
+    assert loud_text == quiet_text
+    assert np.array_equal(loud_pred, quiet_pred)
 
 
 def test_per_booster_timer_isolation(synthetic_binary):

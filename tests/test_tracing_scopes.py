@@ -391,7 +391,9 @@ def _span_counts(tmp_path, n, tag):
     _train(42, n=n, seed=17, trace_output=str(out))
     counts = {}
     for e in json.loads(out.read_text())["traceEvents"]:
-        if e.get("ph") == "X":
+        # the compile stages' spans (``jit_*``, obs/compile_events.py)
+        # follow the programs a process has yet to compile, not the job
+        if e.get("ph") == "X" and not e["name"].startswith("jit_"):
             counts[e["name"]] = counts.get(e["name"], 0) + 1
     return counts
 
@@ -402,10 +404,16 @@ def test_span_count_follows_dispatches_and_trees_not_rows(tmp_path):
     assert small == large
     dispatches, trees = 2, 42
     # the train and the valid Dataset construct inside the job's span
-    # and one valid set's bins are placed and mirrored in ``booster_init``
-    assert small == {"train": 1, "booster_init": 1, "valid_mirror": 1,
-                     "train_fused": 1,
-                     "construct": 2, "fused_prepare": dispatches,
+    # (a valid set takes the training set's bin mappers) and one valid
+    # set's bins are placed and mirrored in ``booster_init``; a serial
+    # booster places its scores and the objective's row array, a valid
+    # set's scores and bins
+    assert small == {"train": 1, "booster_init": 1, "objective_init": 1,
+                     "place": 4, "valid_mirror": 1,
+                     "train_fused": 1, "fused_operands": 1,
+                     "construct": 2,
+                     "dense_bin_mappers": 1, "dense_bin_matrix": 2,
+                     "fused_prepare": dispatches,
                      "fused_round_scan": dispatches,
                      "fused_chunk_transfer": dispatches,
                      "dispatch_done": dispatches,

@@ -57,6 +57,9 @@ _COMPILE_COUNTERS = (
     "rank_compile_hits", "rank_compile_misses",
 )
 
+#: rows of the compile table the "compile" column prints
+_TABLE_ROWS = 8
+
 #: final-snapshot gauges surfaced as the "rank" join column (query
 #: bucketing geometry: padded-row overhead and ladder width)
 _RANK_GAUGES = ("rank_pad_rows", "rank_bucket_count")
@@ -273,12 +276,16 @@ def telemetry_stats(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Final-state summary of the per-round telemetry stream."""
     counters: Dict[str, Any] = {}
     gauges: Dict[str, Any] = {}
+    compile_table: List[Dict[str, Any]] = []
     iters = []
     for row in rows:
         if isinstance(row.get("counters"), dict):
             counters = row["counters"]
         if isinstance(row.get("gauges"), dict):
             gauges = row["gauges"]
+        if isinstance(row.get("compile_table"), list):
+            # a record carries the table only after it has grown
+            compile_table = row["compile_table"]
         it = row.get("iteration")
         if isinstance(it, (int, float)):
             iters.append(int(it))
@@ -290,6 +297,7 @@ def telemetry_stats(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
         "gauges": gauges,
         "compile": {k: counters[k] for k in _COMPILE_COUNTERS
                     if k in counters},
+        "compile_table": compile_table,
         "rank": {k: gauges[k] for k in _RANK_GAUGES if k in gauges},
         "watchtower": {k: counters[k] for k in _WATCHTOWER_COUNTERS
                        if k in counters},
@@ -458,6 +466,18 @@ def _render_report(payload: Dict[str, Any]) -> str:
                 lines.append(f"  {section}:")
                 for k in sorted(vals):
                     lines.append(f"    {k}: {vals[k]}")
+            table = tel.get("compile_table") if section == "compile" \
+                else None
+            if table:
+                # which programs: the process's compile table, largest
+                # first (obs/compile_events.py ``table()``)
+                lines.append("  compile table (seconds, count, stage, "
+                             "program, [span > innermost span]):")
+                for r in table[:_TABLE_ROWS]:
+                    lines.append(
+                        f"    {r['seconds']:9.3f}s x{r['count']:<3d} "
+                        f"{r['stage']:<10s} {r['program']}  "
+                        f"[{r['span']} > {r['inside']}]")
     if not payload["findings"]:
         lines.append("")
         lines.append("run artifacts healthy")
