@@ -34,7 +34,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
+import re
 import sys
 import tempfile
 import time
@@ -135,6 +137,23 @@ def _require_kernel(text: str, kernel: str, scope: str, what: str) -> None:
     under the device scope ``scope``."""
     _require(any("tpu_custom_call" in line and f"%{kernel}" in line
                  and scope in line for line in text.splitlines()), what)
+
+
+def _require_partition_kernel(text: str, where: str) -> None:
+    """Rows move by the fused partition kernel, which reads the resident
+    transposed bins as they lie: a ``pad`` under the scope is a copy of
+    the whole bin matrix every round pass (21-49 ms a round in the
+    benchmark's cells until PR 41)."""
+    _require_kernel(text, "partition_select_pallas", "partition",
+                    f"the partition is not the fused "
+                    f"partition_select_pallas kernel in {where}")
+    pads = [re.search(r"= \w+\[([\d,]+)\]", line)
+            for line in text.splitlines()
+            if " pad(" in line and "partition/" in line]
+    # (gathers of the K split descriptors leave pads of a few words)
+    big = [m.group(0) for m in pads
+           if m and math.prod(int(d) for d in m.group(1).split(",")) >= 1 << 16]
+    _require(not big, f"a pad of {big} under the scope partition in {where}")
 
 
 def _require_compaction_kernel(text: str, where: str) -> None:
@@ -265,6 +284,7 @@ def phase_train(args, lgb, data):
         _require_kernel(text, "_sum_pallas", "leaf_renew",
                         "leaf renewal's sums are not the _sum_pallas kernel "
                         "in the compiled round program")
+        _require_partition_kernel(text, "the compiled round program")
         _require_compaction_kernel(text, "the compiled round program")
         off = [n for n, a in _device_arrays(gb)
                if {d.platform for d in a.devices()} != {"tpu"}]
@@ -366,9 +386,7 @@ def phase_bundled(args, lgb):
         _require("bundle_search" in text,
                  "no operation under the scope bundle_search in the "
                  "compiled round program")
-        _require_kernel(text, "partition_select_pallas", "partition",
-                        "the bundled job's partition is not the fused "
-                        "partition_select_pallas kernel")
+        _require_partition_kernel(text, "the bundled round program")
         _require_compaction_kernel(text, "the bundled round program")
     _emit("bundled", t0, rows=rows, features=X.shape[1], bundles=bundles,
           iters=args.iters, bundle_space_search_rounds=args.iters,
@@ -445,6 +463,7 @@ def phase_ranking(args, lgb):
         missing = [s for s in RANK_SCOPES if s not in text]
         _require(not missing, f"no operation under the scopes {missing} in "
                               "the compiled round program")
+        _require_partition_kernel(text, "the ranking round program")
         _require_compaction_kernel(text, "the ranking round program")
     _emit("ranking", t0, rows=rows, queries=len(sizes), buckets=buckets,
           iters=args.iters, tpu_custom_calls=calls,
@@ -570,6 +589,7 @@ def phase_four_chips(args, lgb, data):
              "no operation under the scope hist_allreduce in the sharded "
              "round program")
     if jax.devices()[0].platform == "tpu":
+        _require_partition_kernel(text, "the sharded round program")
         _require_compaction_kernel(text, "the sharded round program")
     auc_d, auc_s = runs["data"]["auc"], runs["serial"]["auc"]
     _require(abs(auc_d - auc_s) < 5e-3,

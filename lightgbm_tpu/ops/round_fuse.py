@@ -115,13 +115,11 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
     """
     num_f, n = bins_t.shape
     K = cols.shape[0]
+    # no operand is padded to the block: every output lane depends on the
+    # SAME lane of the row inputs only (the one contraction runs over F, the
+    # sums over K), so whatever the last block's lanes past n hold stays in
+    # lanes the write-back drops
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
-    n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
-        lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-        mask = jnp.pad(mask, (0, n_pad - n))
-    nb = n_pad // blk
 
     a1, n1, a2, n2 = xor_ranges(lo, hi, pos, default_left, miss)
 
@@ -167,16 +165,16 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
     k_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
     out_lor, out_key = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=(pl.cdiv(n, blk),),
         in_specs=[pl.BlockSpec((num_f, blk), lambda i: (0, i)),
                   row_spec, row_spec,
                   k_spec, k_spec, k_spec, k_spec, k_spec, k_spec, k_spec,
                   k_spec, k_spec],
         out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
+                   jax.ShapeDtypeStruct((1, n), jnp.int32)],
         interpret=interpret,
     )(bins_t, lor[None, :], mask[None, :], cols[None, :], a1[None, :],
       n1[None, :], a2[None, :], n2[None, :], parents[None, :],
       new_leaves[None, :], validk[None, :], smaller[None, :])
-    return out_lor[0, :n], out_key[0, :n]
+    return out_lor[0], out_key[0]

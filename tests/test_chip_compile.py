@@ -265,10 +265,20 @@ def _partition(bins_t, lor, mask, *per_slot):
         bins_t, lor, mask, *per_slot, rows_per_block=2048)
 
 
-def test_partition_kernel_compiles(one_chip):
-    c = _compile(one_chip, _partition, ((F, N), jnp.uint8),
-                 ((N,), jnp.int32), ((N,), jnp.int32), *K_ARGS)
+@pytest.mark.parametrize("f,n", [(F, N), (67, CRITEO_SHARE_ROWS),
+                                 (220, 7_325_625)],
+                         ids=["higgs", "criteo_share", "istella"])
+def test_partition_kernel_compiles(one_chip, f, n):
+    """At the cells' own shapes, whose row counts no block divides, the
+    kernel reads the resident bins as they lie: its last block is ragged,
+    so the program pads nothing and holds no copy of the bin matrix."""
+    c = _compile(one_chip, _partition, ((f, n), jnp.uint8),
+                 ((n,), jnp.int32), ((n,), jnp.int32), *K_ARGS)
     _assert_kernel(c, "partition_select_pallas")
+    u8_pads = [line for line in c.as_text().splitlines()
+               if " pad(" in line and "u8[" in line]
+    assert not u8_pads, u8_pads
+    assert c.memory_analysis().temp_size_in_bytes < f * n
 
 
 def test_take_small_table_kernel_compiles(one_chip, on_tpu):

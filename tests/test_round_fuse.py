@@ -318,13 +318,16 @@ def test_bins_to_words_roundtrip():
     np.testing.assert_array_equal(np.asarray(back), np.asarray(bins))
 
 
-def test_partition_kernel_matches_xla():
+@pytest.mark.parametrize("n", [2048, 3000, 1537, 300])
+def test_partition_kernel_matches_xla(n):
     """Four slots: a missing bin that is NOT the last (the zero bin, as
     ``zero_as_missing`` puts it) going left, no missing bin, a missing bin
     that is the last going left past the threshold, and a disabled slot
-    whose parent has rows."""
+    whose parent has rows.  Over the tail of the last block of 512 rows,
+    which the kernel's grid reads ragged from the unpadded operands: none,
+    440 rows, ONE row, and a row count below one block."""
     rng = np.random.default_rng(3)
-    n, f, K = 3000, 7, 4
+    f, K = 7, 4
     bins = rng.integers(0, 32, size=(n, f)).astype(np.uint8)
     lor = rng.integers(0, 5, size=n).astype(np.int32)
     mask = rng.integers(0, 2, size=n).astype(np.int32)
