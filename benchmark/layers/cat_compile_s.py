@@ -1,0 +1,9 @@
+"""``compile_s`` in a categorical job (the cell ``allstate-cat-train``):
+backend compile seconds. The reader is ``layers/compile_s.py``'s, which
+says what is read and from where; an accepted metric's list of cells is
+not a new cell's to extend, so the cell reports it under a name of its
+own."""
+
+from harness import load_module
+
+read = load_module("layers", "compile_s").read

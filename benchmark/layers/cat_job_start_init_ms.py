@@ -1,0 +1,10 @@
+"""``job_start_init_ms`` in a categorical job (the cell
+``allstate-cat-train``): a job's start from its open to the open of
+``train_fused``, less the placements. The reader is
+``layers/job_start_init_ms.py``'s, which says what is read and from
+where; an accepted metric's list of cells is not a new cell's to extend,
+so the cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "job_start_init_ms").read

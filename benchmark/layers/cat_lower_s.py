@@ -1,0 +1,9 @@
+"""``lower_s`` in a categorical job (the cell ``allstate-cat-train``):
+tracing and lowering, by the program's counters. The reader is
+``layers/lower_s.py``'s, which says what is read and from where; an
+accepted metric's list of cells is not a new cell's to extend, so the
+cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "lower_s").read

@@ -1,0 +1,10 @@
+"""``program_backend_compile_s`` in a categorical job (the cell
+``allstate-cat-train``): seconds of backend compilation under the
+program's own spans (the compile table's stage ``backend_compile``). The
+reader is ``layers/program_backend_compile_s.py``'s, which says what is
+read and from where; an accepted metric's list of cells is not a new
+cell's to extend, so the cell reports it under a name of its own."""
+
+from harness import load_module
+
+read = load_module("layers", "program_backend_compile_s").read

@@ -7,7 +7,8 @@ reduction (``--rows``, default 1,000,000 of the published 10.5M) and the
 weights are whatever ``--iters`` rounds learn from ``--seed``:
 
     device -> train (fused scan + valid set) -> bundled (CSR in, EFB)
-           -> predict -> save/load -> serve
+           -> categorical (a 600-level column, splits by sets of bins)
+           -> ranking -> predict -> save/load -> serve
 
 One process, no children that touch JAX, JAX imported once.  Every phase
 prints one JSON line as it finishes and raises on any failure, so the
@@ -395,6 +396,94 @@ def phase_bundled(args, lgb):
           smoke_train_s=round(secs, 2))
 
 
+def _claims(rows: int, seed: int):
+    """Four numeric columns and three categorical ones: 600 levels
+    (zipf-distributed, codes permuted), 3 and 20."""
+    rng = np.random.default_rng([seed, 43])
+    p = 1.0 / np.arange(1, 601) ** 1.1
+    level = rng.choice(600, size=rows, p=p / p.sum())
+    small, mid = rng.integers(0, 3, rows), rng.integers(0, 20, rows)
+    x = rng.standard_normal((rows, 4))
+    z = x[:, 0] + rng.normal(size=600)[level] + 0.5 * (small == 1) \
+        + 0.3 * np.sin(mid)
+    y = (z + rng.standard_normal(rows) > 0).astype(np.float32)
+    X = np.column_stack([x, rng.permutation(600)[level], small, mid])
+    return X.astype(np.float64), y
+
+
+def phase_categorical(args, lgb):
+    """A short job with categorical columns, one of 600 levels (more than
+    a column has bins for): the partition by sets of bins in the fused
+    kernel (``fused_partition_declined`` 0), the split search's scopes
+    ``cat_subset`` and ``cat_bitset`` by name, and the training scores
+    the job holds equal to what the model it returns says of the raw
+    training rows (a level without a bin goes right in both).  Then
+    eight rounds with the 3-level column alone categorical: a job whose
+    categorical columns are all one-hot trains on the same path."""
+    import jax
+
+    t0 = time.time()
+    rows = args.rows
+    X, y = _claims(rows + args.valid_rows, args.seed)
+    ds = lgb.Dataset(X[:rows], label=y[:rows], params=PARAMS,
+                     categorical_feature=[4, 5, 6]).construct()
+    dv = ds.create_valid(X[rows:], label=y[rows:])
+    bst, auc, secs = _train(lgb, PARAMS, ds, dv, args.iters)
+    gb = bst._gbdt
+    _require(_took_fused_path(bst, args.iters), "fused path not taken")
+    counted = {c: int(gb.metrics.counter(c)) for c in (
+        "cat_features", "cat_subset_features", "cat_other_rows", "cat_splits",
+        "cat_subset_splits", "fused_partition_declined")}
+    _require((counted["cat_features"], counted["cat_subset_features"])
+             == (3, 2), f"categorical columns counted {counted}")
+    _require(counted["cat_other_rows"] > 0 and counted["cat_subset_splits"] > 0,
+             f"no row in an other bin or no split by a set: {counted}")
+    _check_auc(auc, args.iters)
+    held = np.asarray(gb.scores)[:, 0]
+    said = bst.predict(X[:rows], raw_score=True)
+    gap = float(np.abs(held - said).max())
+    _require(gap < 1e-4, f"the training scores are not the returned "
+                         f"model's: worst row {gap}")
+    calls = None
+    if jax.devices()[0].platform == "tpu":
+        _require(counted["fused_partition_declined"] == 0,
+                 f"{counted['fused_partition_declined']} rounds' partition "
+                 "took the XLA path")
+        text = _fused_program_text(gb)
+        calls = text.count("tpu_custom_call")
+        for scope in ("cat_subset", "cat_bitset"):
+            _require(scope in text, f"no operation under the scope {scope} "
+                                    "in the compiled round program")
+        _require_partition_kernel(text, "the categorical round program")
+    # the same rows with the 3-level column alone handed over: every
+    # categorical column of the job is one-hot, the list of subset columns
+    # is empty and the round program traces no scan
+    flag = lgb.Dataset(X[:rows], label=y[:rows], params=PARAMS,
+                       categorical_feature=[5]).construct()
+    bst1, _, _ = _train(lgb, PARAMS, flag,
+                        flag.create_valid(X[rows:], label=y[rows:]), 8)
+    gb1 = bst1._gbdt
+    _require(_took_fused_path(bst1, 8) and gb1.hp.cat_subset_cols == (),
+             f"the one-hot-only job: fused {_took_fused_path(bst1, 8)}, "
+             f"subset columns {gb1.hp.cat_subset_cols}")
+    flag_splits = int(gb1.metrics.counter("cat_splits"))
+    flag_gap = float(np.abs(np.asarray(gb1.scores)[:, 0] - bst1.predict(
+        X[:rows], raw_score=True)).max())
+    _require(flag_splits > 0 and flag_gap < 1e-4
+             and gb1.metrics.counter("cat_subset_splits") == 0,
+             f"the one-hot-only job: {flag_splits} categorical splits, "
+             f"worst row {flag_gap}")
+    if jax.devices()[0].platform == "tpu":
+        _require(gb1.metrics.counter("fused_partition_declined") == 0,
+                 "the one-hot-only job's partition took the XLA path")
+    _emit("categorical", t0, rows=rows, iters=args.iters, **counted,
+          train_score_gap=gap, tpu_custom_calls=calls,
+          onehot_only_cat_splits=flag_splits,
+          onehot_only_train_score_gap=flag_gap,
+          valid_auc_first=auc[0], valid_auc_last=auc[-1],
+          smoke_train_s=round(secs, 2))
+
+
 RANK_SCOPES = ("rank_gather", "rank_sort", "rank_pairs", "rank_accumulate",
                "ndcg_sort")
 
@@ -638,6 +727,7 @@ def main(argv=None) -> int:
     else:
         bst = phase_train(args, lgb, data)
         phase_bundled(args, lgb)
+        phase_categorical(args, lgb)
         phase_ranking(args, lgb)
         phase_predict(args, bst, data[0])
         phase_save_load(lgb, bst, data[0])

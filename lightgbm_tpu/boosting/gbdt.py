@@ -296,6 +296,15 @@ class GBDT:
             # them when the Dataset was constructed (io/dataset.py)
             self.metrics.inc("efb_bundles", train_set.bundle_plan.num_bundles)
             self.metrics.inc("efb_features", self.num_features)
+        if bool(train_set.categorical_array().any()):
+            # as above: the process-wide registry counted them when the
+            # Dataset was constructed
+            counts = train_set.categorical_counts()
+            self.metrics.inc("cat_features", counts["cat_features"])
+            self.metrics.inc("cat_subset_features",
+                             counts["cat_subset_features"])
+            self.metrics.inc("cat_levels_kept", counts["cat_levels_kept"])
+            self.metrics.inc("cat_other_rows", counts["cat_other_rows"])
 
         # distributed tree learner over all visible devices
         # (reference tree_learner=serial/data/feature/voting,
@@ -437,6 +446,15 @@ class GBDT:
         if fin["rows"]:
             self._count("hist_rows_selected", fin["rows"])
             counts["hist_rows_selected"] = fin["rows"]
+        if self.hp.has_categorical:
+            self._count("cat_splits", fin["cat_splits"])
+            self._count("cat_subset_splits", fin["cat_subset_splits"])
+            self._count("cat_left_levels", fin["cat_left_levels"])
+            counts.update({k: fin[k] for k in (
+                "splits", "cat_splits", "cat_subset_splits",
+                "cat_left_levels")})
+        if fin["declined"]:
+            self._count("fused_partition_declined", fin["declined"])
         with self._phase("dispatch_done", **counts):
             pass
 
@@ -646,7 +664,13 @@ class GBDT:
         self._resolve_auto_params(config)
         self.hp = _hp_from_config(config, train_set.device_n_bins())
         if bool(train_set.categorical_array().any()):
-            self.hp = dataclasses.replace(self.hp, has_categorical=True)
+            # what is static at trace time about the categorical columns:
+            # that there are some, and which take the subset scan (not
+            # known to a feature-parallel shard, which holds a slice)
+            self.hp = dataclasses.replace(
+                self.hp, has_categorical=True,
+                cat_subset_cols=None if self.parallel_mode == "feature"
+                else train_set.cat_subset_columns())
 
         # monotone constraints: per-ORIGINAL-feature directions from config,
         # remapped to packed (used) features; categorical features forced 0
@@ -1007,15 +1031,14 @@ class GBDT:
     def _matmul_valid_ok(self) -> bool:
         """True when per-tree valid scoring can take the matmul
         path-aggregation (models/predict.py predict_bins_tree_matmul)
-        instead of the frontier walk: numeric non-linear models whose
-        splits are range predicates on the physical column (unbundled, or
-        an EFB plan with ranges; learner/grower.py ``split_ranges``) —
-        categorical bitsets and the inverse table of a plan without
-        ranges are per-row lookups the matmul formulation has no cheap
-        equivalent for, and linear leaves score through their own
+        instead of the frontier walk: non-linear models whose splits
+        are range predicates on the physical column (unbundled, or an EFB
+        plan with ranges; learner/grower.py ``split_ranges``) or sets of
+        its bins (categorical columns) — the inverse table of a plan
+        without ranges is a per-row lookup the matmul formulation has no
+        cheap equivalent for, and linear leaves score through their own
         raw-feature path."""
-        return (not self.hp.has_categorical
-                and (self.bundle is None or self.bundle.search is not None)
+        return ((self.bundle is None or self.bundle.search is not None)
                 and not self.linear)
 
     def _valid_tree_scores(self, arrays: TreeArrays, vi: int) -> jax.Array:
@@ -1027,7 +1050,8 @@ class GBDT:
             from ..models.predict import predict_bins_tree_matmul
             return predict_bins_tree_matmul(
                 arrays, self._valid_bins_t[vi], self.nan_bin_arr,
-                self.bundle, n_bins=self.hp.n_bins)
+                self.bundle, n_bins=self.hp.n_bins,
+                has_categorical=self.hp.has_categorical)
         return predict_bins_tree(arrays, self._valid_bins[vi],
                                  self.nan_bin_arr, self.bundle,
                                  self.hp.has_categorical)
@@ -1485,7 +1509,8 @@ class GBDT:
                     from ..models.predict import predict_bins_tree_matmul
                     return predict_bins_tree_matmul(
                         arrays_s, valid_bins_t[vi], self.nan_bin_arr,
-                        self.bundle, n_bins=self.hp.n_bins)
+                        self.bundle, n_bins=self.hp.n_bins,
+                        has_categorical=self.hp.has_categorical)
 
                 def round_real(carry, qkey_raw, node_keys, fm, it):
                     sc, vsc, es = carry
@@ -1744,7 +1769,15 @@ class GBDT:
                 mhost = np.asarray(jax.device_get(mvals)) \
                     if nvalid else None
             # what this dispatch finalized, for its closing span
-            fin = {"rounds": 0, "trees": 0, "rows": 0}
+            fin = {"rounds": 0, "trees": 0, "rows": 0, "splits": 0,
+                   "cat_splits": 0, "cat_subset_splits": 0,
+                   "cat_left_levels": 0, "declined": 0}
+            from ..learner.batch_grower import fuses_partition
+            declined = self._use_batched_grower() and \
+                not fuses_partition(self.bundle)
+            if self.hp.has_categorical:
+                subset_col = np.zeros(self.num_features, bool)
+                subset_col[list(self.train_set.cat_subset_columns())] = True
             try:
                 for t in range(T):
                     stumps = 0
@@ -1755,6 +1788,16 @@ class GBDT:
                         fin["trees"] += 1
                         if count_rows:
                             fin["rows"] += _hist_rows_selected(arrays_tc, n_rows)
+                        if self.hp.has_categorical:
+                            ni = int(arrays_tc.num_leaves) - 1
+                            cat = np.asarray(arrays_tc.split_cat[:ni], bool)
+                            by_set = cat & subset_col[np.asarray(
+                                arrays_tc.split_feature[:ni])]
+                            fin["splits"] += ni
+                            fin["cat_splits"] += int(cat.sum())
+                            fin["cat_subset_splits"] += int(by_set.sum())
+                            fin["cat_left_levels"] += int(np.asarray(
+                                arrays_tc.cat_bitset[:ni])[by_set].sum())
                         tree.apply_shrinkage(self.shrinkage_rate)
                         if self.iter_ == 0 and \
                                 abs(self.init_scores[cls]) > 1e-10:
@@ -1767,6 +1810,7 @@ class GBDT:
                     fin["rounds"] += 1
                     self._count("iterations")
                     self._count("fused_rounds")
+                    fin["declined"] += int(declined)
                     if self._bundle_space:
                         self._count("bundle_space_search_rounds")
                     self._count("trees_grown", k)

@@ -14,7 +14,25 @@ packed device bin tensor.  Semantics preserved from the reference:
   * missing handling (bin.h:27 ``MissingType``): None / Zero (zero bin doubles
     as the missing bin) / NaN (dedicated last bin);
   * categorical bins ordered by descending frequency (bin.cpp categorical
-    branch), ``bin_2_categorical`` kept for model serialization;
+    branch), ``bin_2_categorical`` kept for model serialization.  The
+    ``max_bin - 1`` most frequent levels get a bin each, bins
+    ``0..k-1``.  A column whose levels do NOT all get a bin (more levels
+    than that, or negative / NaN values among the sampled rows) keeps ONE
+    more bin for every row that has none, the OTHER bin: the LAST one,
+    ``num_bin - 1 = k`` (``BinMapper.other_bin``; upstream keeps bin 0 for
+    them and counts the kept levels from 1).  It stands where a numeric
+    column's NaN bin stands (``nan_bin``), no level of
+    ``bin_2_categorical`` maps to it, and no categorical split's left set
+    ever holds it (ops/split.py), so such a row goes RIGHT in the training
+    partition, in valid scoring and in the stated model alike, whose sets
+    are over raw codes (a code in no set goes right).  A column whose
+    levels all fit has no such bin and keeps bins ``0..k-1`` alone; there
+    a level first seen after binning still folds into bin 0, the most
+    frequent level's.  That holds for a level of the TRAINING rows too
+    that the binning sample (``bin_construct_sample_cnt`` rows) never
+    met: its rows go with bin 0 in the training partition and right in
+    the stated model, so the guarantee above is the column's with an
+    other bin, and a fully sampled column's;
   * trivial features (num_bin <= 1) are flagged so the Dataset can drop them
     (reference ``feature_pre_filter``, dataset.cpp).
 
@@ -272,25 +290,30 @@ class BinMapper:
                               max_bin: int, na_cnt: int) -> None:
         self.bin_type = BIN_CATEGORICAL
         ivals = dv.astype(np.int64)
+        dropped = False
         if (ivals[dcnts > 0] < 0).any():
             log.warning("Met negative value in categorical features, will convert "
                         "it to NaN")
             keep = ivals >= 0
             ivals, dcnts = ivals[keep], dcnts[keep]
+            dropped = True
         # distinct floats can collapse onto one int code: re-aggregate
         cats, inv = np.unique(ivals, return_inverse=True)
         counts = np.bincount(inv, weights=dcnts.astype(np.float64),
                              minlength=len(cats)).astype(np.int64)
         order = np.argsort(-counts, kind="stable")
         cats, counts = cats[order], counts[order]
-        # cap at max_bin - 1; rare categories collapse into bin 0
+        # cap at max_bin - 1 levels; where a row can be without a level's
+        # bin (a rarer level, a negative code, NaN) the column keeps the
+        # OTHER bin for it, the last one (module docstring)
         keep = min(len(cats), max_bin - 1)
+        unbinned = keep < len(cats) or dropped or na_cnt > 0
         cats = cats[:keep]
         self.bin_2_categorical = [int(c) for c in cats]
         self._cat_2_bin = {int(c): i for i, c in enumerate(cats)}
-        self.num_bin = max(1, len(cats))
-        # categorical NaN folds into bin 0 (most frequent category) so the
-        # device path stays pure one-hot — no missing-bin default routing
+        self.num_bin = max(1, len(cats)) + (1 if unbinned and keep else 0)
+        # no missing-bin default routing: a row in the other bin goes
+        # right because no left set holds that bin
         self.missing_type = MISSING_NONE
         self.default_bin = 0
 
@@ -301,12 +324,22 @@ class BinMapper:
         return self.num_bin <= 1
 
     @property
+    def other_bin(self) -> int:
+        """A categorical column's bin for rows whose value has no bin of
+        its own (a level beyond the ``max_bin - 1`` kept, a negative
+        code, NaN): the last bin, or -1 where every level got a bin."""
+        if self.bin_type == BIN_CATEGORICAL \
+                and 0 < len(self.bin_2_categorical) < self.num_bin:
+            return self.num_bin - 1
+        return -1
+
+    @property
     def nan_bin(self) -> int:
         """Bin index holding missing values, or -1 when missing maps nowhere.
-        Categorical features always return -1: NaN folds into bin 0 and the
-        device partition stays pure one-hot."""
+        A categorical column's is its OTHER bin (``other_bin``), which no
+        left set holds; without one NaN folds into bin 0."""
         if self.bin_type == BIN_CATEGORICAL:
-            return -1
+            return self.other_bin
         if self.missing_type == MISSING_NAN:
             return self.num_bin - 1
         if self.missing_type == MISSING_ZERO:
@@ -329,7 +362,8 @@ class BinMapper:
             except ImportError:
                 pass
         if self.bin_type == BIN_CATEGORICAL:
-            return self._cat_values_to_bins(values, 0, 0)
+            fill = max(self.other_bin, 0)
+            return self._cat_values_to_bins(values, fill, fill)
         isnan = np.isnan(values)
         if self.missing_type == MISSING_ZERO:
             values = np.where(isnan, 0.0, values)
@@ -345,7 +379,8 @@ class BinMapper:
     def _cat_values_to_bins(self, values: np.ndarray, unseen_bin: int,
                             nan_bin_out: int) -> np.ndarray:
         """THE categorical raw->bin lookup, shared by training binning
-        (``values_to_bins``: unseen/NaN fold to bin 0) and the bitset
+        (``values_to_bins``: unseen/NaN take the other bin, bin 0 where
+        the column has none) and the bitset
         predictor (``values_to_bins_pred``: dedicated sentinel bins).
         int64 truncation matches the host walk's ``int(v)`` coercion;
         negative codes never match a category and take the unseen fill."""

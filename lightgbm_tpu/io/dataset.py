@@ -16,7 +16,8 @@ the mapper list so model I/O refers to original feature indices (reference
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Union
+import contextlib
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -147,6 +148,8 @@ class Dataset:
         # persisted through save_binary so audits can tell a streamed
         # build from an in-memory one
         self.ingest_provenance: Optional[Dict[str, Any]] = None
+        # what the categorical columns hold (``categorical_counts``)
+        self.cat_counts: Optional[Dict[str, int]] = None
 
     # ------------------------------------------------------------ properties
     @property
@@ -306,6 +309,7 @@ class Dataset:
         with phase("dense_bin_matrix", global_timer,
                    seconds="construct_bin_matrix_s"):
             ds._bin_all(arr)
+        ds._count_categorical()
         if bool(cfg.enable_bundle) and cfg.tree_learner not in (
                 "feature", "feature_parallel"):
             # cap bundle width at the pre-EFB histogram width so EFB can
@@ -486,13 +490,15 @@ class Dataset:
         cat_set = set(cat_idx)
         for j in range(f):
             fmax = mbf[j] if j < len(mbf) and mbf[j] > 1 else max_bin
-            m = BinMapper.find_bin(
-                sample[:, j], total_sample_cnt=len(sample), max_bin=int(fmax),
-                min_data_in_bin=int(cfg.min_data_in_bin),
-                use_missing=bool(cfg.use_missing),
-                zero_as_missing=bool(cfg.zero_as_missing),
-                is_categorical=(j in cat_set),
-                forced_bounds=forced.get(j))
+            with _cat_span(j in cat_set):
+                m = BinMapper.find_bin(
+                    sample[:, j], total_sample_cnt=len(sample),
+                    max_bin=int(fmax),
+                    min_data_in_bin=int(cfg.min_data_in_bin),
+                    use_missing=bool(cfg.use_missing),
+                    zero_as_missing=bool(cfg.zero_as_missing),
+                    is_categorical=(j in cat_set),
+                    forced_bounds=forced.get(j))
             self.mappers.append(m)
         self.used_feature_idx = [j for j in range(f)
                                  if not self.mappers[j].is_trivial()]
@@ -504,13 +510,58 @@ class Dataset:
                       "(single bin). Check your data or binning parameters.")
 
     def _bin_all(self, arr: np.ndarray) -> None:
-        self.bins = self._bin_matrix(arr)
+        self.bins = self._bin_matrix(arr, in_construct=True)
 
-    def _bin_matrix(self, arr: np.ndarray) -> np.ndarray:
+    def _count_categorical(self) -> None:
+        """The job's categorical counters (obs/metrics.py), once a
+        training set: its categorical columns, those of them the split
+        search scans by sorted subsets (``cat_subset_columns``), the bins
+        that hold a level, and the rows in a column's other bin (read
+        from the bins while a column of them is a feature: before a
+        bundle plan is applied, 0 after)."""
+        cats = [(p, self.mappers[j])
+                for p, j in enumerate(self.used_feature_idx)
+                if self.mappers[j].bin_type == BIN_CATEGORICAL]
+        per_feature = self.bundle_plan is None and self.bins.size
+        self.cat_counts = counts = {
+            "cat_features": len(cats),
+            "cat_subset_features": len(self.cat_subset_columns()),
+            "cat_levels_kept": sum(len(m.bin_2_categorical)
+                                   for _, m in cats),
+            "cat_other_rows": sum(
+                int(np.count_nonzero(self.bins[:, p] == m.other_bin))
+                for p, m in cats if per_feature and m.other_bin >= 0)}
+        if cats:
+            count_event("cat_features", counts["cat_features"])
+            count_event("cat_subset_features", counts["cat_subset_features"])
+            count_event("cat_levels_kept", counts["cat_levels_kept"])
+            count_event("cat_other_rows", counts["cat_other_rows"])
+
+    def categorical_counts(self) -> Dict[str, int]:
+        """``cat_counts``, counted on first use for a set the dense
+        construct did not build."""
+        if self.cat_counts is None:
+            self._count_categorical()
+        return self.cat_counts
+
+    def cat_subset_columns(self) -> Tuple[int, ...]:
+        """Packed indices of the categorical columns whose levels (the
+        other bin left out) outnumber ``max_cat_to_onehot``: those take
+        the sorted-subset variant of the split search, the others the
+        one-hot variant (ops/split.py)."""
+        onehot = int(self.config.max_cat_to_onehot)
+        return tuple(
+            p for p, j in enumerate(self.used_feature_idx)
+            if self.mappers[j].bin_type == BIN_CATEGORICAL
+            and len(self.mappers[j].bin_2_categorical) > onehot)
+
+    def _bin_matrix(self, arr: np.ndarray,
+                    in_construct: bool = False) -> np.ndarray:
         """Apply this dataset's per-feature mappers to a raw matrix —
         the one binning implementation shared by construction
         (``_bin_all``) and external-matrix prediction
-        (``bin_external``)."""
+        (``bin_external``).  ``in_construct``: the categorical columns'
+        share is timed into the construct's span ``cat_bin_mappers``."""
         n = arr.shape[0]
         used = self.used_feature_idx
         bins = np.zeros((n, len(used)), dtype=np.uint8)
@@ -518,7 +569,9 @@ class Dataset:
             log.fatal(f"The number of features in data ({arr.shape[1]}) does not "
                       f"match Dataset ({self.num_total_features})")
         for col, j in enumerate(used):
-            bins[:, col] = self.mappers[j].values_to_bins(arr[:, j]).astype(np.uint8)
+            m = self.mappers[j]
+            with _cat_span(in_construct and m.bin_type == BIN_CATEGORICAL):
+                bins[:, col] = m.values_to_bins(arr[:, j]).astype(np.uint8)
         return np.ascontiguousarray(bins)
 
     def bin_external(self, arr: np.ndarray) -> np.ndarray:
@@ -778,6 +831,15 @@ def _sparse_bundled_matrix(csc, mappers, used_idx, plan, n: int) -> np.ndarray:
     if lost:
         count_event("efb_conflict_rows", lost)
     return np.ascontiguousarray(out.T)
+
+
+def _cat_span(is_categorical: bool):
+    """The span ``cat_bin_mappers`` around one categorical column's share
+    of the dense construct (its level counts in ``_construct_mappers``,
+    its bins in ``_bin_matrix``); nothing for a numeric column."""
+    if not is_categorical:
+        return contextlib.nullcontext()
+    return phase("cat_bin_mappers", global_timer, seconds="cat_bin_mappers_s")
 
 
 def _load_forced_bins(cfg: Config, num_features: int) -> dict:

@@ -43,10 +43,11 @@ from jax import lax
 
 from ..ops.histogram import (bins_to_words, hist_dispatch,
                              histogram_for_leaves_auto, root_histogram)
-from ..ops.round_fuse import partition_select_pallas, use_fused_partition
+from ..ops.round_fuse import (pack_left_bins, partition_select_pallas,
+                              use_fused_partition)
 from ..ops.table import sum_small_table
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, SplitHyper,
-                         categorical_left_bitset, leaf_output)
+                         cat_levels, categorical_left_bitset, leaf_output)
 from .grower import (CegbInput, DeviceBundle, TreeArrays, _INF_BOUND,
                      _empty_tree, _expand_hist_col, _feature_bin_of_rows,
                      best_split_of_hist, pv_vote_best_split,
@@ -91,6 +92,15 @@ def _recount_leaves(leaf_of_row: jax.Array, mask_f: jax.Array, size: int,
             with jax.named_scope("stats_allreduce"):
                 counts = lax.psum(counts, axis_name)
         return counts.astype(jnp.float32)
+
+
+def fuses_partition(bundle: Optional[DeviceBundle]) -> bool:
+    """Whether a round's row partition runs in ops/round_fuse.py's
+    kernel: a Pallas backend, and every split a range predicate on its
+    physical column or a set of its bins (no bundle plan, or one with
+    ranges); else the XLA path (counter ``fused_partition_declined``)."""
+    return use_fused_partition() and (bundle is None
+                                      or bundle.search is not None)
 
 
 @functools.partial(jax.jit, static_argnames=("hp", "batch", "axis_name",
@@ -191,11 +201,13 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             if hist_dispatch(hp.hist_kernel, hp.n_bins).mirror else None
     # fused partition+key kernel (ops/round_fuse.py): splits a range of
     # the physical column states (grower.py split_ranges) — numeric
-    # features, bundled or not.  Categorical bitsets and the EFB inverse
-    # table of a plan without ranges are per-row gathers, kept on the
-    # XLA path
-    fuse_partition = (use_fused_partition() and not hp.has_categorical
-                      and (bundle is None or bundle.search is not None))
+    # features, bundled or not — and, in a job with categorical columns,
+    # a slot's left set as 8 words of 32 bins.  The EFB inverse table of
+    # a plan without ranges is a per-row gather, kept on the XLA path
+    fuse_partition = fuses_partition(bundle)
+    # bins that hold a level: a column's other bin is in no left set
+    levels = cat_levels(num_bins, nan_bin, is_cat) \
+        if hp.has_categorical else num_bins
     pooled = 0 < hp.hist_pool_slots < hp.num_leaves
     from ..ops.histogram import use_pallas as _use_pallas
     INF = jnp.float32(_INF_BOUND)
@@ -237,23 +249,33 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     def child_best(h_phys, g_, h_, c_, depth, lmin, lmax, fm, pout,
                    key=None, pen=None, adv=None):
+        """``(best split, its left bins)`` of one leaf; the left bins
+        (bool [B], ``None`` in a job without categorical columns) are
+        what the split search's own sort gives, or under voting
+        ``winner_bitset``'s second sort of the psum-ed column."""
         if voting:
             # PV-Tree two-phase vote per child — ONE protocol definition
             # shared with the strict grower (learner/grower.py
             # pv_vote_best_split)
-            return pv_vote_best_split(
+            res = pv_vote_best_split(
                 h_phys, g_, h_, c_, depth, fm, pout, lmin, lmax, key,
                 hp=hp, hp_vote=hp_vote, num_bins=num_bins,
                 nan_bin=nan_bin, is_cat=is_cat, monotone=monotone,
                 bundle=bundle, num_f=num_f, top_k=top_k,
                 axis_name=axis_name)
+            return res, (winner_bitset(h_phys, g_, h_, c_, res.feature,
+                                       res.variant, res.threshold)
+                         if hp.has_categorical else None)
+        left_bins = [] if hp.has_categorical else None
         res = best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin,
                                  is_cat, fm, hp, bundle, monotone=monotone,
                                  leaf_min=lmin, leaf_max=lmax, depth=depth,
                                  parent_output=pout, rng_key=key,
-                                 gain_penalty=pen, adv_bounds=adv)
+                                 gain_penalty=pen, adv_bounds=adv,
+                                 left_bins_out=left_bins)
         depth_ok = (hp.max_depth <= 0) | (depth < hp.max_depth)
-        return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
+        return (res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF)),
+                left_bins[0] if left_bins else None)
 
     def forced_col_hist(ff, lor_now, fl):
         """[B, C] VIRTUAL histogram column of leaf ``fl`` for feature
@@ -312,7 +334,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         hist_col = pf_col if bundle is None else \
             _expand_hist_col(pf_col, bundle, feat, g_, h_, c_)
         return categorical_left_bitset(
-            hist_col, num_bins[feat], var, thr, hp) & is_cat[feat]
+            hist_col, levels[feat], var, thr, hp) & is_cat[feat]
 
     # quantized-levels mode (ops/quantize.py): grad/hess hold integer
     # levels; one deterministic multiply restores real units right after
@@ -353,9 +375,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             pen0 = cegb_penalty(cegb.feature_used, cnt0, c0)
         else:
             pen0 = None
-        best0 = child_best(hist0_b, g0, h0, c0, jnp.int32(0), -INF, INF,
-                           node_mask(empty_path, key_root), root_out, key_root,
-                           pen=pen0)
+        best0, bits0 = child_best(hist0_b, g0, h0, c0, jnp.int32(0), -INF,
+                                  INF, node_mask(empty_path, key_root),
+                                  root_out, key_root, pen=pen0)
 
         tree = _empty_tree(L, hp.n_bins, num_f)
         tree = tree._replace(
@@ -399,9 +421,8 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             progress=jnp.bool_(True),
         )
         if hp.has_categorical:
-            state["best_bitset"] = jnp.zeros((L, hp.n_bins), bool).at[0].set(
-                winner_bitset(hist0_b, g0, h0, c0, best0.feature,
-                              best0.variant, best0.threshold))
+            state["best_bitset"] = jnp.zeros((L, hp.n_bins),
+                                             bool).at[0].set(bits0)
         if cegb is not None:
             state["cegb_used"] = cegb.feature_used
             if use_lazy:
@@ -498,7 +519,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       # same direct column carries the bitset (the pool
                       # may not hold this leaf's histogram)
                       bs_f = categorical_left_bitset(
-                          hf, num_bins[ff], var_f, ft, hp) & is_cat[ff]
+                          hf, levels[ff], var_f, ft, hp) & is_cat[ff]
                   else:
                       bs_f = winner_bitset(st["hist"][fl], pgf, phf, pcf,
                                            ff, var_f, ft)
@@ -866,12 +887,24 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   col_k, lo_k, hi_k, pos_k, dl_k, miss_k = split_ranges(
                       feats_k, st["best_thr"][parents],
                       st["best_dl"][parents], nan_bin, bundle, hp.n_bins)
+                  sets_k = ()
+                  if hp.has_categorical:
+                      # the slots' left sets as words of 32 bins, and which
+                      # slots go by theirs: the split search's part of the
+                      # hand-over, under its scope
+                      with jax.named_scope("find_splits"), \
+                              jax.named_scope("cat_bitset"):
+                          sets_k = (
+                              pack_left_bins(jnp.stack(bitsets) if use_boxes
+                                             else bitsets_arr),
+                              is_cat[feats_k].astype(jnp.int32))
                   lor, sort_key = partition_select_pallas(
                       bins_t, lor, mask_f.astype(jnp.int32),
                       col_k, lo_k, hi_k, pos_k, dl_k.astype(jnp.int32),
                       miss_k.astype(jnp.int32),
                       parents, new_leaves, valid.astype(jnp.int32),
-                      smaller, rows_per_block=min(hp.rows_per_block, 2048),
+                      smaller, *sets_k,
+                      rows_per_block=min(hp.rows_per_block, 2048),
                       interpret=not _use_pallas())
               else:
                   cols_k = jax.vmap(
@@ -1088,7 +1121,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                           st["tree"].num_leaves, lf, hp.n_bins))(kids)
               else:
                   advs = None
-              res = jax.vmap(
+              res, kb = jax.vmap(
                   child_best,
                   in_axes=(0, 0, 0, 0, 0, 0, 0,
                            None if fms is None else 0, 0,
@@ -1113,10 +1146,6 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               st["best_dl"] = st["best_dl"].at[kids].set(
                   jnp.where(ok2, res.default_left, st["best_dl"][kids]))
               if hp.has_categorical:
-                  kb = jax.vmap(winner_bitset)(
-                      kid_hist, st["sum_g"][kids], st["sum_h"][kids],
-                      st["count"][kids], res.feature, res.variant,
-                      res.threshold)
                   st["best_bitset"] = st["best_bitset"].at[kids].set(
                       jnp.where(ok2[:, None], kb, st["best_bitset"][kids]))
           return st

@@ -42,7 +42,8 @@ from ..ops.histogram import (bins_to_words, hist_dispatch,
 from ..obs.metrics import count_event
 from ..ops.split import (NEG_INF, VAR_CAT_BWD, VAR_CAT_FWD, VAR_CAT_ONEHOT,
                          VAR_NUM_RIGHT, SplitHyper, SplitResult,
-                         categorical_left_bitset, find_best_split,
+                         cat_levels, categorical_left_bitset,
+                         find_best_split,
                          find_best_split_ranges, leaf_gain, leaf_output,
                          smoothed_output)
 
@@ -159,11 +160,15 @@ def best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin, is_cat, fm,
                        hp: SplitHyper, bundle: Optional[DeviceBundle], *,
                        monotone=None, parent_output=0.0, leaf_min=None,
                        leaf_max=None, depth=None, rng_key=None,
-                       gain_penalty=None, adv_bounds=None) -> SplitResult:
+                       gain_penalty=None, adv_bounds=None,
+                       left_bins_out=None) -> SplitResult:
     """Best split of one leaf from its PHYSICAL histogram: in bundle
     space where the plan and the job allow it
     (``searches_in_bundle_space``), else ``find_best_split`` on the
-    histogram itself (no bundles) or on its expansion to virtual space."""
+    histogram itself (no bundles) or on its expansion to virtual space.
+    ``left_bins_out`` is ``find_best_split``'s: a list that takes the
+    winner's left bins (none in bundle space, which holds no categorical
+    column)."""
     if searches_in_bundle_space(bundle, hp, gain_penalty is not None):
         with jax.named_scope("bundle_search"):
             return find_best_split_ranges(h_phys, g_, h_, c_, bundle.search,
@@ -175,7 +180,8 @@ def best_split_of_hist(h_phys, g_, h_, c_, num_bins, nan_bin, is_cat, fm,
                            monotone=monotone, parent_output=parent_output,
                            leaf_min=leaf_min, leaf_max=leaf_max, depth=depth,
                            rng_key=rng_key, gain_penalty=gain_penalty,
-                           adv_bounds=adv_bounds)
+                           adv_bounds=adv_bounds,
+                           left_bins_out=left_bins_out)
 
 
 def _feature_bin_of_rows(bins_t: jax.Array, bundle: Optional[DeviceBundle],
@@ -346,8 +352,11 @@ def pv_vote_best_split(h_phys, g_, h_, c_, depth, fm, parent_output, lmin,
     sel_k = min(2 * top_k, num_f)
     _, sel = lax.top_k(score, sel_k)                           # [2k]
     h_sel = lax.psum(hv_local[sel], axis_name)                 # [2k, B, C]
+    # a selection of the features: the job's list of subset columns
+    # names other rows
     res = _fbs(h_sel, g_, h_, c_, num_bins[sel], nan_bin[sel], is_cat[sel],
-               None if fm is None else fm[sel], hp,
+               None if fm is None else fm[sel],
+               dataclasses.replace(hp, cat_subset_cols=None),
                monotone=None if monotone is None else monotone[sel],
                parent_output=parent_output, leaf_min=lmin, leaf_max=lmax,
                depth=depth, rng_key=key)
@@ -702,9 +711,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     _expand_hist_col(pf_col, bundle, f_safe,
                                      st.sum_g[bl], st.sum_h[bl],
                                      st.count[bl])
-                bitset = categorical_left_bitset(hist_pf,
-                                                 num_bins[f_safe], var, thr,
-                                                 hp)
+                bitset = categorical_left_bitset(
+                    hist_pf, cat_levels(num_bins, nan_bin, is_cat)[f_safe],
+                    var, thr, hp)
                 if mode == "feature" and axis_name is not None:
                     # owner broadcasts its bitset
                     bitset = lax.psum(

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..learner.grower import TreeArrays, split_ranges
-from ..ops.round_fuse import xor_ranges
+from ..ops.round_fuse import bin_in_set, pack_left_bins, xor_ranges
 
 
 @functools.partial(jax.jit, static_argnames=("has_categorical",))
@@ -145,10 +145,11 @@ def tree_path_masks(tree: TreeArrays):
 _MATMUL_VALID_BLOCK = 131_072
 
 
-@functools.partial(jax.jit, static_argnames=("n_bins",))
+@functools.partial(jax.jit, static_argnames=("n_bins", "has_categorical"))
 def predict_bins_tree_matmul(tree: TreeArrays, bins_t: jax.Array,
                              nan_bin: jax.Array, bundle=None,
-                             n_bins: int = 256) -> jax.Array:
+                             n_bins: int = 256,
+                             has_categorical: bool = False) -> jax.Array:
     """Leaf VALUE per row for one device tree — the matmul
     path-aggregation formulation of ``predict_bins_tree`` (round-6
     fused-valid lift, VERDICT r5 #4: the per-iteration frontier walk
@@ -156,9 +157,12 @@ def predict_bins_tree_matmul(tree: TreeArrays, bins_t: jax.Array,
     slowest TPU primitive).  NUMERIC trees whose every split is a range
     predicate on its physical column (learner/grower.py
     ``split_ranges``): unbundled features, and members of an EFB plan
-    with ranges (``bundle.search``).  Categorical bitsets and the inverse
-    table of a plan without ranges are per-row gathers; those models
-    keep the frontier walk.
+    with ranges (``bundle.search``).  With ``has_categorical`` a node
+    that splits by a set of its column's bins is decided as the partition
+    kernel decides it (ops/round_fuse.py): the set as words of 32 bins,
+    the word by compares on ``bin >> 5``, the bit by a shift.  The inverse
+    table of a plan without ranges is a per-row gather; those models keep
+    the frontier walk.
 
     ``bins_t``: u8/i32 [F, n] TRANSPOSED valid bins (cached by the
     booster; bundle columns under a plan).  Every node's decision bit
@@ -175,12 +179,17 @@ def predict_bins_tree_matmul(tree: TreeArrays, bins_t: jax.Array,
     # the partition kernel's two ranges: left is being in exactly one
     a1, n1, a2, n2 = (a[:, None] for a in xor_ranges(*ranges))
     value = tree.leaf_value
+    if has_categorical:
+        words = pack_left_bins(tree.cat_bitset)             # [W, ni]
+        by_set = tree.split_cat[:, None]
 
     def block(b0, rows):
         cols = lax.dynamic_slice_in_dim(bins_t, b0, rows, axis=1)[col] \
             .astype(jnp.int32)                              # [ni, blk]
         go = ((cols >= a1) & (cols - a1 < n1)) \
             != ((cols >= a2) & (cols - a2 < n2))
+        if has_categorical:
+            go = jnp.where(by_set, bin_in_set(cols, list(words)) != 0, go)
         bits = go.astype(jnp.bfloat16)
         counts = lax.dot_general(
             mpos, bits, (((1,), (0,)), ((), ())),

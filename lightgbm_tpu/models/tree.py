@@ -161,8 +161,11 @@ class Tree:
                 t.cat_threshold.append(
                     [mapper.bin_2_categorical[int(b)] for b in left_bins
                      if int(b) < len(mapper.bin_2_categorical)])
-                # NaN was binned as bin 0 (most frequent cat) during training
-                t.cat_nan_left.append(bool(bitsets[i][0]))
+                # NaN was binned as bin 0 (most frequent cat) during
+                # training, unless the column keeps an other bin for it,
+                # which no left set holds (io/binning.py)
+                t.cat_nan_left.append(bool(bitsets[i][0])
+                                      and mapper.other_bin < 0)
                 t.threshold[i] = float(t.cat_split_index[i])
             else:
                 t.threshold[i] = mapper.bin_to_value(int(t.threshold_bin[i]))

@@ -82,6 +82,33 @@ COUNTERS: Dict[str, str] = {
         "expansions of a bundle histogram to virtual-feature space "
         "(_expand_hist, _expand_hist_col) put into a program, counted when "
         "TRACED: 0 for a job searched wholly in bundle space",
+    "cat_features":
+        "categorical columns of the training sets constructed "
+        "(io/dataset.py); in a booster's own registry, of the set it "
+        "trains on",
+    "cat_subset_features":
+        "those of them whose levels outnumber max_cat_to_onehot: the split "
+        "search scans them by sorted subsets, the others one level at a time",
+    "cat_levels_kept":
+        "bins that hold a level, summed over the categorical columns",
+    "cat_other_rows":
+        "training rows in a categorical column's other bin (a level beyond "
+        "the max_bin - 1 kept, a negative code, NaN), summed over columns",
+    "cat_bin_mappers_s":
+        "seconds of the dense construct spent on categorical columns "
+        "(span cat_bin_mappers: their level counts and their bins)",
+    "cat_splits":
+        "splits on a categorical column in the trees a fused dispatch "
+        "brought back",
+    "cat_subset_splits":
+        "those of them whose left set came from the sorted-subset scan "
+        "(the column has more levels than max_cat_to_onehot)",
+    "cat_left_levels":
+        "levels in the left sets of those splits, summed",
+    "fused_partition_declined":
+        "rounds of a fused dispatch whose row partition took the XLA path "
+        "(no Pallas backend, or a bundle plan without ranges) and not "
+        "ops/round_fuse.py's kernel",
     "nan_guard_trips": "rounds where the numeric guard saw non-finite values",
     "nan_guard_raises": "numeric-guard trips escalated to an exception",
     "nan_rounds_skipped": "rounds dropped by nan_policy=skip_round",

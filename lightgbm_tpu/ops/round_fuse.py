@@ -34,15 +34,26 @@ the predicate folded into TWO ranges of the column whose exclusive-or is
 "left" (``xor_ranges``): two unsigned compares a row and slot, the
 parent's count of ``[K, block]`` operations but two casts (six
 descriptors compared in the body cost the Criteo cells 1.25%).
-Categorical bitset lookups and the inverse table of a bundle plan without
-ranges are per-row gathers (the slowest TPU primitive); those keep the
-XLA path in learner/batch_grower.py.
+
+In a job with categorical columns a slot may split by a SET of its
+column's bins instead.  The kernel is then built as a static variant (a
+numeric job's kernel has neither the operand nor the operations) in which
+EVERY slot goes by a set: the left set as ``n_bins / 32`` words of 32
+bins (``pack_left_bins``: bit b of word w set = bin 32w + b goes left),
+a numeric slot's made outside the kernel from its two ranges (the bins
+in exactly one), so the body has one test and no blend of two.  The
+value's word is picked by compares on ``c >> 5``, its bit by a
+shift and a mask (``bin_in_set``), from the ``[K, block]`` values the
+kernel holds already: no ``[K, n_bins]`` one-hot, no gather.  Only the
+inverse table of a bundle plan without ranges is still a per-row gather
+(the slowest TPU primitive) and keeps the XLA path in
+learner/batch_grower.py, which is also the tests' oracle.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +96,45 @@ def xor_ranges(lo: jax.Array, hi: jax.Array, pos: jax.Array,
     return a1, n1, a2, n2
 
 
+def pack_left_bins(left_bins: jax.Array) -> jax.Array:
+    """Left sets bool ``[K, B]`` as words i32 ``[ceil(B / 32), K]``: bit b
+    of word w of slot k is set when bin ``32 w + b`` of its column goes
+    left (what ``partition_select_pallas`` takes as ``cat_words``)."""
+    K, B = left_bins.shape
+    W = -(-B // 32)
+    bits = jnp.pad(left_bins, ((0, 0), (0, W * 32 - B))) \
+        .reshape(K, W, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << lax.iota(jnp.uint32, 32)[None, None, :],
+                    axis=2, dtype=jnp.uint32)
+    return lax.bitcast_convert_type(words, jnp.int32).T
+
+
+def bin_in_set(cols: jax.Array, word_rows) -> jax.Array:
+    """0/1 i32 ``[K, block]``: whether each value's bin is in its slot's
+    set.  ``cols`` i32 ``[K, block]`` bin values, ``word_rows`` the set's
+    words, one ``[K]`` i32 a word (``pack_left_bins``'s rows): the word
+    that holds the bin by one compare and one 32-bit select a word (a tree
+    of 7 selects on the bits of ``cols >> 5`` read the same time on the
+    chip: PERF.md section 6, PR 43), then the bin's bit by a shift and a
+    mask.  Shared by the partition kernel's body and ``models/predict.py``
+    ``predict_bins_tree_matmul``."""
+    high = cols >> 5
+    word = jnp.zeros_like(cols)
+    for w, row in enumerate(word_rows):
+        word = jnp.where(high == w, row[:, None], word)
+    return lax.shift_right_logical(word, cols & 31) & 1
+
+
+def range_left_bins(a1: jax.Array, n1: jax.Array, a2: jax.Array,
+                    n2: jax.Array, n_bins: int) -> jax.Array:
+    """bool ``[K, n_bins]``: the bins of ``xor_ranges``' two ranges that go
+    left (those in exactly one), a numeric slot's split as a left set."""
+    b = lax.iota(jnp.int32, n_bins)[None, :]
+    within = lambda a, m: ((b - a[:, None]).astype(jnp.uint32)
+                           < m[:, None].astype(jnp.uint32))
+    return within(a1, n1) ^ within(a2, n2)
+
+
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
 def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
                             mask: jax.Array, cols: jax.Array,
@@ -92,7 +142,9 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
                             pos: jax.Array, default_left: jax.Array,
                             miss: jax.Array, parents: jax.Array,
                             new_leaves: jax.Array, validk: jax.Array,
-                            smaller: jax.Array, *,
+                            smaller: jax.Array,
+                            cat_words: Optional[jax.Array] = None,
+                            slot_is_cat: Optional[jax.Array] = None, *,
                             rows_per_block: int = 2048,
                             interpret: bool = False
                             ) -> Tuple[jax.Array, jax.Array]:
@@ -108,7 +160,11 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
     the feature's missing bin, -1 for none), parents (parent leaf id, -1
     disables the slot), new_leaves (right-child leaf id), validk (0/1),
     smaller (the leaf ids the NEXT histogram pass will compact, dummy
-    slots may repeat).
+    slots may repeat).  In a job with categorical columns also cat_words
+    i32 [W, K] (``pack_left_bins``) and slot_is_cat i32 [K] (0/1): a slot
+    with the flag set sends a row left when its value's bit is set in its
+    words; the others' words are made here from their range descriptors,
+    and the kernel tests every slot's set.
 
     Returns (new_lor i32 [n], sort_key i32 [n]) where sort_key =
     (row in smaller-frontier AND mask) ? row : row | 2^30.
@@ -123,9 +179,22 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
 
     a1, n1, a2, n2 = xor_ranges(lo, hi, pos, default_left, miss)
 
-    def kernel(bins_ref, lor_ref, mask_ref, cols_ref, a1_ref, n1_ref,
-               a2_ref, n2_ref, par_ref, nl_ref, vk_ref, sm_ref,
-               out_lor_ref, out_key_ref):
+    k_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
+    # what the body tests a slot's value against: its two ranges, or in
+    # the static variant with sets every slot's words
+    with_sets = cat_words is not None
+    if with_sets:
+        words = jnp.where(
+            slot_is_cat[None, :] != 0, cat_words,
+            pack_left_bins(range_left_bins(a1, n1, a2, n2,
+                                           32 * cat_words.shape[0])))
+        test_specs = [pl.BlockSpec(words.shape, lambda i: (0, 0))]
+    else:
+        test_specs = [k_spec] * 4
+
+    def kernel(bins_ref, lor_ref, mask_ref, cols_ref, *refs):
+        (*test_refs, par_ref, nl_ref, vk_ref, sm_ref,
+         out_lor_ref, out_key_ref) = refs
         step = pl.program_id(0)
         fk = cols_ref[0, :]                                   # [K]
         iota_f = lax.iota(jnp.int32, num_f)
@@ -142,13 +211,19 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
         # 32-bit cmp/select here — a select_n over i1 payloads fails to
         # compile (arith.trunci i8->i1), so where() is reserved for
         # 32-bit payloads only
-        # a <= c < a + n as ONE unsigned compare, c - a < n (a c below a
-        # wraps past every n, and n = 0 is the empty range); left is being
-        # in exactly one of the slot's two ranges (xor_ranges)
-        within = lambda a_ref, n_ref: (
-            (cols - a_ref[0, :][:, None]).astype(jnp.uint32)
-            < n_ref[0, :][:, None].astype(jnp.uint32)).astype(jnp.int32)
-        go_left = within(a1_ref, n1_ref) ^ within(a2_ref, n2_ref)  # [K, blk]
+        if with_sets:
+            words_ref, = test_refs
+            go_left = bin_in_set(cols, [words_ref[w, :] for w
+                                        in range(words_ref.shape[0])])
+        else:
+            a1_ref, n1_ref, a2_ref, n2_ref = test_refs
+            # a <= c < a + n as ONE unsigned compare, c - a < n (a c below
+            # a wraps past every n, and n = 0 is the empty range); left is
+            # being in exactly one of the slot's two ranges (xor_ranges)
+            within = lambda a_ref, n_ref: (
+                (cols - a_ref[0, :][:, None]).astype(jnp.uint32)
+                < n_ref[0, :][:, None].astype(jnp.uint32)).astype(jnp.int32)
+            go_left = within(a1_ref, n1_ref) ^ within(a2_ref, n2_ref)
         in_par = (lor_b[None, :] == par_ref[0, :][:, None]
                   ).astype(jnp.int32) * vk_ref[0, :][:, None]
         move = in_par * (1 - go_left)     # one-hot across K: parents are
@@ -162,19 +237,19 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
         out_key_ref[0, :] = jnp.where(selv > 0, row, row | (1 << 30))
 
     row_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
-    k_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
     out_lor, out_key = pl.pallas_call(
         kernel,
         grid=(pl.cdiv(n, blk),),
         in_specs=[pl.BlockSpec((num_f, blk), lambda i: (0, i)),
-                  row_spec, row_spec,
-                  k_spec, k_spec, k_spec, k_spec, k_spec, k_spec, k_spec,
-                  k_spec, k_spec],
+                  row_spec, row_spec, k_spec] + test_specs
+        + [k_spec, k_spec, k_spec, k_spec],
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
                    jax.ShapeDtypeStruct((1, n), jnp.int32)],
         interpret=interpret,
-    )(bins_t, lor[None, :], mask[None, :], cols[None, :], a1[None, :],
-      n1[None, :], a2[None, :], n2[None, :], parents[None, :],
-      new_leaves[None, :], validk[None, :], smaller[None, :])
+    )(bins_t, lor[None, :], mask[None, :], cols[None, :],
+      *((words,) if with_sets else
+        (a1[None, :], n1[None, :], a2[None, :], n2[None, :])),
+      parents[None, :], new_leaves[None, :], validk[None, :],
+      smaller[None, :])
     return out_lor[0], out_key[0]

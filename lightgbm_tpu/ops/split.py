@@ -23,7 +23,7 @@ the improvement over the parent minus ``min_gain_to_split``.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +66,16 @@ class SplitHyper:
     # static gate: skip the categorical argsort/cumsum machinery entirely
     # on all-numeric datasets (argsort is expensive on TPU)
     has_categorical: bool = False
+    # the packed indices of the categorical columns that take the
+    # sorted-subset variant (num_bin without the other bin >
+    # max_cat_to_onehot), where they are known at trace time
+    # (boosting/gbdt.py from the train set's bin mappers):
+    # ``find_best_split``'s subset scan then sorts those rows of the
+    # histogram alone, and none where the tuple is empty.  ``None``: not
+    # known; every column is scanned, the others masked.  A caller that
+    # hands ``find_best_split`` a selection of the features replaces the
+    # tuple by ``None`` (learner/grower.py ``pv_vote_best_split``)
+    cat_subset_cols: Optional[Tuple[int, ...]] = None
     n_bins: int = 256
     rows_per_block: int = 4096
     path_smooth: float = 0.0
@@ -217,6 +227,72 @@ def children_gain(gl, hl, nl, sum_g, sum_h, count, l2, parent_output,
     return jnp.where(ok, gain, NEG_INF)
 
 
+def cat_levels(num_bins: jax.Array, nan_bin: jax.Array,
+               is_cat: jax.Array) -> jax.Array:
+    """Bins that hold a level: ``num_bins`` without a categorical column's
+    other bin (its last, where ``nan_bin >= 0``; io/binning.py).  What the
+    one-hot / subset choice and every left set go by."""
+    return num_bins - (is_cat & (nan_bin >= 0)).astype(num_bins.dtype)
+
+
+def _take_rows(a: jax.Array, rows: Tuple[int, ...]) -> jax.Array:
+    """Rows ``rows`` (static) of ``a``: static slices of runs of
+    consecutive rows, joined; no gather."""
+    runs, start = [], 0
+    for i in range(1, len(rows) + 1):
+        if i == len(rows) or rows[i] != rows[i - 1] + 1:
+            runs.append(a[rows[start]:rows[i - 1] + 1])
+            start = i
+    if not runs:
+        return a[:0]
+    return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=0)
+
+
+def _spread_rows(a_s: jax.Array, rows: Tuple[int, ...], num_rows: int,
+                 fill) -> jax.Array:
+    """``_take_rows`` undone: ``a_s``'s rows back at ``rows`` of an array
+    of ``num_rows`` rows, ``fill`` elsewhere; static slices, no scatter."""
+    parts, at, k = [], 0, 0
+    pad = lambda m: jnp.full((m,) + a_s.shape[1:], fill, a_s.dtype)
+    while k < len(rows):
+        j = k
+        while j + 1 < len(rows) and rows[j + 1] == rows[j] + 1:
+            j += 1
+        if rows[k] > at:
+            parts.append(pad(rows[k] - at))
+        parts.append(a_s[k:j + 1])
+        at, k = rows[j] + 1, j + 1
+    if at < num_rows:
+        parts.append(pad(num_rows - at))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _row_lookup(rows: Tuple[int, ...], num_rows: int):
+    """Feature index -> its row among ``rows`` (0 for the others, whose
+    subset candidates never win)."""
+    table = [0] * num_rows
+    for k, r in enumerate(rows):
+        table[r] = k
+    table = jnp.asarray(table, jnp.int32)
+    return lambda f: table[f]
+
+
+def sort_by_score(score: jax.Array, cand_bin: jax.Array, stats,
+                  descending: bool):
+    """One direction of the sorted-subset scan: ``stats`` (arrays
+    ``[F, B]``, zero outside ``cand_bin``) and the bin index, sorted along
+    the bin axis by ``score`` (falling where ``descending``), the
+    candidate bins first.  ONE variadic stable sort that carries them with
+    the key, ties by bin index as an argsort gives them: no argsort and no
+    gather a payload.  Returns ``(*sorted stats, order i32 [F, B])``."""
+    key = jnp.where(cand_bin, -score if descending else score,
+                    jnp.float32(1e30))
+    bins = jnp.broadcast_to(lax.iota(jnp.int32, key.shape[1])[None, :],
+                            key.shape)
+    return lax.sort((key,) + tuple(stats) + (bins,), dimension=1,
+                    is_stable=True, num_keys=1)[1:]
+
+
 def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                     count: jax.Array, num_bins: jax.Array, nan_bin: jax.Array,
                     is_cat: jax.Array, feature_mask: Optional[jax.Array],
@@ -228,7 +304,8 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                     rng_key: Optional[jax.Array] = None,
                     per_feature_out: Optional[list] = None,
                     gain_penalty: Optional[jax.Array] = None,
-                    adv_bounds=None) -> SplitResult:
+                    adv_bounds=None,
+                    left_bins_out: Optional[list] = None) -> SplitResult:
     """Pick the best (feature, threshold, default-dir) for one leaf.
 
     hist: f32 [F, B, C>=3] (grad, hess, count); sum_g/sum_h/count: leaf totals.
@@ -237,6 +314,11 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
     MUST be 0) when ``hp.use_monotone``; leaf_min/leaf_max: this leaf's output
     bounds (basic-method constraint entry); parent_output: this leaf's own
     output (path smoothing target); depth: leaf depth (monotone penalty).
+    left_bins_out: a list the winner's set of LEFT bins (bool [B], all
+    False for a numeric winner) is appended to in a job with categorical
+    columns, read off the order the scan sorted its candidates into
+    (``categorical_left_bitset`` sorts them again).  The subset scan's
+    rows of ``hist`` are ``hp.cat_subset_cols``.
     """
     num_f, n_b = hist.shape[0], hist.shape[1]
     g, h, n = hist[..., 0], hist[..., 1], hist[..., 2]
@@ -262,7 +344,7 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                    or hp.max_delta_step > 0.0)
     min_shift = parent_gain_shift(sum_g, sum_h, parent_output, hp)
 
-    def variant_gain(gl_v, hl_v, nl_v, l2_v, bnds=None):
+    def variant_gain(gl_v, hl_v, nl_v, l2_v, bnds=None, mono=None):
         if not hp.use_monotone:
             return children_gain(gl_v, hl_v, nl_v, sum_g, sum_h, count,
                                  l2_v, parent_output, hp)
@@ -292,7 +374,8 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
             if hp.use_monotone:
                 # monotone direction violated → split forbidden
                 # (feature_histogram.hpp:788-791 returns 0 = below gain_shift)
-                mono = monotone[:, None] if gl_v.ndim == 2 else monotone
+                mono = monotone if mono is None else mono
+                mono = mono[:, None] if gl_v.ndim == 2 else mono
                 bad = ((mono > 0) & (lo > ro)) | ((mono < 0) & (lo < ro))
                 gain = jnp.where(bad, NEG_INF, gain)
         ok = ((nl_v >= hp.min_data_in_leaf) & (nr >= hp.min_data_in_leaf)
@@ -310,57 +393,90 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                           variant_gain(gl + gm, hl + hm, nl + nm, l2,
                                        bnds=adv_bounds), NEG_INF)
 
+    # the subset scan's own rows of the histogram: the job's static list
+    # of subset columns, every row where it is not known (None), and no
+    # scan at all where the job's categorical columns are all one-hot
+    sub = hp.cat_subset_cols if hp.has_categorical else ()
+    if sub and max(sub) >= num_f:
+        raise ValueError(f"cat_subset_cols {sub} name no row of a "
+                         f"histogram of {num_f} features")
+    order_f = order_b = None
     if hp.has_categorical:
+        # a categorical column's other bin (io/binning.py) stands where a
+        # numeric column's NaN bin does: no left set holds it, and it is
+        # not one of the column's levels
+        levels = cat_levels(num_bins, nan_bin, is_cat)
         # one-hot categorical: {bin == t} goes left, gated to low-cardinality
         # features (reference feature_histogram.cpp:179 ``use_onehot =
         # num_bin <= max_cat_to_onehot``; plain lambda_l2 in this branch)
-        onehot_ok = is_cat[:, None] & (num_bins[:, None]
+        onehot_ok = is_cat[:, None] & (levels[:, None]
                                        <= hp.max_cat_to_onehot)
-        gain_cat = jnp.where(valid_bin & onehot_ok,
+        gain_cat = jnp.where(valid_bin & ~is_nan & onehot_ok,
                              variant_gain(g, h, n, l2), NEG_INF)
 
+    if sub != ():
         # sorted-subset categorical (reference feature_histogram.cpp:241-340):
         # candidate bins with count >= cat_smooth, sorted by
         # g/(h+cat_smooth); prefixes of the ascending and descending orders
         # are the left sets, capped at max_cat_threshold, evaluated with
-        # l2 + cat_l2 and gated by min_data_per_group.  Vectorized: argsort +
-        # cumsum per direction, the reference's sequential ``cnt_cur_group``
-        # reset becoming "left count crosses a multiple of
-        # min_data_per_group" (a static approximation of the same evaluation
-        # density).
-        l2c = l2 + hp.cat_l2
-        subset_feat_ok = is_cat & (num_bins > hp.max_cat_to_onehot)   # [F]
-        cand_bin = valid_bin & subset_feat_ok[:, None] & (n >= hp.cat_smooth)
-        used_bin = jnp.sum(cand_bin, axis=1)                          # [F]
-        max_num_cat = jnp.minimum(hp.max_cat_threshold, (used_bin + 1) // 2)
-        k_limit = jnp.minimum(used_bin, max_num_cat)[:, None]         # [F, 1]
-        score = g / (h + hp.cat_smooth)
-        INF = jnp.float32(1e30)
+        # l2 + cat_l2 and gated by min_data_per_group.  Vectorized:
+        # ``sort_by_score`` per direction, then cumulative sums; the
+        # reference's sequential ``cnt_cur_group`` reset becoming "left
+        # count crosses a multiple of min_data_per_group" (a static
+        # approximation of the same evaluation density).
+        with jax.named_scope("cat_subset"):
+            l2c = l2 + hp.cat_l2
+            subset_feat_ok = is_cat & (levels > hp.max_cat_to_onehot)   # [F]
+            if sub is None:
+                rows_of = lambda a: a
+                spread = lambda a, fill: a
+                row_of_feat = lambda f: f
+            else:
+                rows_of = lambda a: _take_rows(a, sub)
+                spread = lambda a, fill: _spread_rows(a, sub, num_f, fill)
+                row_of_feat = _row_lookup(sub, num_f)
+            gS, hS, nS = rows_of(g), rows_of(h), rows_of(n)
+            cand_bin = rows_of(valid_bin & ~is_nan
+                               & subset_feat_ok[:, None]) \
+                & (nS >= hp.cat_smooth)
+            used_bin_s = jnp.sum(cand_bin, axis=1)                    # [Fs]
+            max_num_cat_s = jnp.minimum(hp.max_cat_threshold,
+                                        (used_bin_s + 1) // 2)
+            k_limit = jnp.minimum(used_bin_s, max_num_cat_s)[:, None]
+            score = gS / (hS + hp.cat_smooth)
+            stats_s = (gS * cand_bin, hS * cand_bin, nS * cand_bin)
+            # variant_gain reads the per-feature monotone directions
+            mono_s = _take_rows(monotone, sub) \
+                if hp.use_monotone and sub is not None else None
 
-        def subset_scan(descending: bool):
-            key = jnp.where(cand_bin, -score if descending else score, INF)
-            order = jnp.argsort(key, axis=1)                          # [F, B]
-            gs = jnp.take_along_axis(g * cand_bin, order, axis=1)
-            hs = jnp.take_along_axis(h * cand_bin, order, axis=1)
-            ns = jnp.take_along_axis(n * cand_bin, order, axis=1)
-            glv = _cumsum_bins(gs, exact_scan)
-            hlv = _cumsum_bins(hs, exact_scan)
-            nlv = _cumsum_bins(ns, exact_scan)
-            ok = bin_idx < k_limit
-            if hp.min_data_per_group > 1:
-                mdpg = jnp.float32(hp.min_data_per_group)
-                crossed = jnp.floor(nlv / mdpg) > jnp.floor((nlv - ns) / mdpg)
-                ok = ok & crossed & ((count - nlv) >= mdpg)
-            gain = jnp.where(ok, variant_gain(glv, hlv, nlv, l2c), NEG_INF)
-            return gain, glv, hlv, nlv
+            def subset_scan(descending: bool):
+                gs, hs, ns, order = sort_by_score(score, cand_bin, stats_s,
+                                                  descending)
+                glv = _cumsum_bins(gs, exact_scan)
+                hlv = _cumsum_bins(hs, exact_scan)
+                nlv = _cumsum_bins(ns, exact_scan)
+                ok = bin_idx < k_limit
+                if hp.min_data_per_group > 1:
+                    mdpg = jnp.float32(hp.min_data_per_group)
+                    crossed = jnp.floor(nlv / mdpg) \
+                        > jnp.floor((nlv - ns) / mdpg)
+                    ok = ok & crossed & ((count - nlv) >= mdpg)
+                gain = jnp.where(ok, variant_gain(glv, hlv, nlv, l2c,
+                                                  mono=mono_s), NEG_INF)
+                return spread(gain, NEG_INF), glv, hlv, nlv, order
 
-        gain_fwd, gl_f, hl_f, nl_f = subset_scan(False)
-        gain_bwd, gl_b, hl_b, nl_b = subset_scan(True)
+            gain_fwd, gl_f, hl_f, nl_f, order_f = subset_scan(False)
+            gain_bwd, gl_b, hl_b, nl_b, order_b = subset_scan(True)
+            used_bin = spread(used_bin_s, 0)
+            max_num_cat = spread(max_num_cat_s, 0)
     else:
         neg = jnp.full((num_f, n_b), NEG_INF)
-        gain_cat = gain_fwd = gain_bwd = neg
+        gain_fwd = gain_bwd = neg
+        if not hp.has_categorical:
+            gain_cat = neg
         gl_f = hl_f = nl_f = gl_b = hl_b = nl_b = jnp.zeros_like(g)
         used_bin = max_num_cat = jnp.zeros((num_f,), jnp.int32)
+        row_of_feat = lambda f: f
 
     if hp.extra_trees and rng_key is not None:
         # extremely-randomized mode: per (feature, node) keep exactly ONE
@@ -428,15 +544,31 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
     variant = (rem % NUM_VARIANTS).astype(jnp.int32)
 
     # recover the winner's left-side stats
+    srow = row_of_feat(feat)     # the winner's row of the subset scan
     glw = jnp.stack([gl[feat, thr], gl[feat, thr] + gm[feat, 0], g[feat, thr],
-                     gl_f[feat, thr], gl_b[feat, thr]])
+                     gl_f[srow, thr], gl_b[srow, thr]])
     hlw = jnp.stack([hl[feat, thr], hl[feat, thr] + hm[feat, 0], h[feat, thr],
-                     hl_f[feat, thr], hl_b[feat, thr]])
+                     hl_f[srow, thr], hl_b[srow, thr]])
     nlw = jnp.stack([nl[feat, thr], nl[feat, thr] + nm[feat, 0], n[feat, thr],
-                     nl_f[feat, thr], nl_b[feat, thr]])
+                     nl_f[srow, thr], nl_b[srow, thr]])
     lg = glw[variant]
     lh = hlw[variant]
     ln = nlw[variant]
+
+    if left_bins_out is not None and hp.has_categorical:
+        with jax.named_scope("cat_bitset"):
+            pos = lax.iota(jnp.int32, n_b)
+            left = (variant == VAR_CAT_ONEHOT) & (pos == thr)
+            if order_f is not None:
+                # a subset winner: the first thr + 1 bins of the winning
+                # direction's order, as a [B, B] compare (a scatter by the
+                # order is a bin-sized scatter)
+                order_w = jnp.where(variant == VAR_CAT_BWD, order_b[srow],
+                                    order_f[srow])
+                in_prefix = jnp.any((order_w[:, None] == pos[None, :])
+                                    & (pos[:, None] <= thr), axis=0)
+                left = left | (in_prefix & (variant > VAR_CAT_ONEHOT))
+            left_bins_out.append(left)
 
     gain = best_gain_raw - min_shift
     return SplitResult(
@@ -544,6 +676,8 @@ def categorical_left_bitset(hist_f: jax.Array, num_bins_f: jax.Array,
     """Materialize the set of bins going LEFT for a categorical split.
 
     hist_f: f32 [B, C] — the PARENT leaf's histogram of the split feature;
+    num_bins_f: the feature's bins that hold a level (``cat_levels``: the
+    other bin is never a candidate);
     variant/threshold: the winning ``SplitResult`` fields.  Returns bool [B].
     For one-hot the set is {threshold}; for sorted-subset it re-derives the
     score ordering (deterministic given the histogram) and takes the first

@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
@@ -125,7 +126,9 @@ def test_batched_supports_path_smooth(synthetic_binary):
 
 
 def test_batched_fallback_for_categorical():
-    """Categorical data silently routes through the strict learner."""
+    """Categorical data rides the batched grower (it once fell back to
+    the strict learner, whence the name): a job with a categorical column
+    at ``tpu_split_batch=8`` trains and fits."""
     rng = np.random.default_rng(0)
     n = 1000
     X = np.column_stack([rng.normal(size=n), rng.integers(0, 5, size=n)])
@@ -134,6 +137,81 @@ def test_batched_fallback_for_categorical():
          "verbose": -1, "tpu_split_batch": 8, "categorical_feature": [1]}
     b = lgb.train(p, lgb.Dataset(X, label=y, params=p), num_boost_round=10)
     assert float(((b.predict(X) > 0.5) == y).mean()) > 0.9
+
+
+def _onehot_only_job(levels: int, n: int = 3000):
+    """Four columns of which one is a ``levels``-level code (2: a binary
+    flag), at most ``max_cat_to_onehot`` levels: every categorical column
+    of the job takes the one-hot variant."""
+    rng = np.random.default_rng(levels)
+    X = rng.normal(size=(n, 4))
+    X[:, 2] = rng.integers(0, levels, size=n)
+    y = ((X[:, 0] + 1.5 * (X[:, 2] == 1)
+          + rng.normal(scale=0.3, size=n)) > 0.5).astype(np.float64)
+    return X, y
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("levels", [2, 3])
+def test_a_job_whose_categorical_columns_are_all_one_hot(levels, fused):
+    """Such a job states an EMPTY list of subset columns
+    (``hp.cat_subset_cols == ()``): the batched grower traces no subset
+    scan (it once read row 0 of a ``[0, B]`` array there and failed to
+    trace), trains through the fused scan with the partition on either
+    path, splits on the column by one level, and returns the model it
+    trained."""
+    from lightgbm_tpu.ops import round_fuse
+    X, y = _onehot_only_job(levels)
+    p = {"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 5,
+         "verbose": -1, "tpu_split_batch": 8}
+    round_fuse._FUSE_TEST_INTERPRET = fused     # read when traced
+    jax.clear_caches()
+    try:
+        b = lgb.train(p, lgb.Dataset(X, label=y, params=p,
+                                     categorical_feature=[2]),
+                      num_boost_round=8)
+    finally:
+        round_fuse._FUSE_TEST_INTERPRET = False
+    gb = b._gbdt
+    assert gb._use_batched_grower() and gb.hp.has_categorical
+    assert gb.hp.cat_subset_cols == ()
+    assert gb.metrics.counter("fused_rounds") == 8
+    assert gb.metrics.counter("fused_partition_declined") == (0 if fused
+                                                               else 8)
+    assert gb.metrics.counter("cat_splits") > 0
+    assert gb.metrics.counter("cat_subset_splits") == 0
+    np.testing.assert_allclose(np.asarray(gb.scores)[:, 0],
+                               b.predict(X, raw_score=True),
+                               rtol=0, atol=2e-6)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("levels", [2, 3])
+def test_no_subset_columns_grow_the_tree_of_the_scan_of_every_column(levels):
+    """The tree of a job whose categorical columns are all one-hot, with
+    the empty list stated (no scan) and with the list not known (every
+    column scanned, those columns' subset candidates masked: the form
+    before the list existed): equal field for field."""
+    X, y = _onehot_only_job(levels)
+    ds = lgb.Dataset(X, label=y, categorical_feature=[2]).construct()._inner
+    bins = jnp.asarray(ds.bins)
+    g = jnp.asarray((0.5 - y).astype(np.float32))
+    h = jnp.full((len(y),), 0.25, jnp.float32)
+    consts = (jnp.asarray(ds.num_bins_array()), jnp.asarray(ds.nan_bin_array()),
+              jnp.asarray(ds.categorical_array()))
+    assert ds.cat_subset_columns() == ()
+    trees = []
+    for cols in ((), None):
+        hp = SplitHyper(num_leaves=15, min_data_in_leaf=5,
+                        n_bins=ds.device_n_bins(), has_categorical=True,
+                        cat_subset_cols=cols)
+        trees.append(grow_tree_batched(bins, g, h, None, *consts, None, hp,
+                                       batch=8))
+    (t0, lor0), (t1, lor1) = trees
+    assert int(t0.num_leaves) == 15 and bool(np.asarray(t0.split_cat).any())
+    for a, b in zip(t0, t1):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(lor0), np.asarray(lor1))
 
 
 def test_batch1_categorical_identical_to_strict():

@@ -67,19 +67,67 @@ def test_zero_as_missing():
     assert m.values_to_bins(np.array([np.nan]))[0] == m.nan_bin
 
 
-def test_categorical_by_frequency():
-    vals = np.array([5] * 50 + [2] * 30 + [9] * 20 + [7] * 5)
-    m = BinMapper.find_bin(vals.astype(float), len(vals), max_bin=32,
+@pytest.mark.parametrize("with_nan", [False, True],
+                         ids=["every_level_binned", "nan_among_the_rows"])
+def test_categorical_by_frequency(with_nan):
+    """A column whose levels all get a bin keeps the layout it had: an
+    unseen category and NaN fold into bin 0.  One that met NaN keeps an
+    other bin for them, its last, which is no level's."""
+    vals = np.array([5] * 50 + [2] * 30 + [9] * 20 + [7] * 5, float)
+    if with_nan:
+        vals = np.concatenate([vals, [np.nan] * 3])
+    m = BinMapper.find_bin(vals, len(vals), max_bin=32,
                            min_data_in_bin=1, use_missing=True,
                            zero_as_missing=False, is_categorical=True)
     assert m.bin_type == BIN_CATEGORICAL
-    assert m.bin_2_categorical[0] == 5          # most frequent first
+    assert m.bin_2_categorical == [5, 2, 9, 7]  # most frequent first
     assert m.values_to_bins(np.array([5.0]))[0] == 0
     assert m.values_to_bins(np.array([2.0]))[0] == 1
-    # unseen category -> bin 0; NaN -> bin 0; nan_bin disabled for cats
-    assert m.values_to_bins(np.array([123.0]))[0] == 0
-    assert m.values_to_bins(np.array([np.nan]))[0] == 0
-    assert m.nan_bin == -1
+    if with_nan:
+        # unseen category -> the other bin; NaN -> the other bin
+        assert (m.num_bin, m.other_bin, m.nan_bin) == (5, 4, 4)
+        assert m.values_to_bins(np.array([123.0]))[0] == 4
+        assert m.values_to_bins(np.array([np.nan]))[0] == 4
+    else:
+        # unseen category -> bin 0; NaN -> bin 0; nan_bin disabled
+        assert (m.num_bin, m.other_bin, m.nan_bin) == (4, -1, -1)
+        assert m.values_to_bins(np.array([123.0]))[0] == 0
+        assert m.values_to_bins(np.array([np.nan]))[0] == 0
+
+
+@pytest.mark.parametrize("levels", [5, 254, 255, 1000])
+def test_categorical_levels_and_the_other_bin(levels):
+    """``max_bin - 1`` = 254 levels get a bin each, the most frequent
+    first; a column with more keeps ONE more bin, the last, for every row
+    that has none (a rarer level, an unseen code, a negative code, NaN),
+    and ``bin_2_categorical`` round-trips the kept levels."""
+    rng = np.random.default_rng(levels)
+    codes = rng.permutation(levels) * 3 + 1         # not 0..levels-1
+    p = 1.0 / np.arange(1, levels + 1) ** 1.1
+    vals = np.concatenate([codes, codes[rng.choice(levels, size=60000,
+                                                   p=p / p.sum())]])
+    m = BinMapper.find_bin(vals.astype(float), len(vals), max_bin=255,
+                           min_data_in_bin=1, use_missing=True,
+                           zero_as_missing=False, is_categorical=True)
+    kept, other = min(levels, 254), levels > 254
+    assert len(m.bin_2_categorical) == kept
+    assert (m.num_bin, m.other_bin, m.nan_bin) == \
+        (kept + other, kept if other else -1, kept if other else -1)
+    count = np.bincount(vals)
+    by_count = sorted(np.flatnonzero(count), key=lambda c: (-count[c], c))
+    assert m.bin_2_categorical == by_count[:kept]
+    binned = m.values_to_bins(np.asarray(m.bin_2_categorical, float))
+    np.testing.assert_array_equal(binned, np.arange(kept))
+    assert [m.bin_to_value(b) for b in range(kept)] == m.bin_2_categorical
+    fill = kept if other else 0
+    probe = np.array([float(c) for c in by_count[kept:kept + 3]]
+                     + [10.0 ** 6, -4.0, np.nan])
+    np.testing.assert_array_equal(m.values_to_bins(probe), fill)
+    back = BinMapper.from_dict(m.to_dict())
+    assert (back.num_bin, back.other_bin) == (m.num_bin, m.other_bin)
+    np.testing.assert_array_equal(back.values_to_bins(probe), fill)
+    np.testing.assert_array_equal(back.values_to_bins(vals.astype(float)),
+                                  m.values_to_bins(vals.astype(float)))
 
 
 def test_trivial_feature_dropped():

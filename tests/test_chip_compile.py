@@ -281,6 +281,22 @@ def test_partition_kernel_compiles(one_chip, f, n):
     assert c.memory_analysis().temp_size_in_bytes < f * n
 
 
+def test_partition_kernel_with_left_sets_compiles(one_chip):
+    """The static variant a job with categorical columns runs (cell
+    ``allstate-cat-train``: 13,184,290 x 32, K = 42): every slot goes by
+    its left set, 8 words of 32 bins (a numeric slot's made from its
+    ranges before the kernel); the word by compares, the bit by a shift.
+    No ``[K, n]`` temporary (42 x 13.18M i32 would be 2.2 GB), no
+    gather."""
+    f, n = 32, 13_184_290
+    c = _compile(one_chip, _partition, ((f, n), jnp.uint8),
+                 ((n,), jnp.int32), ((n,), jnp.int32), *K_ARGS,
+                 ((8, 42), jnp.int32), ((42,), jnp.int32))
+    _assert_kernel(c, "partition_select_pallas")
+    assert " gather(" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 4 * n
+
+
 def test_take_small_table_kernel_compiles(one_chip, on_tpu):
     c = _compile(one_chip, take_small_table, ((255,), jnp.float32),
                  ((N,), jnp.int32))

@@ -38,12 +38,20 @@ def test_chip_smoke_rehearsal_runs_every_phase_but_cannot_succeed():
     lines = [json.loads(ln) for ln in out.stdout.splitlines()
              if ln.startswith("{")]
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "device", "data", "train", "bundled", "ranking", "predict", "save_load",
-        "serve"]
+        "device", "data", "train", "bundled", "categorical", "ranking",
+        "predict", "save_load", "serve"]
     bundled = lines[3]
     assert bundled["bundle_expand_calls"] == 0 and bundled["bundles"] < 76
     assert bundled["bundle_space_search_rounds"] == bundled["iters"]
-    ranking = lines[4]
+    categorical = lines[4]
+    assert (categorical["cat_features"], categorical["cat_subset_features"]) \
+        == (3, 2)
+    assert categorical["cat_other_rows"] > 0 < categorical["cat_subset_splits"]
+    assert categorical["train_score_gap"] < 1e-4
+    # ... and the job whose categorical columns are all one-hot
+    assert categorical["onehot_only_cat_splits"] > 0
+    assert categorical["onehot_only_train_score_gap"] < 1e-4
+    ranking = lines[5]
     assert ranking["buckets"] >= 4 and ranking["rank_slot_rows"] > ranking["rows"]
     assert ranking["device_against_host_ndcg"] < 1e-5
     assert ranking["valid_ndcg10_last"] > ranking["valid_ndcg10_first"]

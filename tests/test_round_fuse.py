@@ -366,6 +366,123 @@ def test_partition_kernel_matches_xla(n):
     np.testing.assert_array_equal(np.asarray(key), want_key)
 
 
+@pytest.mark.parametrize("n", [2048, 3000, 300])
+def test_partition_kernel_with_left_sets_matches_xla(n):
+    """The variant a job with categorical columns runs: numeric and
+    categorical slots side by side.  Slot 0 numeric with a missing bin,
+    1 a set that holds its column's LAST bin (255: bit 31 of word 7), 2
+    the EMPTY set (every row of the parent moves right), 3 a set of
+    scattered bins across words, 4 numeric, 5 a disabled categorical slot
+    whose parent has rows; the kernel tests every slot's set, a numeric
+    slot's made from its range descriptors (the bits its row of the sets
+    held are not looked at, nor a categorical slot's descriptors).
+    Over no tail, a ragged tail of the last block of 512 rows, and a row
+    count below one block."""
+    rng = np.random.default_rng(11)
+    f, K, B = 6, 6, 256
+    bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
+    bins[::7, 1] = 255
+    lor = rng.integers(0, 7, size=n).astype(np.int32)
+    mask = rng.integers(0, 2, size=n).astype(np.int32)
+    feats = np.array([2, 1, 4, 0, 5, 3], np.int32)
+    thr = np.array([100, 7, 200, 31, 17, 90], np.int32)
+    dl = np.array([1, 0, 1, 0, 0, 1], np.int32)
+    nanb = np.array([255, -1, -1, 254, -1, -1], np.int32)
+    cat = np.array([0, 1, 1, 1, 0, 1], np.int32)
+    sets = np.zeros((K, B), bool)
+    sets[1, [3, 64, 255]] = True
+    sets[3, rng.choice(254, size=40, replace=False)] = True
+    sets[5, :128] = True
+    sets[0, 5] = sets[4, 9] = True      # a numeric slot's stale bits
+    parents = np.array([1, 3, 4, 2, 0, 6], np.int32)
+    new_leaves = np.array([7, 8, 9, 10, 11, 12], np.int32)
+    validk = np.array([1, 1, 1, 1, 1, 0], np.int32)
+    smaller = np.array([1, 8, 9, 2, 11, 12], np.int32)
+
+    words = RF.pack_left_bins(jnp.asarray(sets))
+    assert words.shape == (8, K) and words.dtype == jnp.int32
+    unpacked = (np.asarray(words).astype(np.uint32).T[:, :, None]
+                >> np.arange(32, dtype=np.uint32)) & 1
+    np.testing.assert_array_equal(unpacked.reshape(K, B).astype(bool), sets)
+
+    new_lor, key = RF.partition_select_pallas(
+        jnp.asarray(bins.T), jnp.asarray(lor), jnp.asarray(mask),
+        *grower.split_ranges(jnp.asarray(feats), jnp.asarray(thr),
+                             jnp.asarray(dl),
+                             jnp.full((f,), -1).at[feats].set(nanb), None, B),
+        jnp.asarray(parents), jnp.asarray(new_leaves),
+        jnp.asarray(validk), jnp.asarray(smaller), words, jnp.asarray(cat),
+        rows_per_block=512, interpret=True)
+
+    # XLA reference (the batch grower's partition by table lookup)
+    cols = bins[:, feats].T.astype(np.int32)                  # [K, n]
+    go_left = np.where(cols == nanb[:, None], dl[:, None] != 0,
+                       cols <= thr[:, None])
+    go_left = np.where(cat[:, None] != 0,
+                       np.take_along_axis(sets, cols, axis=1), go_left)
+    in_par = (lor[None, :] == parents[:, None]) & (validk[:, None] != 0)
+    move = in_par & ~go_left
+    want_lor = np.where(move.any(axis=0),
+                        (move * new_leaves[:, None]).sum(axis=0), lor)
+    np.testing.assert_array_equal(np.asarray(new_lor), want_lor)
+    assert (want_lor[lor == 4] == 9).all() and (lor == 4).any()   # the empty set
+    assert (want_lor[(lor == 3) & (bins[:, 1] == 255)] == 3).all()  # last bin
+    lor_m = np.where(mask != 0, want_lor, -1)
+    sel = (lor_m[None, :] == smaller[:, None]).any(axis=0)
+    rows = np.arange(n, dtype=np.int32)
+    np.testing.assert_array_equal(np.asarray(key),
+                                  np.where(sel, rows, rows | (1 << 30)))
+
+
+def test_a_categorical_job_returns_the_model_it_trained():
+    """6,000 rows with a 600-level column through ``lgb.train``'s fused
+    scan: the trees with the partition in the fused kernel (interpret
+    mode) are the XLA path's, and the training scores the job holds are
+    what the model it returns says of the raw training rows.  The parent
+    folded the 346 rarest levels into the most frequent level's bin:
+    wherever a left set held that bin the rows went left in training and
+    right in the returned model."""
+    import chip_smoke
+    import lightgbm_tpu as lgb
+    # four numeric columns, then 600 levels (zipf, codes permuted), 3, 20
+    X, y = chip_smoke._claims(6000, 0)
+    params = dict(objective="binary", metric="auc", num_leaves=15,
+                  min_data_in_leaf=5, verbose=-1, tpu_split_batch=4,
+                  min_data_per_group=20)
+
+    def train(fused):
+        RF._FUSE_TEST_INTERPRET = fused         # read when traced
+        jax.clear_caches()
+        try:
+            ds = lgb.Dataset(X[:5000], label=y[:5000], params=params,
+                             categorical_feature=[4, 5, 6])
+            dv = ds.create_valid(X[5000:], label=y[5000:])
+            evals = {}
+            bst = lgb.train(params, ds, num_boost_round=8, valid_sets=[dv],
+                            callbacks=[lgb.record_evaluation(evals)])
+        finally:
+            RF._FUSE_TEST_INTERPRET = False
+        return bst, evals["valid_0"]["auc"]
+
+    (xla, auc0), (fused, auc1) = train(False), train(True)
+    gb = fused._gbdt
+    assert gb.metrics.counter("fused_rounds") == 8
+    assert gb.metrics.counter("fused_partition_declined") == 0
+    assert xla._gbdt.metrics.counter("fused_partition_declined") == 8
+    assert fused.model_to_string() == xla.model_to_string() and auc0 == auc1
+    mapper = gb.train_set.mappers[4]
+    assert (mapper.num_bin, mapper.other_bin) == (255, 254)
+    assert gb.metrics.counter("cat_other_rows") > 0
+    used = {(int(t.split_feature[i]), len(t.cat_threshold[int(t.cat_split_index[i])]))
+            for t in gb.models for i in range(t.num_leaves - 1)
+            if t.decision_type[i] & 1}
+    assert any(f == 4 and size > 1 for f, size in used)
+    held = np.asarray(gb.scores)[:, 0]
+    said = fused.predict(X[:5000], raw_score=True)
+    np.testing.assert_allclose(held, said, rtol=0, atol=2e-6)
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("batch", [4, 8])
 def test_fused_round_tree_identical(batch):
     """grow_tree_batched with the fused kernels (interpret mode) produces
