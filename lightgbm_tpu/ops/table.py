@@ -7,9 +7,18 @@ A pair of kernels over one [n]-sized index vector and a table bounded by
 the slowest primitive on TPU (~8 ms per 1M rows through XLA's gather,
 docs/PERF_NOTES.md) yet the GBDT score update needs exactly that:
 ``scores += lr * leaf_value[leaf_of_row]`` (reference
-score_updater.hpp:21 AddScore).  Reformulated as a VMEM one-hot
-contraction: per row block, onehot(idx) @ table rides the MXU and costs
-~0.3 ms/1M — ~25x faster than the gather.
+score_updater.hpp:21 AddScore).  Reformulated as a one-hot selection in
+VMEM with the rows on the lanes throughout (``_take_pallas``).  What the
+v5e read, kernel alone at 13,281,250 rows (the Criteo and Allstate cells'
+row count; PERF.md section 6, PR 44): the kernel before PR 44, which
+turned each index block to the sublanes and worked on 16 of a register's
+128 lanes, 37.6 ms at 255 entries (2.8 ms per 1M rows; 38.4 at 31, 43.0
+at 2,048); this one 1.30 ms at any size up to 2,048 entries, 0.3 of it
+the pad and the slice around the call; at the ranking cell's 7,325,625
+rows 20.8 -> 0.76.  (Compares and selects over the whole table, no MXU,
+read 0.47 ms at 31 entries, 1.37 at 255 and 9.1 at 2,048: faster under
+160 entries by half a millisecond at most, and not kept as a second
+form.)
 
 ``sum_small_table``: ``zeros(T).at[idx].add(values)``, the sums INTO the
 table, for two value vectors in one pass over the rows (leaf renewal's
@@ -33,61 +42,104 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .compile_cache import get_or_build, mesh_signature
 from .hist_pallas import _round_up
 
-# keep the in-kernel one-hot under ~4 MB so the scoped-VMEM budget holds at
-# any admitted table size
-_ONEHOT_BUDGET = 1 << 20  # f32 elements
+# the lookup's radix: 8 reads 0.72 ms a call at 13.28M rows up to 1,024 entries
+# and 1.29 at 2,048, 16 reads 1.00 at every size, 32 1.83 (PERF.md, PR 44)
+_TAKE_LO = 16
+_PARTS = 3   # an f32 is the exact sum of three bfloat16 (3 x 8 significand bits)
 
 
-@functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
+def _bf16_parts(v):
+    """``v`` f32 as three bfloat16 whose f32 sum is ``v`` exactly (round
+    to nearest leaves a residual of at most 16, then 8, significant
+    bits), returned widened to f32."""
+    parts = []
+    for _ in range(_PARTS):
+        p = v.astype(jnp.bfloat16).astype(jnp.float32)
+        parts.append(p)
+        v = v - p
+    return parts
+
+
+def _row_blocks(n: int, rows_per_block: int, rows_per_dot: int):
+    """Rows a grid step and rows a chunk of it (which bounds the one-hots'
+    VMEM): whole lane tiles, a block whole chunks, neither past ``n``."""
+    cap = max(128, _round_up(n, 128))
+    sub = min(rows_per_dot, cap)
+    return _round_up(min(rows_per_block, cap), sub), sub
+
+
+@functools.partial(jax.jit, static_argnames=("rows_per_block", "rows_per_dot",
+                                             "interpret"))
 def _take_pallas(idx: jax.Array, table: jax.Array, *,
-                 rows_per_block: int = 8192,
+                 rows_per_block: int = 8192, rows_per_dot: int = 1024,
                  interpret: bool = False) -> jax.Array:
-    n = idx.shape[0]
-    t = table.shape[0]
-    t_pad = _round_up(max(t, 1), 128)
-    if t_pad != t:
-        table = jnp.pad(table, (0, t_pad - t))
-    blk = min(rows_per_block, max(128, _ONEHOT_BUDGET // t_pad // 128 * 128))
-    blk = min(blk, max(128, _round_up(n, 128)))
-    n_pad = _round_up(max(n, 1), blk)
-    if n_pad != n:
-        idx = jnp.pad(idx, (0, n_pad - n))
-    nb = n_pad // blk
-    idx2 = idx[None, :]
-    table2 = table.reshape(t_pad // 16, 16)   # radix rows (hi, lo)
+    """``table[idx]`` with the ROWS ON THE LANES from load to store and the
+    table along the sublanes, as ``_sum_pallas`` lays the same index
+    vector: the index block ``[1, rows]`` is never turned to sublanes.
 
-    nhi = t_pad // 16
+    With ``idx = nlo * hi + lo``, the ``nlo`` candidates ``table[nlo * hi
+    + (0 .. nlo)]`` of every row of a chunk come from ONE MXU product of
+    the table's three exact bfloat16 parts ``[3 * nlo, nhi]`` with the
+    one-hot of ``hi`` ``[nhi, rows]`` (a part times 0 or 1, one term a
+    sum: exact in f32; the parts add up small first, which is exact
+    too).  The row's own is then picked by ``lo``: one compare and one
+    select a sublane tile into ``[8, rows]`` (a row matches at most once,
+    so the chain's last word is the table's, bit for bit) and one sum
+    over the sublanes, seven of whose terms are 0.  The time follows
+    ``nlo`` (the candidate rows popped and added), not the table's size.
+    An index outside ``[0, size)`` matches nothing and reads 0.0.  (A
+    non-finite entry spoils every row, as it did in the one-hot product
+    before PR 44.)
+
+    The index is padded to whole blocks and the result sliced back, in
+    the shapes the kernel before PR 44 had (blocks of 8,192 rows): two
+    row-sized copies, 0.3 ms a call at 13.28M rows.  A ragged last block
+    needs neither (built and measured in PR 44: PERF.md section 6), but
+    with them gone XLA's memory-space assignment moved other row-sized
+    buffers into VMEM in the ranking cell's round program (7,325,625
+    rows: a vector is 28 MiB of the 128) and the flat histogram kernel
+    there, whose 27 MiB output block fits only while XLA keeps it in
+    VMEM, no longer compiled.  Until those kernels state the VMEM they
+    need, the program around this call stays the one every cell compiled.
+    """
+    n, size = idx.shape[0], table.shape[0]
+    blk, sub = _row_blocks(n, rows_per_block, rows_per_dot)
+    n_pad = _round_up(max(n, 1), blk)
+    idx = jnp.pad(idx, (0, n_pad - n))
+    nlo, shift = _TAKE_LO, _TAKE_LO.bit_length() - 1
+    nhi = _round_up(pl.cdiv(max(size, 1), nlo), 16)     # a bfloat16 tile
+    tab = jnp.pad(table, (0, nlo * nhi - size)).reshape(nhi, nlo).T
 
     def kernel(idx_ref, tab_ref, out_ref):
-        ix = idx_ref[0, :]                                   # [blk] i32
-        # radix-split lookup: idx = 16*hi + lo.  tmp = oh_hi @ TAB[nhi, 16]
-        # then a 16-wide elementwise select on lo — 2*(nhi+16) one-hot
-        # elements per row instead of t_pad (same trick as the histogram
-        # radix kernels; measured ~5x on the 1M-row score update).
-        # HIGHEST precision: the one-hot payload must come through exact.
-        hi = ix >> 4
-        lo = ix & 15
-        iota_h = lax.iota(jnp.int32, nhi)
-        oh_hi = (hi[:, None] == iota_h[None, :]).astype(jnp.float32)
-        tmp = lax.dot_general(
-            oh_hi, tab_ref[:, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST)                 # [blk, 16]
-        iota_l = lax.iota(jnp.int32, 16)
-        sel = (lo[:, None] == iota_l[None, :]).astype(jnp.float32)
-        out_ref[0, :] = jnp.sum(tmp * sel, axis=1)
+        parts = jnp.concatenate(_bf16_parts(tab_ref[...]), axis=0
+                                ).astype(jnp.bfloat16)       # [3 * nlo, nhi]
+        iota_h = lax.broadcasted_iota(jnp.int32, (nhi, sub), 0)
+        iota_8 = lax.broadcasted_iota(jnp.int32, (8, sub), 0)
+        for c in range(blk // sub):
+            cols = slice(c * sub, (c + 1) * sub)
+            ix = idx_ref[:, cols]                            # [1, sub]
+            oh_hi = ((ix >> shift) == iota_h).astype(jnp.bfloat16)
+            cand = lax.dot_general(
+                parts, oh_hi, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [3 * nlo, sub]
+            cand = (cand[2 * nlo:] + cand[nlo:2 * nlo]) + cand[:nlo]
+            at = (ix & (nlo - 1)) - iota_8                   # [8, sub]
+            acc = jnp.zeros((8, sub), jnp.float32)
+            for r in range(0, nlo, 8):
+                acc = jnp.where(at == r, cand[r:r + 8], acc)
+            out_ref[:, cols] = jnp.sum(acc, axis=0, keepdims=True)
 
     out = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=(n_pad // blk,),
         in_specs=[
             pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((t_pad // 16, 16), lambda i: (0, 0)),
+            pl.BlockSpec((nlo, nhi), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
-    )(idx2, table2)
+    )(idx[None, :], tab)
     return out[0, :n]
 
 
@@ -140,21 +192,6 @@ def take_small_table(table: jax.Array, idx: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------- the sums
-_PARTS = 3   # an f32 is the exact sum of three bfloat16 (3 x 8 significand bits)
-
-
-def _bf16_parts(v):
-    """``v`` f32 as three bfloat16 whose f32 sum is ``v`` exactly (round
-    to nearest leaves a residual of at most 16, then 8, significant
-    bits), returned widened to f32."""
-    parts = []
-    for _ in range(_PARTS):
-        p = v.astype(jnp.bfloat16).astype(jnp.float32)
-        parts.append(p)
-        v = v - p
-    return parts
-
-
 @functools.partial(jax.jit, static_argnames=("size", "rows_per_block",
                                              "rows_per_dot", "interpret"))
 def _sum_pallas(idx: jax.Array, g: jax.Array, h: jax.Array,
@@ -181,9 +218,7 @@ def _sum_pallas(idx: jax.Array, g: jax.Array, h: jax.Array,
     nlo, shift = 128, 7
     nhi = _round_up(pl.cdiv(max(size, 1), nlo), 8)
     rows = 2 * _PARTS * nhi
-    cap = max(128, _round_up(n, 128))
-    sub = min(rows_per_dot, cap)        # bounds the one-hots' VMEM
-    blk = _round_up(min(rows_per_block, cap), sub)
+    blk, sub = _row_blocks(n, rows_per_block, rows_per_dot)
     operands = [jnp.asarray(idx, jnp.int32)[None, :],
                 jnp.asarray(g, jnp.float32)[None, :],
                 jnp.asarray(h, jnp.float32)[None, :]]

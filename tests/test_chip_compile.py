@@ -297,10 +297,18 @@ def test_partition_kernel_with_left_sets_compiles(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 4 * n
 
 
-def test_take_small_table_kernel_compiles(one_chip, on_tpu):
-    c = _compile(one_chip, take_small_table, ((255,), jnp.float32),
-                 ((N,), jnp.int32))
+@pytest.mark.parametrize("n,size", [(N, 255), (CRITEO_SHARE_ROWS, 31),
+                                    (CRITEO_SHARE_ROWS, 255),
+                                    (CRITEO_SHARE_ROWS, 2048)])
+def test_take_small_table_kernel_compiles(one_chip, on_tpu, n, size):
+    """At the cells' row count and the smallest and largest one-hot of
+    ``hi`` (16 and 128 sublanes).  The program around the call keeps the
+    shapes every cell's round program compiled with: the index padded to
+    whole blocks, the result sliced back (``_take_pallas`` says why)."""
+    c = _compile(one_chip, take_small_table, ((size,), jnp.float32),
+                 ((n,), jnp.int32))
     _assert_kernel(c, "_take_pallas")
+    assert c.memory_analysis().temp_size_in_bytes <= 8 * (n + 8192)
 
 
 def test_take_small_table_compiles_per_shard_for_four_chips(topo):
@@ -308,15 +316,18 @@ def test_take_small_table_compiles_per_shard_for_four_chips(topo):
     shard_map grower returns.  The chip refuses the bare kernel there
     ("Mosaic kernels cannot be automatically partitioned", first met on
     four chips in PR 24); the per-shard form take_small_table routes to
-    compiles for the 2x2 mesh."""
+    compiles for the 2x2 mesh, at the four-chip cell's rows a chip."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices), ("data",))
-    idx = jax.ShapeDtypeStruct((N,), jnp.int32,
+    n = 4 * CRITEO_SHARE_ROWS
+    idx = jax.ShapeDtypeStruct((n,), jnp.int32,
                                sharding=NamedSharding(mesh, P("data")))
     table = jax.ShapeDtypeStruct((255,), jnp.float32,
                                  sharding=NamedSharding(mesh, P()))
     c = _take_per_shard(mesh, P("data")).lower(idx, table).compile()
     _assert_kernel(c, "_take_pallas")
+    assert c.memory_analysis().temp_size_in_bytes <= 8 * (
+        CRITEO_SHARE_ROWS + 8192)
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["all_rows", "masked"])

@@ -1,4 +1,4 @@
-"""ops/table.py: the pair of one-hot MXU kernels over a table bounded by
+"""ops/table.py: the pair of one-hot kernels over a table bounded by
 ``num_leaves`` — ``take_small_table`` (lookup from it, the score update)
 and ``sum_small_table`` (sums into it, leaf renewal) — in interpret mode
 against their XLA references, and the routing that picks a path from
@@ -337,16 +337,42 @@ def test_data_parallel_training_renews_leaves_per_shard(monkeypatch):
 
 
 # ------------------------------------------------------------- the lookup
-@pytest.mark.parametrize("size", [2, 31, 255, 2048])
-@pytest.mark.parametrize("n", [256, 257, 13, 1])
-def test_take_kernel_against_indexing(n, size):
-    rng = np.random.default_rng(n + size)
+def _lookup_case(n, size, seed):
+    rng = np.random.default_rng(seed)
     idx = rng.integers(-2, size + 3, size=n).astype(np.int32)
     table = (rng.normal(size=size)
              * 10.0 ** rng.uniform(-8, 3, size=size)).astype(np.float32)
-    got = T._take_pallas(idx, table, rows_per_block=128, interpret=True)
     ok = (idx >= 0) & (idx < size)
     want = np.where(ok, table[np.clip(idx, 0, size - 1)], np.float32(0))
+    return idx, table, want
+
+
+# n, rows_per_block, rows_per_dot: whole blocks, a tail of one row, n below
+# a chunk, one row; a block of four chunks whose last block ends in its
+# third chunk; n below 128 under the shipped block shape
+TAKE_SHAPES = [(256, 128, 128), (257, 128, 128), (13, 128, 128),
+               (1, 128, 128), (1337, 512, 128), (127, 8192, 1024)]
+
+
+@pytest.mark.parametrize("size", [2, 8, 9, 16, 17, 31, 255, 256, 257, 1024,
+                                  2048])
+@pytest.mark.parametrize("n,blk,dot", TAKE_SHAPES)
+def test_take_kernel_against_indexing(n, blk, dot, size):
+    """Bit for bit at every size (one entry short of, on and past a
+    radix digit and a one-hot tile), strays on either side reading 0."""
+    idx, table, want = _lookup_case(n, size, n + size)
+    got = T._take_pallas(idx, table, rows_per_block=blk, rows_per_dot=dot,
+                         interpret=True)
+    assert got.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("size", [31, 255])
+def test_take_kernel_default_blocks_walk_the_tail(size):
+    """The shipped block shape on a row count that no block divides."""
+    n = 4 * 8192 + 1250
+    idx, table, want = _lookup_case(n, size, size)
+    got = T._take_pallas(idx, table, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
