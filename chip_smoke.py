@@ -8,7 +8,9 @@ weights are whatever ``--iters`` rounds learn from ``--seed``:
 
     device -> train (fused scan + valid set) -> bundled (CSR in, EFB)
            -> categorical (a 600-level column, splits by sets of bins)
-           -> ranking -> predict -> save/load -> serve
+           -> ranking -> wide (2,000 dense columns: the histogram
+           kernels' output in column blocks) -> predict -> save/load
+           -> serve
 
 One process, no children that touch JAX, JAX imported once.  Every phase
 prints one JSON line as it finishes and raises on any failure, so the
@@ -23,6 +25,7 @@ runs the same phases at a tiny size on the CPU backend to find wrong
 paths and arguments before chip time is spent, skips the checks only a
 chip can pass, and can never print the success line.
 
+``--only wide`` runs the device phase and the wide phase alone.
 ``--chips 4`` runs ONLY the four-chip path and what it is compared with:
 the same job trained ``tree_learner=data`` over all four chips and
 ``tree_learner=serial`` on device 0.
@@ -562,6 +565,68 @@ def phase_ranking(args, lgb):
           device_against_host_ndcg=gap, smoke_train_s=round(secs, 2))
 
 
+WIDE_FEATURES = 2000
+
+
+def phase_wide(args, lgb):
+    """A short wide dense job: 2,000 numeric columns (upstream's Epsilon
+    width, a tenth of the rows a phase takes), 255 bins, 255 leaves.  Every
+    histogram kernel's output is blocked over the columns under the one
+    VMEM budget (ops/hist_pallas.py ``col_blocks``): the booster counts
+    the blocks, the per-leaf state's bytes and the budget, every round runs
+    in the fused scan, and on a chip the compiled round program holds the
+    payload, compaction and partition kernels under their scopes."""
+    import jax
+
+    t0 = time.time()
+    tiny = args.rehearse_cpu
+    rows = 4096 if tiny else max(args.rows // 10, 100_000)
+    valid_rows = max(args.valid_rows // 10, 1024)
+    rng = np.random.default_rng(args.seed + 45)
+    w = rng.normal(size=WIDE_FEATURES)
+    w *= 3.0 / np.linalg.norm(w)
+    y = (rng.random(rows + valid_rows) < 0.5).astype(np.float64)
+    X = rng.standard_normal((rows + valid_rows, WIDE_FEATURES),
+                            dtype=np.float32).astype(np.float64)
+    X += (2 * y - 1)[:, None] * w[None, :]
+    params = {**PARAMS, "min_data_in_leaf": 1,
+              "bin_construct_sample_cnt": 20_000}
+    if tiny:
+        # under 100,000 rows auto mode takes the strict grower: ask for
+        # the batched grower and int8 histograms by name
+        params.update(num_leaves=15, min_sum_hessian_in_leaf=1,
+                      tpu_split_batch=8, tpu_hist_dtype="int8",
+                      use_quantized_grad=True, quant_train_renew_leaf=True)
+    ds = lgb.Dataset(X[:rows], label=y[:rows], params=params).construct()
+    dv = ds.create_valid(X[rows:], label=y[rows:])
+    bst, auc, secs = _train(lgb, params, ds, dv, args.iters)
+    gb = bst._gbdt
+    _require(_took_fused_path(bst, args.iters), "fused path not taken")
+    blocks = int(gb.metrics.counter("hist_col_blocks"))
+    state = int(gb.metrics.counter("hist_state_bytes"))
+    budget = int(gb.metrics.counter("hist_vmem_budget_bytes"))
+    _require(blocks > 1, f"{blocks} column block(s) at {WIDE_FEATURES} columns")
+    _require(state == gb.hp.num_leaves * WIDE_FEATURES * gb.hp.n_bins * 16,
+             f"hist_state_bytes {state}")
+    # (a direction over 2,000 columns takes more rounds than a smoke has)
+    _require(len(auc) == args.iters and 0.6 < auc[0] < auc[-1],
+             f"valid AUC per round: {auc}")
+    calls = None
+    if jax.devices()[0].platform == "tpu":
+        text = _fused_program_text(gb)
+        calls = text.count("tpu_custom_call")
+        _require_kernel(text, "histogram_payload_pallas", "hist_kernel",
+                        "no payload kernel under hist_kernel in the wide "
+                        "round program")
+        _require_partition_kernel(text, "the wide round program")
+        _require_compaction_kernel(text, "the wide round program")
+    _emit("wide", t0, rows=rows, features=WIDE_FEATURES, iters=args.iters,
+          hist_col_blocks=blocks, hist_state_bytes=state,
+          hist_vmem_budget_bytes=budget, tpu_custom_calls=calls,
+          valid_auc_first=auc[0], valid_auc_last=auc[-1],
+          smoke_train_s=round(secs, 2))
+
+
 def phase_predict(args, bst, X):
     import jax
     from lightgbm_tpu.boosting.gbdt import GBDT
@@ -709,6 +774,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="rehearse the phases on the CPU backend at a tiny "
                          "size; never prints the success line")
+    ap.add_argument("--only", choices=("wide",), default=None,
+                    help="run the device phase and this one phase alone")
     args = ap.parse_args(argv)
     tiny = args.rehearse_cpu
     # 100,000 rows is the least at which auto mode engages (K=42 / int8)
@@ -718,6 +785,11 @@ def main(argv=None) -> int:
 
     device = phase_device(args)
     import lightgbm_tpu as lgb
+    if args.only == "wide":
+        phase_wide(args, lgb)
+        print(json.dumps({"ok": not tiny, "only": "wide", "device": device}),
+              flush=True)
+        return 0
     t0 = time.time()
     data = _make_data(args)
     _emit("data", t0, seed=args.seed, rows=args.rows,
@@ -729,6 +801,7 @@ def main(argv=None) -> int:
         phase_bundled(args, lgb)
         phase_categorical(args, lgb)
         phase_ranking(args, lgb)
+        phase_wide(args, lgb)
         phase_predict(args, bst, data[0])
         phase_save_load(lgb, bst, data[0])
         phase_serve(bst, data[0])

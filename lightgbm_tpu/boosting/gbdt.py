@@ -53,8 +53,12 @@ GradFn = Callable[[np.ndarray, Any], Tuple[np.ndarray, np.ndarray]]
 
 #: a valid set's transposed bins larger than this ride the fused round
 #: program as an argument; smaller ones stay literals of its HLO, which
-#: is how every job before the ranking cell's 688 MB compiled them
-_LITERAL_MAX_BYTES = 256 << 20
+#: is how every job before the ranking cell's 688 MB compiled them.  A
+#: literal is stored with the executable about three times over: the
+#: Epsilon job's 200 MB made a 647 MB entry, which the persistent compile
+#: cache refused (192 MiB an entry on the chip tool's machines), so every
+#: process compiled the round program again (PERF.md section 6, PR 45)
+_LITERAL_MAX_BYTES = 128 << 20
 
 
 @functools.lru_cache(maxsize=None)
@@ -446,6 +450,9 @@ class GBDT:
         if fin["rows"]:
             self._count("hist_rows_selected", fin["rows"])
             counts["hist_rows_selected"] = fin["rows"]
+            # the histograms those rows went into: a root's and one smaller
+            # child's a split (what a pass has to write, whatever it writes)
+            counts["hist_leaves_built"] = fin["hists"]
         if self.hp.has_categorical:
             self._count("cat_splits", fin["cat_splits"])
             self._count("cat_subset_splits", fin["cat_subset_splits"])
@@ -785,6 +792,18 @@ class GBDT:
                     # columns directly — batch_grower.forced_col_hist)
                     self.hp = dataclasses.replace(
                         self.hp, hist_pool_slots=slots)
+
+        # what the histogram kernels and their state take at this width,
+        # once a booster (docs/OBSERVABILITY.md): the column blocks of a
+        # compacted pass over the split batch's leaves, the per-leaf state
+        # the grower carries, the VMEM budget the blocks follow from
+        from ..ops import hist_pallas
+        self._count("hist_col_blocks", hist_pallas.pass_col_blocks(
+            n_cols, max(1, int(config.tpu_split_batch)), self.hp.n_bins,
+            self.hp.hist_dtype))
+        self._count("hist_state_bytes", bytes_per_leaf * (
+            self.hp.hist_pool_slots or self.hp.num_leaves))
+        self._count("hist_vmem_budget_bytes", hist_pallas.VMEM_BUDGET_BYTES)
 
         # packed-word mirror (round-6 packed histogram mode): ship the
         # dataset's construction-time mirror ONCE per booster instead of
@@ -1769,8 +1788,8 @@ class GBDT:
                 mhost = np.asarray(jax.device_get(mvals)) \
                     if nvalid else None
             # what this dispatch finalized, for its closing span
-            fin = {"rounds": 0, "trees": 0, "rows": 0, "splits": 0,
-                   "cat_splits": 0, "cat_subset_splits": 0,
+            fin = {"rounds": 0, "trees": 0, "rows": 0, "hists": 0,
+                   "splits": 0, "cat_splits": 0, "cat_subset_splits": 0,
                    "cat_left_levels": 0, "declined": 0}
             from ..learner.batch_grower import fuses_partition
             declined = self._use_batched_grower() and \
@@ -1788,6 +1807,7 @@ class GBDT:
                         fin["trees"] += 1
                         if count_rows:
                             fin["rows"] += _hist_rows_selected(arrays_tc, n_rows)
+                            fin["hists"] += max(int(arrays_tc.num_leaves), 1)
                         if self.hp.has_categorical:
                             ni = int(arrays_tc.num_leaves) - 1
                             cat = np.asarray(arrays_tc.split_cat[:ni], bool)

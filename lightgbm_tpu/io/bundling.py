@@ -47,6 +47,8 @@ from typing import List, NamedTuple, Optional
 
 import numpy as np
 
+from .binning import columns_first
+
 MAX_BUNDLE_BINS = 256  # uint8 storage
 
 
@@ -88,10 +90,11 @@ def plan_bundles(bins: np.ndarray, num_bins: np.ndarray,
     # default (most frequent) bin per feature + non-default rows of the sample
     default_bin = np.zeros(num_f, np.int32)
     nz_rows = []
+    sample = columns_first(sample)      # a feature's bins contiguous
     for f in range(num_f):
-        counts = np.bincount(sample[:, f], minlength=int(num_bins[f]))
+        counts = np.bincount(sample[f], minlength=int(num_bins[f]))
         default_bin[f] = int(np.argmax(counts))
-        nz_rows.append(np.flatnonzero(sample[:, f] != default_bin[f]))
+        nz_rows.append(np.flatnonzero(sample[f] != default_bin[f]))
     return _plan_from_rows(nz_rows.__getitem__,
                            np.array([len(r) for r in nz_rows], np.int64),
                            default_bin, num_bins, ns, max_conflict_rate,

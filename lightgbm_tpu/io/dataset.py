@@ -25,7 +25,7 @@ from ..config import Config, as_config
 from ..obs.metrics import count_event
 from ..utils import log
 from ..utils.timer import global_timer, phase
-from .binning import BIN_CATEGORICAL, BinMapper
+from .binning import BIN_CATEGORICAL, BinMapper, columns_first
 from .bundling import (BundlePlan, apply_bundles, bundle_ranges, plan_bundles,
                        plan_bundles_sparse)
 
@@ -35,6 +35,22 @@ MAX_UINT8_BINS = 256
 #: v1 = unversioned seed format (marker only); v2 adds the version field
 #: and streaming-ingest provenance.  Readers accept <= their own version.
 BINARY_FORMAT_VERSION = 2
+
+
+#: columns the dense construct handles at a time (``_construct_mappers``,
+#: ``_bin_matrix``)
+_COL_BLOCK = 64
+
+
+def _column_block(arr: np.ndarray, cols, rows=slice(None)) -> np.ndarray:
+    """Columns ``cols`` of ``arr`` at ``rows`` (each a slice or sorted
+    indices, not indices both), each column contiguous: C-contiguous
+    ``[columns, rows]``, whichever way ``arr`` lies in memory."""
+    if arr.T.flags.c_contiguous:        # the columns lie contiguous
+        block = arr.T[cols]
+        return block if isinstance(rows, slice) \
+            else np.take(block, rows, axis=1)
+    return columns_first(arr[rows, cols])
 
 
 def device_bins_pow2(widest: int) -> int:
@@ -480,26 +496,31 @@ class Dataset:
         sample_cnt = min(n, int(cfg.bin_construct_sample_cnt))
         if sample_cnt < n:
             rng = np.random.default_rng(cfg.data_random_seed)
-            sample_rows = rng.choice(n, size=sample_cnt, replace=False)
-            sample = arr[np.sort(sample_rows)]
+            sample_rows = np.sort(rng.choice(n, size=sample_cnt,
+                                             replace=False))
         else:
-            sample = arr
+            sample_rows = slice(None)
         mbf = list(cfg.max_bin_by_feature or [])
         forced = _load_forced_bins(cfg, f)
         self.mappers = []
         cat_set = set(cat_idx)
-        for j in range(f):
-            fmax = mbf[j] if j < len(mbf) and mbf[j] > 1 else max_bin
-            with _cat_span(j in cat_set):
-                m = BinMapper.find_bin(
-                    sample[:, j], total_sample_cnt=len(sample),
-                    max_bin=int(fmax),
-                    min_data_in_bin=int(cfg.min_data_in_bin),
-                    use_missing=bool(cfg.use_missing),
-                    zero_as_missing=bool(cfg.zero_as_missing),
-                    is_categorical=(j in cat_set),
-                    forced_bounds=forced.get(j))
-            self.mappers.append(m)
+        for j0 in range(0, f, _COL_BLOCK):
+            # a block of columns at a time, each column contiguous: one
+            # column of a row-major matrix of thousands is a cache miss
+            # a value
+            block = _column_block(arr, slice(j0, j0 + _COL_BLOCK), sample_rows)
+            for j in range(j0, j0 + len(block)):
+                fmax = mbf[j] if j < len(mbf) and mbf[j] > 1 else max_bin
+                with _cat_span(j in cat_set):
+                    m = BinMapper.find_bin(
+                        block[j - j0], total_sample_cnt=block.shape[1],
+                        max_bin=int(fmax),
+                        min_data_in_bin=int(cfg.min_data_in_bin),
+                        use_missing=bool(cfg.use_missing),
+                        zero_as_missing=bool(cfg.zero_as_missing),
+                        is_categorical=(j in cat_set),
+                        forced_bounds=forced.get(j))
+                self.mappers.append(m)
         self.used_feature_idx = [j for j in range(f)
                                  if not self.mappers[j].is_trivial()]
         dropped = f - len(self.used_feature_idx)
@@ -568,11 +589,20 @@ class Dataset:
         if arr.shape[1] != self.num_total_features:
             log.fatal(f"The number of features in data ({arr.shape[1]}) does not "
                       f"match Dataset ({self.num_total_features})")
-        for col, j in enumerate(used):
-            m = self.mappers[j]
-            with _cat_span(in_construct and m.bin_type == BIN_CATEGORICAL):
-                bins[:, col] = m.values_to_bins(arr[:, j]).astype(np.uint8)
-        return np.ascontiguousarray(bins)
+        # by blocks of columns, as ``_construct_mappers`` reads them
+        for c0 in range(0, len(used), _COL_BLOCK):
+            cols = used[c0:c0 + _COL_BLOCK]
+            whole = cols[-1] - cols[0] + 1 == len(cols)
+            block = _column_block(
+                arr, slice(cols[0], cols[-1] + 1) if whole else cols)
+            out = np.empty((len(cols), n), dtype=np.uint8)
+            for k, j in enumerate(cols):
+                m = self.mappers[j]
+                with _cat_span(in_construct
+                               and m.bin_type == BIN_CATEGORICAL):
+                    out[k] = m.values_to_bins(block[k])
+            columns_first(out, into=bins[:, c0:c0 + len(cols)])
+        return bins
 
     def bin_external(self, arr: np.ndarray) -> np.ndarray:
         """Bin an EXTERNAL raw matrix with this dataset's mappers (and

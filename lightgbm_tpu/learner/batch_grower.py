@@ -94,6 +94,20 @@ def _recount_leaves(leaf_of_row: jax.Array, mask_f: jax.Array, size: int,
         return counts.astype(jnp.float32)
 
 
+def write_children(hist: jax.Array, parents: jax.Array,
+                   new_leaves: jax.Array, valid: jax.Array,
+                   h_left: jax.Array, h_right: jax.Array) -> jax.Array:
+    """A round's update of the per-leaf histogram state ``[L, F, B, C]``:
+    each valid slot's left child takes its parent's place, its right child
+    the new leaf's; an invalid slot writes back what was there.  Two
+    scatters of ``[K, F, B, C]`` into the carried state, in place inside
+    the round loop (the state is never copied: PERF.md section 5)."""
+    hist = hist.at[parents].set(
+        jnp.where(valid[:, None, None, None], h_left, hist[parents]))
+    return hist.at[new_leaves].set(
+        jnp.where(valid[:, None, None, None], h_right, hist[new_leaves]))
+
+
 def fuses_partition(bundle: Optional[DeviceBundle]) -> bool:
     """Whether a round's row partition runs in ops/round_fuse.py's
     kernel: a Pallas backend, and every split a range predicate on its
@@ -969,14 +983,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       h_large = h_parent - h_small
                       h_left = jnp.where(left_small, h_small, h_large)
                       h_right = jnp.where(left_small, h_large, h_small)
-                      hist = st["hist"]
-                      hist = hist.at[parents].set(
-                          jnp.where(valid[:, None, None, None], h_left,
-                                    hist[parents]))
-                      hist = hist.at[safe_nl].set(
-                          jnp.where(valid[:, None, None, None], h_right,
-                                    hist[safe_nl]))
-                      st["hist"] = hist
+                      st["hist"] = write_children(
+                          st["hist"], parents, safe_nl, valid, h_left,
+                          h_right)
               else:
                   # -- bounded pool: parents with an evicted histogram get
                   # BOTH children computed directly (no subtraction);

@@ -253,6 +253,19 @@ COUNTERS: Dict[str, str] = {
     "valid_mirror_host_bytes":
         "bytes of those mirrors transposed on the host and copied to "
         "the device: 0 since PR 39, what a return to the host path shows",
+    "hist_col_blocks":
+        "column blocks of one compacted histogram pass over the split "
+        "batch's leaves (ops/hist_pallas.py col_blocks: 1 where the "
+        "[3K, F x bins] accumulator fits the VMEM budget whole), once a "
+        "booster",
+    "hist_state_bytes":
+        "bytes of the per-leaf histogram state the grower carries "
+        "through a tree ([leaves or pool slots, F, bins, 4] f32), once a "
+        "booster",
+    "hist_vmem_budget_bytes":
+        "the one VMEM budget every histogram and partition kernel's "
+        "blocks follow from (ops/hist_pallas.py VMEM_BUDGET_BYTES), once "
+        "a booster",
     "construct_bin_mappers_s":
         "seconds of the dense Dataset.construct in the span "
         "`dense_bin_mappers` (row sample and the bin finder of every "

@@ -15,7 +15,19 @@ einsum can't be used on the hot path):
 Layouts put the row dimension last (lane dim, 128-aligned):
   bins_T [F, n] uint8, vals_T [C, n] f32, out [C, F*B] f32.
 The sequential TPU grid revisits the same output block, giving cheap
-cross-block accumulation (zeroed at step 0 via pl.when).
+cross-block accumulation (zeroed at a block's first row step via pl.when).
+
+Every kernel's OUTPUT is blocked over the columns: the grid is (column
+blocks, row blocks) with the row blocks innermost, the accumulator of one
+column block resident while its rows stream and only that block's bins (or
+word rows) read, each input byte once a pass.  ``col_blocks`` sizes the
+block from the one VMEM budget (``VMEM_BUDGET_BYTES``), the number of leaf
+channels, the bins and the accumulator's type, and bounds what a body
+unrolls; each ``pallas_call`` tells the compiler what its blocks take
+(``vmem_limit``).  Where the whole accumulator fits (every shape up to a
+few hundred columns) there is one column block and the kernel is what it
+was before there were blocks; at 2,000 columns every pass takes 63 blocks of
+32 columns.
 
 The contraction dtype defaults to float32 for split-decision parity with
 the reference (its CUDA learner accumulates fp64 by default, config.h:1129
@@ -28,6 +40,7 @@ channel stays exact since 1.0 is representable.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +51,86 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+#: The ONE VMEM budget: no Pallas kernel of the histogram or partition path
+#: may hold more than this at once (72 of a v5e core's 128 MiB: XLA's
+#: memory-space assignment keeps row vectors and small tables in the rest
+#: around a kernel).  Every block size below follows from it and from the
+#: shapes a kernel is given, and every ``pallas_call`` hands the compiler
+#: what its blocks take of it (``vmem_limit``) instead of compiling under
+#: Mosaic's 16 MiB default by the assignment's leave.
+VMEM_BUDGET_BYTES = 72 << 20
+#: the part of the budget left to a kernel's input windows (two buffers
+#: each) and its body's temporaries (13.0 MiB in the K = 4 radix kernel's
+#: float32 mode at 2,048 rows, the most any takes); what remains holds
+#: output blocks
+_VMEM_BODY_BYTES = 16 << 20
+#: what Mosaic grants a kernel that states nothing
+_VMEM_DEFAULT_BYTES = 16 << 20
+#: a column block is whole tiles of the resident operands: 32 rows of the
+#: u8 bins, 8 rows of their packed words
+_COL_TILE = 32
+#: chunks (contractions) a kernel body unrolls at most
+_BLOCK_CHUNKS = 64
+
+
+def col_blocks(num_cols: int, col_bytes: int, chunk_cols: int = 1):
+    """``(cb, ncb)``: the columns of one output block and the number of
+    blocks a histogram kernel's grid takes over ``num_cols`` columns whose
+    accumulator is ``col_bytes`` a column, ``chunk_cols`` columns to one
+    unrolled contraction.
+
+    One block (the whole accumulator resident once, no operand blocked)
+    where that fits the budget and the unroll; else blocks of ONE tile of
+    columns, the last one ragged on the way in.  What a body unrolls is
+    what the chip's compiler takes its time over, and more than in
+    proportion (a kernel of 224 to 256 columns a block took it 14 to 29 s
+    and the round program of 2,000 columns 141 s; at 32 columns 2 to 3 s
+    a kernel and 56 s), while a pass gains nothing from a wider block:
+    each input byte is read once whatever the width, and a grid step costs
+    a third of a microsecond beside the tens its contractions take."""
+    room = VMEM_BUDGET_BYTES - _VMEM_BODY_BYTES
+    if num_cols * col_bytes <= room and num_cols <= _BLOCK_CHUNKS * chunk_cols:
+        return num_cols, 1
+    return _COL_TILE, pl.cdiv(num_cols, _COL_TILE)
+
+
+def vmem_limit(block_bytes: int, blocks: int = 1) -> int:
+    """What a kernel tells the compiler it holds: its output block (two
+    buffers where the grid moves it) and the body's share, never under
+    Mosaic's default nor over the budget."""
+    held = block_bytes * (1 if blocks == 1 else 2) + _VMEM_BODY_BYTES
+    return min(VMEM_BUDGET_BYTES, max(_VMEM_DEFAULT_BYTES, held))
+
+
+def _leaf_col_bytes(K: int, n_bins: int, compute_dtype) -> int:
+    """Accumulator bytes a column of a K-leaf pass: ``[3K, bins]``."""
+    return 3 * K * n_bins * jnp.dtype(_acc_dtype(compute_dtype)).itemsize
+
+
+def pass_col_blocks(num_f: int, K: int, n_bins: int, hist_dtype) -> int:
+    """Column blocks of one compacted pass over ``K`` leaves (the payload
+    kernel's grid; the flat kernel's differs by a tile at most): what the
+    counter ``hist_col_blocks`` says of a job."""
+    col_bytes = _leaf_col_bytes(K, n_bins, jnp.dtype(hist_dtype).type)
+    return col_blocks(4 * pl.cdiv(num_f, 4), col_bytes, 4)[1]
+
+
+def _col_grid(ncb: int, nb: int):
+    """The grid over (column blocks, row blocks), rows innermost, and what
+    adapts a kernel to it: ``at(fn)`` makes ``fn(j, i, *prefetch)`` an
+    index map of the grid, ``axis`` is the row blocks' grid axis.  With one
+    column block the grid is the row blocks alone, as before there were
+    column blocks."""
+    if ncb == 1:
+        return (nb,), (lambda fn: lambda i, *s: fn(0, i, *s)), 0
+    return (ncb, nb), (lambda fn: fn), 1
+
+
+def _params(block_bytes: int, ncb: int):
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=vmem_limit(block_bytes, ncb))
 
 
 def _pick_fc(num_f: int, requested: int = 0) -> int:
@@ -147,17 +240,24 @@ def _histogram_leaves_impl(bins_t: jax.Array, grad: jax.Array,
             leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
                                   constant_values=-1)
         fc = _pick_fc(num_f, feats_per_chunk)
-        f_pad = _round_up(num_f, fc)
-        if f_pad != num_f:
-            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
+        acc_t = _acc_dtype(compute_dtype)
+        col_bytes = _leaf_col_bytes(K, n_bins, compute_dtype)
+        cb, ncb = col_blocks(_round_up(num_f, fc), col_bytes, fc)
+        if ncb > 1:
+            # column blocks of whole tiles, the last ragged on the way in:
+            # a row of bins past F fills output columns past F alone
+            fc = math.gcd(cb, feats_per_chunk or 16)
+        elif cb != num_f:
+            bins_t = jnp.pad(bins_t, ((0, cb - num_f), (0, 0)))
     nb = n_pad // blk
+    grid, at, axis = _col_grid(ncb, nb)
     grad2 = grad[None, :]
     hess2 = hess[None, :]
     lor2 = leaf_of_row[None, :]
     leaves2 = leaves[None, :]
 
     def kernel(bins_ref, g_ref, h_ref, lor_ref, leaves_ref, out_ref):
-        step = pl.program_id(0)
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -182,31 +282,31 @@ def _histogram_leaves_impl(bins_t: jax.Array, grad: jax.Array,
             vals = jnp.concatenate([gm, hm, m], axis=0).astype(compute_dtype)
         b_blk = bins_ref[:].astype(jnp.int32)
         iota = lax.iota(jnp.int32, n_bins)
-        for f0 in range(0, f_pad, fc):
+        for f0 in range(0, cb, fc):     # the block's columns
             chunk = b_blk[f0:f0 + fc]                       # [fc, blk]
             oh_b = (chunk[:, None, :] == iota[None, :, None]
                     ).reshape(fc * n_bins, blk)
             acc = _oh_contract(vals, oh_b, compute_dtype)      # [3K, fc*B]
             out_ref[:, f0 * n_bins:(f0 + fc) * n_bins] += acc
 
+    row = pl.BlockSpec((1, blk), at(lambda j, i: (0, i)))
     out = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
+            pl.BlockSpec((cb, blk), at(lambda j, i: (j, i))),
+            row, row, row,
+            pl.BlockSpec((1, K), at(lambda j, i: (0, 0))),
         ],
-        out_specs=pl.BlockSpec((3 * K, f_pad * n_bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((3 * K, f_pad * n_bins),
-                                       _acc_dtype(compute_dtype)),
+        out_specs=pl.BlockSpec((3 * K, cb * n_bins),
+                               at(lambda j, i: (0, j))),
+        out_shape=jax.ShapeDtypeStruct((3 * K, ncb * cb * n_bins), acc_t),
+        compiler_params=_params(cb * col_bytes, ncb),
         interpret=interpret,
     )(bins_t, grad2, hess2, lor2, leaves2)
     out = out.astype(jnp.float32)
     # [3K, F*B] -> [K, F, B, 3] -> pad channel dim to 4
-    out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
+    out = out.reshape(3, K, ncb * cb, n_bins)[:, :, :num_f]
     out = out.transpose(1, 2, 3, 0)
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
 
@@ -251,10 +351,20 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
     assert rows >= W + 3
     K = leaves.shape[0]
     blk = min(rows_per_block, max(128, _round_up(S, 128)))
-    f_pad = 4 * W
+    acc_t = _acc_dtype(compute_dtype)
+    col_bytes = _leaf_col_bytes(K, n_bins, compute_dtype)
+    cb, ncb = col_blocks(4 * W, col_bytes, 4)
+    wb = cb // 4                # the block's word rows
+    grid, at, axis = _col_grid(ncb, pl.cdiv(S, blk))
+    # one block takes every row of the payload, the riding words among
+    # them; column blocks take their own word rows, and the three riding
+    # rows come beside them as a slice of their own (12 bytes a position)
+    ride = () if ncb == 1 else (lax.slice_in_dim(payload, W, W + 3),)
 
-    def kernel(cnt_ref, payload_ref, leaves_ref, out_ref):
-        step = pl.program_id(0)
+    def kernel(cnt_ref, payload_ref, *refs):
+        *ride_ref, leaves_ref, out_ref = refs
+        riding = lambda r: ride_ref[0][r] if ride_ref else payload_ref[W + r]
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -262,9 +372,9 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
 
         @pl.when(step * blk < cnt_ref[0])
         def _():
-            g = lax.bitcast_convert_type(payload_ref[W], jnp.float32)
-            h = lax.bitcast_convert_type(payload_ref[W + 1], jnp.float32)
-            lor_b = payload_ref[W + 2]
+            g = lax.bitcast_convert_type(riding(0), jnp.float32)
+            h = lax.bitcast_convert_type(riding(1), jnp.float32)
+            lor_b = riding(2)
             iota_r = lax.iota(jnp.int32, blk)
             pos_ok = step * blk + iota_r < cnt_ref[0]       # [blk]
             sel = (lor_b[None, :] == leaves_ref[0, :][:, None]) \
@@ -286,7 +396,7 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
             iota = lax.iota(jnp.int32, n_bins)
             # (a 4-words-per-dot widening was tried in round 4 and measured
             # neutral)
-            for j in range(W):
+            for j in range(wb):         # the block's words
                 w = payload_ref[j]                          # [blk] i32
                 chunk = jnp.stack([w & 255, (w >> 8) & 255, (w >> 16) & 255,
                                    (w >> 24) & 255])        # [4, blk]
@@ -295,26 +405,31 @@ def histogram_payload_pallas(payload: jax.Array, leaves: jax.Array,
                 acc = _oh_contract(vals, oh_b, compute_dtype)  # [3K, 4B]
                 out_ref[:, j * 4 * n_bins:(j + 1) * 4 * n_bins] += acc
 
+    # a step past the count keeps the last needed block: no DMA
+    last = lambda i, c: jnp.minimum(i, jnp.maximum(c[0] - 1, 0) // blk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(pl.cdiv(S, blk),),
+        grid=grid,
         in_specs=[
-            # a step past the count keeps the last needed block: no DMA
-            pl.BlockSpec((rows, blk), lambda i, c: (
-                0, jnp.minimum(i, jnp.maximum(c[0] - 1, 0) // blk))),
-            pl.BlockSpec((1, K), lambda i, c: (0, 0)),
+            pl.BlockSpec((rows if ncb == 1 else wb, blk),
+                         at(lambda j, i, c: (j, last(i, c)))),
+            *[pl.BlockSpec((3, blk), at(lambda j, i, c: (0, last(i, c))))
+              for _ in ride],
+            pl.BlockSpec((1, K), at(lambda j, i, c: (0, 0))),
         ],
-        out_specs=pl.BlockSpec((3 * K, f_pad * n_bins), lambda i, c: (0, 0)),
+        out_specs=pl.BlockSpec((3 * K, cb * n_bins),
+                               at(lambda j, i, c: (0, j))),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((3 * K, f_pad * n_bins),
-                                       _acc_dtype(compute_dtype)),
+        out_shape=jax.ShapeDtypeStruct((3 * K, ncb * cb * n_bins), acc_t),
+        compiler_params=_params(cb * col_bytes, ncb),
         interpret=interpret,
-    )(jnp.asarray(cnt, jnp.int32).reshape(1), payload, leaves[None, :])
+    )(jnp.asarray(cnt, jnp.int32).reshape(1), payload, *ride,
+      leaves[None, :])
     out = out.astype(jnp.float32)
-    out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
+    out = out.reshape(3, K, ncb * cb, n_bins)[:, :, :num_f]
     out = out.transpose(1, 2, 3, 0)
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
 
@@ -617,6 +732,11 @@ def compact_payload_pallas(src: jax.Array, key: jax.Array, grad: jax.Array,
                             pltpu.VMEM((n_ring + 1, R, fb), jnp.int32),
                             pltpu.SemaphoreType.DMA((n_ring,))]),
         out_shape=jax.ShapeDtypeStruct((R, nb_out * fb), jnp.int32),
+        # the scratch and the source's two windows; the rows move whole,
+        # so it is the row block that shrank with the width, above
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            4 * R * blk + 8 * R * blk + 4 * (n_ring + 1) * R * fb
+            + 2 * src.dtype.itemsize * f_rows * blk)),
         interpret=interpret,
     )(cum, src, t, grad[None, :], hess[None, :],
       jnp.asarray(leaf_of_row, jnp.int32)[None, :])
@@ -684,10 +804,14 @@ def histogram_leaves_packed_pallas(words_t: jax.Array, grad: jax.Array,
             leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
                                   constant_values=-1)
     nb = n_pad // blk
-    f_pad = 4 * W
+    acc_t = _acc_dtype(compute_dtype)
+    col_bytes = _leaf_col_bytes(K, n_bins, compute_dtype)
+    cb, ncb = col_blocks(4 * W, col_bytes, 4)
+    wb = cb // 4                # the block's word rows
+    grid, at, axis = _col_grid(ncb, nb)
 
     def kernel(words_ref, g_ref, h_ref, lor_ref, leaves_ref, out_ref):
-        step = pl.program_id(0)
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -708,7 +832,7 @@ def histogram_leaves_packed_pallas(words_t: jax.Array, grad: jax.Array,
             hm = jnp.where(sel, h_ref[0, :][None, :], 0.0)
             vals = jnp.concatenate([gm, hm, m], axis=0).astype(compute_dtype)
         iota = lax.iota(jnp.int32, n_bins)
-        for j in range(W):
+        for j in range(wb):             # the block's words
             planes = _swar_byte_eq_planes(words_ref[j], iota)  # [4, B, blk]
             oh_i = planes.reshape(4 * n_bins, blk)
             if _is_int8(compute_dtype):
@@ -722,31 +846,31 @@ def histogram_leaves_packed_pallas(words_t: jax.Array, grad: jax.Array,
                                       precision=_prec(compute_dtype))
             out_ref[:, j * 4 * n_bins:(j + 1) * 4 * n_bins] += acc
 
+    row = pl.BlockSpec((1, blk), at(lambda j, i: (0, i)))
     out = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((W, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
+            pl.BlockSpec((wb, blk), at(lambda j, i: (j, i))),
+            row, row, row,
+            pl.BlockSpec((1, K), at(lambda j, i: (0, 0))),
         ],
-        out_specs=pl.BlockSpec((3 * K, f_pad * n_bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((3 * K, f_pad * n_bins),
-                                       _acc_dtype(compute_dtype)),
+        out_specs=pl.BlockSpec((3 * K, cb * n_bins),
+                               at(lambda j, i: (0, j))),
+        out_shape=jax.ShapeDtypeStruct((3 * K, ncb * cb * n_bins), acc_t),
+        compiler_params=_params(cb * col_bytes, ncb),
         interpret=interpret,
     )(words_t, grad[None, :], hess[None, :], leaf_of_row[None, :],
       leaves[None, :])
     out = out.astype(jnp.float32)
-    out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
+    out = out.reshape(3, K, ncb * cb, n_bins)[:, :, :num_f]
     out = out.transpose(1, 2, 3, 0)
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
 
 
-#: VMEM budget for the radix2 accumulator (f32/i32 [p*nhi, nch*3K*p*nlo]);
-#: beyond it the dispatcher falls back to the flat kernel.  The flat
-#: kernel's [3K, F*B] accumulator at the shipped K=42/255-bin config is
+#: Largest radix2 accumulator (f32/i32 [p*nhi, nch*3K*p*nlo]) for which
+#: the dispatcher picks the kernel; beyond it the flat kernel takes the
+#: pass.  The flat kernel's [3K, F*B] accumulator at K=42/255 bins is
 #: ~4 MB and already crowds double-buffering at blk=2048 (round-4 note);
 #: radix2 multiplies that by its diagonal-waste factor p.
 _RADIX2_ACC_BYTES = 8 << 20
@@ -754,8 +878,11 @@ _RADIX2_ACC_BYTES = 8 << 20
 
 def radix2_pick_p(num_f: int, K: int, n_bins: int) -> int:
     """Feature group width for the shared-radix kernel: largest p in
-    (4, 2) whose accumulator fits ``_RADIX2_ACC_BYTES``; 0 = does not
-    fit (caller falls back to the flat kernel)."""
+    (4, 2) whose accumulator over ``num_f`` columns is ONE column block of
+    at most ``_RADIX2_ACC_BYTES``; 0 = it is not (caller falls back to the
+    flat kernel, whose accumulator is p times smaller a column: at 2,000
+    columns every K > 4 pass).  A dispatch rule, not a VMEM limit: the
+    kernel's blocks follow from ``col_blocks`` like every other's."""
     for p in (4, 2):
         f_pad = _round_up(num_f, p)
         if 3 * K * f_pad * n_bins * p * 4 <= _RADIX2_ACC_BYTES:
@@ -805,15 +932,18 @@ def histogram_leaves_radix2_pallas(bins_t: jax.Array, grad: jax.Array,
             hess = jnp.pad(hess, (0, n_pad - n))
             leaf_of_row = jnp.pad(leaf_of_row, (0, n_pad - n),
                                   constant_values=-1)
-        f_pad = _round_up(num_f, p)
-        if f_pad != num_f:
-            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
-    nch = f_pad // p
+        acc_t = _acc_dtype(compute_dtype)
+        cb, ncb = col_blocks(_round_up(num_f, p),
+                             M * NW // p * jnp.dtype(acc_t).itemsize, p)
+        if ncb == 1 and cb != num_f:
+            bins_t = jnp.pad(bins_t, ((0, cb - num_f), (0, 0)))
+    nch = cb // p               # the block's chunks
     nb = n_pad // blk
+    grid, at, axis = _col_grid(ncb, nb)
     prec = _prec(compute_dtype)
 
     def kernel(bins_ref, g_ref, h_ref, lor_ref, leaves_ref, out_ref):
-        step = pl.program_id(0)
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -862,30 +992,40 @@ def histogram_leaves_radix2_pallas(bins_t: jax.Array, grad: jax.Array,
                                       precision=prec)       # [M, NW]
             out_ref[:, c0 * NW:(c0 + 1) * NW] += acc
 
-    out = pl.pallas_call(
-        kernel, grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((M, nch * NW), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, nch * NW),
-                                       _acc_dtype(compute_dtype)),
-        interpret=interpret,
-    )(bins_t, grad[None, :], hess[None, :], leaf_of_row[None, :],
-      leaves[None, :])
-    out = out.astype(jnp.float32)
+    out = _radix_call(kernel, grid, at, cb, ncb, blk, M, nch * NW, acc_t,
+                      interpret, bins_t, grad, hess, leaf_of_row, leaves)
     # rows (p_l, nhi); cols (nch, 3K-ch, p_r, nlo) — keep the f == f' diag
-    out = out.reshape(p, nhi, nch, 3 * K, p, nlo)
+    out = out.reshape(p, nhi, ncb * nch, 3 * K, p, nlo)
     idx = jnp.arange(p)
     out = out[idx, :, :, :, idx]            # [p, nhi, nch, 3K, nlo]
     out = out.transpose(3, 2, 0, 1, 4)      # [3K, nch, p, nhi, nlo]
-    out = out.reshape(3, K, f_pad, n_bins)[:, :, :num_f]
+    out = out.reshape(3, K, ncb * cb, n_bins)[:, :, :num_f]
     out = out.transpose(1, 2, 3, 0)
     return jnp.pad(out, ((0, 0), (0, 0), (0, 0), (0, 1)))
+
+
+def _radix_call(kernel, grid, at, cb, ncb, blk, m, block_cols, acc_t,
+                interpret, bins_t, grad, hess, lor, leaves=None):
+    """The ``pallas_call`` the three radix kernels share: the u8 bins in
+    column blocks ``[cb, blk]``, the three row vectors, the leaves where
+    the kernel takes them; an accumulator block ``[m, block_cols]`` a
+    column block.  Returns f32 ``[m, ncb * block_cols]``."""
+    row = pl.BlockSpec((1, blk), at(lambda j, i: (0, i)))
+    operands = [bins_t, grad[None, :], hess[None, :], lor[None, :]]
+    specs = [pl.BlockSpec((cb, blk), at(lambda j, i: (j, i))), row, row, row]
+    if leaves is not None:
+        operands.append(leaves[None, :])
+        specs.append(pl.BlockSpec((1, leaves.shape[0]),
+                                  at(lambda j, i: (0, 0))))
+    out = pl.pallas_call(
+        kernel, grid=grid, in_specs=specs,
+        out_specs=pl.BlockSpec((m, block_cols), at(lambda j, i: (0, j))),
+        out_shape=jax.ShapeDtypeStruct((m, ncb * block_cols), acc_t),
+        compiler_params=_params(
+            m * block_cols * jnp.dtype(acc_t).itemsize, ncb),
+        interpret=interpret,
+    )(*operands)
+    return out.astype(jnp.float32)
 
 
 def _radix_shapes(n_bins: int, p: int):
@@ -985,15 +1125,18 @@ def histogram_radix_single_pallas(bins_t: jax.Array, grad: jax.Array,
             grad = jnp.pad(grad, (0, n_pad - n))
             hess = jnp.pad(hess, (0, n_pad - n))
             lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-        f_pad = _round_up(num_f, p)
-        if f_pad != num_f:
-            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
-    nch = f_pad // p
+        acc_t = _acc_dtype(compute_dtype)
+        cb, ncb = col_blocks(_round_up(num_f, p),
+                             M * NW // p * jnp.dtype(acc_t).itemsize, p)
+        if ncb == 1 and cb != num_f:
+            bins_t = jnp.pad(bins_t, ((0, cb - num_f), (0, 0)))
+    nch = cb // p               # the block's chunks
     nb = n_pad // blk
+    grid, at, axis = _col_grid(ncb, nb)
     prec = _prec(compute_dtype)
 
     def kernel(bins_ref, g_ref, h_ref, lor_ref, out_ref):
-        step = pl.program_id(0)
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -1016,21 +1159,9 @@ def histogram_radix_single_pallas(bins_t: jax.Array, grad: jax.Array,
                 p=p, blk=blk, compute_dtype=compute_dtype, prec=prec)
             out_ref[:, c0 * NW:(c0 + 1) * NW] += acc
 
-    out = pl.pallas_call(
-        kernel, grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((M, nch * NW), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, nch * NW),
-                                       _acc_dtype(compute_dtype)),
-        interpret=interpret,
-    )(bins_t, grad[None, :], hess[None, :], lor[None, :])
-    out = out.astype(jnp.float32)
-    return _radix_unpack(out[None], n_groups=1, num_f=num_f, f_pad=f_pad,
+    out = _radix_call(kernel, grid, at, cb, ncb, blk, M, nch * NW, acc_t,
+                      interpret, bins_t, grad, hess, lor)
+    return _radix_unpack(out[None], n_groups=1, num_f=num_f, f_pad=ncb * cb,
                          p=p, nhi=nhi, nlo=nlo, n_bins=n_bins)[0]
 
 
@@ -1065,15 +1196,18 @@ def histogram_radix_joint_pallas(bins_t: jax.Array, grad: jax.Array,
             grad = jnp.pad(grad, (0, n_pad - n))
             hess = jnp.pad(hess, (0, n_pad - n))
             lor = jnp.pad(lor, (0, n_pad - n), constant_values=-1)
-        f_pad = _round_up(num_f, p)
-        if f_pad != num_f:
-            bins_t = jnp.pad(bins_t, ((0, f_pad - num_f), (0, 0)))
-    nch = f_pad // p
+        acc_t = _acc_dtype(compute_dtype)
+        cb, ncb = col_blocks(_round_up(num_f, p),
+                             M * NW // p * jnp.dtype(acc_t).itemsize, p)
+        if ncb == 1 and cb != num_f:
+            bins_t = jnp.pad(bins_t, ((0, cb - num_f), (0, 0)))
+    nch = cb // p               # the block's chunks
     nb = n_pad // blk
+    grid, at, axis = _col_grid(ncb, nb)
     prec = _prec(compute_dtype)
 
     def kernel(bins_ref, g_ref, h_ref, lor_ref, leaves_ref, out_ref):
-        step = pl.program_id(0)
+        step = pl.program_id(axis)
 
         @pl.when(step == 0)
         def _():
@@ -1128,22 +1262,9 @@ def histogram_radix_joint_pallas(bins_t: jax.Array, grad: jax.Array,
                                       precision=prec)       # [M, NW]
             out_ref[:, c0 * NW:(c0 + 1) * NW] += acc
 
-    out = pl.pallas_call(
-        kernel, grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((f_pad, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, G), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((M, nch * NW), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, nch * NW),
-                                       _acc_dtype(compute_dtype)),
-        interpret=interpret,
-    )(bins_t, grad[None, :], hess[None, :], lor[None, :], leaves[None, :])
-    out = out.astype(jnp.float32)
+    out = _radix_call(kernel, grid, at, cb, ncb, blk, M, nch * NW, acc_t,
+                      interpret, bins_t, grad, hess, lor, leaves)
     # rows (G, p_l, nhi); cols (nch, 3c, p_r, nlo)
-    out = out.reshape(G, M1, nch * NW)
-    return _radix_unpack(out, n_groups=G, num_f=num_f, f_pad=f_pad, p=p,
+    out = out.reshape(G, M1, ncb * nch * NW)
+    return _radix_unpack(out, n_groups=G, num_f=num_f, f_pad=ncb * cb, p=p,
                          nhi=nhi, nlo=nlo, n_bins=n_bins)

@@ -59,6 +59,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import hist_pallas as _hp
 
 # test hook: CPU suite runs the kernel through the interpreter
 _FUSE_TEST_INTERPRET = False
@@ -176,6 +179,11 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
     # sums over K), so whatever the last block's lanes past n hold stays in
     # lanes the write-back drops
     blk = min(rows_per_block, max(128, _round_up(n, 128)))
+    # the block of bins with its two casts (two u8 windows, i32, bfloat16:
+    # 8 bytes a column and row) stays inside the kernels' one VMEM budget:
+    # the row block follows from the width, and the compiler is told
+    room = _hp.VMEM_BUDGET_BYTES - _hp._VMEM_BODY_BYTES
+    blk = min(blk, max(128, room // (8 * num_f) // 128 * 128))
 
     a1, n1, a2, n2 = xor_ranges(lo, hi, pos, default_left, miss)
 
@@ -246,6 +254,8 @@ def partition_select_pallas(bins_t: jax.Array, lor: jax.Array,
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
                    jax.ShapeDtypeStruct((1, n), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_hp.vmem_limit(8 * num_f * blk)),
         interpret=interpret,
     )(bins_t, lor[None, :], mask[None, :], cols[None, :],
       *((words,) if with_sets else

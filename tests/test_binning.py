@@ -187,3 +187,125 @@ def test_forcedbins_filename(tmp_path):
     # trains fine
     bst = lgb.train(p, ds, num_boost_round=3)
     assert np.isfinite(bst.predict(X[:10])).all()
+
+
+# ------------------------------------------------ the wide construct (PR 45)
+def _greedy_walk(distinct_values, counts, max_bin, total_cnt):
+    """The reference's walk over the distinct values ONE BY ONE
+    (``GreedyFindBin``'s large-set branch as the program had it before it
+    jumped from cut to cut): the oracle of the jumps."""
+    num_distinct = len(distinct_values)
+    mean_bin_size = total_cnt / max_bin
+    is_big = counts >= mean_bin_size
+    rest_cnt = total_cnt - int(counts[is_big].sum())
+    rest_bins = max_bin - int(is_big.sum())
+    if rest_bins > 0:
+        mean_bin_size = rest_cnt / rest_bins
+    upper, lower, cur_cnt, bin_cnt = [], [], 0, 0
+    cur_lower = float(distinct_values[0])
+    for i in range(num_distinct):
+        if not is_big[i]:
+            rest_cnt -= int(counts[i])
+        cur_cnt += int(counts[i])
+        if (is_big[i] or cur_cnt >= mean_bin_size
+                or (i + 1 < num_distinct and is_big[i + 1]
+                    and cur_cnt >= max(1.0, mean_bin_size * 0.5))):
+            upper.append(float(distinct_values[i]))
+            lower.append(cur_lower)
+            bin_cnt += 1
+            if i + 1 < num_distinct:
+                cur_lower = float(distinct_values[i + 1])
+            cur_cnt = 0
+            if not is_big[i] and rest_bins > bin_cnt:
+                mean_bin_size = rest_cnt / (rest_bins - bin_cnt)
+            if bin_cnt >= max_bin - 1:
+                break
+    return [(upper[i] + lower[i + 1]) / 2.0
+            for i in range(len(upper) - 1)] + [np.inf]
+
+
+def _counts_of(kind, nd, rng):
+    if kind == "ones":
+        return np.ones(nd, np.int64)
+    if kind == "small":
+        return rng.integers(1, 5, nd)
+    if kind == "heavy_hitters":
+        c = rng.integers(1, 5, nd)
+        c[rng.integers(0, nd, 12)] = rng.integers(50, 5000, 12)
+        return c
+    if kind == "heavy_neighbours":
+        c = rng.integers(1, 3, nd)
+        j = int(rng.integers(0, nd - 6))
+        c[j:j + 5] = rng.integers(500, 50000)
+        return c
+    if kind == "heavy_ends":
+        c = np.ones(nd, np.int64)
+        c[0] = c[-1] = 10_000
+        return c
+    if kind == "pareto":
+        return (rng.pareto(1.0, nd) * 3 + 1).astype(np.int64)
+    return rng.integers(1, 1000, nd)         # "wide"
+
+
+@pytest.mark.parametrize("kind", ["ones", "small", "heavy_hitters",
+                                  "heavy_neighbours", "heavy_ends", "pareto",
+                                  "wide"])
+def test_greedy_jumps_cut_where_the_per_value_walk_cuts(kind):
+    """``_greedy_find_bin`` jumps from cut to cut over the cumulative
+    counts; the bounds are the per-value walk's to the last bit, with heavy
+    values, heavy neighbours, a total over the counts' sum (elided zeros)
+    and every budget a column can have."""
+    from lightgbm_tpu.io.binning import _greedy_find_bin
+    rng = np.random.default_rng(45)
+    for trial in range(60):
+        dv = np.unique(rng.standard_normal(int(rng.integers(300, 3000))))
+        counts = _counts_of(kind, len(dv), rng).astype(np.int64)
+        max_bin = int(rng.choice([2, 3, 15, 16, 63, 254, 255]))
+        total = int(counts.sum()) + int(rng.choice([0, 0, 7, 1000]))
+        got = _greedy_find_bin(dv, counts, max_bin, total, 3)
+        assert got == _greedy_walk(dv, counts, max_bin, total), (kind, trial)
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (1023, 3), (1024, 64), (1025, 7),
+                                   (5000, 64)])
+def test_columns_first_is_the_transpose(shape):
+    from lightgbm_tpu.io.binning import columns_first as _columns_first
+    a = np.random.default_rng(1).standard_normal(shape)
+    got = _columns_first(a)
+    assert got.flags.c_contiguous and np.array_equal(got, a.T)
+    wide = np.zeros((shape[0], shape[1] + 5))
+    _columns_first(got, into=wide[:, 2:2 + shape[1]])
+    assert np.array_equal(wide[:, 2:2 + shape[1]], a)
+    assert not wide[:, :2].any() and not wide[:, 2 + shape[1]:].any()
+
+
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_wide_construct_bins_by_blocks_as_column_by_column(order):
+    """At the rows where binning goes native a run of numeric columns is
+    binned side by side (``native.apply_bins_block``); the bins are the
+    per-column ``values_to_bins``' (also where the matrix came column-major,
+    as the benchmark hands it, and each column is binned by itself and a
+    block of them written at a time) with NaN, a zero bin, trivial columns
+    dropped between the runs and a categorical column among them, for the
+    training set and for a valid set on its mappers."""
+    rng = np.random.default_rng(3)
+    n, f = 70_000, 150
+    X = rng.standard_normal((n, f))
+    X[:, 3] = 0.0
+    X[:, 10] = rng.integers(0, 40, n)
+    X[:, 20] = np.where(rng.random(n) < 0.7, 0.0, X[:, 20])
+    X[rng.integers(0, n, 500), 30] = np.nan
+    X[:, 40] = np.round(X[:, 40], 1)
+    X[:, 77] = 1.0
+    X = np.asarray(X, order=order)
+    cfg = {"max_bin": 255, "verbose": -1, "enable_bundle": False}
+    ds = Dataset.from_data(X, label=np.zeros(n), config=cfg,
+                           categorical_feature=[10])
+    assert ds.bins.shape == (n, f - 2)
+    valid = Dataset.from_data(X[:66_000] + 0.01, label=np.zeros(66_000),
+                              config=cfg, reference=ds)
+    assert ds.bins.flags.c_contiguous and valid.bins.flags.c_contiguous
+    for bins, raw in ((ds.bins, X), (valid.bins, X[:66_000] + 0.01)):
+        for col, j in enumerate(ds.used_feature_idx):
+            want = ds.mappers[j].values_to_bins(raw[:, j]).astype(np.uint8)
+            assert np.array_equal(bins[:, col], want), j

@@ -418,6 +418,135 @@ def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
                    for line in captured["text"].splitlines())
 
 
+# ------------------------------------------- 2,000 columns (PR 45: Epsilon)
+EPSILON = (2000, 400_000)       # the cell epsilon-train: columns, rows
+ISTELLA = (220, 7_325_696)      # istella-rank-train's rows, whole blocks
+
+
+def _scoped_bytes(compiled, kernel):
+    """What each custom call of ``kernel`` states as its scoped VMEM."""
+    import re
+    return [int(m.group(1)) for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line and f"%{kernel}" in line
+            for m in [re.search(r'scoped_memory_configs":\[\{"memory_space":'
+                                r'"1","offset":"0","size":"(\d+)"', line)] if m]
+
+
+def _rows(f, n):
+    return [((f, n), jnp.uint8), ((n,), jnp.float32), ((n,), jnp.float32),
+            ((n,), jnp.int32)]
+
+
+# (the float32 kernels' six-pass products take the compiler 210-260 s
+# each here: they run with the slow tests)
+@pytest.mark.parametrize("K,dtype,kernel", [
+    (42, "int8", "_histogram_leaves_impl"),
+    pytest.param(42, "float32", "_histogram_leaves_impl",
+                 marks=pytest.mark.slow),
+    (4, "int8", "histogram_radix_joint_pallas"),
+    pytest.param(4, "float32", "histogram_radix_joint_pallas",
+                 marks=pytest.mark.slow),
+], ids=["flat-K42-int8", "flat-K42-f32", "joint-K4-int8", "joint-K4-f32"])
+def test_masked_pass_compiles_at_2000_columns(one_chip, on_tpu, K, dtype,
+                                              kernel):
+    """A full masked pass at the Epsilon job's shapes through the dispatch
+    the grower calls: the parent held ``[3K, 2000 x 256]`` resident (258 MB
+    at K = 42) and unrolled 125 chunks; column blocks of one tile (32
+    columns) fit the budget the kernel states."""
+    from lightgbm_tpu.ops import hist_pallas as HP
+
+    def fn(bins_t, g, h, lor, leaves):
+        return H.histogram_for_leaves_masked(
+            bins_t, g, h, lor, leaves, n_bins=256, rows_per_block=8192,
+            hist_dtype=dtype)
+
+    c = _compile(one_chip, fn, *_rows(*EPSILON), ((K,), jnp.int32))
+    _assert_kernel(c, kernel)
+    stated = _scoped_bytes(c, kernel)
+    assert stated and max(stated) <= HP.VMEM_BUDGET_BYTES, stated
+
+
+def test_root_pass_compiles_at_2000_columns(one_chip, on_tpu):
+    """The root's radix kernel in 63 column blocks (500 unrolled chunks
+    in one block took the compiler 21 minutes)."""
+    def fn(bins_t, g, h):
+        return H.root_histogram(bins_t, g, h, n_bins=256,
+                                rows_per_block=8192, hist_dtype="int8")
+
+    c = _compile(one_chip, fn, *_rows(*EPSILON)[:3])
+    _assert_kernel(c, "histogram_radix_single_pallas")
+
+
+@pytest.mark.parametrize("K,dtype", [
+    (42, "int8"), (4, "int8"),
+    pytest.param(42, "float32", marks=pytest.mark.slow)],
+    ids=["K42-int8", "K4-int8", "K42-f32"])
+def test_compacted_pass_compiles_at_2000_columns(one_chip, K, dtype):
+    """The n/2 bucket of a round pass: the compaction at 504 payload rows
+    (sixteen plane groups, 512-row blocks) and the payload kernel over
+    column blocks of 8 word rows, the three riding rows beside them."""
+    from lightgbm_tpu.ops import hist_pallas as HP
+    f, n = EPSILON
+    S = 200_704
+
+    def fn(src, key, g, h, lor, leaves, cnt):
+        pc = compact_payload_pallas(src, key, g, h, lor, size=S)
+        return histogram_payload_pallas(
+            pc, leaves, cnt, num_f=f, n_bins=256,
+            rows_per_block=H._pallas_blk(dtype, 256),
+            compute_dtype=jnp.dtype(dtype).type)
+
+    c = _compile(one_chip, fn, ((f, n), jnp.uint8), ((n,), jnp.int32),
+                 ((n,), jnp.float32), ((n,), jnp.float32), ((n,), jnp.int32),
+                 ((K,), jnp.int32), ((1,), jnp.int32))
+    _assert_kernel(c, "histogram_payload_pallas")
+    _assert_kernel(c, "compact_payload_pallas")
+    for kernel in ("histogram_payload_pallas", "compact_payload_pallas"):
+        stated = _scoped_bytes(c, kernel)
+        assert stated and max(stated) <= HP.VMEM_BUDGET_BYTES, stated
+    assert HP.col_blocks(2000, 3 * K * 256 * 4, 4)[1] > 1
+
+
+def test_partition_kernel_compiles_at_2000_columns(one_chip):
+    """The block of bins with its casts is 2000 x 2048 x 8 B = 33 MB: over
+    Mosaic's default, inside the budget, and said so."""
+    from lightgbm_tpu.ops import hist_pallas as HP
+    f, n = EPSILON
+    K = 42
+    c = _compile(one_chip, _partition,
+                 ((f, n), jnp.uint8), ((n,), jnp.int32), ((n,), jnp.int32),
+                 *[((K,), jnp.int32)] * 10)
+    _assert_kernel(c, "partition_select_pallas")
+    (stated,) = set(_scoped_bytes(c, "partition_select_pallas"))
+    assert stated == HP.vmem_limit(8 * f * 2048) <= HP.VMEM_BUDGET_BYTES
+    assert " pad(" not in c.as_text()
+
+
+def test_the_ranking_cells_flat_pass_states_its_27_mib(one_chip, on_tpu):
+    """``istella-rank-train``'s K = 42 flat pass keeps a 27.1 MiB output
+    block; it compiled only while XLA's memory-space assignment kept that
+    output in VMEM for it, and stopped when PR 44 took two row vectors out
+    of the round program (``Scoped allocation with size 27.97M and limit
+    16.00M``).  Alone, with whole row blocks and so no row-sized buffer
+    beside it, the kernel now states the block and its body's share."""
+    from lightgbm_tpu.ops import hist_pallas as HP
+    f, n = ISTELLA
+
+    def fn(bins_t, g, h, lor, leaves):
+        return H.histogram_for_leaves_masked(
+            bins_t, g, h, lor, leaves, n_bins=256, rows_per_block=8192,
+            hist_dtype="int8", hist_kernel="onehot")
+
+    c = _compile(one_chip, fn, *_rows(f, n), ((42,), jnp.int32))
+    _assert_kernel(c, "_histogram_leaves_impl")
+    block = 126 * 220 * 256 * 4
+    assert _scoped_bytes(c, "_histogram_leaves_impl") == \
+        [block + HP._VMEM_BODY_BYTES]
+    assert HP.col_blocks(220, 126 * 256 * 4, 11) == (220, 1)
+    assert not [line for line in c.as_text().splitlines()
+                if " pad(" in line and str(n) in line]
+
+
 def test_partition_at_published_higgs_rows_stays_lane_dense(one_chip):
     """Size guard: at the published 10.5M rows the partition step's
     results are two [1, n] i32 vectors, 84 MB.  A kernel that emitted an

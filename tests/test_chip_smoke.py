@@ -39,7 +39,7 @@ def test_chip_smoke_rehearsal_runs_every_phase_but_cannot_succeed():
              if ln.startswith("{")]
     assert [ln.get("phase") for ln in lines[:-1]] == [
         "device", "data", "train", "bundled", "categorical", "ranking",
-        "predict", "save_load", "serve"]
+        "wide", "predict", "save_load", "serve"]
     bundled = lines[3]
     assert bundled["bundle_expand_calls"] == 0 and bundled["bundles"] < 76
     assert bundled["bundle_space_search_rounds"] == bundled["iters"]
@@ -55,6 +55,10 @@ def test_chip_smoke_rehearsal_runs_every_phase_but_cannot_succeed():
     assert ranking["buckets"] >= 4 and ranking["rank_slot_rows"] > ranking["rows"]
     assert ranking["device_against_host_ndcg"] < 1e-5
     assert ranking["valid_ndcg10_last"] > ranking["valid_ndcg10_first"]
+    wide = lines[6]
+    assert (wide["features"], wide["hist_col_blocks"]) == (2000, 63)
+    assert wide["hist_state_bytes"] == 15 * 2000 * 256 * 16
+    assert wide["hist_vmem_budget_bytes"] == 72 << 20
     assert lines[-1]["ok"] is False and "rehearsal" in lines[-1]
     assert '"ok": true' not in out.stdout
 
@@ -72,3 +76,19 @@ def test_persistent_cache_is_placed_from_outside(monkeypatch, tmp_path):
     assert use_persistent_cache("/checkout/.jax_cache") \
         == "/checkout/.jax_cache"
     assert calls == [("jax_compilation_cache_dir", "/checkout/.jax_cache")]
+
+
+def test_chip_smoke_runs_the_wide_phase_alone():
+    """``--only wide``: the device phase and the 2,000-column job, which is
+    what a kernel PR sends to the chip before the cell ``epsilon-train``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py"),
+                          "--rehearse-cpu", "--only", "wide"],
+                         capture_output=True, text=True, env=env,
+                         timeout=1200)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(ln) for ln in out.stdout.splitlines()
+             if ln.startswith("{")]
+    assert [ln.get("phase") for ln in lines[:-1]] == ["device", "wide"]
+    assert lines[-1] == {"ok": False, "only": "wide",
+                         "device": lines[-1]["device"]}

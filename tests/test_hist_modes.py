@@ -142,6 +142,14 @@ _DISPATCH = [
     # accumulator cap: a huge (K, F) product overflows the VMEM budget
     # and radix2 falls back rather than compiling an unshippable kernel
     ("radix2", 256, 512, 4096, True, False, "flat", False, False, 2),
+    # the cell epsilon-train (PR 45: 2,000 columns): the root and the
+    # K <= 4 bodies keep their radix kernels over eight column blocks, and
+    # every K > 4 pass is the flat kernel (a shared-radix accumulator of
+    # 2,000 columns is 196 MB at K = 16: no single block of 8 MiB)
+    ("auto", 256, 1, 2000, False, True, "radix_single", False, True, 4),
+    ("auto", 256, 4, 2000, False, False, "radix_joint", False, True, 4),
+    ("auto", 256, 16, 2000, False, False, "flat", False, True, 2),
+    ("auto", 256, 42, 2000, False, False, "flat", False, True, 2),
 ]
 _DISPATCH_ARGS = "hk,n_bins,K,num_f,words,single,kernel,mirror,ladder,top_rung"
 

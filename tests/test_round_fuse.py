@@ -366,6 +366,49 @@ def test_partition_kernel_matches_xla(n):
     np.testing.assert_array_equal(np.asarray(key), want_key)
 
 
+@pytest.mark.parametrize("budget_mib,n", [(72, 3000), (18, 2900)],
+                         ids=["whole-row-block", "row-block-from-width"])
+def test_partition_kernel_at_2000_columns(monkeypatch, budget_mib, n):
+    """The Epsilon job's width: the block of bins with its two casts is
+    8 bytes a column and row, so the row block follows from the width
+    under the kernels' one VMEM budget (2,048 rows under 72 MiB, 128 under
+    18), the last block ragged as before, and slots on the first, a middle
+    and the last column.  (The budget is read when the kernel is traced:
+    each case has a row count of its own.)"""
+    from lightgbm_tpu.ops import hist_pallas as HP
+    monkeypatch.setattr(HP, "VMEM_BUDGET_BYTES", budget_mib << 20)
+    rng = np.random.default_rng(11)
+    f = 2000
+    bins = rng.integers(0, 256, size=(n, f)).astype(np.uint8)
+    lor = rng.integers(0, 4, size=n).astype(np.int32)
+    mask = rng.integers(0, 2, size=n).astype(np.int32)
+    feats = np.array([0, 1812, 1999, 777], np.int32)
+    thr = np.array([100, 3, 200, 128], np.int32)
+    dl = np.array([0, 0, 1, 0], np.int32)
+    parents = np.array([0, 1, 2, 3], np.int32)
+    new_leaves = np.array([4, 5, 6, 7], np.int32)
+    validk = np.array([1, 1, 1, 0], np.int32)
+    smaller = np.array([4, 1, 6, 7], np.int32)
+    new_lor, key = RF.partition_select_pallas(
+        jnp.asarray(bins.T), jnp.asarray(lor), jnp.asarray(mask),
+        *grower.split_ranges(jnp.asarray(feats), jnp.asarray(thr),
+                             jnp.asarray(dl), jnp.full((f,), -1), None, 256),
+        jnp.asarray(parents), jnp.asarray(new_leaves),
+        jnp.asarray(validk), jnp.asarray(smaller),
+        rows_per_block=2048, interpret=True)
+    go_left = bins[:, feats].T.astype(np.int32) <= thr[:, None]
+    move = (lor[None, :] == parents[:, None]) & (validk[:, None] != 0) \
+        & ~go_left
+    want_lor = np.where(move.any(axis=0),
+                        (move * new_leaves[:, None]).sum(axis=0), lor)
+    np.testing.assert_array_equal(np.asarray(new_lor), want_lor)
+    sel = (np.where(mask != 0, want_lor, -1)[None, :]
+           == smaller[:, None]).any(axis=0)
+    rows = np.arange(n, dtype=np.int32)
+    np.testing.assert_array_equal(np.asarray(key),
+                                  np.where(sel, rows, rows | (1 << 30)))
+
+
 @pytest.mark.parametrize("n", [2048, 3000, 300])
 def test_partition_kernel_with_left_sets_matches_xla(n):
     """The variant a job with categorical columns runs: numeric and
