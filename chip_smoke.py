@@ -169,6 +169,103 @@ def _require_compaction_kernel(text: str, where: str) -> None:
                     f"in {where}")
 
 
+_HLO_HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_HLO_INSTR = re.compile(r"^(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_HLO_ARRAY = re.compile(r"\w+\[([\d,]*)\](?:\{([^}]*)\})?")
+_HLO_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation|"
+    r"branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
+
+
+def _hist_state_copies(text: str, counts) -> list:
+    """The instructions of the compiled program ``text`` that move the
+    per-leaf histogram state inside a loop, where ``counts`` holds the
+    state's element counts.  Listed is a ``copy``, ``copy-start``,
+    ``transpose`` or ``fusion`` in a ``while`` body, or in a computation
+    one calls,
+
+    - one of whose results (a fusion may give a tuple) has the state's
+      count, under any logical shape or layout, unless the fusion's root
+      writes that result in place: a ``dynamic-update-slice`` into a
+      parameter of the result's own shape and layout;
+    - or which takes the state and gives half of its count or more back in
+      other buffers (the compiler's blocked gather did: seven column
+      blocks of ``st["hist"]`` a round pass).
+
+    The grower carries the state in ONE layout and reads and writes it by
+    leading-axis slabs (learner/batch_grower.py ``write_children``), so
+    this list is empty.  Until PR 46 it held two conversions of the whole
+    state a round pass, 2.09 GB each at 2,000 columns (PERF.md section 6)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = _HLO_HEAD.match(line)
+        if head:
+            name = head.group(1)
+            comps[name] = {}
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            m = _HLO_INSTR.match(line.strip())
+            if m:
+                comps[name][m.group(1)] = m
+
+    def arrays(types):
+        return [(math.prod(int(d) for d in dims.split(",") if d), dims,
+                 re.sub(r"S\(\d+\)", "", tiles))
+                for dims, tiles in _HLO_ARRAY.findall(types)]
+
+    def operands(m):
+        args = re.sub(r"/\*.*?\*/", "", m.group(4)).split(")", 1)[0]
+        return [a.split()[-1].lstrip("%") for a in args.split(",") if a.strip()]
+
+    def called(m):
+        return [c.lstrip("%") for attr in _HLO_CALLED.findall(m.group(4))
+                for c in re.findall(r"%?[\w.\-]+", attr)]
+
+    todo = [c for instrs in comps.values() for m in instrs.values()
+            if m.group(3) == "while" for c in called(m)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in looped:
+            looped.add(c)
+            todo.extend(d for m in comps[c].values()
+                        if m.group(3) != "fusion" for d in called(m))
+
+    def in_place(m):
+        """Which results of the fusion ``m`` its root writes in place."""
+        fused = comps.get(called(m)[0], {}) if called(m) else {}
+        root = next((r for r in fused.values()
+                     if r.group(0).startswith("ROOT ")), None)
+        if root is None:
+            return []
+        outs = [fused.get(o) for o in operands(root)] \
+            if root.group(3) == "tuple" else [root]
+        flags = []
+        for out in outs:
+            into = fused.get(operands(out)[0]) if out is not None \
+                and out.group(3) == "dynamic-update-slice" else None
+            flags.append(into is not None and into.group(3) == "parameter"
+                         and arrays(into.group(2)) == arrays(out.group(2)))
+        return flags
+
+    found = []
+    for c in sorted(looped):
+        for name, m in comps[c].items():
+            if m.group(3) not in ("copy", "copy-start", "transpose", "fusion"):
+                continue
+            res = arrays(m.group(2))
+            if m.group(3) == "fusion":
+                res = [r for r, kept in zip(res, in_place(m) + [False] * len(
+                    res)) if not kept]
+            takes = any(a[0] in counts for o in operands(m)
+                        if o in comps[c] for a in arrays(comps[c][o].group(2)))
+            if any(r[0] in counts for r in res) or (
+                    takes and 2 * sum(r[0] for r in res) >= min(counts)):
+                found.append(f"%{name} = {m.group(3)} -> {m.group(2)} in {c}")
+    return found
+
+
 def _synth_higgs(n, f, rng, w=None):
     """Higgs-shaped synthetic binary data (separable-ish continuous
     features; BASELINE.md pairs its 130.094 s with AUC 0.845724 on the real
@@ -575,7 +672,9 @@ def phase_wide(args, lgb):
     VMEM budget (ops/hist_pallas.py ``col_blocks``): the booster counts
     the blocks, the per-leaf state's bytes and the budget, every round runs
     in the fused scan, and on a chip the compiled round program holds the
-    payload, compaction and partition kernels under their scopes."""
+    payload, compaction and partition kernels under their scopes and moves
+    nothing of the per-leaf state's size inside a tree's loop
+    (``hist_state_copies``: 0)."""
     import jax
 
     t0 = time.time()
@@ -611,10 +710,17 @@ def phase_wide(args, lgb):
     # (a direction over 2,000 columns takes more rounds than a smoke has)
     _require(len(auc) == args.iters and 0.6 < auc[0] < auc[-1],
              f"valid AUC per round: {auc}")
-    calls = None
+    calls = copies = None
     if jax.devices()[0].platform == "tpu":
         text = _fused_program_text(gb)
         calls = text.count("tpu_custom_call")
+        # the state with and without its spare row (write_children)
+        moved = _hist_state_copies(text, {
+            (gb.hp.num_leaves + spare) * 4 * WIDE_FEATURES * gb.hp.n_bins
+            for spare in (0, 1)})
+        copies = len(moved)
+        _require(not moved, f"the per-leaf histogram state is copied or "
+                 f"re-tiled inside the tree loop: {moved}")
         _require_kernel(text, "histogram_payload_pallas", "hist_kernel",
                         "no payload kernel under hist_kernel in the wide "
                         "round program")
@@ -623,6 +729,7 @@ def phase_wide(args, lgb):
     _emit("wide", t0, rows=rows, features=WIDE_FEATURES, iters=args.iters,
           hist_col_blocks=blocks, hist_state_bytes=state,
           hist_vmem_budget_bytes=budget, tpu_custom_calls=calls,
+          hist_state_copies=copies,
           valid_auc_first=auc[0], valid_auc_last=auc[-1],
           smoke_train_s=round(secs, 2))
 

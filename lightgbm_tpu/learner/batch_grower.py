@@ -94,18 +94,57 @@ def _recount_leaves(leaf_of_row: jax.Array, mask_f: jax.Array, size: int,
         return counts.astype(jnp.float32)
 
 
+def _planes(h: jax.Array) -> jax.Array:
+    """``[..., F, B, C]`` as the per-leaf state carries it, ``[..., C, F, B]``."""
+    return jnp.moveaxis(h, -1, -3)
+
+
+def _channels_last(s: jax.Array) -> jax.Array:
+    """Slabs of the per-leaf state ``[..., C, F, B]`` as the histogram
+    kernels hand them over and the split search takes them,
+    ``[..., F, B, C]``."""
+    return jnp.moveaxis(s, -3, -1)
+
+
+def read_slabs(hist: jax.Array, rows: jax.Array) -> jax.Array:
+    """The slabs ``hist[rows]`` of the per-leaf state, ``[K, C, F, B]``, one
+    leading-axis slice a slot as ``write_children`` writes them: a gather
+    of K rows has the compiler copy the state first, seven column blocks of
+    it at 2,000 columns and all of it re-tiled at 67 (PERF.md section 6)."""
+    return lax.map(
+        lambda r: lax.dynamic_index_in_dim(hist, r, keepdims=False), rows)
+
+
 def write_children(hist: jax.Array, parents: jax.Array,
                    new_leaves: jax.Array, valid: jax.Array,
                    h_left: jax.Array, h_right: jax.Array) -> jax.Array:
-    """A round's update of the per-leaf histogram state ``[L, F, B, C]``:
-    each valid slot's left child takes its parent's place, its right child
-    the new leaf's; an invalid slot writes back what was there.  Two
-    scatters of ``[K, F, B, C]`` into the carried state, in place inside
-    the round loop (the state is never copied: PERF.md section 5)."""
-    hist = hist.at[parents].set(
-        jnp.where(valid[:, None, None, None], h_left, hist[parents]))
-    return hist.at[new_leaves].set(
-        jnp.where(valid[:, None, None, None], h_right, hist[new_leaves]))
+    """A round's update of the per-leaf histogram state: each valid slot's
+    left child ``h_left[j]`` takes the place ``parents[j]``, its right
+    child ``h_right[j]`` the place ``new_leaves[j]``; an invalid slot
+    leaves both of its places as they were.
+
+    ``hist`` is the state as the tree loop carries it, ``f32 [rows + 1, C,
+    F, B]``: a leaf's (or pool slot's) slab contiguous, the channel planes
+    g, h, n and a zero one ahead of the columns, the bins on the lanes, and
+    a last row that no one reads, where an invalid slot's two slabs go.
+    The children come as the kernels and the search have them, ``[K, F, B,
+    C]``.  ONE layout for the whole loop: the reads of ``[K, ...]`` slabs
+    (``read_slabs``) and these writes, one leading-axis
+    ``dynamic_update_slice`` a slab, address the same buffer in place, and
+    nothing of the state's size is copied or re-tiled inside a tree
+    (``chip_smoke.py`` ``hist_state_copies``; PERF.md section 5)."""
+    trash = hist.shape[0] - 1
+    for slabs, places in ((h_left, parents), (h_right, new_leaves)):
+        slabs = _planes(slabs)                                 # [K, C, F, B]
+        places = jnp.where(valid, places, trash)
+
+        def put(j, hist, slabs=slabs, places=places):
+            return lax.dynamic_update_slice(
+                hist, lax.dynamic_index_in_dim(slabs, j, keepdims=True),
+                (places[j], 0, 0, 0))
+
+        hist = lax.fori_loop(0, valid.shape[0], put, hist)
+    return hist
 
 
 def fuses_partition(bundle: Optional[DeviceBundle]) -> bool:
@@ -412,10 +451,11 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         state = dict(
             tree=tree,
             leaf_of_row=jnp.zeros((n,), jnp.int32),
-            hist=(jnp.zeros((P + 1, n_cols, hp.n_bins, C), jnp.float32)
-                  .at[0].set(hist0_b) if pooled else
-                  jnp.zeros((L, n_cols, hp.n_bins, C),
-                            jnp.float32).at[0].set(hist0_b)),
+            # the per-leaf histograms, a row a leaf (a pool slot under the
+            # bounded pool) and a last row for the writes of invalid slots,
+            # in the ONE form the loop carries them (``write_children``)
+            hist=jnp.zeros(((P if pooled else L) + 1, C, n_cols, hp.n_bins),
+                           jnp.float32).at[0].set(_planes(hist0_b)),
             sum_g=jnp.zeros((L,), jnp.float32).at[0].set(g0),
             sum_h=jnp.zeros((L,), jnp.float32).at[0].set(h0),
             count=jnp.zeros((L,), jnp.float32).at[0].set(c0),
@@ -486,9 +526,9 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   resident = (slot >= 0) & (slot < P)
 
                   def hf_from_pool(_):
-                      hc = st["hist"][jnp.clip(slot, 0, P),
+                      hc = st["hist"][jnp.clip(slot, 0, P), :,
                                       ff if bundle is None
-                                      else bundle.feat_col[ff]]
+                                      else bundle.feat_col[ff]].T
                       return hc if bundle is None else \
                           _expand_hist_col(hc, bundle, ff,
                                            st["sum_g"][fl],
@@ -499,8 +539,8 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                 lambda _: forced_col_hist(
                                     ff, st["leaf_of_row"], fl), None)
               else:
-                  hf_col = st["hist"][fl, ff if bundle is None
-                                      else bundle.feat_col[ff]]  # [B, C]
+                  hf_col = st["hist"][fl, :, ff if bundle is None
+                                      else bundle.feat_col[ff]].T  # [B, C]
                   hf = hf_col if bundle is None else \
                       _expand_hist_col(hf_col, bundle, ff,
                                        st["sum_g"][fl],
@@ -535,8 +575,8 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       bs_f = categorical_left_bitset(
                           hf, levels[ff], var_f, ft, hp) & is_cat[ff]
                   else:
-                      bs_f = winner_bitset(st["hist"][fl], pgf, phf, pcf,
-                                           ff, var_f, ft)
+                      bs_f = winner_bitset(_channels_last(st["hist"][fl]),
+                                           pgf, phf, pcf, ff, var_f, ft)
                   st["best_bitset"] = st["best_bitset"].at[fl].set(
                       jnp.where(use_f, bs_f, st["best_bitset"][fl]))
               forced_sel = (fl, use_f)
@@ -979,7 +1019,7 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   # own keys
                   h_small = hist_call(smaller, small_cnt, sort_key)
                   with jax.named_scope("hist_update"):
-                      h_parent = st["hist"][parents]
+                      h_parent = _channels_last(read_slabs(st["hist"], parents))
                       h_large = h_parent - h_small
                       h_left = jnp.where(left_small, h_small, h_large)
                       h_right = jnp.where(left_small, h_large, h_small)
@@ -1007,7 +1047,8 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   h_ext = hist_call(leaves_ext, ext_cnt)
                   with jax.named_scope("hist_update"):
                       h_small = h_ext[:Kr]
-                      h_parent = st["hist"][jnp.maximum(p_slot, 0)]
+                      h_parent = _channels_last(
+                          read_slabs(st["hist"], jnp.maximum(p_slot, 0)))
                       h_large = jnp.where(present[:, None, None, None],
                                           h_parent - h_small, h_ext[Kr:])
                       h_left = jnp.where(left_small, h_small, h_large)
@@ -1038,9 +1079,8 @@ def grow_tree_batched(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                       slot_r = alloc[Kr:]
                       tgt_l = jnp.where(valid, slot_l, P)
                       tgt_r = jnp.where(valid, slot_r, P)
-                      hist = st["hist"].at[tgt_l].set(h_left)
-                      hist = hist.at[tgt_r].set(h_right)
-                      st["hist"] = hist
+                      st["hist"] = write_children(
+                          st["hist"], slot_l, slot_r, valid, h_left, h_right)
                       slot_leaf = slot_leaf.at[tgt_l].set(
                           jnp.where(valid, parents, -1))
                       slot_leaf = slot_leaf.at[tgt_r].set(

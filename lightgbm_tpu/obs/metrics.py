@@ -260,8 +260,9 @@ COUNTERS: Dict[str, str] = {
         "booster",
     "hist_state_bytes":
         "bytes of the per-leaf histogram state the grower carries "
-        "through a tree ([leaves or pool slots, F, bins, 4] f32), once a "
-        "booster",
+        "through a tree (f32 [leaves or pool slots, 4, F, bins]; it also "
+        "carries one spare row for the writes of invalid slots, which "
+        "this does not count), once a booster",
     "hist_vmem_budget_bytes":
         "the one VMEM budget every histogram and partition kernel's "
         "blocks follow from (ops/hist_pallas.py VMEM_BUDGET_BYTES), once "

@@ -367,12 +367,10 @@ def test_sum_small_table_compiles_per_shard_for_four_chips(topo):
         assert "all-reduce" in c.as_text()
 
 
-def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
-                                                           monkeypatch):
-    """The round program ``train_fused`` builds for a quantised job,
-    compiled for the described chip: ``leaf_renew`` holds the kernel by
-    name (what the benchmark's breakdown and chip_smoke.py look for) and
-    the scatter-add is gone from it."""
+def _fused_round_text(monkeypatch, one_chip, X, y, params, rounds=8):
+    """Compiled text of the round program ``train_fused`` builds for the
+    job, for the described chip (the booster is built on the CPU; only
+    the runner's trace sees a TPU)."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.boosting import gbdt as gbdt_mod
     from lightgbm_tpu.ops.compile_cache import GLOBAL_COMPILE_CACHE
@@ -387,7 +385,6 @@ def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
         fn = real(key, build, **kw)
 
         def call(*args):
-            # the booster was built on the CPU; only this trace sees a TPU
             monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
             avals = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -397,25 +394,76 @@ def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
         return call
 
     monkeypatch.setattr(gbdt_mod, "cc_get_or_build", spy)
+    try:
+        with pytest.raises(Captured):
+            lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                      num_boost_round=rounds)
+    finally:
+        GLOBAL_COMPILE_CACHE.clear()     # a runner traced for the TPU
+    return captured["text"]
+
+
+def test_fused_round_program_renews_leaves_with_the_kernel(topo, one_chip,
+                                                           monkeypatch):
+    """The round program ``train_fused`` builds for a quantised job,
+    compiled for the described chip: ``leaf_renew`` holds the kernel by
+    name (what the benchmark's breakdown and chip_smoke.py look for) and
+    the scatter-add is gone from it."""
     rng = np.random.default_rng(5)
     X = rng.normal(size=(3210, 8))
     y = (X @ rng.normal(size=8) > 0).astype(np.float64)
     params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
               "min_data_in_leaf": 5, "tpu_split_batch": 4,
               "use_quantized_grad": True, "quant_train_renew_leaf": True}
-    try:
-        with pytest.raises(Captured):
-            lgb.train(params, lgb.Dataset(X, label=y, params=params),
-                      num_boost_round=8)
-    finally:
-        GLOBAL_COMPILE_CACHE.clear()     # a runner traced for the TPU
-    calls = [line for line in captured["text"].splitlines()
+    text = _fused_round_text(monkeypatch, one_chip, X, y, params)
+    calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "leaf_renew" in line]
     assert len(calls) == 1
     assert "%_sum_pallas" in calls[0]
     assert "leaf_renew/jit(_sum_pallas)/pallas_call" in calls[0]
     assert not any("leaf_renew" in line and "scatter" in line
-                   for line in captured["text"].splitlines())
+                   for line in text.splitlines())
+
+
+@pytest.mark.parametrize("f", [2000, 67])
+def test_fused_round_program_carries_the_histogram_state_in_one_layout(
+        topo, one_chip, monkeypatch, f):
+    """The round program of a 2,000-column job and of a 67-column one,
+    compiled for the described chip: the per-leaf histogram state has ONE
+    tiled layout in every loop (bins on the lanes, a leaf's slab
+    contiguous), its slabs are read by ``dynamic-slice`` and written in
+    place by ``dynamic-update-slice``, and nothing in a ``while`` body
+    gives the state, or half of it in blocks, anew (``chip_smoke.py``
+    counts the same on the chip: ``hist_state_copies``).  Until PR 46 the
+    loop carried the state as ``[L, F, C, B]`` tiles for the scatters and
+    converted all of it to ``[L, C, F, B]`` tiles twice a round pass for
+    the gathers, which themselves copied seven column blocks of it out at
+    2,000 columns and re-tiled all of it at 67."""
+    import re
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(repo)
+    rng = np.random.default_rng(46)
+    leaves = 15
+    X = rng.normal(size=(2048, f))
+    y = (X[:, :50].sum(axis=1) > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": leaves, "verbose": -1,
+              "min_data_in_leaf": 1, "tpu_split_batch": 8, "max_bin": 255,
+              "tpu_hist_dtype": "int8", "use_quantized_grad": True,
+              "quant_train_renew_leaf": True}
+    text = _fused_round_text(monkeypatch, one_chip, X, y, params)
+    state = rf"f32\[{leaves + 1},4,{f},256\]"
+    # one tiling, whichever memory space (``S(1)``) a small state is given
+    layouts = {re.sub(r"S\(\d+\)", "", tiles)
+               for tiles in re.findall(state + r"\{([^}]*)\}", text)}
+    assert layouts == {"3,2,1,0:T(8,128)"}, layouts
+    assert re.search(state + r"\{[^}]*\} dynamic-update-slice\(", text)
+    counts = {(leaves + spare) * 4 * f * 256 for spare in (0, 1)}
+    assert chip_smoke._hist_state_copies(text, counts) == []
 
 
 # ------------------------------------------- 2,000 columns (PR 45: Epsilon)

@@ -56,21 +56,24 @@ def test_pooled_equals_unpooled(batch):
 
 
 def test_pool_state_is_bounded():
-    """The jit-traced histogram state is [P+1, F, B, 4], not [L, ...]."""
+    """The jit-traced histogram state is [P+1, 4, F, B] (the form
+    ``batch_grower.write_children`` documents), not a row a leaf."""
     import jax
     bins, grad, hess, num_bins, nan_bin, is_cat = _mk(n=2000)
     P = 14
     hp = SplitHyper(num_leaves=63, min_data_in_leaf=5, n_bins=64,
                     hist_dtype="float32", hist_pool_slots=P)
-    # trace only: any [L, F, B, 4] buffer would appear in the jaxpr text;
-    # the pooled state must appear as [P+1, F, B, 4]
+    # trace only: a buffer of a row a leaf would appear in the jaxpr text;
+    # the pooled state must appear as [P+1, 4, F, B]
     jaxpr = jax.make_jaxpr(
         lambda *a: grow_tree_batched(*a, hp, batch=4))(
         bins, grad, hess, None, num_bins, nan_bin, is_cat, None)
     text = str(jaxpr)
     f = bins.shape[1]
-    assert f"f32[{P + 1},{f},64,4]" in text
-    assert f"f32[{hp.num_leaves},{f},64,4]" not in text
+    assert f"f32[{P + 1},4,{f},64]" in text
+    for rows in (hp.num_leaves, hp.num_leaves + 1):
+        assert f"f32[{rows},4,{f},64]" not in text
+        assert f"f32[{rows},{f},64,4]" not in text
 
 
 def test_pool_via_train_params(synthetic_binary):
