@@ -177,6 +177,52 @@ _HLO_CALLED = re.compile(
     r"branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
 
 
+def _hlo_arrays(types: str) -> list:
+    """``(element count, dims, tiling)`` of every array in an HLO type."""
+    return [(math.prod(int(d) for d in dims.split(",") if d), dims,
+             re.sub(r"S\(\d+\)", "", tiles))
+            for dims, tiles in _HLO_ARRAY.findall(types)]
+
+
+def _hlo_operands(m) -> list:
+    args = re.sub(r"/\*.*?\*/", "", m.group(4)).split(")", 1)[0]
+    return [a.split()[-1].lstrip("%") for a in args.split(",") if a.strip()]
+
+
+def _hlo_called(m) -> list:
+    return [c.lstrip("%") for attr in _HLO_CALLED.findall(m.group(4))
+            for c in re.findall(r"%?[\w.\-]+", attr)]
+
+
+def _hlo_loops(text: str):
+    """``(computations, looped)`` of a compiled program's text: every
+    computation as ``{instruction name: match of _HLO_INSTR}``, and the
+    names of those a ``while`` runs: its body and condition and what they
+    call, a fusion's own computation left out (the fusion stands for it)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = _HLO_HEAD.match(line)
+        if head:
+            name = head.group(1)
+            comps[name] = {}
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            m = _HLO_INSTR.match(line.strip())
+            if m:
+                comps[name][m.group(1)] = m
+    todo = [c for instrs in comps.values() for m in instrs.values()
+            if m.group(3) == "while" for c in _hlo_called(m)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in looped:
+            looped.add(c)
+            todo.extend(d for m in comps[c].values()
+                        if m.group(3) != "fusion" for d in _hlo_called(m))
+    return comps, looped
+
+
 def _hist_state_copies(text: str, counts) -> list:
     """The instructions of the compiled program ``text`` that move the
     per-leaf histogram state inside a loop, where ``counts`` holds the
@@ -196,57 +242,23 @@ def _hist_state_copies(text: str, counts) -> list:
     leading-axis slabs (learner/batch_grower.py ``write_children``), so
     this list is empty.  Until PR 46 it held two conversions of the whole
     state a round pass, 2.09 GB each at 2,000 columns (PERF.md section 6)."""
-    comps, name = {}, None
-    for line in text.splitlines():
-        head = _HLO_HEAD.match(line)
-        if head:
-            name = head.group(1)
-            comps[name] = {}
-        elif line.startswith("}"):
-            name = None
-        elif name is not None:
-            m = _HLO_INSTR.match(line.strip())
-            if m:
-                comps[name][m.group(1)] = m
-
-    def arrays(types):
-        return [(math.prod(int(d) for d in dims.split(",") if d), dims,
-                 re.sub(r"S\(\d+\)", "", tiles))
-                for dims, tiles in _HLO_ARRAY.findall(types)]
-
-    def operands(m):
-        args = re.sub(r"/\*.*?\*/", "", m.group(4)).split(")", 1)[0]
-        return [a.split()[-1].lstrip("%") for a in args.split(",") if a.strip()]
-
-    def called(m):
-        return [c.lstrip("%") for attr in _HLO_CALLED.findall(m.group(4))
-                for c in re.findall(r"%?[\w.\-]+", attr)]
-
-    todo = [c for instrs in comps.values() for m in instrs.values()
-            if m.group(3) == "while" for c in called(m)]
-    looped = set()
-    while todo:
-        c = todo.pop()
-        if c in comps and c not in looped:
-            looped.add(c)
-            todo.extend(d for m in comps[c].values()
-                        if m.group(3) != "fusion" for d in called(m))
+    comps, looped = _hlo_loops(text)
 
     def in_place(m):
         """Which results of the fusion ``m`` its root writes in place."""
-        fused = comps.get(called(m)[0], {}) if called(m) else {}
+        fused = comps.get(_hlo_called(m)[0], {}) if _hlo_called(m) else {}
         root = next((r for r in fused.values()
                      if r.group(0).startswith("ROOT ")), None)
         if root is None:
             return []
-        outs = [fused.get(o) for o in operands(root)] \
+        outs = [fused.get(o) for o in _hlo_operands(root)] \
             if root.group(3) == "tuple" else [root]
         flags = []
         for out in outs:
-            into = fused.get(operands(out)[0]) if out is not None \
+            into = fused.get(_hlo_operands(out)[0]) if out is not None \
                 and out.group(3) == "dynamic-update-slice" else None
             flags.append(into is not None and into.group(3) == "parameter"
-                         and arrays(into.group(2)) == arrays(out.group(2)))
+                         and _hlo_arrays(into.group(2)) == _hlo_arrays(out.group(2)))
         return flags
 
     found = []
@@ -254,14 +266,54 @@ def _hist_state_copies(text: str, counts) -> list:
         for name, m in comps[c].items():
             if m.group(3) not in ("copy", "copy-start", "transpose", "fusion"):
                 continue
-            res = arrays(m.group(2))
+            res = _hlo_arrays(m.group(2))
             if m.group(3) == "fusion":
                 res = [r for r, kept in zip(res, in_place(m) + [False] * len(
                     res)) if not kept]
-            takes = any(a[0] in counts for o in operands(m)
-                        if o in comps[c] for a in arrays(comps[c][o].group(2)))
+            takes = any(a[0] in counts for o in _hlo_operands(m)
+                        if o in comps[c] for a in _hlo_arrays(comps[c][o].group(2)))
             if any(r[0] in counts for r in res) or (
                     takes and 2 * sum(r[0] for r in res) >= min(counts)):
+                found.append(f"%{name} = {m.group(3)} -> {m.group(2)} in {c}")
+    return found
+
+
+def _search_candidate_arrays(text: str, kids: int, features: int,
+                             bins: int) -> list:
+    """What the split search of the compiled program ``text`` still holds
+    or moves of its candidates inside a loop: the instructions under the
+    scope ``find_splits`` (by their ``op_name``) in a ``while`` body, or
+    in a computation one calls,
+
+    - one of whose results has ``kids x features x bins x V`` elements for
+      2 <= V <= 5: the gains of V variants side by side, under whatever
+      shape (until PR 47 ``[2K, F, bins, 5]`` and ``[2K, F x bins x 5]``).
+      The children's own slabs ``[kids, features, bins, 4]``, joined under
+      the scope, are the search's input and do not count; the kernels'
+      ``[K, F, bins, 4]`` has the count of V = 2 and is ``round_hist``'s;
+    - or which is a ``copy``, ``transpose`` or ``reshape`` of ``kids x
+      features x bins`` elements or more: a layout converted.
+
+    ``find_best_split`` reduces the live variants elementwise and takes
+    the winner in two steps (ops/split.py), so this list is empty; the
+    three it held at 2,000 columns were 140 ms of a round (PERF.md section
+    6, PR 47)."""
+    from lightgbm_tpu.ops.split import NUM_VARIANTS
+    comps, looped = _hlo_loops(text)
+    per_variant = kids * features * bins
+    stacked = {per_variant * v for v in range(2, NUM_VARIANTS + 1)}
+    slabs = f"{kids},{features},{bins},4"
+    found = []
+    for c in sorted(looped):
+        for name, m in comps[c].items():
+            scope = re.search(r'op_name="([^"]*)"', m.group(4))
+            if scope is None or "find_splits" not in scope.group(1):
+                continue
+            res = _hlo_arrays(m.group(2))
+            if any(n in stacked and dims != slabs for n, dims, _ in res) or (
+                    m.group(3) in ("copy", "copy-start", "transpose",
+                                   "reshape")
+                    and any(n >= per_variant for n, _, _ in res)):
                 found.append(f"%{name} = {m.group(3)} -> {m.group(2)} in {c}")
     return found
 
@@ -672,9 +724,11 @@ def phase_wide(args, lgb):
     VMEM budget (ops/hist_pallas.py ``col_blocks``): the booster counts
     the blocks, the per-leaf state's bytes and the budget, every round runs
     in the fused scan, and on a chip the compiled round program holds the
-    payload, compaction and partition kernels under their scopes and moves
+    payload, compaction and partition kernels under their scopes, moves
     nothing of the per-leaf state's size inside a tree's loop
-    (``hist_state_copies``: 0)."""
+    (``hist_state_copies``: 0), and its split search holds no array of the
+    candidates' variants and converts no layout
+    (``search_candidate_arrays``: 0)."""
     import jax
 
     t0 = time.time()
@@ -710,7 +764,7 @@ def phase_wide(args, lgb):
     # (a direction over 2,000 columns takes more rounds than a smoke has)
     _require(len(auc) == args.iters and 0.6 < auc[0] < auc[-1],
              f"valid AUC per round: {auc}")
-    calls = copies = None
+    calls = copies = stacked = None
     if jax.devices()[0].platform == "tpu":
         text = _fused_program_text(gb)
         calls = text.count("tpu_custom_call")
@@ -721,6 +775,12 @@ def phase_wide(args, lgb):
         copies = len(moved)
         _require(not moved, f"the per-leaf histogram state is copied or "
                  f"re-tiled inside the tree loop: {moved}")
+        kept = _search_candidate_arrays(
+            text, 2 * int(gb.config.tpu_split_batch), WIDE_FEATURES,
+            gb.hp.n_bins)
+        stacked = len(kept)
+        _require(not kept, f"the split search holds its candidates' variants "
+                 f"side by side or converts a layout: {kept}")
         _require_kernel(text, "histogram_payload_pallas", "hist_kernel",
                         "no payload kernel under hist_kernel in the wide "
                         "round program")
@@ -729,7 +789,7 @@ def phase_wide(args, lgb):
     _emit("wide", t0, rows=rows, features=WIDE_FEATURES, iters=args.iters,
           hist_col_blocks=blocks, hist_state_bytes=state,
           hist_vmem_budget_bytes=budget, tpu_custom_calls=calls,
-          hist_state_copies=copies,
+          hist_state_copies=copies, search_candidate_arrays=stacked,
           valid_auc_first=auc[0], valid_auc_last=auc[-1],
           smoke_train_s=round(secs, 2))
 

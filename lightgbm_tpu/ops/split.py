@@ -8,11 +8,13 @@ with in-block prefix scans + arg-reduction).
 
 On TPU the whole thing is a handful of vector ops over the [F, B] histogram:
 cumulative sums along the bin axis give every threshold's left-side stats at
-once, both missing-value default directions are evaluated as a 2-wide variant
-axis (the reference's forward/backward scans), one-hot categorical candidates
-ride the same argmax, and a single flat argmax picks the winner.  Bins beyond
-a feature's ``num_bin`` and the dedicated NaN bin are masked, replacing the
-reference's per-feature loop bounds.
+once, both missing-value default directions are evaluated as two candidate
+variants (the reference's forward/backward scans), one-hot and sorted-subset
+categorical candidates are further variants where the job has such columns,
+and the winner is the first maximum in (feature, bin, variant) order: the
+variants reduced elementwise, then the bins of a feature, then the features.
+Bins beyond a feature's ``num_bin`` and the dedicated NaN bin are masked,
+replacing the reference's per-feature loop bounds.
 
 Gain/regularization semantics mirror feature_histogram.hpp:
 ``ThresholdL1`` soft-shrink, gain = GL'^2/(HL+l2) + GR'^2/(HR+l2), validity =
@@ -102,7 +104,8 @@ class SplitHyper:
     hist_pool_slots: int = 0
 
 
-#: candidate-variant indices along the last axis of the gain tensor
+#: candidate variants; a winner's place in the search order is
+#: ``(feature * n_bins + bin) * NUM_VARIANTS + variant``
 VAR_NUM_RIGHT = 0    # numerical, missing goes right
 VAR_NUM_LEFT = 1     # numerical, missing goes left
 VAR_CAT_ONEHOT = 2   # categorical one-hot: {bin == t} left
@@ -293,6 +296,33 @@ def sort_by_score(score: jax.Array, cand_bin: jax.Array, stats,
                     is_stable=True, num_keys=1)[1:]
 
 
+def _best_variant(gains: dict):
+    """``(best gain, its variant)`` elementwise over the live variants'
+    gains ``{VAR_*: [F, B]}``; the lowest variant keeps a tie."""
+    (v0, best), *rest = sorted(gains.items())
+    var = jnp.full(best.shape, v0, jnp.int32)
+    for v, gain in rest:
+        better = gain > best
+        best = jnp.where(better, gain, best)
+        var = jnp.where(better, jnp.int32(v), var)
+    return best, var
+
+
+def _first_max(val: jax.Array, order: jax.Array, axis: int):
+    """``(max of val, the lowest order among its maxima)`` along ``axis``:
+    an argmax that hands on a position (i32 ``order``) other than the
+    index along ``axis``."""
+    def pick(a, b):
+        (a_val, a_ord), (b_val, b_ord) = a, b
+        take_b = (b_val > a_val) | ((b_val == a_val) & (b_ord < a_ord))
+        return (jnp.where(take_b, b_val, a_val),
+                jnp.where(take_b, b_ord, a_ord))
+    return lax.reduce((val, order),
+                      (jnp.array(-jnp.inf, val.dtype),
+                       jnp.array(jnp.iinfo(order.dtype).max, order.dtype)),
+                      pick, (axis,))
+
+
 def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                     count: jax.Array, num_bins: jax.Array, nan_bin: jax.Array,
                     is_cat: jax.Array, feature_mask: Optional[jax.Array],
@@ -326,7 +356,8 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
     valid_bin = bin_idx < num_bins[:, None]                      # [F, B]
     is_nan = bin_idx == nan_bin[:, None]                         # [F, B]
 
-    # base cumulatives exclude the missing bin; its stats ride the variant axis
+    # base cumulatives exclude the missing bin; the missing-left variant adds
+    # its stats
     gz = jnp.where(is_nan, 0.0, g)
     hz = jnp.where(is_nan, 0.0, h)
     nz = jnp.where(is_nan, 0.0, n)
@@ -387,11 +418,13 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
     # only splits off the missing bin, t at the nan bin itself is invalid
     thr_ok = valid_bin & (bin_idx < num_bins[:, None] - 1) & ~is_nan
     thr_ok = thr_ok & ~is_cat[:, None]
-    gain_right = jnp.where(thr_ok, variant_gain(gl, hl, nl, l2,
-                                                bnds=adv_bounds), NEG_INF)
-    gain_left = jnp.where(thr_ok & has_missing,
-                          variant_gain(gl + gm, hl + hm, nl + nm, l2,
-                                       bnds=adv_bounds), NEG_INF)
+    # the live variants' gains, each [F, B]: a variant the job cannot have
+    # (``hp``) has no array anywhere below
+    gains = {
+        VAR_NUM_RIGHT: jnp.where(thr_ok, variant_gain(
+            gl, hl, nl, l2, bnds=adv_bounds), NEG_INF),
+        VAR_NUM_LEFT: jnp.where(thr_ok & has_missing, variant_gain(
+            gl + gm, hl + hm, nl + nm, l2, bnds=adv_bounds), NEG_INF)}
 
     # the subset scan's own rows of the histogram: the job's static list
     # of subset columns, every row where it is not known (None), and no
@@ -411,8 +444,8 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
         # num_bin <= max_cat_to_onehot``; plain lambda_l2 in this branch)
         onehot_ok = is_cat[:, None] & (levels[:, None]
                                        <= hp.max_cat_to_onehot)
-        gain_cat = jnp.where(valid_bin & ~is_nan & onehot_ok,
-                             variant_gain(g, h, n, l2), NEG_INF)
+        gains[VAR_CAT_ONEHOT] = jnp.where(valid_bin & ~is_nan & onehot_ok,
+                                          variant_gain(g, h, n, l2), NEG_INF)
 
     if sub != ():
         # sorted-subset categorical (reference feature_histogram.cpp:241-340):
@@ -465,60 +498,57 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
                                                   mono=mono_s), NEG_INF)
                 return spread(gain, NEG_INF), glv, hlv, nlv, order
 
-            gain_fwd, gl_f, hl_f, nl_f, order_f = subset_scan(False)
-            gain_bwd, gl_b, hl_b, nl_b, order_b = subset_scan(True)
+            gains[VAR_CAT_FWD], gl_f, hl_f, nl_f, order_f = subset_scan(False)
+            gains[VAR_CAT_BWD], gl_b, hl_b, nl_b, order_b = subset_scan(True)
             used_bin = spread(used_bin_s, 0)
             max_num_cat = spread(max_num_cat_s, 0)
-    else:
-        neg = jnp.full((num_f, n_b), NEG_INF)
-        gain_fwd = gain_bwd = neg
-        if not hp.has_categorical:
-            gain_cat = neg
-        gl_f = hl_f = nl_f = gl_b = hl_b = nl_b = jnp.zeros_like(g)
-        used_bin = max_num_cat = jnp.zeros((num_f,), jnp.int32)
-        row_of_feat = lambda f: f
 
     if hp.extra_trees and rng_key is not None:
         # extremely-randomized mode: per (feature, node) keep exactly ONE
         # random candidate threshold per variant family (reference
         # feature_histogram.cpp USE_RAND rand_threshold draws)
         kn, kc, ks = jax.random.split(rng_key, 3)
+        keep = {}
         u_num = jax.random.uniform(kn, (num_f,))
         rand_num = jnp.floor(
             u_num * jnp.maximum(num_bins - 1, 1).astype(jnp.float32)
         ).astype(jnp.int32)
-        keep_num = bin_idx == rand_num[:, None]
-        gain_right = jnp.where(keep_num, gain_right, NEG_INF)
-        gain_left = jnp.where(keep_num, gain_left, NEG_INF)
+        keep[VAR_NUM_RIGHT] = keep[VAR_NUM_LEFT] = \
+            bin_idx == rand_num[:, None]
         if hp.has_categorical:
             u_cat = jax.random.uniform(kc, (num_f,))
             rand_cat = jnp.floor(
                 u_cat * num_bins.astype(jnp.float32)).astype(jnp.int32)
-            gain_cat = jnp.where(bin_idx == rand_cat[:, None], gain_cat,
-                                 NEG_INF)
+            keep[VAR_CAT_ONEHOT] = bin_idx == rand_cat[:, None]
+        if sub != ():
             u_sub = jax.random.uniform(ks, (num_f,))
             max_thr = jnp.maximum(jnp.minimum(max_num_cat, used_bin) - 1, 0)
             rand_k = jnp.floor(
                 u_sub * (max_thr + 1).astype(jnp.float32)).astype(jnp.int32)
-            keep_sub = bin_idx == rand_k[:, None]
-            gain_fwd = jnp.where(keep_sub, gain_fwd, NEG_INF)
-            gain_bwd = jnp.where(keep_sub, gain_bwd, NEG_INF)
+            keep[VAR_CAT_FWD] = keep[VAR_CAT_BWD] = \
+                bin_idx == rand_k[:, None]
+        gains = {v: jnp.where(keep[v], c, NEG_INF) for v, c in gains.items()}
 
-    cand = jnp.stack([gain_right, gain_left, gain_cat, gain_fwd, gain_bwd],
-                     axis=-1)                                  # [F, B, V]
+    # What is left to do to the candidates is per feature and keeps their
+    # order, so the variants could be reduced first; but rounding can make
+    # two of a bin's variants EQUAL (a penalty far above the gains), and
+    # among equals the lowest variant wins.  So each variant takes it
+    # before the reduction: elementwise on [F, B], fused into the one pass.
     if feature_mask is not None:
-        cand = jnp.where(feature_mask[:, None, None], cand, NEG_INF)
+        gains = {v: jnp.where(feature_mask[:, None], c, NEG_INF)
+                 for v, c in gains.items()}
     if gain_penalty is not None:
         # CEGB: per-feature acquisition cost subtracted from the split gain
         # before the argmax (cost_effective_gradient_boosting.hpp DeltaGain)
-        cand = jnp.where(cand > NEG_INF / 2,
-                         cand - gain_penalty[:, None, None], cand)
+        gains = {v: jnp.where(c > NEG_INF / 2, c - gain_penalty[:, None], c)
+                 for v, c in gains.items()}
 
     if per_feature_out is not None:
         # voting-parallel hook: per-feature best gain before the global
         # argmax (reference voting_parallel_tree_learner.cpp:344 votes on
         # per-feature local split gains)
-        per_feature_out.append(jnp.max(cand, axis=(1, 2)) - min_shift)
+        per_feature_out.append(
+            jnp.max(_best_variant(gains)[0], axis=1) - min_shift)
 
     if hp.use_monotone and hp.monotone_penalty > 0.0:
         # depth-decaying gain penalty on monotone features, applied to the
@@ -530,30 +560,48 @@ def find_best_split(hist: jax.Array, sum_g: jax.Array, sum_h: jax.Array,
         pen = jnp.where(p >= d + 1.0, eps,
                         jnp.where(p <= 1.0, 1.0 - p / (2.0 ** d) + eps,
                                   1.0 - 2.0 ** (p - 1.0 - d) + eps))
-        pen_f = jnp.where(monotone != 0, pen, 1.0)[:, None, None]
-        final = cand - min_shift
-        cand = jnp.where(final > 0, final * pen_f, NEG_INF)
+        pen_f = jnp.where(monotone != 0, pen, 1.0)[:, None]
+
+        def penalised(c, shift=min_shift):
+            final = c - shift
+            return jnp.where(final > 0, final * pen_f, NEG_INF)
+        gains = {v: penalised(c) for v, c in gains.items()}
         min_shift = jnp.float32(0.0)
 
-    flat = cand.reshape(-1)
-    best = jnp.argmax(flat)
-    best_gain_raw = flat[best]
-    feat = (best // (n_b * NUM_VARIANTS)).astype(jnp.int32)
+    # the winner: the first maximum in (feature, bin, variant) order.  No
+    # array holds the variants side by side (84 children x 2,000 columns
+    # x 256 bins x 5 variants were 0.86 GB a round pass, written with the
+    # variants on the sublanes and converted twice before one argmax;
+    # PERF.md section 6, PR 47): the variants are reduced elementwise, then
+    # the bins of a feature along the lanes, then the features
+    gain_b, var_b = _best_variant(gains)                         # [F, B]
+    gain_f, place_f = _first_max(
+        gain_b, bin_idx * NUM_VARIANTS + var_b, axis=1)          # [F]
+    best_gain_raw, best = _first_max(
+        gain_f, lax.iota(jnp.int32, num_f) * (n_b * NUM_VARIANTS) + place_f,
+        axis=0)
+    feat = best // (n_b * NUM_VARIANTS)
     rem = best % (n_b * NUM_VARIANTS)
-    thr = (rem // NUM_VARIANTS).astype(jnp.int32)
-    variant = (rem % NUM_VARIANTS).astype(jnp.int32)
+    thr = rem // NUM_VARIANTS
+    variant = rem % NUM_VARIANTS
 
-    # recover the winner's left-side stats
-    srow = row_of_feat(feat)     # the winner's row of the subset scan
-    glw = jnp.stack([gl[feat, thr], gl[feat, thr] + gm[feat, 0], g[feat, thr],
-                     gl_f[srow, thr], gl_b[srow, thr]])
-    hlw = jnp.stack([hl[feat, thr], hl[feat, thr] + hm[feat, 0], h[feat, thr],
-                     hl_f[srow, thr], hl_b[srow, thr]])
-    nlw = jnp.stack([nl[feat, thr], nl[feat, thr] + nm[feat, 0], n[feat, thr],
-                     nl_f[srow, thr], nl_b[srow, thr]])
-    lg = glw[variant]
-    lh = hlw[variant]
-    ln = nlw[variant]
+    # the winner's left-side stats, by its variant
+    g_t, h_t, n_t = gl[feat, thr], hl[feat, thr], nl[feat, thr]
+    sums = {VAR_NUM_RIGHT: (g_t, h_t, n_t),
+            VAR_NUM_LEFT: (g_t + gm[feat, 0], h_t + hm[feat, 0],
+                           n_t + nm[feat, 0])}
+    if hp.has_categorical:
+        sums[VAR_CAT_ONEHOT] = (g[feat, thr], h[feat, thr], n[feat, thr])
+    if sub != ():
+        srow = row_of_feat(feat)     # the winner's row of the subset scan
+        sums[VAR_CAT_FWD] = (gl_f[srow, thr], hl_f[srow, thr],
+                             nl_f[srow, thr])
+        sums[VAR_CAT_BWD] = (gl_b[srow, thr], hl_b[srow, thr],
+                             nl_b[srow, thr])
+    lg, lh, ln = sums.pop(VAR_NUM_RIGHT)
+    for v, of_v in sums.items():
+        lg, lh, ln = (jnp.where(variant == v, a, b)
+                      for a, b in zip(of_v, (lg, lh, ln)))
 
     if left_bins_out is not None and hp.has_categorical:
         with jax.named_scope("cat_bitset"):

@@ -438,7 +438,12 @@ def test_fused_round_program_carries_the_histogram_state_in_one_layout(
     loop carried the state as ``[L, F, C, B]`` tiles for the scatters and
     converted all of it to ``[L, C, F, B]`` tiles twice a round pass for
     the gathers, which themselves copied seven column blocks of it out at
-    2,000 columns and re-tiled all of it at 67."""
+    2,000 columns and re-tiled all of it at 67.  And the split search
+    under ``find_splits`` holds no array of its candidates' variants side
+    by side and converts no layout (``search_candidate_arrays``): until PR
+    47 a ``[2K, F, 256, 5]`` fusion with the variants on the sublanes, its
+    ``reshape`` to ``[2K, F x 1280]`` and that one's ``copy``, before one
+    argmax."""
     import re
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -448,11 +453,11 @@ def test_fused_round_program_carries_the_histogram_state_in_one_layout(
     finally:
         sys.path.remove(repo)
     rng = np.random.default_rng(46)
-    leaves = 15
+    leaves, batch = 15, 8
     X = rng.normal(size=(2048, f))
     y = (X[:, :50].sum(axis=1) > 0).astype(np.float64)
     params = {"objective": "binary", "num_leaves": leaves, "verbose": -1,
-              "min_data_in_leaf": 1, "tpu_split_batch": 8, "max_bin": 255,
+              "min_data_in_leaf": 1, "tpu_split_batch": batch, "max_bin": 255,
               "tpu_hist_dtype": "int8", "use_quantized_grad": True,
               "quant_train_renew_leaf": True}
     text = _fused_round_text(monkeypatch, one_chip, X, y, params)
@@ -464,6 +469,8 @@ def test_fused_round_program_carries_the_histogram_state_in_one_layout(
     assert re.search(state + r"\{[^}]*\} dynamic-update-slice\(", text)
     counts = {(leaves + spare) * 4 * f * 256 for spare in (0, 1)}
     assert chip_smoke._hist_state_copies(text, counts) == []
+    assert 'find_splits/' in text
+    assert chip_smoke._search_candidate_arrays(text, 2 * batch, f, 256) == []
 
 
 # ------------------------------------------- 2,000 columns (PR 45: Epsilon)

@@ -265,3 +265,83 @@ def test_hist_state_copies_reads_the_compiled_text(where, line, found, sigil):
     counts = {rows * 4 * 2000 * 256 for rows in (255, 256)}
     got = chip_smoke._hist_state_copies(text.replace("%", sigil), counts)
     assert [g.split(" = ")[0] for g in got] == found
+
+
+# the round body of ``_round_program`` with the split search's instructions
+# as the chip's compiler printed them at 2,000 columns, K = 42 (PR 47)
+_SEARCH_OP = ('metadata={op_name="jit(run)/while/body/closed_call/'
+              'jit(grow_tree_batched)/tree_select/while/body/%s"}')
+_KIDS = "f32[84,2000,256]{2,1,0:T(8,128)}"
+_NEXT = "\n" + " " * 10      # a second instruction, at ``_round_program``'s margin
+_SEARCH_CASES = [
+    ("nothing_of_the_search", "in_round_body", "", []),
+    # what the search writes since PR 47: the bins' cumulative sums, a
+    # plane each, and a feature's best gain and its place
+    ("cumulative_sums_and_the_reduction", "in_round_body",
+     f"%fusion.520 = {_KIDS} fusion(%copy.7), kind=kOutput, "
+     "calls=%fused_write, " + _SEARCH_OP % "find_splits/vmap()/dot_general"
+     + _NEXT + "%fusion.534 = (f32[84,2000]{1,0:T(8,128)S(1)}, "
+     "s32[84,2000]{1,0:T(8,128)S(1)}) fusion(%fusion.520), kind=kLoop, "
+     "calls=%fused_write, " + _SEARCH_OP % "find_splits/vmap()/reduce", []),
+    # the parent's: five variants stacked, the variants on the sublanes
+    ("five_variants_stacked", "in_round_body",
+     "%maximum_maximum_fusion.5 = f32[84,2000,256,5]{0,3,2,1:T(8,128)} "
+     "fusion(%copy.7), kind=kLoop, calls=%fused_write, "
+     + _SEARCH_OP % "find_splits/vmap()/concatenate",
+     ["%maximum_maximum_fusion.5"]),
+    ("the_stack_flattened_and_copied", "in_round_body",
+     "%reshape.2223 = f32[84,2560000]{0,1:T(8,128)} reshape(%copy.7), "
+     + _SEARCH_OP % "find_splits/vmap()/reshape"
+     + _NEXT + "%copy.102 = f32[84,2560000]{1,0:T(8,128)} copy(%reshape.2223), "
+     + _SEARCH_OP % "find_splits/vmap()/reshape",
+     ["%reshape.2223", "%copy.102"]),
+    ("two_variants_stacked", "in_round_body",
+     "%fusion.77 = f32[84,2000,256,2]{3,2,1,0:T(2,128)} fusion(%copy.7), "
+     "kind=kLoop, calls=%fused_write, "
+     + _SEARCH_OP % "find_splits/vmap()/concatenate", ["%fusion.77"]),
+    # one variant's worth, converted: a layout copy of a plane
+    ("a_plane_copied", "in_round_body",
+     "%copy.101 = f32[84,2000,256,1]{0,3,2,1:T(1,128)} copy(%copy.7), "
+     + _SEARCH_OP % "find_splits/vmap()/broadcast_in_dim", ["%copy.101"]),
+    ("a_plane_transposed", "in_round_body",
+     "%transpose.9 = f32[2000,84,256]{2,1,0:T(8,128)} transpose(%copy.7), "
+     "dimensions={1,0,2}, " + _SEARCH_OP % "find_splits/vmap()/transpose",
+     ["%transpose.9"]),
+    # the children's slabs joined under the scope are the search's input
+    ("the_childrens_slabs", "in_round_body",
+     "%concatenate.3 = f32[84,2000,256,4]{2,1,3,0:T(8,128)} "
+     "concatenate(%copy.7, %copy.7), dimensions={0}, "
+     + _SEARCH_OP % "find_splits/concatenate", []),
+    # the kernels' output has the count of two variants and is round_hist's
+    ("the_kernels_output", "in_round_body",
+     "%reshape.9 = f32[42,2000,256,4]{2,1,3,0:T(8,128)} reshape(%copy.7), "
+     + _SEARCH_OP % "round_hist/hist_update/reshape", []),
+    # a small copy under the scope: 84 winners' columns
+    ("a_copy_of_the_winners", "in_round_body",
+     "%copy.5 = f32[84,2000]{0,1:T(8,128)} copy(%copy.7), "
+     + _SEARCH_OP % "find_splits/vmap()/gather", []),
+    # outside every loop the root's search may do as it likes
+    ("outside_every_loop", "in_entry",
+     "%reshape.1 = f32[84,2560000]{0,1:T(8,128)} reshape(%broadcast.1), "
+     + _SEARCH_OP % "tree_root/find_splits/reshape", []),
+]
+
+
+@pytest.mark.parametrize("name,where,lines,found", _SEARCH_CASES,
+                         ids=[c[0] for c in _SEARCH_CASES])
+def test_search_candidate_arrays_reads_the_compiled_text(name, where, lines,
+                                                         found):
+    """``chip_smoke.py``'s engagement check of the split search, on a
+    canned text: under the scope ``find_splits`` in a loop, an array of
+    2 to 5 variants of ``[2K, F, bins]`` candidates counts under any shape,
+    and so does a ``copy``, ``transpose`` or ``reshape`` of one variant's
+    worth or more; what the search writes since PR 47, the children's
+    slabs, ``round_hist``'s output and anything outside a loop do not."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    text = _round_program(**{where: lines})
+    got = chip_smoke._search_candidate_arrays(text, 84, 2000, 256)
+    assert [g.split(" = ")[0] for g in got] == found
