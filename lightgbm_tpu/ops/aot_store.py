@@ -53,6 +53,7 @@ pipeline/trainer.py)::
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -75,6 +76,37 @@ META_SUFFIX = ".json"
 #: bumped when the artifact encoding changes; readers refuse unknown
 #: formats the same way they refuse stale fingerprints
 FORMAT = 1
+
+
+#: one store compile at a time has JAX's persistent cache switched off
+_FRESH_COMPILE_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def _fresh_compile():
+    """JAX's persistent compilation cache out of the way for the block,
+    and back as it was after it.  An executable that cache hands back was
+    itself deserialised, and XLA:CPU serialises such a one into an
+    artifact that loads and then fails at its first call (``NOT_FOUND:
+    Function <name> not found``): the store keeps only what was compiled
+    for it.  It IS the disk tier of the programs it holds, so nothing is
+    compiled twice for want of the other cache.  (The switch is the
+    process's: a compile on another thread meanwhile misses that cache
+    once, no more.)"""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    with _FRESH_COMPILE_LOCK:
+        # (jax's own config, not this package's: hence the three waivers)
+        was = jax.config.jax_enable_compilation_cache  # tpulint: disable=CFG201
+        jax.config.update(  # tpulint: disable=CFG201
+            "jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()     # drops the memoised "in use"
+        try:
+            yield
+        finally:
+            jax.config.update(  # tpulint: disable=CFG201
+                "jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
 
 
 def runtime_fingerprint() -> Dict[str, Any]:
@@ -252,7 +284,8 @@ class AOTStore:
         always the fallback, never an error."""
         try:
             import jax
-            compiled = jax.jit(fn).lower(*args).compile()
+            with _fresh_compile():
+                compiled = jax.jit(fn).lower(*args).compile()
         except Exception as e:
             log.warning(f"aot_store: AOT lowering failed "
                         f"({type(e).__name__}: {e}); using the live "

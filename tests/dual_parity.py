@@ -54,7 +54,7 @@ def run_backend(backend: str, params) -> dict:
     env = dict(os.environ, JAX_PLATFORMS=backend, PYTHONPATH=repo)
     r = subprocess.run([sys.executable, "-c", WORKER, json.dumps(params),
                         str(ROUNDS)], env=env, capture_output=True,
-                       text=True, timeout=3000)
+                       text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
     assert line, r.stdout

@@ -10,24 +10,19 @@ without the counters."""
 import json
 import os
 import re
-import subprocess
-import sys
 
 import jax
 import numpy as np
 import pytest
 
+import cells
 import lightgbm_tpu as lgb
+from cells import BENCH
 from lightgbm_tpu.obs.metrics import COUNTERS, global_metrics
 from lightgbm_tpu.ops import round_fuse
 from lightgbm_tpu.utils.timer import global_timer
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-for p in (BENCH, os.path.join(BENCH, "tools")):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
+CELL = "allstate-cat-train"
 # two columns with more levels than a column has bins for, the four
 # smallest of the published schema (2, 3, 3, 4: the one-hot variant)
 LEVELS = [40, 300, 600, 2, 3, 3, 4, 9, 24]
@@ -37,49 +32,34 @@ COUNTS = ("cat_features", "cat_subset_features", "cat_levels_kept",
           "cat_left_levels", "fused_partition_declined")
 
 
-def _cell():
-    import run as bench
-    _, cell, cfg, _ = bench.find_cell("allstate-cat-train", rehearse_cpu=True)
-    cfg = dict(cfg, rows=ROWS, valid_rows=VALID_ROWS,
-               features=15 + len(LEVELS),
-               data=dict(cfg["data"], levels=LEVELS, pos_rate=0.2, logit_sd=1.5),
-               params={**cfg["params"], "num_leaves": 15,
-                       "min_sum_hessian_in_leaf": 5.0,
-                       "min_data_per_group": 50,
-                       # the cell's path: under 100,000 rows auto mode
-                       # picks the strict grower and float32 histograms,
-                       # whose leaves are not renewed from full gradients
-                       # (a subset split's children then carry cat_l2)
-                       "tpu_split_batch": 8, "tpu_hist_dtype": "int8",
-                       "use_quantized_grad": True,
-                       "quant_train_renew_leaf": True},
-               categorical=dict(cfg["categorical"],
-                                columns=list(range(15, 15 + len(LEVELS))),
-                                cat_features=len(LEVELS), cat_subset_features=5),
-               compare={**cfg["compare"], "block_rows": 8192, "split_nodes": 8,
-                        "split_min_share": 0.05, "split_trees": ROUNDS,
-                        "auc_floor": {"round": ROUNDS, "auc": 0.66}})
-    return cell, cfg
-
-
 @pytest.fixture(scope="module")
 def cell():
-    return _cell()[1]
+    return cells.find(
+        CELL, rows=ROWS, valid_rows=VALID_ROWS, features=15 + len(LEVELS),
+        data={"levels": LEVELS, "pos_rate": 0.2, "logit_sd": 1.5},
+        params={"num_leaves": 15, "min_sum_hessian_in_leaf": 5.0,
+                "min_data_per_group": 50,
+                # the cell's path: under 100,000 rows auto mode picks the
+                # strict grower and float32 histograms, whose leaves are
+                # not renewed from full gradients (a subset split's
+                # children then carry cat_l2)
+                "tpu_split_batch": 8, "tpu_hist_dtype": "int8",
+                "use_quantized_grad": True, "quant_train_renew_leaf": True},
+        categorical={"columns": list(range(15, 15 + len(LEVELS))),
+                     "cat_features": len(LEVELS), "cat_subset_features": 5},
+        compare={"block_rows": 8192, "split_nodes": 8, "split_min_share": 0.05,
+                 "split_trees": ROUNDS,
+                 "auc_floor": {"round": ROUNDS, "auc": 0.66}})[1]
 
 
 @pytest.fixture(scope="module")
 def data(cell):
-    from harness import load_module
-    gen = load_module("datagen", cell["data"]["generator"])
-    f = int(cell["features"])
-    return (gen.make(cell["data"], 0, 0, ROWS, f),
-            gen.make(cell["data"], 0, 1, VALID_ROWS, f))
+    return cells.data(cell)
 
 
 @pytest.fixture(scope="module")
 def inputs(data):
-    (xt32, _, y), (xv32, _, yv) = data
-    return {"train": (xt32, y), "valid": (xv32, yv)}
+    return cells.inputs(data)
 
 
 def _construct(cell, data):
@@ -90,36 +70,22 @@ def _construct(cell, data):
     return ds, ds.create_valid(xv64.T, label=yv).construct()
 
 
-def _train(cell, data, sets=None):
+def _job(cell, data, sets=None):
     """One job of the cell at the test's size, its partition in the fused
     kernel (interpret mode), as on the chip."""
-    ds, dv = sets or _construct(cell, data)
-    evals = {}
-    round_fuse._FUSE_TEST_INTERPRET = True      # read when traced
-    try:
-        bst = lgb.train(cell["params"], ds, num_boost_round=ROUNDS,
-                        valid_sets=[dv],
-                        callbacks=[lgb.record_evaluation(evals)])
-    finally:
-        round_fuse._FUSE_TEST_INTERPRET = False
-    return bst, evals["valid_0"]["auc"]
+    return cells.train(cell, sets or _construct(cell, data), ROUNDS,
+                       interpret_partition=True)
 
 
-def _judged(cell, inputs, bst, aucs, limits=None):
-    from harness import compare, load_module, program
-    driver = load_module("drivers", "train_jobs_cat")
-    answers = {"trees": driver.plain_trees(bst._gbdt.models),
-               "valid_auc": aucs, "train_scores": program.train_scores(bst)}
-    ref = load_module("reference", cell["reference"])
-    numbers = load_module("comparisons", cell["comparison"]).gaps(
-        ref, cell, answers, inputs, 2147483659)
-    return compare.judge(numbers, limits or cell["limits"])
+def _judged(cell, inputs, bst, series):
+    driver = cells.load_module("drivers", "train_jobs_cat")
+    return cells.judged(cell, inputs,
+                        cells.answers(bst, series, driver.plain_trees))
 
 
 @pytest.fixture(scope="module")
 def job(cell, data):
-    from harness import program
-    program.free_everything()
+    cells.program.free_everything()
     before = {c: global_metrics.counter(c) for c in COUNTS}
     global_timer.reset()
     global_timer.enable()       # a booster's start resets this table
@@ -128,17 +94,14 @@ def job(cell, data):
         spans = global_timer.as_dict()
     finally:
         global_timer.disable()
-    bst, aucs = _train(cell, data, sets)
+    bst, aucs = _job(cell, data, sets)
     moved = {c: global_metrics.counter(c) - v for c, v in before.items()}
     return bst, aucs, moved, spans
 
 
 # ------------------------------------------------------------------ manifest
 def test_the_manifest_names_the_cell_and_its_metrics():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
-    import run as bench
-    _, cell, cfg, traffic = bench.find_cell("allstate-cat-train")
+    manifest, cell, cfg, traffic = cells.bench.find_cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("allstate-categorical", "train-jobs-cat", 1)
     mine = [m for m in manifest["per_layer"]
@@ -294,22 +257,15 @@ FAULTS = {"fold_unbinned_levels": "leaf_count_mismatch",
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_planted_fault_is_not_correct(monkeypatch, cell, data, inputs, fault):
+def test_a_planted_fault_is_not_correct(cell, data, inputs, fault):
     """The faults of ``tools/faults_cat.py`` that break what the job
     returns fail the limit that holds it, under the published limits:
     the parent's fold of unbinned levels and a left set shifted by a bin
     put rows where the stated model does not; a set of more levels than
     ``max_cat_threshold`` is one no scan could state."""
     import faults_cat
-    from harness import program
-    program.free_everything()
-    getattr(faults_cat, fault)(monkeypatch.setattr)
-    try:
-        bst, aucs = _train(cell, data)
-        correct, compared = _judged(cell, inputs, bst, aucs)
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+    with cells.planted(getattr(faults_cat, fault)):
+        correct, compared = _judged(cell, inputs, *_job(cell, data))
     assert not correct
     assert compared[FAULTS[fault]]["value"] > compared[FAULTS[fault]]["limit"], \
         compared
@@ -323,8 +279,7 @@ def test_a_planted_fault_is_not_correct(monkeypatch, cell, data, inputs, fault):
 
 @pytest.mark.parametrize("fault,pos_rate", [("drop_cat_l2", 0.2),
                                             ("skip_descending", 0.85)])
-def test_a_fault_of_the_search_gives_gain_away(monkeypatch, cell, fault,
-                                               pos_rate):
+def test_a_fault_of_the_search_gives_gain_away(cell, fault, pos_rate):
     """The two faults that state true and allowed splits and only give
     gain away, read by ``split_regret_mean`` against the sound job's on
     the same rows, with float32 histograms (at 30,000 rows the int8
@@ -335,25 +290,16 @@ def test_a_fault_of_the_search_gives_gain_away(monkeypatch, cell, fault,
     positive the ascending direction finds it all, and the fault reads
     as the sound job does)."""
     import faults_cat
-    from harness import load_module, program
     cell = dict(cell, data=dict(cell["data"], pos_rate=pos_rate),
                 params={k: v for k, v in cell["params"].items()
                         if k not in ("tpu_hist_dtype", "use_quantized_grad",
                                      "quant_train_renew_leaf")})
-    gen = load_module("datagen", cell["data"]["generator"])
-    data = (gen.make(cell["data"], 0, 0, ROWS, int(cell["features"])),
-            gen.make(cell["data"], 0, 1, VALID_ROWS, int(cell["features"])))
-    inputs = {"train": (data[0][0], data[0][2]),
-              "valid": (data[1][0], data[1][2])}
-    program.free_everything()
-    sound = _judged(cell, inputs, *_train(cell, data))[1]
-    program.free_everything()
-    getattr(faults_cat, fault)(monkeypatch.setattr)
-    try:
-        planted = _judged(cell, inputs, *_train(cell, data))[1]
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+    data = cells.data(cell)
+    inputs = cells.inputs(data)
+    cells.program.free_everything()
+    sound = _judged(cell, inputs, *_job(cell, data))[1]
+    with cells.planted(getattr(faults_cat, fault)):
+        planted = _judged(cell, inputs, *_job(cell, data))[1]
     assert sound["split_regret_mean"]["value"] < 0.01
     assert planted["leaf_count_mismatch"]["value"] == 0
     limit = 3.0 * sound["split_regret_mean"]["value"]
@@ -368,16 +314,7 @@ def test_the_cell_rehearses_on_the_cpu():
     reference and comparison) at 120,000 rows, where auto mode still
     picks K=42 and int8; it can never print a result line.  ``correct`` is
     not asked for: the split search's regret means nothing at this size."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "allstate-cat-train", "--seed", "3000000019", "--seconds", "1",
-         "--rehearse-cpu"], capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=1200, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-3000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    window = next(ln["window"] for ln in lines if "window" in ln)
-    assert not any(window["compiled_in_window"].values())
+    lines = cells.rehearse(CELL, 3000000019)
     setup = next(ln for ln in lines if "setup_phases_s" in ln)
     path = setup["path"]
     assert {k: path[k] for k in ("tpu_split_batch", "hist_dtype",
@@ -390,28 +327,11 @@ def test_the_cell_rehearses_on_the_cpu():
     assert set(setup["setup_spans_s"]) == {"construct", "dense_bin_mappers",
                                            "dense_bin_matrix",
                                            "cat_bin_mappers"}
-    last = lines[-1]
-    assert "rehearsal" in last and "metrics" not in last
-    exact = ("leaf_count_mismatch", "leaf_value_gap_median", "train_score_gap",
-             "valid_auc_gap")
-    assert all(last["compared"][k]["value"] <= last["compared"][k]["limit"]
-               for k in exact), last["compared"]
+    cells.assert_compared_within_limits(lines, (
+        "leaf_count_mismatch", "leaf_value_gap_median", "train_score_gap",
+        "valid_auc_gap"))
 
 
 def test_a_program_without_the_counters_is_refused_at_once(monkeypatch):
-    """The parent of this cell's PR: refused before any data is made."""
-    from harness import load_module, program
-    from lightgbm_tpu.obs import metrics
-    driver = load_module("drivers", "train_jobs_cat")
-    monkeypatch.setattr(metrics, "COUNTERS", {
-        k: v for k, v in metrics.COUNTERS.items()
-        if k != "fused_partition_declined"})
-    monkeypatch.setattr(driver, "make_data",
-                        lambda ctx: pytest.fail("data was made"))
-
-    class Ctx:
-        cfg = traffic = phases = {}
-    with pytest.raises(program.Refused) as refused:
-        driver.prepare(Ctx())
-    assert refused.value.code == 2
-    assert "fused_partition_declined" in refused.value.why
+    cells.assert_refused_without(monkeypatch, "train_jobs_cat",
+                                 "fused_partition_declined")

@@ -6,19 +6,18 @@ scoring against the frontier walk, a bundled job against the same data
 unbundled, the program against the plain CSR reference through the
 cell's own comparison, that reference against the dense one, the planted
 fault, and what the program counts and names for the cell's ``efb_*``
-metrics."""
+metrics.  (One tree at the cell's shapes compiled for a described chip:
+``tests/test_chip_compile.py``, with the other such compiles.)"""
 
-import json
 import os
-import subprocess
-import sys
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import cells
 import lightgbm_tpu as lgb
+from cells import BENCH
 from lightgbm_tpu.boosting.gbdt import _bundle_search
 from lightgbm_tpu.io.bundling import apply_bundles, bundle_ranges, plan_bundles
 from lightgbm_tpu.learner import batch_grower, grower
@@ -31,16 +30,12 @@ from lightgbm_tpu.ops.split import (SplitHyper, find_best_split,
                                     find_best_split_ranges)
 from lightgbm_tpu.utils.timer import global_timer
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-for p in (BENCH, os.path.join(BENCH, "tools")):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
+CELL = "allstate-train"
 # no block of two levels: its two columns are complements, an exact tie
 # that the default bin's mass, a difference, breaks by rounding
 LEVELS = [40, 120, 200, 3, 4, 5, 9]
 ROWS, VALID_ROWS, ROUNDS = 30000, 4000, 4
+FEW_ROWS = 12000
 
 
 # ------------------------------------------------ a plan with every layout
@@ -148,70 +143,42 @@ def test_range_predicate_partition_routes_as_the_inverse_table(mixed):
 
 
 # ----------------------------------------------------------- the cell, small
-def _cell():
-    import run as bench
-    _, cell, cfg, _ = bench.find_cell("allstate-train", rehearse_cpu=True)
-    cfg = dict(cfg, rows=ROWS, valid_rows=VALID_ROWS,
-               features=15 + sum(LEVELS),
-               data=dict(cfg["data"], levels=LEVELS, pos_rate=0.2, logit_sd=1.5),
-               params={**cfg["params"], "num_leaves": 15,
-                       "min_sum_hessian_in_leaf": 5.0},
-               compare={**cfg["compare"], "block_rows": 8192, "split_nodes": 8,
-                        "split_min_share": 0.05, "split_trees": ROUNDS,
-                        "auc_floor": {"round": ROUNDS, "auc": 0.66}})
-    return cell, cfg
-
-
-def _data(cfg):
-    from harness import load_module
-    gen = load_module("datagen", cfg["data"]["generator"])
-    f = int(cfg["features"])
-    return (gen.make(cfg["data"], 0, 0, int(cfg["rows"]), f),
-            gen.make(cfg["data"], 0, 1, int(cfg["valid_rows"]), f))
-
-
 def _construct(params, data):
     (x, y), (xv, yv) = data
     ds = lgb.Dataset(x, label=y, params=params).construct()
     return ds, ds.create_valid(xv, label=yv).construct()
 
 
-def _train(cfg, data, sets=None, **more):
-    params = {**cfg["params"], **more}
-    ds, dv = sets or _construct(params, data)
-    evals = {}
-    bst = lgb.train(params, ds, num_boost_round=ROUNDS, valid_sets=[dv],
-                    callbacks=[lgb.record_evaluation(evals)])
-    return bst, evals["valid_0"]["auc"]
+def _job(cfg, data, sets=None, **more):
+    """One job of the cell at the test's size: the booster and what it
+    recorded of the valid set a round."""
+    cfg = dict(cfg, params={**cfg["params"], **more})
+    return cells.train(cfg, sets or _construct(cfg["params"], data), ROUNDS)
 
 
-def _answers(bst, aucs):
-    from harness import program
-    return {"trees": program.plain_trees(bst._gbdt.models),
-            "valid_auc": aucs, "train_scores": program.train_scores(bst)}
-
-
-def _numbers(cfg, inputs, answers, reference=None, comparison=None,
-             seed=2147483659):
-    from harness import load_module
-    ref = load_module("reference", reference or cfg["reference"])
-    cmp_ = load_module("comparisons", comparison or cfg["comparison"])
-    return cmp_.gaps(ref, cfg, answers, inputs, seed)
+def _judged(cfg, inputs, bst, series):
+    return cells.judged(cfg, inputs, cells.answers(bst, series))
 
 
 @pytest.fixture(scope="module")
 def cell():
-    return _cell()[1]
+    return cells.find(
+        CELL, rows=ROWS, valid_rows=VALID_ROWS, features=15 + sum(LEVELS),
+        data={"levels": LEVELS, "pos_rate": 0.2, "logit_sd": 1.5},
+        params={"num_leaves": 15, "min_sum_hessian_in_leaf": 5.0},
+        compare={"block_rows": 8192, "split_nodes": 8, "split_min_share": 0.05,
+                 "split_trees": ROUNDS,
+                 "auc_floor": {"round": ROUNDS, "auc": 0.66}})[1]
 
 
 @pytest.fixture(scope="module")
 def data(cell):
-    return _data(cell)
+    return cells.data(cell)
 
 
 @pytest.fixture(scope="module")
 def inputs(data):
-    return {"train": data[0], "valid": data[1]}
+    return cells.inputs(data)
 
 
 @pytest.fixture(scope="module")
@@ -226,16 +193,13 @@ def job(cell, data):
         spans = global_timer.as_dict()
     finally:
         global_timer.disable()
-    bst, aucs = _train(cell, data, sets)
+    bst, aucs = _job(cell, data, sets)
     moved = {c: global_metrics.counter(c) - v for c, v in before.items()}
     return bst, aucs, moved, spans
 
 
 def test_the_manifest_names_the_cell_and_its_metrics():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
-    import run as bench
-    _, cell, cfg, traffic = bench.find_cell("allstate-train")
+    manifest, cell, cfg, traffic = cells.bench.find_cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("allstate-onehot", "train-jobs-csr", 1)
     mine = [m for m in manifest["per_layer"]
@@ -313,10 +277,19 @@ def test_the_program_counts_and_names_what_the_cell_reads(job):
     assert inner <= spans["construct"]["total_s"]
 
 
-def test_a_bundled_job_grows_the_unbundled_job_s_trees(cell, data, job):
-    plain, aucs_p = _train(cell, data, enable_bundle=False)
+def test_a_bundled_job_grows_the_unbundled_job_s_trees(cell, data):
+    """Both jobs on the first 12,000 rows: the unbundled job's histograms are
+    one-hot contractions over rows x 396 columns x 256 bins on the CPU (the
+    strict grower: 200 s at the file's 30,000 rows), and what is compared
+    is the trees, split for split, not what they have learned.  (At 8,000
+    rows two numeric splits tie over an empty bin and the two searches
+    state the same partition by two thresholds.)"""
+    (x, y), valid = data
+    few = ((x[:FEW_ROWS], y[:FEW_ROWS]), valid)
+    plain, aucs_p = _job(cell, few, enable_bundle=False)
     assert plain._gbdt.bundle is None
-    bst, aucs = job[:2]
+    bst, aucs = _job(cell, few)
+    assert len(bst._gbdt.train_set.bundle_plan.bundles) < 30
     assert len(bst._gbdt.models) == len(plain._gbdt.models) == ROUNDS
     for a, b in zip(bst._gbdt.models, plain._gbdt.models):
         ni = a.num_leaves - 1
@@ -326,7 +299,7 @@ def test_a_bundled_job_grows_the_unbundled_job_s_trees(cell, data, job):
         assert np.array_equal(a.leaf_count[:15], b.leaf_count[:15])
         np.testing.assert_allclose(a.leaf_value[:15], b.leaf_value[:15],
                                    rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(aucs, aucs_p, atol=1e-6)
+    np.testing.assert_allclose(aucs["auc"], aucs_p["auc"], atol=1e-6)
 
 
 def test_matmul_valid_scoring_gives_the_frontier_walk_s_scores(job):
@@ -366,21 +339,18 @@ def test_matmul_valid_scoring_gives_the_frontier_walk_s_scores(job):
 
 
 def test_the_program_agrees_with_the_plain_csr_reference(cell, inputs, job):
-    from harness import compare
-    bst, aucs = job[:2]
-    correct, compared = compare.judge(
-        _numbers(cell, inputs, _answers(bst, aucs)), cell["limits"])
+    correct, compared = _judged(cell, inputs, *job[:2])
     assert correct, compared
     assert compared["leaf_count_mismatch"]["value"] == 0
 
 
 def test_the_csr_reference_reads_what_the_dense_reference_reads(cell, inputs,
                                                                 job):
-    bst, aucs = job[:2]
-    sparse = _numbers(cell, inputs, _answers(bst, aucs))
+    answers = cells.answers(*job[:2])
+    sparse = cells.numbers(cell, inputs, answers)
     dense = {part: (np.ascontiguousarray(x.toarray().T.astype(np.float32)), y)
              for part, (x, y) in inputs.items()}
-    want = _numbers(cell, dense, _answers(bst, aucs), "gbdt_plain", "gbdt_binary")
+    want = cells.numbers(cell, dense, answers, "gbdt_plain", "gbdt_binary")
     assert sparse.keys() == want.keys()
     for k in want:
         np.testing.assert_allclose(sparse[k], want[k], rtol=1e-9, atol=1e-12,
@@ -388,19 +358,10 @@ def test_the_csr_reference_reads_what_the_dense_reference_reads(cell, inputs,
     assert sparse["split_searched"] > 0
 
 
-def test_a_shifted_segment_in_the_search_is_not_correct(monkeypatch, cell,
-                                                        data, inputs):
+def test_a_shifted_segment_in_the_search_is_not_correct(cell, data, inputs):
     import faults_efb
-    from harness import compare, program
-    program.free_everything()
-    faults_efb.shift_member_segments(monkeypatch.setattr)
-    try:
-        bst, aucs = _train(cell, data)
-        correct, compared = compare.judge(
-            _numbers(cell, inputs, _answers(bst, aucs)), cell["limits"])
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+    with cells.planted(faults_efb.shift_member_segments):
+        correct, compared = _judged(cell, inputs, *_job(cell, data))
     assert not correct
     over = [k for k in ("leaf_count_mismatch", "split_regret_mean")
             if compared[k]["value"] > compared[k]["limit"]]
@@ -408,23 +369,16 @@ def test_a_shifted_segment_in_the_search_is_not_correct(monkeypatch, cell,
 
 
 def test_a_search_that_skips_half_the_features_reads_a_finite_regret(
-        monkeypatch, cell, data, inputs):
+        cell, data, inputs):
     """The fault the regret's limit rests on: every stated split is a true
     and allowed one (the counts agree), only gain is given away, and the
     comparison reads that as a finite number over its limit."""
     import faults_efb
-    from harness import compare, program
-    program.free_everything()
-    faults_efb.skip_odd_features(monkeypatch.setattr)
-    try:
-        bst, aucs = _train(cell, data)
+    with cells.planted(faults_efb.skip_odd_features):
+        bst, aucs = _job(cell, data)
         used = np.concatenate([t.split_feature[:t.num_leaves - 1]
                                for t in bst._gbdt.models])
-        correct, compared = compare.judge(
-            _numbers(cell, inputs, _answers(bst, aucs)), cell["limits"])
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+        correct, compared = _judged(cell, inputs, bst, aucs)
     assert (used % 2 == 0).all()
     assert not correct and compared["leaf_count_mismatch"]["value"] == 0
     regret = compared["split_regret_mean"]
@@ -440,16 +394,8 @@ def test_the_cell_rehearses_on_the_cpu():
     picks K=42 and int8; it can never print a result line.  ``correct``
     is not asked for: 12,000 positives under int8 gradient noise give
     the split search's regret no meaning at this size."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "allstate-train", "--seed", "3000000019", "--seconds", "1",
-         "--rehearse-cpu"], capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=1200, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-3000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
+    lines = cells.rehearse(CELL, 3000000019)
     window = next(ln["window"] for ln in lines if "window" in ln)
-    assert not any(window["compiled_in_window"].values())
     setup = next(ln for ln in lines if "setup_phases_s" in ln)
     path = setup["path"]
     assert {k: path[k] for k in ("tpu_split_batch", "hist_dtype",
@@ -461,85 +407,11 @@ def test_the_cell_rehearses_on_the_cpu():
     assert path["efb_conflict_rows"]["train"] == 0
     assert set(setup["setup_spans_s"]) == {"construct", "sparse_bin_mappers",
                                            "bundle_plan", "bundle_matrix"}
-    last = lines[-1]
-    assert "rehearsal" in last and "metrics" not in last
-    exact = ("leaf_count_mismatch", "leaf_value_gap_median", "train_score_gap",
-             "valid_auc_gap")
-    assert all(last["compared"][k]["value"] <= last["compared"][k]["limit"]
-               for k in exact), last["compared"]
+    cells.assert_compared_within_limits(lines, (
+        "leaf_count_mismatch", "leaf_value_gap_median", "train_score_gap",
+        "valid_auc_gap"))
 
 
 def test_a_program_without_the_counters_is_refused_at_once(monkeypatch):
-    """The parent of this cell's PR: refused before any data is made."""
-    from harness import load_module, program
-    from lightgbm_tpu.obs import metrics
-    driver = load_module("drivers", "train_jobs_csr")
-    monkeypatch.setattr(metrics, "COUNTERS", {
-        k: v for k, v in metrics.COUNTERS.items()
-        if k != "bundle_space_search_rounds"})
-    monkeypatch.setattr(driver, "make_data",
-                        lambda ctx: pytest.fail("data was made"))
-
-    class Ctx:
-        cfg = traffic = phases = {}
-    with pytest.raises(program.Refused) as refused:
-        driver.prepare(Ctx())
-    assert refused.value.code == 2
-    assert "bundle_space_search_rounds" in refused.value.why
-
-
-# --------------------------------------------- compiled for a described chip
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(desc.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
-
-
-def test_one_tree_at_the_cell_s_shapes_never_visits_virtual_space(
-        one_chip, monkeypatch):
-    """One tree of the cell's job (13,184,290 rows, 46 bundle columns over
-    4,228 features, K=42, int8) compiled for the described chip: nothing
-    in the program has the virtual ``[*, 4228, 256]`` shape (the
-    expansion's tables are dropped as unused arguments), the split search
-    sits under its scope, and the partition is the fused kernel."""
-    import re
-    from lightgbm_tpu.learner.grower import BundleSearch
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n, fb, fv, b = 13_184_290, 46, 4228, 256
-    A = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-    i32 = jnp.int32
-    search = BundleSearch(*([A((fv,), i32)] * 3 + [A((fb, b), i32)] * 6))
-    bundle = DeviceBundle(A((fv,), i32), A((fv, b), i32), A((fv, b), jnp.bool_),
-                          A((fv,), i32), A((fv, b), i32), search)
-    hp = SplitHyper(num_leaves=255, min_data_in_leaf=0,
-                    min_sum_hessian_in_leaf=100.0, hist_dtype="int8",
-                    n_bins=256, rows_per_block=8192)
-    before = global_metrics.counter("bundle_expand_calls")
-    c = batch_grower.grow_tree_batched.lower(
-        A((n, fb), jnp.uint8), A((n,), jnp.float32), A((n,), jnp.float32),
-        None, A((fv,), i32), A((fv,), i32), A((fv,), jnp.bool_), None, hp,
-        batch=42, bundle=bundle, hist_scale=A((2,), jnp.float32)).compile()
-    assert global_metrics.counter("bundle_expand_calls") == before
-    text = c.as_text()
-    assert not re.findall(r"\[[\d,]*4228,256[\d,]*\]", text)
-    assert "bundle_search" in text and "partition_select_pallas" in text
-    # the fused kernel under its scope, reading the resident bins as they
-    # lie: no row-sized pad there (the bins: a copy of 606 MB a round pass)
-    import chip_smoke
-    chip_smoke._require_partition_kernel(text, "the bundled tree")
-    m = c.memory_analysis()
-    # rehearsal on this tree: 1.40 GB of temporaries, 0.74 GB of arguments
-    assert m.temp_size_in_bytes < 2 * 1024 ** 3
+    cells.assert_refused_without(monkeypatch, "train_jobs_csr",
+                                 "bundle_space_search_rounds")

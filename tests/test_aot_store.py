@@ -79,6 +79,47 @@ def test_store_round_trip_and_counters(tmp_path):
                                   np.asarray(_toy(*args)))
 
 
+def test_the_store_keeps_a_fresh_compile_not_the_persistent_caches_copy(
+        tmp_path):
+    """JAX's persistent compilation cache holds the program already (a
+    compile of over a second puts it there, and under six loaded workers a
+    tiny one takes that long): what ``compile()`` then hands back was
+    itself deserialised, and XLA:CPU serialises such an executable into an
+    artifact that loads and fails at its first call (``NOT_FOUND:
+    Function ... not found``; the driver's PR 45 run of
+    ``tests/test_pipeline.py``).  The store compiles with that cache out of
+    the way and puts it back as it was."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    try:
+        args = _toy_args()
+        shifted = lambda a, b: _toy(a, b) * 3.0 - a    # this test's program
+        want = np.asarray(jax.jit(shifted)(*args))     # ... in the cache
+        assert os.listdir(tmp_path / "xla")
+        jax.clear_caches()
+        store = AOTStore(str(tmp_path / "s"))
+        key = ("shifted", cc.sig(args))
+        store.compile_and_save(key, shifted, args)
+        # ... and the cache takes the next program as it did before
+        held = len(os.listdir(tmp_path / "xla"))
+        jax.jit(lambda a: a * 5.0 + 2.0)(args[0])
+        assert len(os.listdir(tmp_path / "xla")) > held
+        loaded = AOTStore(str(tmp_path / "s")).load(key)
+        np.testing.assert_array_equal(np.asarray(loaded(*args)), want)
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
 def test_store_miss_reasons_and_events(tmp_path):
     store = AOTStore(str(tmp_path / "s"))
     args = _toy_args()

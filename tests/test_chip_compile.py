@@ -602,6 +602,47 @@ def test_the_ranking_cells_flat_pass_states_its_27_mib(one_chip, on_tpu):
                 if " pad(" in line and str(n) in line]
 
 
+def test_one_tree_at_the_cell_s_shapes_never_visits_virtual_space(
+        one_chip, on_tpu):
+    """(From ``tests/test_allstate_cell.py``, PR 48: that file was the
+    suite's longest, and compiles for a described chip belong in this one
+    file.)  One tree of the job of ``allstate-train`` (13,184,290 rows, 46
+    bundle columns over 4,228 features, K=42, int8) compiled for the
+    described chip: nothing
+    in the program has the virtual ``[*, 4228, 256]`` shape (the
+    expansion's tables are dropped as unused arguments), the split search
+    sits under its scope, and the partition is the fused kernel."""
+    import re
+    from lightgbm_tpu.learner.grower import BundleSearch, DeviceBundle
+    from lightgbm_tpu.obs.metrics import global_metrics
+    from lightgbm_tpu.ops.split import SplitHyper
+    n, fb, fv, b = 13_184_290, 46, 4228, 256
+    A = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    i32 = jnp.int32
+    search = BundleSearch(*([A((fv,), i32)] * 3 + [A((fb, b), i32)] * 6))
+    bundle = DeviceBundle(A((fv,), i32), A((fv, b), i32), A((fv, b), jnp.bool_),
+                          A((fv,), i32), A((fv, b), i32), search)
+    hp = SplitHyper(num_leaves=255, min_data_in_leaf=0,
+                    min_sum_hessian_in_leaf=100.0, hist_dtype="int8",
+                    n_bins=256, rows_per_block=8192)
+    before = global_metrics.counter("bundle_expand_calls")
+    c = batch_grower.grow_tree_batched.lower(
+        A((n, fb), jnp.uint8), A((n,), jnp.float32), A((n,), jnp.float32),
+        None, A((fv,), i32), A((fv,), i32), A((fv,), jnp.bool_), None, hp,
+        batch=42, bundle=bundle, hist_scale=A((2,), jnp.float32)).compile()
+    assert global_metrics.counter("bundle_expand_calls") == before
+    text = c.as_text()
+    assert not re.findall(r"\[[\d,]*4228,256[\d,]*\]", text)
+    assert "bundle_search" in text and "partition_select_pallas" in text
+    # the fused kernel under its scope, reading the resident bins as they
+    # lie: no row-sized pad there (the bins: a copy of 606 MB a round pass)
+    import chip_smoke
+    chip_smoke._require_partition_kernel(text, "the bundled tree")
+    m = c.memory_analysis()
+    # rehearsal on this tree: 1.40 GB of temporaries, 0.74 GB of arguments
+    assert m.temp_size_in_bytes < 2 * 1024 ** 3
+
+
 def test_partition_at_published_higgs_rows_stays_lane_dense(one_chip):
     """Size guard: at the published 10.5M rows the partition step's
     results are two [1, n] i32 vectors, 84 MB.  A kernel that emitted an

@@ -12,21 +12,17 @@ import re
 import subprocess
 import sys
 
-import jax
 import numpy as np
 import pytest
 
+import cells
 import lightgbm_tpu as lgb
+from cells import BENCH, REPO
 from lightgbm_tpu.obs.metrics import COUNTERS, global_metrics
 from lightgbm_tpu.parallel.mesh import device_window
 from test_tracing_scopes import _brute_force_rows
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-for p in (BENCH, os.path.join(BENCH, "tools")):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
+CELL = "criteo-dp4-train"
 ROWS, VALID_ROWS, ROUNDS, CHIPS = 20000, 2000, 3, 4
 # the batched int8 learner auto mode picks at the cell's size, asked for
 # by name at this one (auto mode engages from 100,000 rows)
@@ -39,51 +35,23 @@ def _cell():
     """The cell's configuration at rehearsal size, cut further: fewer
     rows and leaves, the floor under the AUC to match.  Limits as they
     stand."""
-    import run as bench
-    _, cell, cfg, _ = bench.find_cell("criteo-dp4-train", rehearse_cpu=True)
-    cfg = dict(cfg, rows=ROWS, valid_rows=VALID_ROWS,
-               params={**cfg["params"], **SMALL},
-               compare={**cfg["compare"], "block_rows": 8192,
-                        "split_nodes": 8,
-                        "auc_floor": {"round": ROUNDS, "auc": 0.66}})
-    return cell, cfg
+    return cells.find(CELL, rows=ROWS, valid_rows=VALID_ROWS, params=SMALL,
+                      compare={"block_rows": 8192, "split_nodes": 8,
+                               "auc_floor": {"round": ROUNDS, "auc": 0.66}})
 
 
-def _data(cfg):
-    from harness import load_module
-    gen = load_module("datagen", cfg["data"]["generator"])
-    f = int(cfg["features"])
-    return (gen.make(cfg["data"], 0, 0, int(cfg["rows"]), f),
-            gen.make(cfg["data"], 0, 1, int(cfg["valid_rows"]), f))
-
-
-def _train(cfg, data, tree_learner, callbacks=()):
-    (xt32, xt64, y), (xv32, xv64, yv) = data
-    params = {**cfg["params"], "tree_learner": tree_learner}
-    ds = lgb.Dataset(xt64.T, label=y, params=params).construct()
-    dv = ds.create_valid(xv64.T, label=yv).construct()
-    evals = {}
+def _job(cfg, data, tree_learner, callbacks=()):
+    """One job over four of the CPU's devices: the booster and what it
+    recorded of the valid set a round."""
+    (_, xt64, y), (_, xv64, yv) = data
+    cfg = dict(cfg, params={**cfg["params"], "tree_learner": tree_learner})
+    sets = cells.program.construct(lgb, cfg["params"], (xt64, y), (xv64, yv))
     with device_window(CHIPS):
-        bst = lgb.train(params, ds, num_boost_round=ROUNDS, valid_sets=[dv],
-                        callbacks=[lgb.record_evaluation(evals),
-                                   *callbacks])
-    return bst, evals["valid_0"]["auc"]
+        return cells.train(cfg, sets, ROUNDS, callbacks=callbacks)
 
 
-def _answers(bst, aucs):
-    from harness import program
-    return {"trees": program.plain_trees(bst._gbdt.models),
-            "valid_auc": aucs, "train_scores": program.train_scores(bst)}
-
-
-def _judge(cfg, data, answers, seed=2147483659):
-    from harness import compare, load_module
-    (xt32, _, y), (xv32, _, yv) = data
-    ref = load_module("reference", cfg["reference"])
-    comparison = load_module("comparisons", cfg["comparison"])
-    numbers = comparison.gaps(ref, cfg, answers,
-                              {"train": (xt32, y), "valid": (xv32, yv)}, seed)
-    return compare.judge(numbers, cfg["limits"])
+def _judged(cfg, data, bst, series):
+    return cells.judged(cfg, cells.inputs(data), cells.answers(bst, series))
 
 
 @pytest.fixture(scope="module")
@@ -93,21 +61,20 @@ def cell():
 
 @pytest.fixture(scope="module")
 def data(cell):
-    return _data(cell)
+    return cells.data(cell)
 
 
 @pytest.fixture(scope="module")
 def data_job(cell, data):
     before = {c: global_metrics.counter(c) for c in
               ("sharded_rounds", "collective_bytes")}
-    bst, aucs = _train(cell, data, "data")
+    bst, aucs = _job(cell, data, "data")
     moved = {c: global_metrics.counter(c) - v for c, v in before.items()}
     return bst, aucs, moved
 
 
 def test_the_manifest_names_the_cell_and_its_six_metrics():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
+    manifest = cells.bench.find_cell(CELL)[0]
     cell, cfg = _cell()
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("criteo-host4", "train-jobs-dp", 4)
@@ -155,13 +122,13 @@ def test_the_job_ran_over_four_devices_in_the_per_iteration_loop(data_job):
 def test_data_over_four_devices_agrees_with_the_plain_reference(
         cell, data, data_job):
     bst, aucs, _ = data_job
-    correct, compared = _judge(cell, data, _answers(bst, aucs))
+    correct, compared = _judged(cell, data, bst, aucs)
     assert correct, compared
     assert compared["leaf_count_mismatch"]["value"] == 0
 
 
 def test_data_over_four_devices_grows_the_serial_trees(cell, data, data_job):
-    serial, aucs_s = _train(cell, data, "serial")
+    serial, aucs_s = _job(cell, data, "serial")
     assert serial._gbdt.parallel_mode is None
     bst, aucs, _ = data_job
     assert len(bst._gbdt.models) == len(serial._gbdt.models) == ROUNDS
@@ -172,20 +139,13 @@ def test_data_over_four_devices_grows_the_serial_trees(cell, data, data_job):
         assert np.array_equal(a.threshold[:ni], b.threshold[:ni])
         assert np.array_equal(a.leaf_count[:a.num_leaves],
                               b.leaf_count[:b.num_leaves])
-    np.testing.assert_allclose(aucs, aucs_s, atol=1e-6)
+    np.testing.assert_allclose(aucs["auc"], aucs_s["auc"], atol=1e-6)
 
 
-def test_a_shard_left_out_of_the_sum_is_not_correct(monkeypatch, cell, data):
+def test_a_shard_left_out_of_the_sum_is_not_correct(cell, data):
     import faults_dp
-    from harness import program
-    program.free_everything()
-    faults_dp.hist_drop_shard(monkeypatch.setattr, shard=1)
-    try:
-        bst, aucs = _train(cell, data, "data")
-        correct, compared = _judge(cell, data, _answers(bst, aucs))
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+    with cells.planted(faults_dp.hist_drop_shard, shard=1):
+        correct, compared = _judged(cell, data, *_job(cell, data, "data"))
     assert not correct
     over = [k for k in ("leaf_count_mismatch", "split_regret_mean")
             if compared[k]["value"] > compared[k]["limit"]]
@@ -241,7 +201,7 @@ def test_a_round_of_the_loop_is_covered_by_spans(cell, data, tmp_path):
     unnamed."""
     path = tmp_path / "trace.json"
     params = {**cell["params"], "trace_output": str(path)}
-    _train(dict(cell, params=params), data, "data")
+    _job(dict(cell, params=params), data, "data")
     events = json.loads(path.read_text())
     events = events["traceEvents"] if isinstance(events, dict) else events
     by_name = {}
@@ -261,24 +221,18 @@ def test_a_round_of_the_loop_is_covered_by_spans(cell, data, tmp_path):
         assert covered >= 0.9 * it["dur"], (covered, it["dur"])
 
 
-def test_leaf_counts_are_recounted_where_f32_sums_round(monkeypatch, cell,
-                                                        data):
+def test_leaf_counts_are_recounted_where_f32_sums_round(cell, data):
     """From 2**24 rows on the counts a tree carries can round; the
     recount from the rows' leaves, forced here at a small size, states
     the same (exact) counts and changes nothing else."""
-    from harness import program
     from lightgbm_tpu.learner import batch_grower
-    base, _ = _train(cell, data, "data")
-    program.free_everything()
-    monkeypatch.setattr(batch_grower, "_F32_EXACT_ROWS", 1)
-    try:
-        bst, _ = _train(cell, data, "data")
+    base, _ = _job(cell, data, "data")
+    with cells.planted(lambda setattr_: setattr_(batch_grower,
+                                                 "_F32_EXACT_ROWS", 1)):
+        bst, _ = _job(cell, data, "data")
         with device_window(CHIPS):
             import chip_smoke
             text = chip_smoke._sharded_program_text(bst._gbdt)
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
     assert "leaf_recount" in text
     X = data[0][1].T
     for a, b in zip(bst._gbdt.models, base._gbdt.models):
@@ -293,10 +247,10 @@ def test_a_second_job_compiles_no_gradient_program(cell, data):
     """The binary objective's gradients are one program a shape, the
     rows' signs an argument: a new booster on the same rows finds it."""
     from lightgbm_tpu.objectives import _binary_gradients_jit
-    _train(cell, data, "data")
+    _job(cell, data, "data")
     size = _binary_gradients_jit._cache_size()
     assert size > 0
-    _train(cell, data, "data")
+    _job(cell, data, "data")
     assert _binary_gradients_jit._cache_size() == size
 
 
@@ -311,8 +265,8 @@ from jax._src.interpreters import pxla
 import test_dp4_cell as T
 
 cfg = T._cell()[1]
-data = T._data(cfg)
-T._train(cfg, data, "data")            # everything compiled
+data = T.cells.data(cfg)
+T._job(cfg, data, "data")              # everything compiled
 # every execution through Python: no C++ fast path, its entries dropped
 calls = [0]
 execute = pxla.ExecuteReplicated.__call__
@@ -355,7 +309,7 @@ def test_the_loop_runs_the_parents_programs_and_leaves_the_dataset_alone():
     no execution and was never counted here); the rounds keep their 74."""
     out = subprocess.run(
         [sys.executable, "-c", _COUNT_PROGRAMS.format(repo=REPO)],
-        capture_output=True, text=True, timeout=1200, cwd=REPO,
+        capture_output=True, text=True, timeout=600, cwd=REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0, out.stderr[-3000:]
     got = json.loads(out.stdout.splitlines()[-1])
@@ -369,34 +323,15 @@ def test_the_cell_rehearses_on_four_cpu_devices():
     the cell's whole control flow (driver, path check, nothing compiled
     inside the window, comparison) at 100,000 rows, where auto mode
     still picks K=42 and int8; it can never print a result line."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "criteo-dp4-train", "--seed", "3000000019", "--seconds", "1",
-         "--rehearse-cpu"], capture_output=True, text=True, env=env,
-        timeout=1200, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-3000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    window = next(ln["window"] for ln in lines if "window" in ln)
-    assert not any(window["compiled_in_window"].values())
+    lines = cells.rehearse(
+        CELL, 3000000019,
+        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
     setup = next(ln for ln in lines if "setup_phases_s" in ln)
     assert setup["path"] == {"tpu_split_batch": 42, "hist_dtype": "int8",
                              "packed_mirror": False, "device_n_bins": 256,
                              "parallel_mode": "data", "mesh_devices": 4}
-    last = lines[-1]
-    assert "rehearsal" in last and "metrics" not in last
-    assert last["correct"] is True, last["compared"]
+    assert lines[-1]["correct"] is True, lines[-1]["compared"]
 
 
 def test_a_program_without_the_counters_is_refused_at_once(monkeypatch):
-    """The parent of this cell's PR: refused before any data is made."""
-    from harness import load_module, program
-    from lightgbm_tpu.obs import metrics
-    driver = load_module("drivers", "train_jobs_dp")
-    monkeypatch.setattr(metrics, "COUNTERS", {
-        k: v for k, v in metrics.COUNTERS.items() if k != "sharded_rounds"})
-    with pytest.raises(program.Refused) as e:
-        driver.prepare(object())
-    assert "sharded_rounds" in e.value.why
+    cells.assert_refused_without(monkeypatch, "train_jobs_dp", "sharded_rounds")

@@ -1,119 +1,81 @@
 """The wide dense job of the benchmark's cell ``epsilon-train``
 (configuration ``epsilon-dense``: upstream's Epsilon job, 400,000 x 2,000
-dense columns), at a few thousand rows x all 2,000 columns on the CPU:
-the program against the plain reference through the cell's own
-comparison, the bfloat16 control and the planted faults of
-``tools/faults_wide.py`` each failing a limit, what the manifest names,
-what the program counts for the cell's readers, the byte-count functions
-of ``harness/wide_bytes.py`` against a hand reckoning, and the cell's
-rehearsal through ``benchmark/run.py``."""
+dense columns), at a few thousand rows on the CPU: ONE job at all 2,000
+columns against the plain reference through the cell's own comparison,
+with its bfloat16 control and what it counts for the cell's readers; the
+planted faults of ``tools/faults_wide.py`` each failing a limit, at 300
+columns (ten column blocks); what the manifest names, the byte-count
+functions of ``harness/wide_bytes.py`` against a hand reckoning, and the
+cell's rehearsal through ``benchmark/run.py``."""
 
 import json
 import os
-import subprocess
-import sys
 
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
+import cells
 import lightgbm_tpu as lgb
+from cells import BENCH
 from lightgbm_tpu.obs.metrics import COUNTERS
-from lightgbm_tpu.ops import hist_pallas, round_fuse
+from lightgbm_tpu.ops import hist_pallas
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-for p in (BENCH, os.path.join(BENCH, "tools")):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
-ROWS, VALID_ROWS, ROUNDS = 4096, 1024, 3
+CELL = "epsilon-train"
+ROWS, ROUNDS = 4096, 3
+#: columns of the jobs that need column blocks, not the cell's 63 of them:
+#: ten blocks of 32 under the real budget, the last with 12 columns
+NARROW = 300
 METRICS = ("wide_hist_ms", "wide_hist_roofline_share", "wide_find_splits_ms",
            "wide_partition_ms", "wide_device_idle_share",
            "wide_construct_bin_mappers_s")
 
 
-def _cell():
-    import run as bench
-    _, cell, cfg, _ = bench.find_cell("epsilon-train", rehearse_cpu=True)
+def _sized(features, rows=ROWS):
+    """The cell at ``rows`` rows and ``features`` columns: its
+    configuration, the comparison's inputs and the constructed sets."""
     # a stronger signal than the cell's: 4,096 rows have to show a planted
     # fault that the cell shows on 400,000
-    cfg = dict(cfg, rows=ROWS, valid_rows=VALID_ROWS,
-               data=dict(cfg["data"], separation=3.0),
-               compare={**cfg["compare"], "split_trees": ROUNDS,
-                        "auc_floor": {"round": ROUNDS, "auc": 0.6}})
-    return cell, cfg
-
-
-@pytest.fixture(scope="module")
-def cell():
-    return _cell()[1]
-
-
-@pytest.fixture(scope="module")
-def data(cell):
-    from harness import load_module
-    gen = load_module("datagen", cell["data"]["generator"])
-    f = int(cell["features"])
-    return (gen.make(cell["data"], 0, 0, ROWS, f),
-            gen.make(cell["data"], 0, 1, VALID_ROWS, f))
-
-
-@pytest.fixture(scope="module")
-def inputs(data):
-    (xt32, _, y), (xv32, _, yv) = data
-    return {"train": (xt32, y), "valid": (xv32, yv)}
-
-
-@pytest.fixture(scope="module")
-def sets(cell, data):
-    from harness import program
+    cfg = cells.find(CELL, rows=rows, valid_rows=rows // 4, features=features,
+                     data={"separation": 3.0},
+                     compare={"split_trees": ROUNDS,
+                              "auc_floor": {"round": ROUNDS, "auc": 0.6}})[1]
+    data = cells.data(cfg)
     (_, xt64, y), (_, xv64, yv) = data
-    return program.construct(lgb, cell["params"], (xt64, y), (xv64, yv))
+    sets = cells.program.construct(lgb, cfg["params"], (xt64, y), (xv64, yv))
+    return cfg, cells.inputs(data), sets
 
 
-def _train(cell, sets):
-    """One job of the cell at the test's size, its partition in the fused
-    kernel (interpret mode), as on the chip."""
-    evals = {}
-    round_fuse._FUSE_TEST_INTERPRET = True      # read when traced
-    try:
-        bst = lgb.train(cell["params"], sets[0], num_boost_round=ROUNDS,
-                        valid_sets=[sets[1]],
-                        callbacks=[lgb.record_evaluation(evals)])
-    finally:
-        round_fuse._FUSE_TEST_INTERPRET = False
-    return bst, evals["valid_0"]["auc"]
-
-
-def _answers(bst, aucs):
-    from harness import program
-    return {"trees": program.plain_trees(bst._gbdt.models),
-            "valid_auc": aucs, "train_scores": program.train_scores(bst)}
-
-
-def _judged(cell, inputs, answers):
-    from harness import compare, load_module
-    ref = load_module("reference", cell["reference"])
-    numbers = load_module("comparisons", cell["comparison"]).gaps(
-        ref, cell, answers, inputs, 2147483659)
-    return compare.judge(numbers, cell["limits"])
+def _job(cfg, sets):
+    """One job at the test's size, its partition in the fused kernel
+    (interpret mode), as on the chip: the booster and its answers."""
+    bst, series = cells.train(cfg, sets, ROUNDS, interpret_partition=True)
+    return bst, cells.answers(bst, series)
 
 
 @pytest.fixture(scope="module")
-def job(cell, sets):
-    from harness import program
-    program.free_everything()
-    bst, aucs = _train(cell, sets)
-    return bst, aucs, _answers(bst, aucs)
+def wide():
+    """The file's ONE job at the cell's own 2,000 columns, on 2,048 rows
+    (its counters, the reference and the bfloat16 control read it).  On the
+    CPU every histogram of it holds rows x 2,000 x 256 floats of one-hot
+    at once (``ops/histogram.py build_histogram`` unrolls the columns'
+    chunks and XLA:CPU shares no buffer between them): 4.2 GB a call here,
+    8.4 GB at 4,096 rows, and a process's first touch of that much memory
+    costs minutes of system time.  What needs column BLOCKS and not this
+    width runs on ``narrow``."""
+    cfg, inputs, sets = _sized(2000, rows=2048)
+    cells.program.free_everything()
+    return (cfg, inputs, *_job(cfg, sets))
+
+
+@pytest.fixture(scope="module")
+def narrow():
+    return _sized(NARROW)
 
 
 # ------------------------------------------------------------------ manifest
 def test_the_manifest_names_the_cell_and_its_metrics():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        manifest = json.load(fh)
-    import run as bench
-    _, cell, cfg, traffic = bench.find_cell("epsilon-train")
+    bench = cells.bench
+    manifest, cell, cfg, traffic = bench.find_cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("epsilon-dense", "train-jobs-wide", 1)
     mine = [m for m in manifest["per_layer"]
@@ -158,8 +120,8 @@ def test_the_manifest_names_the_cell_and_its_metrics():
 
 
 # ------------------------------------------------- what the program counts
-def test_the_program_counts_what_the_readers_read(cell, job):
-    bst, _, _ = job
+def test_the_program_counts_what_the_readers_read(wide):
+    cell, _, bst, _ = wide
     gb = bst._gbdt
     assert {"hist_col_blocks", "hist_state_bytes", "hist_vmem_budget_bytes",
             "construct_bin_mappers_s"} <= set(COUNTERS)
@@ -176,8 +138,7 @@ def test_the_program_counts_what_the_readers_read(cell, job):
     assert 255 * 2000 * 256 * 4 * 4 == 2_088_960_000
 
 
-def test_the_dispatch_span_carries_the_histograms_built(monkeypatch, cell,
-                                                        sets):
+def test_the_dispatch_span_carries_the_histograms_built(monkeypatch, narrow):
     """``dispatch_done`` says how many leaves' histograms its rows went
     into (a root's and one child's a split): what the roofline share's
     bytes are counted from."""
@@ -190,7 +151,7 @@ def test_the_dispatch_span_carries_the_histograms_built(monkeypatch, cell,
             seen.append(counts)
         return real(self, name, **counts)
     monkeypatch.setattr(GBDT, "_phase", spy)
-    bst, _ = _train(cell, sets)
+    bst, _ = _job(narrow[0], narrow[2])
     leaves = sum(t.num_leaves for t in bst._gbdt.models)
     assert sum(c["hist_leaves_built"] for c in seen) == leaves
     assert sum(c["hist_rows_selected"] for c in seen) >= ROUNDS * ROWS
@@ -243,18 +204,19 @@ def test_the_readers_reduce_a_window_as_reckoned_by_hand(monkeypatch, capsys):
 
 
 # ------------------------------------------------------- against the reference
-def test_the_program_agrees_with_the_plain_reference(cell, inputs, job):
-    correct, compared = _judged(cell, inputs, job[2])
+def test_the_program_agrees_with_the_plain_reference(wide):
+    cell, inputs, _, answers = wide
+    correct, compared = cells.judged(cell, inputs, answers)
     assert correct, compared
     assert compared["leaf_count_mismatch"]["value"] == 0
 
 
-def test_the_bfloat16_control_is_not_correct(cell, inputs, job):
-    from harness import load_module
-    ref = load_module("reference", cell["reference"])
-    ctrl = load_module("comparisons", cell["comparison"]).control_answers(
-        ref, cell, job[2], inputs, jnp.bfloat16)
-    correct, compared = _judged(cell, inputs, ctrl)
+def test_the_bfloat16_control_is_not_correct(wide):
+    cell, inputs, _, answers = wide
+    ref = cells.load_module("reference", cell["reference"])
+    ctrl = cells.load_module("comparisons", cell["comparison"]).control_answers(
+        ref, cell, answers, inputs, jnp.bfloat16)
+    correct, compared = cells.judged(cell, inputs, ctrl)
     assert not correct
     assert compared["leaf_value_gap_median"]["value"] > \
         compared["leaf_value_gap_median"]["limit"]
@@ -266,27 +228,21 @@ FAULTS = {"drop_col_block": "split_regret_mean",
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_planted_fault_is_not_correct(monkeypatch, cell, sets, inputs,
-                                        fault):
+def test_a_planted_fault_is_not_correct(narrow, fault):
     """The faults of ``tools/faults_wide.py`` each fail the limit that
     holds what they break: a dropped column block states splits the raw
     rows do not bear out (the block of the data's heaviest column); a
     block written one column off and a state slot never written put rows
-    where the stated counts do not."""
+    where the stated counts do not.  A block in the middle needs three
+    blocks, not the cell's 63."""
     import faults_wide
-    from harness import load_module, program
-    program.free_everything()
-    gen = load_module("datagen", cell["data"]["generator"])
-    target = gen.strongest_feature(cell["data"], 2000)
-    cb, ncb = faults_wide._blocks(2000)
+    cell, inputs, sets = narrow
+    gen = cells.load_module("datagen", cell["data"]["generator"])
+    target = gen.strongest_feature(cell["data"], NARROW)
+    cb, ncb = faults_wide._blocks(NARROW)
     assert ncb > 1 and 0 < target // cb < ncb - 1   # a block in the middle
-    faults_wide.WIDE[fault](monkeypatch.setattr, feature=target)
-    try:
-        bst, aucs = _train(cell, sets)
-        correct, compared = _judged(cell, inputs, _answers(bst, aucs))
-    finally:
-        monkeypatch.undo()
-        program.free_everything()
+    with cells.planted(faults_wide.WIDE[fault], feature=target):
+        correct, compared = cells.judged(cell, inputs, _job(cell, sets)[1])
     assert not correct
     held = compared[FAULTS[fault]]
     assert not held["value"] <= held["limit"], compared
@@ -300,41 +256,18 @@ def test_the_cell_rehearses_on_the_cpu():
     comparison) at 4,096 rows x 2,000 columns with the batched grower and
     int8 histograms asked for by name; it can never print a result
     line."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "epsilon-train", "--seed", "4500000019", "--seconds", "1",
-         "--rehearse-cpu"], capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=1200, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-3000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    window = next(ln["window"] for ln in lines if "window" in ln)
-    assert not any(window["compiled_in_window"].values())
+    lines = cells.rehearse(CELL, 4500000019)
     path = next(ln for ln in lines if "setup_phases_s" in ln)["path"]
     assert path == {"tpu_split_batch": 8, "hist_dtype": "int8",
                     "packed_mirror": False, "device_n_bins": 256,
                     "hist_col_blocks": 63,
                     "hist_state_bytes": 15 * 2000 * 256 * 16,
                     "hist_vmem_budget_bytes": hist_pallas.VMEM_BUDGET_BYTES}
-    last = lines[-1]
-    assert "rehearsal" in last and "metrics" not in last
-    assert last["correct"], last["compared"]
+    assert lines[-1]["correct"], lines[-1]["compared"]
 
 
 def test_a_program_without_the_counters_is_refused_at_once(monkeypatch):
     """The parent of this cell's PR, whose round program at this width
     does not finish compiling: refused before any data is made."""
-    from harness import load_module, program
-    from lightgbm_tpu.obs import metrics
-    driver = load_module("drivers", "train_jobs_wide")
-    monkeypatch.setattr(metrics, "COUNTERS", {
-        k: v for k, v in metrics.COUNTERS.items() if k != "hist_col_blocks"})
-    monkeypatch.setattr(driver._base, "make_data",
-                        lambda ctx: pytest.fail("data was made"))
-
-    class Ctx:
-        cfg = traffic = phases = {}
-    with pytest.raises(program.Refused) as refused:
-        driver.prepare(Ctx())
-    assert refused.value.code == 2
-    assert "hist_col_blocks" in refused.value.why
+    cells.assert_refused_without(monkeypatch, "train_jobs_wide",
+                                 "hist_col_blocks")

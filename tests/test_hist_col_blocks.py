@@ -149,12 +149,15 @@ def test_root_kernel_blocked(small_budget):
 @pytest.mark.parametrize("kernel", ["flat", "radix_joint", "payload_bytes",
                                     "payload_words", "packed"])
 def test_kernels_at_2000_columns(kernel):
-    """The Epsilon job's width under the REAL budget, 3,000 rows: the
-    K = 42 flat and payload passes the cell runs and the K = 4 radix pass
-    take 63 blocks of 32 columns, the last with 16; so do the packed-word
-    kernels (not the cell's: 255 bins keep no mirror) at K = 8."""
+    """The Epsilon job's width under the REAL budget: the K = 42 flat and
+    payload passes the cell runs and the K = 4 radix pass take 63 blocks of
+    32 columns, the last with 16; so do the packed-word kernels (not the
+    cell's: 255 bins keep no mirror) at K = 8.  520 rows: two whole row
+    blocks and a ragged one under every column block, which is all the
+    rows a column block's accumulator has to live through (the time here
+    is the interpreter's, a grid step at a time: 63 x 3 of them)."""
     K = {"radix_joint": 4, "flat": 42, "payload_bytes": 42}.get(kernel, 8)
-    p = _problem(2000, 3000, K, seed=2)
+    p = _problem(2000, 520, K, seed=2)
     want = _xla(*p)
     got = np.asarray(KERNELS[kernel](p, jnp.int8))
     npt.assert_array_equal(got, want)
@@ -193,7 +196,7 @@ def test_one_tree_at_2000_columns_through_the_blocked_kernels():
     histogram kernel.  The tree grown through the
     kernels (interpret mode) is the tree the sorted gather grows."""
     rng = np.random.default_rng(3)
-    n, f = 3000, 2000
+    n, f = 1000, 2000
     bins = jnp.asarray(rng.integers(0, 255, size=(n, f)).astype(np.uint8))
     grad = jnp.asarray(rng.integers(-2, 3, size=n).astype(np.float32))
     hess = jnp.asarray(rng.integers(1, 5, size=n).astype(np.float32))

@@ -9,9 +9,6 @@ categorical column and forced splits.  An invalid slot leaves its two
 places as they were, which ``benchmark/tools/faults_wide.py``
 ``skip_state_update`` plants its fault by."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,9 +17,6 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner import batch_grower
 from lightgbm_tpu.ops.split import SplitHyper
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
 
 
 def _channel_last_write(hist, parents, new_leaves, valid, h_left, h_right):
@@ -55,17 +49,14 @@ def _dense(n, f, seed):
 
 
 def _epsilon_rehearsal():
-    """4,096 x 2,000 from the cell's own generator, the cell's rehearsal
+    """1,024 x 2,000 from the cell's own generator (the state's shape, which
+    this file is about, follows from the columns and the leaves; the rows
+    only size the CPU's one-hot: 2 GB a histogram here), the cell's rehearsal
     parameters: 15 leaves, 8 splits a pass, quantised gradients as
     integer levels with their scales."""
-    for p in (BENCH, os.path.join(BENCH, "tools")):
-        if p not in sys.path:
-            sys.path.insert(0, p)
-    import run as bench
-    from harness import load_module
-    _, _, cfg, _ = bench.find_cell("epsilon-train", rehearse_cpu=True)
-    gen = load_module("datagen", cfg["data"]["generator"])
-    _, x64, y = gen.make(cfg["data"], 0, 0, 4096, int(cfg["features"]))
+    import cells
+    cfg = cells.find("epsilon-train", rows=1024, valid_rows=128)[1]
+    (_, x64, y), _ = cells.data(cfg)
     ds, args = _dataset(x64.T, y)         # the generator is feature-major
     levels = jnp.asarray(np.where(y > 0, -2.0, 2.0).astype(np.float32))
     args = (args[0], levels, jnp.ones_like(levels)) + args[3:]

@@ -10,18 +10,13 @@ the counters, and one tree at 220 columns through the two-plane-group
 compaction in interpret mode.
 """
 
-import copy
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+import cells
 import lightgbm_tpu as lgb
 import lightgbm_tpu.ops.histogram as H
 import lightgbm_tpu.ops.round_fuse as RF
@@ -32,15 +27,7 @@ from lightgbm_tpu.learner.batch_grower import grow_tree_batched
 from lightgbm_tpu.obs.metrics import COUNTERS
 from lightgbm_tpu.ops.split import SplitHyper
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
-for _p in (os.path.join(BENCH, "tools"), BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
-
-import run as bench                                    # noqa: E402
-from faults import Planted                             # noqa: E402
-from harness import compare, load_module, program      # noqa: E402
+from cells import bench, load_module
 
 CELL = "istella-rank-train"
 KS = [1, 3, 5, 10]
@@ -122,48 +109,32 @@ def test_device_ndcg_matches_the_reference(ref):
 
 
 # --------------------------------------------- a job against the reference
-def _tiny_cfg():
+@pytest.fixture(scope="module")
+def tiny(ref):
     """The cell's configuration at a size the strict learner trains in
     seconds: the comparison needs trees, NDCG series and scores, whatever
     loop gave them."""
-    _, _, cfg, _ = bench.find_cell(CELL, rehearse_cpu=True)
-    cfg = copy.deepcopy(cfg)
-    cfg.update(rows=6000, queries=60, valid_rows=2000, valid_queries=20,
-               features=24)
-    cfg["data"].update(queries=[60, 20], informative=8, min_docs=8,
-                       max_docs=400)
-    cfg["params"].update(num_leaves=15, min_sum_hessian_in_leaf=5.0)
-    cfg["compare"].update(block_rows=8192, split_nodes=8, split_candidates=16,
-                          split_min_share=0.1, split_trees=None)
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def tiny(ref):
-    cfg = _tiny_cfg()
-    gen = load_module("datagen", cfg["data"]["generator"])
-    parts = [gen.make(cfg["data"], 0, p, cfg[k], cfg["features"])
-             for p, k in ((0, "rows"), (1, "valid_rows"))]
-    driver = load_module("drivers", "train_jobs_rank")
-    ds, dv = driver.construct(lgb, cfg["params"], *parts)
+    cfg = cells.find(
+        CELL, rows=6000, queries=60, valid_rows=2000, valid_queries=20,
+        features=24,
+        data={"queries": [60, 20], "informative": 8, "min_docs": 8,
+              "max_docs": 400},
+        params={"num_leaves": 15, "min_sum_hessian_in_leaf": 5.0},
+        compare={"block_rows": 8192, "split_nodes": 8, "split_candidates": 16,
+                 "split_min_share": 0.1, "split_trees": None})[1]
+    parts = cells.data(cfg)
+    sets = load_module("drivers", "train_jobs_rank").construct(
+        lgb, cfg["params"], *parts)
     comparison = load_module("comparisons", cfg["comparison"])
     inputs = {"train": parts[0], "valid": parts[1]}
 
     def job(plant_it=None, rounds=4):
-        with Planted() as plant:
-            if plant_it is not None:
-                plant_it(plant)
-            evals = {}
-            bst = lgb.train(cfg["params"], ds, num_boost_round=rounds,
-                            valid_sets=[dv],
-                            callbacks=[lgb.record_evaluation(evals)])
-            program.free_everything()
-        answers = {"trees": program.plain_trees(bst._gbdt.models),
-                   "valid_ndcg": {k: evals["valid_0"][f"ndcg@{k}"] for k in KS},
-                   "train_scores": program.train_scores(bst)}
-        return answers
-    judge = lambda answers: compare.judge(
-        comparison.gaps(ref, cfg, answers, inputs, 0), cfg["limits"])
+        # the faults of tools/faults_rank.py patch the objective, the
+        # metric and a hyper-parameter: the strict grower stays compiled
+        with cells.planted(plant_it, grower=False):
+            bst, series = cells.train(cfg, sets, rounds)
+        return cells.answers(bst, series)
+    judge = lambda answers: cells.judged(cfg, inputs, answers, seed=0)
     return cfg, job, judge, comparison, inputs
 
 
@@ -287,17 +258,7 @@ def test_the_program_counts_and_names_what_the_readers_read():
 
 def test_a_program_without_the_counters_is_refused_at_once(monkeypatch):
     driver = load_module("drivers", "train_jobs_rank")
-    for name in driver.NEEDS:
-        monkeypatch.delitem(COUNTERS, name)
-    made = []
-    monkeypatch.setattr(driver, "make_data", lambda ctx: made.append(ctx))
-
-    class Ctx:
-        cfg = traffic = {}
-    with pytest.raises(program.Refused) as refused:
-        driver.prepare(Ctx())
-    assert refused.value.code == 2 and not made
-    assert "rank_queries" in refused.value.why
+    cells.assert_refused_without(monkeypatch, "train_jobs_rank", *driver.NEEDS)
 
 
 # ------------------------------------------------------------- the rehearsal
@@ -309,25 +270,16 @@ def test_the_cell_rehearses_on_the_cpu():
     persistent compile cache to keep every program, however small, and a
     test process that took that setting over would fill ``tests/.jax_cache``
     with entries that the AOT store's tests then trip over."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", CELL,
-         "--seed", "1", "--seconds", "1", "--rehearse-cpu"],
-        capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=1200, cwd=ROOT)
-    assert out.returncode == 0, out.stderr[-3000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
+    lines = cells.rehearse(CELL, 1, limit_s=900)   # 216 s in the driver's PR 47 run
     window = next(ln["window"] for ln in lines if "window" in ln)
-    assert not any(window["compiled_in_window"].values())
     setup = next(ln for ln in lines if "setup_s" in ln)
     assert setup["path"]["rank_queries"] == 318
     assert setup["path"]["rank_docs"] == 100_352
     assert setup["path"]["rank_bucket_count"] >= 4
     assert all(len(v) == window["rounds"] for v in setup["valid_ndcg"].values())
-    compared = lines[-1]["compared"]
-    for name in ("leaf_count_mismatch", "leaf_value_gap_median",
-                 "leaf_value_gap_p99", "train_score_gap", "valid_ndcg_gap"):
-        assert compared[name]["value"] <= compared[name]["limit"], compared
+    cells.assert_compared_within_limits(lines, (
+        "leaf_count_mismatch", "leaf_value_gap_median", "leaf_value_gap_p99",
+        "train_score_gap", "valid_ndcg_gap"))
 
 
 # ------------------------------------- 220 columns: two byte-plane groups

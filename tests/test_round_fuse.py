@@ -5,6 +5,7 @@ bit-identical to the XLA reference paths.
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -261,20 +262,44 @@ def test_auto_through_each_compacted_bucket_equals_the_full_pass(
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@functools.lru_cache(maxsize=None)
+def _ladder(n_bins):
+    """``histogram_for_leaves_auto`` through the kernels (interpret mode)
+    under ONE jit for all the cases of a bin count: they differ in which
+    rows are selected, not in a shape.  ``ran`` takes the size of every
+    compaction that RUNS (a callback from inside the taken branch)."""
+    import lightgbm_tpu.ops.hist_pallas as HP
+    ran = []
+    real = HP.compact_payload_pallas
+
+    def spy(src, key, *rest, size, **kw):
+        jax.debug.callback(lambda _: ran.append(size), key[0])
+        return real(src, key, *rest, size=size, **kw)
+
+    @jax.jit
+    def auto(bins, grad, hess, lor, leaves):
+        # both are read when this is traced, which is once
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(HP, "compact_payload_pallas", spy)
+            patch.setattr(H, "_PAYLOAD_TEST_INTERPRET", True)
+            return H.histogram_for_leaves_auto(
+                bins, bins.T, grad, hess, lor, leaves, None, n_bins=n_bins,
+                rows_per_block=256, hist_dtype="int8", hist_kernel="onehot")
+    return auto, ran
+
+
 @pytest.mark.parametrize("n_bins,share,branch", [
     (128, 0.40, "n/2"), (128, 0.60, "full"), (128, 0.20, "n/4"),
     (64, 0.40, "full"), (64, 0.20, "n/4"),
 ], ids=["128bins-0.40n", "128bins-0.60n", "128bins-0.20n", "64bins-0.40n",
         "64bins-0.20n"])
-def test_auto_starts_the_ladder_where_the_dispatch_says(
-        monkeypatch, n_bins, share, branch):
+def test_auto_starts_the_ladder_where_the_dispatch_says(n_bins, share, branch):
     """The flat kernel's full pass above 64 bins is dear enough for a
     bucket of n/2 (``hist_dispatch(...).top_rung == 2``): a count in
     (n/4, n/2] takes it, a count above n/2 the full pass.  At 64 bins
     the ladder starts at n/4 as before.  Which branch RAN is told by the
     compaction it called; the histograms equal the masked pass's either
     way."""
-    import lightgbm_tpu.ops.hist_pallas as HP
     bins, grad, hess, _, leaves = _mk(n=8192 + 994, n_bins=n_bins)
     n = bins.shape[0]
     top = H.hist_dispatch("onehot", n_bins, 4, bins.shape[1]).top_rung
@@ -283,18 +308,9 @@ def test_auto_starts_the_ladder_where_the_dispatch_says(
     lor = np.where(rng.random(n) < share,
                    np.asarray(leaves)[rng.integers(0, 4, size=n)], 1)
     lor = jnp.asarray(lor.astype(np.int32))
-    ran = []
-    real = HP.compact_payload_pallas
-
-    def spy(src, key, *rest, size, **kw):
-        jax.debug.callback(lambda _: ran.append(size), key[0])
-        return real(src, key, *rest, size=size, **kw)
-
-    monkeypatch.setattr(HP, "compact_payload_pallas", spy)
-    monkeypatch.setattr(H, "_PAYLOAD_TEST_INTERPRET", True)
-    got = H.histogram_for_leaves_auto(
-        bins, bins.T, grad, hess, lor, leaves, None, n_bins=n_bins,
-        rows_per_block=256, hist_dtype="int8", hist_kernel="onehot")
+    auto, ran = _ladder(n_bins)
+    ran.clear()
+    got = auto(bins, grad, hess, lor, leaves)
     jax.effects_barrier()
     want = H.histogram_for_leaves_masked(
         bins.T, grad, hess, lor, leaves, None, n_bins=n_bins,
